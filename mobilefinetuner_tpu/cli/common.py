@@ -24,6 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# enable_compile_cache is re-exported: every train CLI's main() calls
+# common.enable_compile_cache() before anything can compile
+from mobilefinetuner_tpu.core.compile_cache import (cache_counts,
+                                                    enable_compile_cache)
 from mobilefinetuner_tpu.core.logging import (JSONLWriter, MetricsLogger,
                                               get_logger)
 from mobilefinetuner_tpu.core.preempt import EXIT_PREEMPTED, PreemptionGuard
@@ -33,7 +37,8 @@ from mobilefinetuner_tpu.core.telemetry import (GoodputMeter, HangWatchdog,
                                                 mfu_from, run_manifest)
 from mobilefinetuner_tpu.core.xla_stats import (compiled_flops,
                                                 compiled_peak_mb,
-                                                live_hbm_mb)
+                                                live_hbm_mb,
+                                                tpu_custom_calls)
 from mobilefinetuner_tpu.data.prefetch import Prefetcher
 from mobilefinetuner_tpu.data.wikitext2 import WikiText2Dataset
 from mobilefinetuner_tpu.ops.loss import perplexity_from_loss
@@ -569,6 +574,11 @@ def build_mesh(args):
                          devices=jax.devices()[:data * fsdp])
     size = data * fsdp
     sp = getattr(args, "sequence_parallel", False)
+    if size < n:
+        # the mesh flags default to one device: say so on a multi-chip
+        # host instead of quietly leaving chips idle
+        log.info(f"mesh: using {size} of {n} devices "
+                 f"(--mesh_data/--mesh_fsdp select more)")
     if size > 1:
         log.info(f"mesh: data={data} fsdp={fsdp}"
                  + (f" over {jax.process_count()} processes" if multi
@@ -594,6 +604,23 @@ def build_mesh(args):
                 f"eval_batch_size={args.eval_batch_size} must be "
                 f"divisible by {b_axis} under multi-host (eval batches "
                 f"shard like train batches)")
+        # the partitioned step cannot hold a Mosaic kernel outside
+        # shard_map (ops/pallas_util.partitioned_impl): `auto` becomes
+        # the XLA path HERE, where the mesh is known — the CLIs apply
+        # args.attention_impl to their config after this call. Under
+        # sequence parallelism attention is the ring (its flash
+        # partials sit inside shard_map) and the flag is not consulted.
+        from mobilefinetuner_tpu.ops.pallas_util import partitioned_impl
+        try:
+            if hasattr(args, "lora_impl"):
+                args.lora_impl = partitioned_impl(
+                    "--lora_impl", args.lora_impl, "fused", "naive", size)
+            if hasattr(args, "attention_impl") and not sp:
+                args.attention_impl = partitioned_impl(
+                    "--attention_impl", args.attention_impl, "flash",
+                    "xla", size)
+        except ValueError as e:
+            raise SystemExit(str(e))
     return mesh, (mesh if sp else None)
 
 
@@ -725,6 +752,28 @@ def evaluate(eval_step, trainable, frozen, dataset: WikiText2Dataset,
 
 def compute_dtype_from_args(args):
     return jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
+
+
+def with_attention_impl(args, config):
+    """`config` with --attention_impl applied, for a train CLI to call
+    AFTER build_mesh (which turns `auto` into the XLA path when the step
+    is partitioned over several devices) and after seq_len is clamped.
+    Also resolves `auto` for the run's (seq_len, head_dim) through the
+    model blocks' own rule (ops/attention.resolve_impl) and stamps it
+    into args, so the run_start manifest says which attention path
+    trained — flash means the Pallas kernels, and the `compile` event's
+    tpu_custom_calls then says they are really in the program."""
+    import dataclasses
+    from mobilefinetuner_tpu.ops.attention import resolve_impl
+    impl = args.attention_impl
+    if getattr(args, "sequence_parallel", False):
+        impl = "ring"
+    elif impl == "auto":
+        impl = resolve_impl(args.seq_len, config.head_dim)
+    args.attention_impl_resolved = impl
+    log.info(f"attention_impl={args.attention_impl} -> {impl}")
+    return dataclasses.replace(config,
+                               attention_impl=args.attention_impl)
 
 
 def log_lora_impl_resolution(args, target_dims, rank: int,
@@ -1663,6 +1712,7 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
             probe = probe_batch()
             meter.enter("compile")
             t_comp = time.perf_counter()
+            hits0 = cache_counts()["hits"]
             try:
                 with pause():
                     compiled_step = step_fn.lower(
@@ -1679,15 +1729,26 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                 tel.emit("mem_check", **c.event())
                 return c
             meter.enter("init")
+            compile_s = round(time.perf_counter() - t_comp, 3)
             peak_hbm["mb"] = compiled_peak_mb(compiled_step)
             xla_flops = compiled_flops(compiled_step)
+            # what is IN the executable: the Pallas kernels (0 = the XLA
+            # paths or interpret mode served every `auto` rule) and the
+            # collectives the partitioner put in; cache_hit: the
+            # persistent compile cache served this program
+            from mobilefinetuner_tpu.core.static_checks import \
+                hlo_collective_census
+            hlo = compiled_step.as_text()
             # at_step: a mid-run ladder recompile (dispatch OOM) logs
             # at the step that forced it, aligned with its degrade/
             # mem_check neighbors — not back at start_step
-            tel.emit("compile", step=at_step,
-                     wall_s=round(time.perf_counter() - t_comp, 3),
+            tel.emit("compile", step=at_step, wall_s=compile_s,
                      flops=xla_flops or None,
-                     peak_hbm_mb=peak_hbm["mb"] or None)
+                     peak_hbm_mb=peak_hbm["mb"] or None,
+                     tpu_custom_calls=tpu_custom_calls(hlo),
+                     collectives={k: n for k, n in
+                                  hlo_collective_census(hlo).items() if n},
+                     cache_hit=cache_counts()["hits"] > hits0)
             c = mg.preflight(compiled_step, cap_mb=adm_cap_mb,
                              headroom=adm_headroom)
             tel.emit("mem_check", **c.event())

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mobilefinetuner_tpu.cli.family import apply_adapter, load_family
+from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
 from mobilefinetuner_tpu.core.logging import JSONLWriter, get_logger
 from mobilefinetuner_tpu.eval import mmlu
 
@@ -156,6 +157,7 @@ def make_batched_logits_fn(hidden_fn, head_key, compute_dtype, params,
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     import time as _time
     from mobilefinetuner_tpu.core.telemetry import Telemetry, run_manifest
     # fleet-aware: each process writes its own host-stamped shard

@@ -20,7 +20,6 @@ Usage (tiny smoke):
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import sys
 import time
@@ -80,10 +79,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    common.enable_compile_cache()
 
     config, params = load_gemma3(args.model_dir)
-    config = dataclasses.replace(
-        config, attention_impl=args.attention_impl)
     log.info(f"Gemma-3 full FT: layers={config.num_hidden_layers} "
              f"hidden={config.hidden_size} vocab={config.vocab_size}")
     if args.no_model_dropout:
@@ -123,6 +121,7 @@ def main(argv=None) -> int:
              f"{total_steps} steps")
 
     mesh, cp_mesh = common.build_mesh(args)
+    config = common.with_attention_impl(args, config)
     compute_dtype = common.compute_dtype_from_args(args)
     step_builder = None
     plan = None

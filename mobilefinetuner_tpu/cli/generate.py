@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
 from mobilefinetuner_tpu.core.logging import get_logger
 from mobilefinetuner_tpu.models.generate import (SampleConfig, gemma3_generate,
                                                  gpt2_generate, left_pad)
@@ -94,6 +95,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     prompts = list(args.prompt)
     if args.prompt_file:
         with open(args.prompt_file, encoding="utf-8") as f:

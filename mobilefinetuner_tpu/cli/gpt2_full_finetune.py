@@ -56,10 +56,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    common.enable_compile_cache()
 
     config, params = load_gpt2(args.pretrained_dir)
-    config = dataclasses.replace(
-        config, attention_impl=args.attention_impl)
     if args.no_model_dropout:
         config = dataclasses.replace(config, embd_pdrop=0.0,
                                      resid_pdrop=0.0, attn_pdrop=0.0)
@@ -98,6 +97,7 @@ def main(argv=None) -> int:
     # Full FT: params themselves are the trainable tree — FSDP-shard them
     # (and thus Adam m/v) over the mesh; no host offload of trainables.
     mesh, cp_mesh = common.build_mesh(args)
+    config = common.with_attention_impl(args, config)
     if cp_mesh is not None and config.attn_pdrop > 0:
         log.warning(f"attn_pdrop={config.attn_pdrop} is unsupported by "
                     f"ring attention; attention-probs dropout is OFF in "

@@ -86,14 +86,13 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    common.enable_compile_cache()
 
     if args.split_qkv and args.peft_export_dir:
         raise SystemExit("--split_qkv adapters have no PEFT "
                          "representation; drop --peft_export_dir "
                          "(the native adapter format supports them)")
     config, params = load_gpt2(args.pretrained_dir)
-    config = dataclasses.replace(
-        config, attention_impl=args.attention_impl)
     if args.no_model_dropout:
         config = dataclasses.replace(config, embd_pdrop=0.0,
                                      resid_pdrop=0.0, attn_pdrop=0.0)
@@ -149,6 +148,7 @@ def main(argv=None) -> int:
         args, lora, tc, mask)
 
     mesh, cp_mesh = common.build_mesh(args)
+    config = common.with_attention_impl(args, config)
     if cp_mesh is not None and config.attn_pdrop > 0:
         log.warning(f"attn_pdrop={config.attn_pdrop} is unsupported by "
                     f"ring attention; attention-probs dropout is OFF in "

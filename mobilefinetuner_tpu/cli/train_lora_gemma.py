@@ -21,7 +21,6 @@ Usage (tiny smoke):
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import sys
 
@@ -91,14 +90,13 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    common.enable_compile_cache()
     if args.batch is not None:
         args.batch_size = args.batch
     if args.max_steps and not args.steps:
         args.steps = args.max_steps
 
     config, params = load_gemma3(args.model_dir)
-    config = dataclasses.replace(
-        config, attention_impl=args.attention_impl)
     log.info(f"Gemma-3: layers={config.num_hidden_layers} "
              f"hidden={config.hidden_size} vocab={config.vocab_size} "
              f"q/kv heads={config.num_attention_heads}/"
@@ -145,6 +143,7 @@ def main(argv=None) -> int:
         args, lora, tc, mask)
 
     mesh, cp_mesh = common.build_mesh(args)
+    config = common.with_attention_impl(args, config)
     params, fetch_fn, offload_arg = common.setup_frozen_params(
         args, params, mesh)
     compute_dtype = common.compute_dtype_from_args(args)

@@ -100,6 +100,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    common.enable_compile_cache()
     family, jobs = load_jobs_file(args.jobs)
     log.info(f"jobs file: {len(jobs)} {family} job(s) "
              f"({', '.join(j.name for j in jobs)})")

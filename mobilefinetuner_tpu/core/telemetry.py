@@ -824,6 +824,7 @@ def run_manifest(config: dict, mesh=None) -> dict:
         "mesh_shape": dict(mesh.shape) if mesh is not None else None,
         "process_count": jax.process_count(),
         "process_index": jax.process_index(),
+        "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,
         "device_count": len(jax.devices()),
         "config": {k: v for k, v in sorted(config.items())

@@ -916,6 +916,24 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
     return logits.astype(jnp.float32), pool_k, pool_v
 
 
+def _paged_attend(attn_impl: str, KV: int, G: int, pool_k, shardings):
+    """The decode steps' attend(q, pool_k, pool_v, tbl, layer, ok, scale):
+    the Pallas paged kernel (under shard_map on a serve mesh) or the
+    gather oracle, by ops/decode_attention.resolve_paged_impl."""
+    from mobilefinetuner_tpu.ops.decode_attention import (
+        paged_attention, paged_decode_attention, resolve_paged_impl,
+        sharded_paged_attend)
+    _, _, _, bT, D = pool_k.shape
+    impl = resolve_paged_impl(attn_impl, KV, G, bT, D,
+                              pool_k.dtype.itemsize,
+                              tp=1 if shardings is None else shardings.tp)
+    if impl != "pallas":
+        return paged_attention
+    if shardings is not None:
+        return sharded_paged_attend(shardings)
+    return paged_decode_attention
+
+
 def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
                            tok, pos, tbl, lora=None,
                            compute_dtype=jnp.float32,
@@ -939,9 +957,6 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     (GSPMD inserts the collectives; check_compiled_contracts pins the
     census), the Pallas gate charges per-shard head counts, and the
     kernel path routes through sharded_paged_attend's shard_map."""
-    from mobilefinetuner_tpu.ops.decode_attention import (
-        paged_attention, paged_decode_attention, paged_eligible,
-        sharded_paged_attend)
     S, M = tbl.shape
     NB, L, H, bT, D = pool_k.shape
     E = config.n_embd
@@ -952,15 +967,7 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
                       if jnp.issubdtype(t.dtype, jnp.floating) else t)
     wb = jax.tree.map(cast, params["blocks"])
     shd = shardings
-    use_pallas = attn_impl == "pallas" or (
-        attn_impl == "auto" and jax.default_backend() == "tpu"
-        and paged_eligible(H, 1, bT, D, pool_k.dtype.itemsize,
-                           tp=1 if shd is None else shd.tp))
-    if shd is not None:
-        attend = sharded_paged_attend(shd) if use_pallas \
-            else paged_attention
-    else:
-        attend = paged_decode_attention if use_pallas else paged_attention
+    attend = _paged_attend(attn_impl, H, 1, pool_k, shd)
 
     x = params["wte"][tok].astype(compute_dtype) \
         + params["wpe"][pos].astype(compute_dtype)            # [S, E]
@@ -1035,9 +1042,6 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     pool's KV axis shards when Hkv % tp == 0, otherwise the query-group
     axis does (pools replicated) — either way the gate charges per-shard
     head counts and constraints pin the 4D [S, KV, G, D] layout."""
-    from mobilefinetuner_tpu.ops.decode_attention import (
-        paged_attention, paged_decode_attention, paged_eligible,
-        sharded_paged_attend)
     c = config
     S, M = tbl.shape
     NB, L, KV, bT, D = pool_k.shape
@@ -1053,15 +1057,7 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
     normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
     shd = shardings
-    use_pallas = attn_impl == "pallas" or (
-        attn_impl == "auto" and jax.default_backend() == "tpu"
-        and paged_eligible(KV, G, bT, D, pool_k.dtype.itemsize,
-                           tp=1 if shd is None else shd.tp))
-    if shd is not None:
-        attend = sharded_paged_attend(shd) if use_pallas \
-            else paged_attention
-    else:
-        attend = paged_decode_attention if use_pallas else paged_attention
+    attend = _paged_attend(attn_impl, KV, G, pool_k, shd)
 
     x = params["embed"][tok].astype(compute_dtype) * normalizer
     if shd is not None:

@@ -1,10 +1,11 @@
 """ctypes binding + lazy build for the native BPE merge engine.
 
-The shared library (libfast_bpe.so) is compiled from fast_bpe.cpp on first
-use with the system g++ (no pybind11 dependency; plain C ABI + ctypes) and
-cached next to the source; a stale .so (older than the .cpp) is rebuilt.
-Any failure — no compiler, unwritable dir, load error — degrades silently
-to None and the tokenizer keeps its pure-Python path
+The shared library is compiled from fast_bpe.cpp on first use with the
+system g++ (no pybind11 dependency; plain C ABI + ctypes) and cached next
+to the source under a name keyed on the source's hash (native/build.py),
+so a library built from any other fast_bpe.cpp is never loaded. Any
+failure — no compiler, unwritable dir, load error — logs a warning,
+degrades to None, and the tokenizer keeps its pure-Python path
 (data/tokenizer_bpe.py), which is the behavioral reference.
 
 Set MFT_NO_NATIVE_BPE=1 to force the Python path (used by parity tests).

@@ -61,6 +61,7 @@ NEG_INF = -1e30
 _VMEM_BUDGET = 12 * 2 ** 20
 
 
+from mobilefinetuner_tpu.ops import pallas_util
 from mobilefinetuner_tpu.ops.pallas_util import tpu_call_params
 
 
@@ -212,12 +213,35 @@ def paged_eligible(KV: int, G: int, bT: int, D: int,
     serve path runs the kernel under shard_map on each shard's pool
     slice, so the VMEM bill is the local one — global counts would be
     both too strict (KV-sharded pools) and, were the budget ever raised
-    per-shard, unsafely lax the other way."""
+    per-shard, unsafely lax the other way.
+
+    The VMEM term tracks what the installed v5e compiler accepts
+    (tests/test_aot_tpu.py compiles both sides of it: every shape this
+    admits compiles, and the over-budget shapes it refuses are refused
+    by Mosaic with RESOURCE_EXHAUSTED). bT % 8 is POLICY, not a
+    compiler limit — Mosaic pads a ragged [bT, D] page, but so does the
+    pool's HBM tiling, and a page that wastes its tile is a bad
+    ServeConfig, not a fast path."""
     KV, G = shard_heads(KV, G, tp)
     need = (4 * KV * bT * D * itemsize          # K+V page, double-buffered
             + KV * G * D * (itemsize + 4)       # q block + f32 ctx block
             + 3 * KV * G * max(D, bT) * 4)      # o/m/l accumulators + p
     return bT % 8 == 0 and need <= _VMEM_BUDGET
+
+
+def resolve_paged_impl(attn_impl: str, KV: int, G: int, bT: int, D: int,
+                       itemsize: int, tp: int = 1) -> str:
+    """The paged-decode `auto` rule, ONE copy: both families' decode
+    steps (models/generate.py) pick their attend through it and the
+    serve engine stamps its answer into the run_start manifest, so the
+    stream says which path served. "pallas" on a TPU backend at an
+    eligible shape, else the gather oracle "xla"; a forced impl
+    resolves to itself."""
+    if attn_impl != "auto":
+        return attn_impl
+    on_tpu = jax.default_backend() == "tpu"
+    return "pallas" if on_tpu and paged_eligible(
+        KV, G, bT, D, itemsize, tp) else "xla"
 
 
 def _paged_kernel(tbl_ref, lyr_ref, q_ref, k_ref, v_ref, ok_ref, o_ref,
@@ -226,7 +250,8 @@ def _paged_kernel(tbl_ref, lyr_ref, q_ref, k_ref, v_ref, ok_ref, o_ref,
     slot's online softmax across its pages. A fully-masked page (e.g.
     beyond a sliding window) contributes exactly zero: probabilities are
     re-masked after the exp, so the NEG_INF-vs-NEG_INF cancellation in
-    `s - m` cannot resurrect dead columns."""
+    `s - m` cannot resurrect dead columns. ok_ref is the page's
+    (1, 1, 1, bT) block of the [S, M, 1, bT] mask."""
     del tbl_ref, lyr_ref  # consumed by the index_maps, not the body
     m = pl.program_id(1)
 
@@ -236,7 +261,7 @@ def _paged_kernel(tbl_ref, lyr_ref, q_ref, k_ref, v_ref, ok_ref, o_ref,
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    ok = ok_ref[0] > 0                                  # [bT] (lanes)
+    ok = ok_ref[0, 0] > 0                               # [1, bT] (lanes)
     for j in range(kv):                                 # static unroll
         k = k_ref[0, 0, j]                              # [bT, D] storage
         v = v_ref[0, 0, j]
@@ -280,7 +305,11 @@ def paged_decode_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
             f"paged_decode_attention ineligible for KV={KV}, G={G}, "
             f"bT={bT}, D={D} (check paged_eligible before calling)")
     kernel = functools.partial(_paged_kernel, scale=scale, kv=KV)
-    ok2 = ok.astype(jnp.int32).reshape(S, M * bT)
+    # the mask rides as [S, M, 1, bT] so a page's (1, 1, 1, bT) block
+    # spans the array's last two dims whole — the TPU lowering takes a
+    # block whose trailing dims are (8k, 128k) or the full extent, and a
+    # (1, bT) window into [S, M*bT] is neither
+    ok4 = ok.astype(jnp.int32).reshape(S, M, 1, bT)
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,        # tbl, layer
@@ -291,7 +320,7 @@ def paged_decode_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
                          lambda s, m, tbl, l: (tbl[s, m], l[0], 0, 0, 0)),
             pl.BlockSpec((1, 1, KV, bT, D),
                          lambda s, m, tbl, l: (tbl[s, m], l[0], 0, 0, 0)),
-            pl.BlockSpec((1, bT), lambda s, m, tbl, l: (s, m)),
+            pl.BlockSpec((1, 1, 1, bT), lambda s, m, tbl, l: (s, m, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, KV, G, D),
                                lambda s, m, tbl, l: (s, 0, 0, 0)),
@@ -304,20 +333,19 @@ def paged_decode_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
     # no dimension_semantics here: the page dimension must stay
     # sequential (the accumulators carry across it), which is the
     # compiler's default for grid_spec-style calls
-    from mobilefinetuner_tpu.ops.pallas_util import interpret_mode
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KV, G, D), jnp.float32),
-        interpret=interpret_mode(),
-    )(tbl.astype(jnp.int32), lyr, q, pool_k, pool_v, ok2)
+        interpret=pallas_util.interpret_mode(),
+    )(tbl.astype(jnp.int32), lyr, q, pool_k, pool_v, ok4)
 
 
 def sharded_paged_attend(shardings):
     """paged_decode_attention under a serve (dp, tp) mesh, via shard_map.
 
     pallas_call is a custom call GSPMD cannot partition, so the sharded
-    serve path wraps the UNCHANGED kernel in core/compat.shard_map and
+    serve path wraps the UNCHANGED kernel in jax.shard_map and
     hands each shard its own operands:
 
       pool_k/pool_v  [NB, L, KV/tp, bT, D] per-shard head slice when
@@ -339,8 +367,6 @@ def sharded_paged_attend(shardings):
     """
     from jax.sharding import PartitionSpec as P
 
-    from mobilefinetuner_tpu.core.compat import shard_map
-
     sh = shardings
     dp = "dp" if sh.dp > 1 else None
     kv_ax = "tp" if sh.kv_shards > 1 else None
@@ -356,10 +382,10 @@ def sharded_paged_attend(shardings):
         # check_vma=False: the replicated-output proof doesn't see
         # through the kernel's custom call; the body is deterministic
         # per shard, so unmentioned axes are replicated by construction
-        fn = shard_map(local, mesh=sh.mesh,
-                       in_specs=(q_spec, pool_spec, pool_spec,
-                                 P(dp, None), P(), P(dp, None)),
-                       out_specs=q_spec, check_vma=False)
+        fn = jax.shard_map(local, mesh=sh.mesh,
+                           in_specs=(q_spec, pool_spec, pool_spec,
+                                     P(dp, None), P(), P(dp, None)),
+                           out_specs=q_spec, check_vma=False)
         return fn(q, pool_k, pool_v, tbl,
                   jnp.asarray(layer, jnp.int32), ok)
 

@@ -63,9 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mobilefinetuner_tpu.ops.pallas_util import (interpret_mode as
-                                                 _interpret,
-                                                 tpu_call_params)
+from mobilefinetuner_tpu.ops import pallas_util
+from mobilefinetuner_tpu.ops.pallas_util import tpu_call_params
 
 NEG_INF = -1e30
 
@@ -756,7 +755,8 @@ def flash_attention_partial(q, k, v, padding_mask=None, *,
     if D not in (64, 128, 256) or k.shape[2] != S:
         return None
     picked = _valid_blocks(S, block_q, block_k)
-    if _interpret() and S % block_q == 0 and S % block_k == 0:
+    if (pallas_util.interpret_mode() and S % block_q == 0
+            and S % block_k == 0):
         picked = (block_q, block_k)
     if picked is None:
         return None
@@ -825,7 +825,8 @@ def flash_attention(q, k, v, *,
     # mirror that so kernel and fallback never diverge
     is_causal = is_causal or sliding_window is not None
     picked = _valid_blocks(S, block_q, block_k)
-    if _interpret() and S % block_q == 0 and S % block_k == 0:
+    if (pallas_util.interpret_mode() and S % block_q == 0
+            and S % block_k == 0):
         # interpret mode has no Mosaic alignment constraints; honor the
         # requested blocks so tests can exercise the multi-block loop at
         # small S (the hardware path is still dispatched via _valid_blocks)

@@ -162,7 +162,6 @@ def _vp_chunked_nll_sum(hidden, lm_head_w, labels, ignore_index, num_chunks,
     own sequence slice, so the backward keeps the sequence sharded too.
     """
     from jax.sharding import PartitionSpec as P
-    from mobilefinetuner_tpu.core.compat import shard_map
 
     if jnp.issubdtype(hidden.dtype, jnp.floating):
         lm_head_w = lm_head_w.astype(hidden.dtype)
@@ -209,7 +208,7 @@ def _vp_chunked_nll_sum(hidden, lm_head_w, labels, ignore_index, num_chunks,
                 jax.lax.psum(count, batch_axis))
 
     chunk_spec = vocab_axis if seq_shard else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, batch_axis, chunk_spec, None),
                   P(None, batch_axis, None), P(vocab_axis, None)),
@@ -243,7 +242,6 @@ def vp_embed_lookup(table, ids, mesh, *, vocab_axis: str = "fsdp",
     divide by the axis size (the caller gates). Returns [B, S, H] in the
     table's dtype, sharded like the SP activations."""
     from jax.sharding import PartitionSpec as P
-    from mobilefinetuner_tpu.core.compat import shard_map
     ba = batch_axis if batch_axis in mesh.axis_names else None
 
     def local(w, ids_loc):
@@ -259,7 +257,7 @@ def vp_embed_lookup(table, ids, mesh, *, vocab_axis: str = "fsdp",
         return jax.lax.psum_scatter(e, vocab_axis, scatter_dimension=1,
                                     tiled=True)            # [B_loc, S/n, H]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(vocab_axis, None), P(ba, vocab_axis)),
         out_specs=P(ba, vocab_axis, None), check_vma=False)(table, ids)

@@ -1,28 +1,49 @@
 """Shared plumbing for the Pallas kernels (flash attention, fused CE,
-decode attention)."""
+decode attention, LoRA epilogue)."""
 
 from __future__ import annotations
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 
 def interpret_mode() -> bool:
     """Pallas interpret mode off-TPU (CPU test mesh, SURVEY.md §4.6) —
-    the ONE copy of the policy every kernel consults."""
+    the ONE copy of the policy every kernel consults. Kernels read it
+    through this module at call time (tests/test_aot_tpu.py steers it
+    to compile the real kernels for a described chip), so a process on
+    a TPU backend never interprets and a CPU process never claims a
+    kernel ran: `tpu_custom_call` in the compiled text is the proof."""
     return jax.default_backend() != "tpu"
 
 
 def tpu_call_params(*dimension_semantics: str) -> dict:
-    """The compiler_params + interpret kwargs every pallas_call in this
-    tree passes — one copy of the dimension-semantics plumbing so a
-    kernel cannot set semantics without also consulting the interpret
-    policy (and one copy of the CompilerParams/TPUCompilerParams rename
-    shim across jax versions). Returns a dict to splat into
-    pl.pallas_call."""
-    from jax.experimental.pallas import tpu as pltpu
-    params_cls = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
+    """The compiler_params + interpret kwargs every gridded pallas_call
+    in this tree passes — one copy of the dimension-semantics plumbing
+    so a kernel cannot set semantics without also consulting the
+    interpret policy. Returns a dict to splat into pl.pallas_call."""
     return dict(
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=dimension_semantics),
         interpret=interpret_mode())
+
+
+def partitioned_impl(flag: str, impl: str, kernel: str, plain: str,
+                     n_devices: int) -> str:
+    """An implementation switch's value for a step GSPMD partitions over
+    `n_devices`. A Mosaic kernel cannot be partitioned automatically
+    (the TPU lowering refuses: "wrap the call in a shard_map"), and
+    interpret mode never shows it — the CPU mesh tests partition the
+    interpreted kernel like any XLA op. So over more than one device
+    `auto` means the XLA path `plain`, and asking for `kernel` by name
+    is an error here rather than deep inside the compile. The kernels
+    that DO run on a mesh are wrapped where they are called
+    (sharded_paged_attend, ring attention's partials)."""
+    if n_devices <= 1 or impl == plain:
+        return impl
+    if impl == kernel:
+        raise ValueError(
+            f"{flag}={kernel!r} is a Pallas kernel outside shard_map: it "
+            f"cannot be partitioned over a {n_devices}-device mesh; use "
+            f"{plain!r} (what 'auto' selects there)")
+    return plain

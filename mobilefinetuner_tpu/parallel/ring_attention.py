@@ -211,8 +211,7 @@ def ring_attention(q, k, v, mesh: Mesh, *,
     else:
         fn = partial(_ring_shard, axis=axis, n=mesh.shape[axis],
                      scale=float(scale), causal=is_causal, window=window)
-    from mobilefinetuner_tpu.core.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec_s, spec_s, spec_s, spec_p),
         out_specs=spec_s,

@@ -393,6 +393,17 @@ class ServeEngine:
             from mobilefinetuner_tpu.serve.sharding import ServeSharding
             self.sharding = ServeSharding.build(
                 family, config, cfg.mesh_dp, cfg.mesh_tp)
+            # the one-shot prefill is GSPMD-partitioned over the mesh,
+            # where a Mosaic kernel outside shard_map cannot go: a
+            # prompt cap long enough for the flash rule (S >= 512) takes
+            # the XLA attention instead (the decode step's paged kernel
+            # is wrapped — sharded_paged_attend)
+            from mobilefinetuner_tpu.ops.pallas_util import \
+                partitioned_impl
+            config = self.config = dataclasses.replace(
+                config, attention_impl=partitioned_impl(
+                    "attention_impl", config.attention_impl, "flash",
+                    "xla", cfg.mesh_dp * cfg.mesh_tp))
 
         S = cfg.num_slots
         # block tables are sized for the TRUE cap (== max_prompt when
@@ -636,6 +647,16 @@ class ServeEngine:
                 bank.tree["blocks"].values()))["A"].shape[-1])
             lora_impl_resolved = impl_summary(
                 dims, S, rank, cfg.lora_impl, self.dtype.itemsize)
+        # likewise the paged-attention path: the SAME rule the decode
+        # step's trace applies (ops/decode_attention.resolve_paged_impl)
+        from mobilefinetuner_tpu.ops.decode_attention import \
+            resolve_paged_impl
+        nq = (config.n_head if family == "gpt2"
+              else config.num_attention_heads)
+        self.attn_impl_resolved = resolve_paged_impl(
+            cfg.attn_impl, KV, nq // KV, cfg.block_T, D,
+            self.dtype.itemsize, tp=cfg.mesh_tp)
+        self.lora_impl_resolved = lora_impl_resolved
         self.telemetry = telemetry or Telemetry("")
         # request-lifecycle span tracing (core/trace.py): queue/prefill/
         # decode spans per request on its own "req:<id>" track. Pure
@@ -648,6 +669,8 @@ class ServeEngine:
             "block_T": cfg.block_T, "num_blocks": cfg.num_blocks,
             "max_prompt": cfg.max_prompt,
             "max_new_tokens": cfg.max_new_tokens, "dtype": cfg.dtype,
+            "attn_impl": cfg.attn_impl,
+            "attn_impl_resolved": self.attn_impl_resolved,
             "lora_impl": cfg.lora_impl,
             "lora_impl_resolved": lora_impl_resolved,
             "adapter_slots": bank.capacity if bank else 0,
@@ -1333,23 +1356,11 @@ class ServeEngine:
                         f"{req.id}: {e}") from e
                 self._tbl[req.slot, j] = req.blocks[-1]
 
-        bank_tree = self.bank.tree if self.bank else None
         t_step = time.perf_counter()
         try:
             if self.step_hook is not None:
                 self.step_hook(self.decode_steps)
-            step_args = [
-                self.params, bank_tree, self.pool_k, self.pool_v,
-                self._dev(self._tok), self._dev(self._pos),
-                self._dev(self._tbl), self._dev(self._aid)]
-            if cfg.sampling:
-                # sampling state rides AFTER the legacy args so the
-                # pool donation indices (2, 3) never move
-                step_args += [self._dev(self._temp),
-                              self._dev(self._topk),
-                              self._dev(self._topp),
-                              self._dev(self._key)]
-            nxt, pool_k, pool_v = self._step(*step_args)
+            nxt, pool_k, pool_v = self._step(*self._step_args())
             # graftlint: disable=sync-hazard(the serve loop's ONE host sync per decode step: this step's tokens drive host-side scheduling)
             nxt = np.asarray(nxt)
         except (KeyboardInterrupt, SystemExit):
@@ -1384,6 +1395,27 @@ class ServeEngine:
         if cfg.stats_every and self.decode_steps % cfg.stats_every == 0:
             self.emit_stats()
         return done
+
+    def _step_args(self) -> list:
+        """The decode step's operands from the engine's live state."""
+        args = [self.params, self.bank.tree if self.bank else None,
+                self.pool_k, self.pool_v,
+                self._dev(self._tok), self._dev(self._pos),
+                self._dev(self._tbl), self._dev(self._aid)]
+        if self.cfg.sampling:
+            # sampling state rides AFTER the legacy args so the pool
+            # donation indices (2, 3) never move
+            args += [self._dev(self._temp), self._dev(self._topk),
+                     self._dev(self._topp), self._dev(self._key)]
+        return args
+
+    def lower_decode_step(self):
+        """The decode step lowered at the engine's live shapes, for
+        INSPECTION (`.compile().as_text()` shows whether the Pallas
+        kernel is in the program, `.memory_analysis()` its footprint).
+        Dispatch keeps using the jit's own cache; lowering re-runs the
+        Python body, so trace_counts["decode_step"] moves by one."""
+        return self._step.lower(*self._step_args())
 
     def drain(self) -> List[Request]:
         """step() until queue and slots are empty; returns every

@@ -3,9 +3,16 @@ code paths run without a TPU pod (the analog of the reference's mocked
 telemetry testing culture, SURVEY.md §4.6). The subtle platform-forcing
 recipe lives in parallel/host_devices.py, shared with __graft_entry__."""
 
+import os
+
 from mobilefinetuner_tpu.parallel.host_devices import force_host_devices
 
 force_host_devices(8)
+# the entry points place a persistent compile cache
+# (core/compile_cache.py); the suite runs them in-process and as
+# children, and must neither depend on nor fill one — off for this
+# process and everything it starts
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax  # noqa: E402
 

@@ -1,0 +1,489 @@
+"""Compile for a DESCRIBED TPU v5e — no chip attached, nothing runs.
+
+Interpret mode (every other test in this tree) cannot see what the TPU's
+compiler refuses: a block shape the Pallas lowering will not tile, more
+VMEM than a kernel may use, a program that does not fit HBM. The compiler
+is installed here and compiles for a topology that is described and not
+attached (`topologies.get_topology_desc`), so these tests hand it the
+kernels of the main path at real widths and assert the kernel is IN the
+executable (`tpu_custom_call`). A compile that passes is not a chip run:
+it says nothing about results or times — chip_smoke.py is the chip run.
+
+The tests steer the `auto` rules themselves (they patch
+`jax.default_backend` to say "tpu", which is all `interpret_mode`, the
+paged-decode rule, the LoRA rule and the serve engine's donation consult);
+the program has no option for it. The persistent compile cache is turned
+off around the compiles: an entry written for a described chip cannot be
+read back without one.
+
+Tier-1 holds the kernel-only cases (well under a second each). The
+whole-program compiles — the GPT-2-small and gpt2-xl train steps, the
+serve engine's prefill / decode-step / prefill-chunk programs, fused CE at
+a 262k vocab — are marked `slow`: the builder runs them before spending
+chip time (`pytest tests/test_aot_tpu.py -m slow`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+from mobilefinetuner_tpu.core.config import GPT2Config  # noqa: E402
+from mobilefinetuner_tpu.core.xla_stats import (  # noqa: E402
+    compiled_peak_bytes, tpu_custom_calls)
+from mobilefinetuner_tpu.ops import decode_attention as da  # noqa: E402
+
+BF16 = jnp.bfloat16
+V5E_HBM = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """One described v5e 2x2 host for the whole module, with the
+    persistent compile cache off around everything compiled for it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compiler in this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Every `auto` rule and interpret_mode() answer as on a TPU, and
+    matmuls at the precision the program runs with there (conftest pins
+    "highest" for CPU parity; Mosaic has no fp32-contract bf16 matmul)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def on_chip0(topo, tree):
+    """Shapes of `tree` (arrays or ShapeDtypeStructs) placed on the
+    described chip 0 — there is no device to hold an array."""
+    sh = SingleDeviceSharding(topo.devices[0])
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sh),
+        tree)
+
+
+def sds(topo, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(
+        topo.devices[0]))
+
+
+def compile_for(fn, *shapes, **jit_kw):
+    return jax.jit(fn, **jit_kw).lower(*shapes).compile()
+
+
+def assert_kernel(compiled, at_least: int = 1):
+    n = tpu_custom_calls(compiled.as_text())
+    assert n >= at_least, f"{n} tpu_custom_call(s) in the executable"
+    return n
+
+
+def assert_fits(compiled):
+    """Under a v5e chip's HBM with the admission preflight's headroom."""
+    gib = compiled_peak_bytes(compiled) / 2 ** 30
+    assert gib < 0.9 * V5E_HBM / 2 ** 30, f"{gib:.1f} GiB on a 16 GiB chip"
+
+
+# --------------------------- flash attention --------------------------------
+
+FLASH_SHAPES = [(4, 12, 1024, 64), (2, 25, 1024, 64)]   # gpt2-small, gpt2-xl
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_forward_compiles(topo, as_tpu, shape):
+    from mobilefinetuner_tpu.ops.flash_attention import flash_attention
+    q = sds(topo, shape, BF16)
+    assert_kernel(compile_for(
+        lambda q, k, v: flash_attention(q, k, v, is_causal=True), q, q, q))
+
+
+@pytest.mark.parametrize("bwd_impl", ["merged", "split"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_backward_compiles(topo, as_tpu, shape, bwd_impl):
+    from mobilefinetuner_tpu.ops.flash_attention import flash_attention
+    q = sds(topo, shape, BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, is_causal=True,
+                               bwd_impl=bwd_impl).astype(jnp.float32).sum()
+
+    # forward + the merged one-pass backward = 2 kernels; the split
+    # pair's dK/dV and dQ passes make it 3
+    n = assert_kernel(compile_for(jax.grad(loss, argnums=(0, 1, 2)),
+                                  q, q, q), at_least=2)
+    assert n == (2 if bwd_impl == "merged" else 3)
+
+
+# --------------------------- paged decode attention -------------------------
+
+# (S, KV, G, D, bT, M, NB, L): the smoke's engine (build_engine("gpt2s"),
+# 8 slots, block_T 16, 256 blocks, prompt 64 + 32 new -> 6 pages a slot),
+# a 128-token page, and gpt2-xl's 25 KV heads
+PAGED_SHAPES = {
+    "smoke_engine": (8, 12, 1, 64, 16, 6, 256, 12),
+    "bT128": (8, 12, 1, 64, 128, 2, 64, 12),
+    "kv25": (8, 25, 1, 64, 16, 6, 128, 4),
+    "gqa_d256": (4, 1, 4, 256, 16, 6, 64, 2),
+}
+# over the VMEM gate: the rule says no AND the compiler says no
+PAGED_REFUSED = {
+    "kv25_bT1024": (8, 25, 1, 64, 1024, 2, 16, 2),
+    "kv12_bT2048": (8, 12, 1, 64, 2048, 2, 16, 2),
+}
+
+
+def paged_operands(topo, shape, shard=lambda name: None):
+    S, KV, G, D, bT, M, NB, L = shape
+    one = SingleDeviceSharding(topo.devices[0])
+    mk = lambda name, shp, dt: jax.ShapeDtypeStruct(
+        shp, dt, sharding=shard(name) or one)
+    return (mk("q", (S, KV, G, D), BF16),
+            mk("pool", (NB, L, KV, bT, D), BF16),
+            mk("pool", (NB, L, KV, bT, D), BF16),
+            mk("tbl", (S, M), jnp.int32),
+            mk("ok", (S, M * bT), jnp.bool_))
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
+def test_paged_decode_compiles_where_eligible(topo, as_tpu, name):
+    S, KV, G, D, bT, M, NB, L = shape = PAGED_SHAPES[name]
+    assert da.paged_eligible(KV, G, bT, D, 2)
+    assert da.resolve_paged_impl("auto", KV, G, bT, D, 2) == "pallas"
+    assert_kernel(compile_for(
+        lambda q, pk, pv, tbl, ok: da.paged_decode_attention(
+            q, pk, pv, tbl, L - 1, ok, D ** -0.5),
+        *paged_operands(topo, shape)))
+
+
+@pytest.mark.parametrize("name", sorted(PAGED_REFUSED))
+def test_paged_rule_and_compiler_refuse_together(topo, as_tpu, name,
+                                                 monkeypatch):
+    """The eligibility rule and the compiler may not disagree: a shape
+    the rule turns away is one Mosaic runs out of VMEM on — so `auto`
+    never selects a kernel that cannot compile, and never gives up one
+    that could (the eligible cases above are the other direction)."""
+    S, KV, G, D, bT, M, NB, L = shape = PAGED_REFUSED[name]
+    assert not da.paged_eligible(KV, G, bT, D, 2)
+    assert da.resolve_paged_impl("auto", KV, G, bT, D, 2) == "xla"
+    monkeypatch.setattr(da, "paged_eligible", lambda *a, **k: True)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|vmem"):
+        compile_for(
+            lambda q, pk, pv, tbl, ok: da.paged_decode_attention(
+                q, pk, pv, tbl, 0, ok, D ** -0.5),
+            *paged_operands(topo, shape))
+
+
+@pytest.mark.parametrize("dp,tp", [(1, 4), (2, 2)])
+def test_sharded_paged_attend_compiles_on_four_chips(topo, as_tpu, dp, tp):
+    """The shard_map wrapper on a mesh built from the described devices:
+    each shard's kernel gets KV/tp heads of its own pool slice, and the
+    attention itself needs no collective."""
+    from mobilefinetuner_tpu.serve.sharding import ServeSharding
+    sh = ServeSharding.build("gpt2", GPT2Config.gpt2_small(), dp, tp,
+                             devices=topo.devices)
+    dpn = "dp" if dp > 1 else None
+    specs = {"q": P(dpn, "tp", None, None),
+             "pool": P(None, None, "tp", None, None),
+             "tbl": P(dpn, None), "ok": P(dpn, None)}
+    ops = paged_operands(
+        topo, PAGED_SHAPES["smoke_engine"],
+        shard=lambda name: NamedSharding(sh.mesh, specs[name]))
+    attend = da.sharded_paged_attend(sh)
+    compiled = compile_for(
+        lambda q, pk, pv, tbl, ok: attend(q, pk, pv, tbl, 3, ok, 0.125),
+        *ops)
+    assert_kernel(compiled)
+    text = compiled.as_text()
+    assert not any(c in text for c in ("all-gather", "all-reduce",
+                                       "all-to-all"))
+
+
+# --------------------------- dense decode + LoRA epilogue -------------------
+
+@pytest.mark.parametrize("T", [256, 1024])
+def test_dense_decode_kernel_compiles(topo, as_tpu, T):
+    B, KV, G, D = 8, 12, 1, 64
+    assert da.decode_eligible(KV, T, D, 2, G)
+    assert_kernel(compile_for(
+        lambda q, k, v, ok: da.decode_attention(q, k, v, ok, D ** -0.5),
+        sds(topo, (B, KV, G, D), BF16), sds(topo, (B, KV, T, D), BF16),
+        sds(topo, (B, KV, T, D), BF16), sds(topo, (B, T), jnp.bool_)))
+
+
+@pytest.mark.parametrize("N,d_out,r", [(8192, 2304, 8), (8192, 768, 8)])
+def test_lora_epilogue_compiles(topo, as_tpu, N, d_out, r):
+    """GPT-2 small's attn_qkv / attn_proj sites at B*S = 8192 tokens —
+    the shapes `--lora_impl auto` resolves to fused on a TPU."""
+    from mobilefinetuner_tpu.models.lora_apply import resolve_lora_impl
+    from mobilefinetuner_tpu.ops.lora_fused import (lora_epilogue_add,
+                                                    lora_epilogue_eligible)
+    assert lora_epilogue_eligible(N, d_out, r, 2)
+    assert resolve_lora_impl(N, 768, d_out, r, 2) == "fused"
+    assert_kernel(compile_for(
+        lora_epilogue_add, sds(topo, (N, d_out), BF16),
+        sds(topo, (N, r), BF16), sds(topo, (r, d_out), BF16),
+        sds(topo, (), jnp.float32)))
+
+
+# --------------------------- whole programs (slow) --------------------------
+
+def gpt2_train_step(cfg: GPT2Config, B: int, S: int, remat: bool,
+                    lora_impl: str = "auto", out_shardings=None):
+    """The GPT-2 LoRA optimizer step as cli/gpt2_lora_finetune builds it
+    (bf16, the smoke's four targets), and its operands as shapes."""
+    from mobilefinetuner_tpu.lora.lora import (LoRASpec, init_lora_gpt2,
+                                               trainable_mask)
+    from mobilefinetuner_tpu.models import gpt2
+    from mobilefinetuner_tpu.ops.loss import lm_cross_entropy_sum
+    from mobilefinetuner_tpu.train.trainer import (TrainConfig,
+                                                   init_optimizer,
+                                                   make_train_step)
+    spec = LoRASpec(rank=8, alpha=16.0, init="gpt2", targets=[
+        "attn_qkv", "attn_proj", "mlp_fc_in", "mlp_fc_out"])
+    lora = init_lora_gpt2(cfg, spec, jax.random.PRNGKey(0))
+    mask = trainable_mask(lora)
+    tc = TrainConfig(total_steps=10)
+    opt = init_optimizer(lora, tc, mask)
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+
+    def loss_fn(lora_t, frozen, mb):
+        logits = gpt2.forward(cfg, frozen, mb["input_ids"],
+                              attention_mask=mb["attention_mask"],
+                              lora=lora_t, compute_dtype=BF16, remat=remat,
+                              lora_impl=lora_impl)
+        return lm_cross_entropy_sum(logits, mb["labels"])
+
+    batch = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "attention_mask": jax.ShapeDtypeStruct((B, S), jnp.float32),
+             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    step = make_train_step(loss_fn, tc, mask=mask, donate=True,
+                           out_shardings=out_shardings)
+    return step, (lora, params, opt, batch,
+                  jax.ShapeDtypeStruct((), jnp.int32))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("model,B,S,remat,kernels", [
+    ("gpt2_small", 64, 128, False, True),    # LoRA epilogue (fused auto)
+    ("gpt2_small", 4, 1024, False, True),    # + flash fwd, merged bwd
+    ("gpt2_xl", 8, 128, True, False),        # d_out 1600/4800: no epilogue
+    ("gpt2_xl", 2, 1024, True, True),        # flash at 25 heads
+])
+def test_train_step_compiles_and_fits(topo, as_tpu, model, B, S, remat,
+                                      kernels):
+    cfg = getattr(GPT2Config, model)()
+    step, operands = gpt2_train_step(cfg, B, S, remat)
+    compiled = step.lower(*on_chip0(topo, operands)).compile()
+    if kernels:
+        assert_kernel(compiled)
+    assert_fits(compiled)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("B,S", [(64, 128), (8, 1024)])
+def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S):
+    """chip_smoke.py --chips 4's FSDP run, rehearsed before a four-chip
+    call is paid for: the frozen base sharded by the CLI's own rule
+    (parallel/mesh.params_shardings) over a (1, 4) mesh of the described
+    devices, the batch over both axes, LoRA + Adam state replicated and
+    pinned on the way out (cli/common.run_training's fixed point).
+
+    The TPU lowering refuses a Mosaic kernel in a GSPMD-partitioned
+    step ("cannot be automatically partitioned"), which no interpret-
+    mode mesh test can see: with the one-chip `auto` choices this step
+    does not compile, with cli/common.build_mesh's (partitioned_impl)
+    it does."""
+    from mobilefinetuner_tpu.core.static_checks import hlo_collective_census
+    from mobilefinetuner_tpu.ops.pallas_util import partitioned_impl
+    from mobilefinetuner_tpu.parallel.mesh import (batch_sharding,
+                                                   make_mesh,
+                                                   params_shardings,
+                                                   replicated_sharding)
+    mesh = make_mesh(data=1, fsdp=4, devices=topo.devices)
+    repl = replicated_sharding(mesh)
+    all_repl = lambda tree: jax.tree.map(lambda _: repl, tree)
+
+    def lowered(attention_impl, lora_impl):
+        cfg = dataclasses.replace(GPT2Config.gpt2_small(),
+                                  attention_impl=attention_impl)
+        # (a sharding is a pytree prefix: one covers a whole subtree)
+        step, (lora, params, opt, batch, _) = gpt2_train_step(
+            cfg, B, S, False, lora_impl=lora_impl,
+            out_shardings=(repl, repl, repl))
+        place = lambda tree, sh: jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                              sharding=s), tree, sh)
+        return params, step.lower(
+            place(lora, all_repl(lora)),
+            place(params, params_shardings(params, mesh)),
+            place(opt, all_repl(opt)),
+            place(batch, jax.tree.map(lambda _: batch_sharding(mesh),
+                                      batch)),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lowered("auto", "auto")
+    params, low = lowered(
+        partitioned_impl("attention_impl", "auto", "flash", "xla", 4),
+        partitioned_impl("lora_impl", "auto", "fused", "naive", 4))
+    compiled = low.compile()
+    census = hlo_collective_census(compiled.as_text())
+    assert census["all-gather"] > 0, census       # weights gathered
+    assert census["all-reduce"] + census["reduce-scatter"] > 0, census
+    # per device: a quarter of the base, not all of it
+    one_chip = 4 * sum(int(np.prod(x.shape)) for x in
+                       jax.tree.leaves(params))
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        < 0.4 * one_chip
+    assert_fits(compiled)
+
+
+@pytest.mark.slow
+def test_tp_decode_step_compiles_on_four_chips(topo, as_tpu):
+    """ServeConfig.mesh_tp=4's decode step (12 heads / 4) through the
+    model function the engine jits, on a (1, 4) mesh of the described
+    devices: the paged kernel under shard_map, weights column/row
+    parallel, pools sharded on their head axis."""
+    from mobilefinetuner_tpu.models import generate as g
+    from mobilefinetuner_tpu.models import gpt2
+    from mobilefinetuner_tpu.serve.sharding import ServeSharding
+    cfg = GPT2Config.gpt2_small()
+    sh = ServeSharding.build("gpt2", cfg, 1, 4, devices=topo.devices)
+    S, bT, NB, M = 8, 16, 256, 6
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        params, sh.param_shardings(params))
+    pool = jax.ShapeDtypeStruct(
+        (NB, cfg.n_layer, cfg.n_head, bT, cfg.head_dim), BF16,
+        sharding=sh.pool_sharding())
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=sh.repl)
+    compiled = compile_for(
+        functools.partial(g.gpt2_decode_step_paged, cfg,
+                          compute_dtype=BF16, shardings=sh),
+        params, pool, pool, i32(S), i32(S), i32(S, M),
+        donate_argnums=(1, 2),
+        out_shardings=(sh.repl, sh.pool_sharding(), sh.pool_sharding()))
+    assert_kernel(compiled)
+    assert "all-reduce" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def smoke_engine():
+    """The smoke's engine, built for real on the CPU (build_engine
+    allocates its params and pools) — its jitted programs are then
+    lowered for the described chip with the TPU rules."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    import serve_bench
+    eng, names = serve_bench.build_engine(
+        "gpt2s", 8, 16, 256, 64, 32, adapters=2, dtype="bfloat16",
+        on_step_error="raise", max_prompt_chunked=128)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.slow
+def test_engine_programs_compile(topo, as_tpu, smoke_engine):
+    """prefill, decode step (with the paged kernel in it) and a prefill
+    chunk of the smoke's engine — the programs `ServeEngine` jits, at
+    its num_slots / block_T / num_blocks, under the TPU `auto` rules."""
+    eng = smoke_engine
+    S, M, cfg = eng.cfg.num_slots, eng.M, eng.cfg
+    bank = eng.bank.tree
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+    args = on_chip0(topo, eng._step_args())
+    compiled = eng._step.lower(*args).compile()
+    assert_kernel(compiled)
+    assert_fits(compiled)
+    prefill = eng._prefill.lower(*on_chip0(topo, (
+        eng.params, bank, i32(1, cfg.max_prompt), i32(1, cfg.max_prompt),
+        i32(1)))).compile()
+    assert_fits(prefill)
+    W = eng.chunk_buckets[-1]
+    chunk = eng._chunk.lower(*on_chip0(topo, (
+        eng.params, bank, eng.pool_k, eng.pool_v, i32(1, W), i32(), i32(),
+        i32(1, M), i32(1)))).compile()
+    assert_fits(chunk)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["decode_step", "prefill",
+                                     "prefill_chunk"])
+def test_gpt2_xl_serve_programs_compile_and_fit(topo, as_tpu, program):
+    """The next PR's anchor model (gpt2-xl: H 1600, 25 heads, 48 layers)
+    through the model functions the engine jits, from shapes alone —
+    known to compile and to fit one 16 GiB chip before chip time is
+    spent on it. The weights are handed over in bf16: the engine keeps
+    params in the dtype it is given and casts inside the step, and with
+    the float32 tree build_engine makes today this decode step needs
+    17.3 GiB (6.2 f32 + the 3.1 bf16 copy + pools) — over one chip."""
+    from mobilefinetuner_tpu.models import generate as g
+    from mobilefinetuner_tpu.models import gpt2
+    cfg = GPT2Config.gpt2_xl()
+    L, H, D = cfg.n_layer, cfg.n_head, cfg.head_dim
+    S, bT, NB, Pmax, M = 8, 16, 256, 64, 6
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, BF16),
+        jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = jax.ShapeDtypeStruct((NB, L, H, bT, D), BF16)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode_step":
+        fn = functools.partial(g.gpt2_decode_step_paged, cfg,
+                               compute_dtype=BF16)
+        ops, kw = (params, pool, pool, i32(S), i32(S), i32(S, M)), \
+            dict(donate_argnums=(1, 2))
+    elif program == "prefill":
+        fn = functools.partial(g.gpt2_prefill, cfg, compute_dtype=BF16)
+        ops, kw = (params, i32(1, Pmax), i32(1, Pmax)), {}
+    else:
+        fn = functools.partial(g.gpt2_prefill_chunk, cfg,
+                               compute_dtype=BF16)
+        ops, kw = (params, pool, pool, i32(1, Pmax), i32(), i32(),
+                   i32(1, M)), dict(donate_argnums=(1, 2))
+    compiled = compile_for(fn, *on_chip0(topo, ops), **kw)
+    if program == "decode_step":
+        assert_kernel(compiled)
+    assert_fits(compiled)
+
+
+@pytest.mark.slow
+def test_fused_ce_compiles_at_262k_vocab(topo, as_tpu):
+    from mobilefinetuner_tpu.ops.fused_ce import (fused_ce_eligible,
+                                                  fused_ce_rows)
+    R, V, H = 1024, 262144, 640
+    assert fused_ce_eligible(R, V, H, 2)
+
+    def loss(h, w, labels):
+        lse, gold = fused_ce_rows(h, w, labels)
+        return (lse - gold).sum()
+
+    assert_kernel(compile_for(
+        jax.grad(loss, argnums=(0, 1)), sds(topo, (R, H), BF16),
+        sds(topo, (V, H), BF16), sds(topo, (R,), jnp.int32)), at_least=2)

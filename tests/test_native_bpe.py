@@ -35,11 +35,14 @@ def tok_pair(tmp_path_factory):
 def test_native_library_builds():
     if os.environ.get("MFT_NO_NATIVE_BPE") == "1":
         pytest.skip("native BPE disabled by env")
-    from mobilefinetuner_tpu.native.fast_bpe import load_library
-    assert load_library() is not None
-    assert os.path.exists(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "..",
-        "mobilefinetuner_tpu", "native", "libfast_bpe.so"))
+    from mobilefinetuner_tpu.native import fast_bpe
+    from mobilefinetuner_tpu.native.build import library_path
+    assert fast_bpe.load_library() is not None
+    # the library on disk is named by its source's hash: one built from
+    # any other fast_bpe.cpp (a stale checkout, a copied disk) can
+    # never be the one that loads
+    hashed = library_path(fast_bpe._SRC, fast_bpe._LIB)
+    assert hashed != fast_bpe._LIB and os.path.exists(hashed)
 
 
 def test_native_matches_python_on_corpus(tok_pair):

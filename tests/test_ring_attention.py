@@ -286,8 +286,7 @@ def test_flash_ring_long_context_8k():
     # dense-body reference at the same sharding
     from jax.sharding import PartitionSpec as P
     pad = jnp.ones((1, 8192), jnp.float32)
-    from mobilefinetuner_tpu.core.compat import shard_map
-    dense = shard_map(
+    dense = jax.shard_map(
         _p(ra._ring_shard, axis="fsdp", n=8, scale=1.0 / 8.0, causal=True,
            window=1024),
         mesh=mesh,

@@ -51,12 +51,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
-def write_synthetic_gpt2(d: str, seed: int = 0):
+def write_synthetic_gpt2(d: str, seed: int = 0, cfg=None):
     """Full-size GPT-2-small HF checkpoint dir with random weights: real
     config.json, model.safetensors in HF GPT2LMHeadModel keys (Conv1D
     [in, out] layout), and a 50257-entry byte-level vocab (256 byte tokens
     + filler + <|endoftext|>=50256; empty merges, so encoding is pure
-    byte-level — ids are valid and the full vocab head is exercised)."""
+    byte-level — ids are valid and the full vocab head is exercised).
+    `cfg` swaps in another GPT2Config (chip_smoke.py's CPU rehearsal
+    writes a tiny one; vocab_size must cover the 256 byte tokens)."""
     import jax
     from mobilefinetuner_tpu.core.config import GPT2Config
     from mobilefinetuner_tpu.data.tokenizer_bpe import bytes_to_unicode
@@ -65,7 +67,7 @@ def write_synthetic_gpt2(d: str, seed: int = 0):
     from mobilefinetuner_tpu.models import gpt2
 
     os.makedirs(d, exist_ok=True)
-    cfg = GPT2Config.gpt2_small()
+    cfg = cfg or GPT2Config.gpt2_small()
     params = gpt2.init_params(cfg, jax.random.PRNGKey(seed))
     sd = gpt2_params_to_hf(jax.device_get(params))
     save_safetensors(os.path.join(d, "model.safetensors"),
@@ -171,11 +173,13 @@ def write_synthetic_corpus(d: str, n_train_words: int = 120_000,
             i += ln
         return "\n".join(lines) + "\n"
 
-    for split, n in (("train", n_train_words),
-                     ("valid", n_train_words // 10),
-                     ("test", n_train_words // 10)):
+    # one stream per split, a fixed offset from the seed (str hash() is
+    # salted per process: the corpus must be the same in every run)
+    for i, (split, n) in enumerate((("train", n_train_words),
+                                    ("valid", n_train_words // 10),
+                                    ("test", n_train_words // 10))):
         with open(os.path.join(d, f"wiki.{split}.tokens"), "w") as f:
-            f.write(gen(n, np.random.default_rng(seed + hash(split) % 97)))
+            f.write(gen(n, np.random.default_rng([seed, i + 1])))
     return d
 
 

@@ -408,8 +408,7 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
                     deadline_ms=None, scrape_s: float = 0.1,
                     collect_s: float = 0.02,
                     startup_timeout_s: float = 300.0,
-                    settle_timeout_s: float = 600.0,
-                    baseline=None) -> list:
+                    settle_timeout_s: float = 600.0) -> list:
     """Round 22: the same seeded open-loop Poisson load, driven over
     HTTP through tools/serve_router.py with `replicas` engine
     processes behind it. One router subprocess per call (one compile
@@ -418,9 +417,12 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
     terminal census, routing-decision histogram from the router's own
     `route` events, per-replica prefix-cache hit rate) plus one row
     per replica — the load-imbalance and per-tenant-locality story a
-    fleet-level mean hides. `baseline` maps rate -> single-engine TTFT
-    p99 (run_rows over the identical gen_schedule workload); when
-    given, the fleet row carries the p99 ratio bench_compare tracks.
+    fleet-level mean hides. (stamp_baseline adds the single-engine
+    p99 ratio afterwards — the baseline engine needs the chip, so it
+    runs once the fleet is down.)
+
+    The calling process must not have initialised a JAX backend: the
+    replicas need the chip(s), and a chip belongs to one process.
 
     Exact accounting is the contract here, same as the kill-replica
     e2e: every rid the router acked MUST settle through /collect
@@ -428,6 +430,13 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
     import signal
     import subprocess
     import serve_router as sr              # sibling tool (no jax)
+    from mobilefinetuner_tpu.parallel.host_devices import \
+        backend_initialized
+    if backend_initialized():
+        # a chip belongs to one process: a parent that holds it makes
+        # every replica fail or hang at backend start
+        raise SystemExit("--router: this process initialised a JAX "
+                         "backend before starting the replica fleet")
     from telemetry_report import load_events
     from mobilefinetuner_tpu.core.config import (GPT2Config,
                                                  Gemma3TextConfig)
@@ -619,11 +628,6 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
                 "sampling": bool(sampling),
                 "prefix_cache": bool(prefix_cache),
             }
-            if baseline and baseline.get(rate) is not None:
-                row["baseline_ttft_p99_ms"] = baseline[rate]
-                if row["ttft_ms"]["p99"] is not None and baseline[rate]:
-                    row["ttft_p99_vs_baseline"] = round(
-                        row["ttft_ms"]["p99"] / baseline[rate], 3)
             rows.append(row)
             fmt = lambda v: "n/a" if v is None else f"{v:.0f}"
             print(f"{name}: {row['req_s']} req/s "
@@ -631,10 +635,7 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
                   f"replicas, TTFT p50/p99 = "
                   f"{fmt(row['ttft_ms']['p50'])}/"
                   f"{fmt(row['ttft_ms']['p99'])} ms, routing "
-                  f"{decisions}, spread {per_replica}"
-                  + (f", p99 vs 1-engine x"
-                     f"{row.get('ttft_p99_vs_baseline')}"
-                     if "ttft_p99_vs_baseline" in row else ""))
+                  f"{decisions}, spread {per_replica}")
             for k in sorted(int(k) for k in per_replica):
                 sub = [r for r in res if r.get("replica") == k]
                 fin_k = [r for r in sub if r["state"] == "finished"]
@@ -662,6 +663,32 @@ def run_router_rows(model: str, rates, n_requests: int, adapters: int,
     print(f"router stream: {base} (replay with "
           f"tools/trace_export.py {base} --router)")
     return rows
+
+
+def stamp_baseline(rows: list, baseline: dict) -> None:
+    """Give each fleet row (one per rate) its single-engine TTFT p99
+    (`baseline`: rate -> p99 ms from run_rows over the identical
+    gen_schedule workload) and the ratio bench_compare tracks."""
+    for row in rows:
+        base = baseline.get(row["offered_rps"])
+        if "replicas" not in row or base is None:
+            continue
+        row["baseline_ttft_p99_ms"] = base
+        if row["ttft_ms"]["p99"] is not None and base:
+            row["ttft_p99_vs_baseline"] = round(
+                row["ttft_ms"]["p99"] / base, 3)
+            print(f"{row['config']}: TTFT p99 vs 1-engine "
+                  f"x{row['ttft_p99_vs_baseline']}")
+
+
+def rows_with_errors(rows: list) -> list:
+    """Configs whose census holds a request in state `error`. With no
+    fault injected nothing may end there: an error is then a step that
+    could not run (a kernel the compiler refused, an OOM), contained by
+    on_step_error=fail_active — a run that answered nothing must not
+    exit 0."""
+    return [r["config"] for r in rows
+            if (r.get("terminal") or {}).get("error")]
 
 
 def run_rows(model: str, rates, n_requests: int, adapters: int,
@@ -991,6 +1018,8 @@ def main(argv=None) -> int:
                     help="watchdog deadline floor (and pre-first-step "
                          "grace) in seconds")
     args = ap.parse_args(argv)
+    from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     model = "gemma270m" if args.gemma else args.model
     if args.inject == "adapter_load_fail" and not args.adapters:
         raise SystemExit("--inject adapter_load_fail needs --adapters k")
@@ -1015,7 +1044,9 @@ def main(argv=None) -> int:
     _reg = RunRegistry.from_args(args)
     run_rec = _reg.begin(
         "serve", "serve_bench", config=vars(args),
-        platform=jax.devices()[0].platform,
+        # a --router parent stays off the backend until its fleet is
+        # down (the replicas own the chips), so it cannot name one here
+        platform=None if args.router > 0 else jax.devices()[0].platform,
         mesh=({"data": mesh_dp, "model": mesh_tp}
               if mesh_dp * mesh_tp > 1 else None),
         artifacts=[p for p in (args.telemetry_out, args.out)
@@ -1037,27 +1068,7 @@ def main(argv=None) -> int:
                     tempfile.mkdtemp(prefix="serve_fleet_"), "fleet.jsonl")
                 print(f"--router: telemetry stream at {base} "
                       f"(pass --telemetry_out to choose)")
-            baseline = None
-            if args.router_baseline:
-                brows = run_rows(
-                    model, args.rate, args.requests, args.adapters,
-                    num_slots=args.num_slots, block_T=args.block_T,
-                    num_blocks=args.num_blocks, max_prompt=args.max_prompt,
-                    max_new=args.max_new, dtype=args.dtype, seed=args.seed,
-                    prompt_lo=args.prompt_lo, prompt_hi=args.prompt_hi,
-                    max_queue=args.max_queue, shed_policy=args.shed_policy,
-                    deadline_ms=args.deadline_ms or None,
-                    prefix_cache=bool(args.prefix_cache),
-                    max_prompt_chunked=args.max_prompt_chunked,
-                    sampling=bool(args.sampling),
-                    prefix_pool=args.prefix_pool,
-                    prefix_frac=args.prefix_frac)
-                baseline = {r["offered_rps"]: r["ttft_ms"]["p99"]
-                            for r in brows}
-                rows = brows
-            else:
-                rows = []
-            rows = rows + run_router_rows(
+            rows = run_router_rows(
                 model, args.rate, args.requests, args.adapters,
                 args.router, base, num_slots=args.num_slots,
                 block_T=args.block_T, num_blocks=args.num_blocks,
@@ -1071,8 +1082,26 @@ def main(argv=None) -> int:
                 sampling=bool(args.sampling),
                 prefix_pool=args.prefix_pool,
                 prefix_frac=args.prefix_frac,
-                deadline_ms=args.deadline_ms or None,
-                baseline=baseline)
+                deadline_ms=args.deadline_ms or None)
+            if args.router_baseline:
+                # AFTER the fleet is down: the in-process baseline
+                # engine needs the chip the replicas were holding
+                brows = run_rows(
+                    model, args.rate, args.requests, args.adapters,
+                    num_slots=args.num_slots, block_T=args.block_T,
+                    num_blocks=args.num_blocks, max_prompt=args.max_prompt,
+                    max_new=args.max_new, dtype=args.dtype, seed=args.seed,
+                    prompt_lo=args.prompt_lo, prompt_hi=args.prompt_hi,
+                    max_queue=args.max_queue, shed_policy=args.shed_policy,
+                    deadline_ms=args.deadline_ms or None,
+                    prefix_cache=bool(args.prefix_cache),
+                    max_prompt_chunked=args.max_prompt_chunked,
+                    sampling=bool(args.sampling),
+                    prefix_pool=args.prefix_pool,
+                    prefix_frac=args.prefix_frac)
+                stamp_baseline(rows, {r["offered_rps"]: r["ttft_ms"]["p99"]
+                                      for r in brows})
+                rows = brows + rows
         else:
             rows = run_rows(model, args.rate, args.requests, args.adapters,
                             num_slots=args.num_slots, block_T=args.block_T,
@@ -1111,6 +1140,11 @@ def main(argv=None) -> int:
             with open(tmp, "w") as f:
                 json.dump(art, f, indent=1)
             os.replace(tmp, args.out)
+        bad = [] if args.inject else rows_with_errors(rows)
+        if bad:
+            raise SystemExit(
+                f"FAILED: requests ended in state `error` with no fault "
+                f"injected, in {bad} — a step could not run")
         return 0
 
 

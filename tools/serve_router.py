@@ -57,6 +57,7 @@ import json
 import os
 import shlex
 import signal
+import subprocess
 import sys
 import threading
 import time
@@ -309,7 +310,14 @@ def replica_main(args) -> int:
     published through the port file, and drains on SIGTERM exactly
     like a directly-driven engine (queue rejected reason=shutdown,
     in-flight decoded out, run_end{exit=preempted}, exit code 75)."""
+    if args.pin_chip:
+        # replica k owns chip k-1 of the host, and no other: a chip
+        # belongs to one process at a time (set before jax starts)
+        from mobilefinetuner_tpu.parallel.host_devices import pin_tpu_chip
+        pin_tpu_chip(args.host - 1)
     import serve_bench  # imports jax — replica processes only
+    from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = dict(DEFAULT_ENGINE_SPEC)
     with open(args.engine_json) as f:
         spec.update(json.load(f))
@@ -368,6 +376,22 @@ def replica_main(args) -> int:
 
 
 # --------------------------- router side ------------------------------------
+
+def probe_devices(timeout_s: float = 180.0) -> dict:
+    """{"platform", "count"} of the accelerator the replicas will find,
+    asked in a THROWAWAY child: the router must never initialise a
+    backend itself (it would hold the chip its replicas need), and the
+    child has released it by the time it exits. With JAX_PLATFORMS=cpu
+    there is nothing to contend for and no child is started."""
+    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return {"platform": "cpu", "count": 0}
+    code = ("import jax, json; d = jax.devices(); print(json.dumps("
+            "{'platform': d[0].platform, 'count': len(d)}))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         timeout=timeout_s).stdout
+    return json.loads(out.strip().splitlines()[-1])
+
 
 class ServeShardTail(ShardTail):
     """The r13 shard tail, extended with the serve-fleet fact the
@@ -645,6 +669,17 @@ class ServeRouter:
             raise SystemExit(
                 f"unknown engine spec keys: {sorted(unknown)}")
         self.spec = spec
+        # one process per chip: on a TPU host replica k pins chip k-1,
+        # and a fleet larger than the host's chip count is refused HERE
+        # — unpinned or oversubscribed replicas would fail or hang at
+        # backend start, long after the router reported itself up
+        dev = probe_devices()
+        pin = dev["platform"] == "tpu"
+        if pin and args.replicas > dev["count"]:
+            raise SystemExit(
+                f"--replicas {args.replicas} exceeds this host's "
+                f"{dev['count']} TPU chip(s): one replica process per "
+                f"chip")
         self.spec_path = f"{base}.engcfg.json"
         with open(self.spec_path, "w") as f:
             json.dump(spec, f)
@@ -653,7 +688,8 @@ class ServeRouter:
                f"--serve_replica --host {{host}} "
                f"--telemetry {shlex.quote(base)} "
                f"--engine_json {shlex.quote(self.spec_path)} "
-               f"--port 0 --linger_s {args.linger_s}")
+               f"--port 0 --linger_s {args.linger_s}"
+               + (" --pin_chip" if pin else ""))
         self.fc = FleetController(argparse.Namespace(
             telemetry=base, cmd=cmd, hosts=args.replicas,
             restart_budget=args.restart_budget,
@@ -949,6 +985,8 @@ def main(argv=None) -> int:
     ap.add_argument("--serve_replica", action="store_true",
                     help=argparse.SUPPRESS)
     ap.add_argument("--host", type=int, default=0,
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--pin_chip", action="store_true",
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     if args.serve_replica:

@@ -1208,6 +1208,28 @@ class EMA:
         return self.value
 
 
+def drained_record(flush_stamps, t_wait, t_input, t_dispatch,
+                   t_dispatched) -> tuple:
+    """(ms, parts): the host time the device had nothing queued after a
+    flush and its split (step_stats.drained_ms / drained_parts_ms).
+
+    flush_stamps = the flush's perf_counter readings (device_get
+    returned, sensors done, telemetry line written, log line written);
+    then the readings around the next `next(stream)` and around the
+    next dispatch. The boundaries are those of the train.flush.* /
+    train.input_wait / train.dispatch spans, and consecutive, so the
+    parts sum to the whole; `loop_tail` is everything of the loop that
+    is neither input wait nor dispatch."""
+    t_sync, t_sensors, t_emit, t_log = flush_stamps
+    ms = lambda seconds: round(seconds * 1000.0, 3)
+    return ms(t_dispatched - t_sync), {
+        "sensors": ms(t_sensors - t_sync), "emit": ms(t_emit - t_sensors),
+        "log": ms(t_log - t_emit),
+        "loop_tail": ms((t_wait - t_log) + (t_dispatch - t_input)),
+        "input_wait": ms(t_input - t_wait),
+        "dispatch": ms(t_dispatched - t_dispatch)}
+
+
 def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                  train_ds: WikiText2Dataset,
                  valid_ds: Optional[WikiText2Dataset],
@@ -1303,14 +1325,15 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
     for _ev in getattr(args, "_ckpt_verify_events", None) or []:
         tel.emit("ckpt_verify", **_ev)
     t_start = time.time()
-    # span tracing (--trace_spans, core/trace.py): ONE tracer threaded
-    # to every producer — the goodput meter's phase track, the async
-    # checkpoint writer, the prefetch producer — all emitting `span`
-    # events into the same stream tools/trace_export.py converts
+    # ONE tracer (core/trace.py) threaded to every producer — the step
+    # loop's own spans, the goodput meter's phase track, the async
+    # checkpoint writer, the prefetch producer. Its spans are in any
+    # profiler trace taken of this run, always; --trace_spans
+    # additionally writes them as `span` events into the stream
+    # tools/trace_export.py converts
     from mobilefinetuner_tpu.core.trace import AutoProfiler, Tracer
-    tracer = (Tracer(tel.emit)
-              if getattr(args, "trace_spans", 0) and tel.enabled
-              else None)
+    tracer = Tracer(tel.emit, enabled=bool(
+        getattr(args, "trace_spans", 0)) and tel.enabled)
     # wall-clock bucket accounting over run_training's whole span; the
     # buckets sum to run_end.wall_s by construction (DESIGN.md §14);
     # under --trace_spans every phase segment also lands as a span, so
@@ -1910,7 +1933,7 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
         prof_end = prof_start + getattr(args, "profile_steps", 5)
         prof_active = False
 
-        def maybe_profile(step):
+        def maybe_profile(step, closing=False):
             nonlocal prof_active
             if not profile_dir:
                 return
@@ -1918,13 +1941,18 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                 if step == prof_start and not prof_active:
                     jax.profiler.start_trace(profile_dir)
                     prof_active = True
-                elif step >= prof_end and prof_active:
+                elif (step >= prof_end or closing) and prof_active:
                     if metrics:
                         # graftlint: disable=sync-hazard(profiler stop drains queued work so the trace window holds it)
                         jax.device_get(metrics["loss"])
                     jax.profiler.stop_trace()
                     prof_active = False
                     log.info(f"profiler trace -> {profile_dir}")
+                    # the stream names every trace taken of its run,
+                    # the manual window like the AutoProfiler's
+                    tel.emit("profile_capture", step=step,
+                             trigger="manual", path=profile_dir,
+                             steps=step - prof_start, budget_left=None)
             except Exception as e:  # profiling must never kill training
                 log.warning(f"profiler: {e}")
                 prof_active = False
@@ -1943,8 +1971,15 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
         # hard cap bounds the device-metrics buffer in all cases.
         flush_every = (min(args.log_interval, 1000) if args.log_interval
                        else (50 if metrics_csv else 1000))
+        # the flush counter (step_stats.drained_ms / drained_parts_ms): a
+        # flush fetches the NEWEST step, so from its device_get's return
+        # until the next dispatch returns the device has nothing queued.
+        # `drain` holds the open period's stamps from the flush until the
+        # loop closes it after that dispatch; `drained` is the closed
+        # (ms, parts), which the NEXT flush's line carries.
+        drain, drained = None, (None, None)
 
-        def flush_metrics(emit_log=True):
+        def flush_buffered(emit_log):
             """One host sync for everything buffered since the last flush —
             the telemetry zero-sync invariant: the on-device health scalars
             (param_norm/update_ratio/nonfinite_count) ride the SAME
@@ -1960,130 +1995,152 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
             telemetry event per flush; the host-side spike detector sees
             every per-step loss and emits `anomaly` events instead of
             silently training through divergence."""
-            nonlocal t_interval, slept_ms, waited_ms
-            if not buffered:
-                return
-            # graftlint: disable=sync-hazard(the zero-sync contract: ONE device_get per metrics flush, DESIGN.md section 13)
-            fetched = jax.device_get([m for _, _, _, m in buffered])
-            dt_ms = ((time.perf_counter() - t_interval) * 1000 - slept_ms) \
-                / len(buffered)
-            wait_ms = waited_ms / len(buffered)
-            # the device_get above SYNCED the interval, so dt_ms is the
-            # honest per-step time (a per-iteration clock under async
-            # dispatch measures only enqueue latency): feed the fleet
-            # timing consumers — the straggler window and the watchdog's
-            # deadline median — from here, the same number step_stats
-            # publishes
-            prior_n, prior_med = step_clock.n, step_clock.median_ms()
-            step_clock.record(dt_ms / 1000.0)
-            if wd is not None:
-                wd.pet(buffered[-1][0], dt_ms / 1000.0)
-            # slow-step flight-recorder trigger (--auto_profile): this
-            # flush interval ran a multiple of the rolling median —
-            # arm a capture over the NEXT steps while whatever made it
-            # slow is plausibly still happening. The median is the
-            # PRIOR window's (the slow sample must not judge itself),
-            # and the manual --profile_dir window keeps priority.
-            if (auto_prof is not None and slow_mult > 0 and prior_n >= 3
-                    and prior_med > 0 and dt_ms > slow_mult * prior_med
-                    and not prof_active):
-                if auto_prof.trigger("slow_step", buffered[-1][0] + 1):
-                    log.warning(
-                        f"auto_profile: step time {dt_ms:.1f} ms > "
-                        f"{slow_mult:g}x rolling median "
-                        f"{prior_med:.1f} ms — capturing "
-                        f"{auto_prof.steps} step(s)")
-            # live bytes when the backend reports them, else the
-            # compiled-peak estimate, else NULL — a backend with no
-            # memory accounting must not masquerade as 0 MB (round 16;
-            # live_hbm_mb logs the backend once)
-            hbm = live_hbm_mb()
-            if hbm is None:
-                hbm = peak_hbm["mb"] or None
-            mfu = mfu_from(flops_per_step, dt_ms / 1000, peak_flops)
-            for (s, ep, toks, _), m in zip(buffered, fetched):
-                loss = float(m["loss"])
-                avg = ema.update(loss)
-                anom = spikes.update(loss)
-                if anom is not None:
-                    tel.emit("anomaly", step=s + 1, loss=loss, ema=avg,
-                             **anom)
-                    log.warning(
-                        f"anomaly @ step {s + 1}: {anom['kind']} "
-                        f"loss={loss:.4f}"
-                        + (f" z={anom['zscore']}" if anom["zscore"] else ""))
-                    if auto_prof is not None and not prof_active:
-                        auto_prof.trigger(anom["kind"], s + 1)
-                if rb is not None:
-                    # rollback triggers, evaluated per flushed step:
-                    # sustained divergence (the detector's escalated
-                    # kind), a streak of skipped/nonfinite steps, or a
-                    # nonfinite loss with the skip guard OFF (params
-                    # already poisoned — waiting is pointless). A
-                    # single skipped step or one-off loss_spike never
-                    # triggers: that is the guard/winsorizer working.
-                    # `suppressed` (set by a FAILED rollback) holds
-                    # triggers until a clean step ends the episode —
-                    # without it a checkpoint-less NaN run would emit
-                    # one ok=false rollback + a full lineage CRC walk
-                    # per step forever (stream-sizing rule).
-                    bad = (int(m.get("skipped") or 0) > 0
-                           or not math.isfinite(loss))
-                    rb["streak"] = rb["streak"] + 1 if bad else 0
-                    if not bad:
-                        rb["suppressed"] = False
-                    if rb["due"] is not None or rb["suppressed"]:
-                        pass
-                    elif anom is not None \
-                            and anom["kind"] == "divergence":
-                        rb["due"] = ("divergence", s + 1)
-                    elif rb["streak"] >= rb["skip_streak"]:
-                        rb["due"] = ("skip_streak", s + 1)
-                    elif (not math.isfinite(loss)
-                          and not tc.skip_nonfinite):
-                        rb["due"] = ("nonfinite_loss", s + 1)
-                if metrics_csv:
-                    metrics_csv.log(epoch=ep, step=s + 1, loss=loss,
-                                    avg_loss=avg, lr=float(m["lr"]),
-                                    grad_norm=float(m["grad_norm"]),
-                                    step_time_ms=dt_ms, host_wait_ms=wait_ms,
-                                    tok_s=toks / (dt_ms / 1000), mfu=mfu,
-                                    hbm_mb=hbm if hbm is not None else 0.0)
-            s, ep, toks, _ = buffered[-1]
-            m = fetched[-1]
-            opt_f = lambda k: (float(m[k]) if k in m else None)
-            tel.emit(
-                "step_stats", step=s + 1, loss=float(m["loss"]),
-                # graftlint: disable=sync-hazard(ema is the host-side spike detector's Python scalar, not a device array)
-                ema=float(ema.value), lr=float(m["lr"]),
-                grad_norm=float(m["grad_norm"]), step_time_ms=dt_ms,
-                host_wait_ms=wait_ms, slept_ms=slept_ms,
-                tok_s=toks / (dt_ms / 1000), mfu=mfu,
-                param_norm=opt_f("param_norm"),
-                update_ratio=opt_f("update_ratio"),
-                nonfinite_count=(int(m["nonfinite_count"])
-                                 if "nonfinite_count" in m else None),
-                # COUNT over the flush interval (unlike the last-step
-                # health scalars): the report's skipped-step total is a
-                # sum of these, so no skip can fall between flushes
-                skipped=(sum(int(fm["skipped"]) for fm in fetched)
-                         if "skipped" in m else None),
-                hbm_mb=hbm, queue_depth=stream.queue_depth(),
-                host_step_ms=host_step_ms["latest"])
-            if emit_log and args.log_interval:
-                log.info(
-                    f"step {s + 1}/{total_steps} loss={float(m['loss']):.4f} "
-                    f"ema={ema.value:.4f} "
-                    f"ppl={perplexity_from_loss(float(m['loss'])):.2f} "
-                    f"grad_norm={float(m['grad_norm']):.3f} "
-                    f"lr={float(m['lr']):.2e} "
-                    f"{toks / (dt_ms / 1000):.0f} tok/s "
-                    + (f"mfu={mfu:.3f} " if mfu is not None else "")
-                    + f"host_wait={wait_ms:.1f}ms")
+            nonlocal t_interval, slept_ms, waited_ms, drain, drained
+            with tracer.span("train.flush.sync", "loop"):
+                # graftlint: disable=sync-hazard(the zero-sync contract: ONE device_get per metrics flush, DESIGN.md section 13)
+                fetched = jax.device_get([m for _, _, _, m in buffered])
+            t_sync = time.perf_counter()
+            with tracer.span("train.flush.sensors", "loop"):
+                dt_ms = ((t_sync - t_interval) * 1000 - slept_ms) \
+                    / len(buffered)
+                wait_ms = waited_ms / len(buffered)
+                # the device_get above SYNCED the interval, so dt_ms is the
+                # honest per-step time (a per-iteration clock under async
+                # dispatch measures only enqueue latency): feed the fleet
+                # timing consumers — the straggler window and the watchdog's
+                # deadline median — from here, the same number step_stats
+                # publishes
+                prior_n, prior_med = step_clock.n, step_clock.median_ms()
+                step_clock.record(dt_ms / 1000.0)
+                if wd is not None:
+                    wd.pet(buffered[-1][0], dt_ms / 1000.0)
+                # slow-step flight-recorder trigger (--auto_profile): this
+                # flush interval ran a multiple of the rolling median —
+                # arm a capture over the NEXT steps while whatever made it
+                # slow is plausibly still happening. The median is the
+                # PRIOR window's (the slow sample must not judge itself),
+                # and the manual --profile_dir window keeps priority.
+                if (auto_prof is not None and slow_mult > 0 and prior_n >= 3
+                        and prior_med > 0 and dt_ms > slow_mult * prior_med
+                        and not prof_active):
+                    if auto_prof.trigger("slow_step", buffered[-1][0] + 1):
+                        log.warning(
+                            f"auto_profile: step time {dt_ms:.1f} ms > "
+                            f"{slow_mult:g}x rolling median "
+                            f"{prior_med:.1f} ms — capturing "
+                            f"{auto_prof.steps} step(s)")
+                # live bytes when the backend reports them, else the
+                # compiled-peak estimate, else NULL — a backend with no
+                # memory accounting must not masquerade as 0 MB (round 16;
+                # live_hbm_mb logs the backend once)
+                hbm = live_hbm_mb()
+                if hbm is None:
+                    hbm = peak_hbm["mb"] or None
+                mfu = mfu_from(flops_per_step, dt_ms / 1000, peak_flops)
+                for (s, ep, toks, _), m in zip(buffered, fetched):
+                    loss = float(m["loss"])
+                    avg = ema.update(loss)
+                    anom = spikes.update(loss)
+                    if anom is not None:
+                        tel.emit("anomaly", step=s + 1, loss=loss, ema=avg,
+                                 **anom)
+                        log.warning(
+                            f"anomaly @ step {s + 1}: {anom['kind']} "
+                            f"loss={loss:.4f}"
+                            + (f" z={anom['zscore']}" if anom["zscore"]
+                               else ""))
+                        if auto_prof is not None and not prof_active:
+                            auto_prof.trigger(anom["kind"], s + 1)
+                    if rb is not None:
+                        # rollback triggers, evaluated per flushed step:
+                        # sustained divergence (the detector's escalated
+                        # kind), a streak of skipped/nonfinite steps, or a
+                        # nonfinite loss with the skip guard OFF (params
+                        # already poisoned — waiting is pointless). A
+                        # single skipped step or one-off loss_spike never
+                        # triggers: that is the guard/winsorizer working.
+                        # `suppressed` (set by a FAILED rollback) holds
+                        # triggers until a clean step ends the episode —
+                        # without it a checkpoint-less NaN run would emit
+                        # one ok=false rollback + a full lineage CRC walk
+                        # per step forever (stream-sizing rule).
+                        bad = (int(m.get("skipped") or 0) > 0
+                               or not math.isfinite(loss))
+                        rb["streak"] = rb["streak"] + 1 if bad else 0
+                        if not bad:
+                            rb["suppressed"] = False
+                        if rb["due"] is not None or rb["suppressed"]:
+                            pass
+                        elif anom is not None \
+                                and anom["kind"] == "divergence":
+                            rb["due"] = ("divergence", s + 1)
+                        elif rb["streak"] >= rb["skip_streak"]:
+                            rb["due"] = ("skip_streak", s + 1)
+                        elif (not math.isfinite(loss)
+                              and not tc.skip_nonfinite):
+                            rb["due"] = ("nonfinite_loss", s + 1)
+                    if metrics_csv:
+                        metrics_csv.log(epoch=ep, step=s + 1, loss=loss,
+                                        avg_loss=avg, lr=float(m["lr"]),
+                                        grad_norm=float(m["grad_norm"]),
+                                        step_time_ms=dt_ms,
+                                        host_wait_ms=wait_ms,
+                                        tok_s=toks / (dt_ms / 1000), mfu=mfu,
+                                        hbm_mb=hbm if hbm is not None else 0.0)
+                s, ep, toks, _ = buffered[-1]
+                m = fetched[-1]
+                opt_f = lambda k: (float(m[k]) if k in m else None)
+            t_sensors = time.perf_counter()
+            with tracer.span("train.flush.emit", "loop"):
+                tel.emit(
+                    "step_stats", step=s + 1, loss=float(m["loss"]),
+                    # graftlint: disable=sync-hazard(ema is the host-side spike detector's Python scalar, not a device array)
+                    ema=float(ema.value), lr=float(m["lr"]),
+                    grad_norm=float(m["grad_norm"]), step_time_ms=dt_ms,
+                    host_wait_ms=wait_ms, slept_ms=slept_ms,
+                    tok_s=toks / (dt_ms / 1000), mfu=mfu,
+                    param_norm=opt_f("param_norm"),
+                    update_ratio=opt_f("update_ratio"),
+                    nonfinite_count=(int(m["nonfinite_count"])
+                                     if "nonfinite_count" in m else None),
+                    # COUNT over the flush interval (unlike the last-step
+                    # health scalars): the report's skipped-step total is a
+                    # sum of these, so no skip can fall between flushes
+                    skipped=(sum(int(fm["skipped"]) for fm in fetched)
+                             if "skipped" in m else None),
+                    hbm_mb=hbm, queue_depth=stream.queue_depth(),
+                    host_step_ms=host_step_ms["latest"],
+                    # the PREVIOUS flush's drained period (a line cannot
+                    # hold the time of its own write)
+                    drained_ms=drained[0], drained_parts_ms=drained[1])
+            t_emit = time.perf_counter()
+            with tracer.span("train.flush.log", "loop"):
+                if emit_log and args.log_interval:
+                    log.info(
+                        f"step {s + 1}/{total_steps} "
+                        f"loss={float(m['loss']):.4f} "
+                        f"ema={ema.value:.4f} "
+                        f"ppl={perplexity_from_loss(float(m['loss'])):.2f} "
+                        f"grad_norm={float(m['grad_norm']):.3f} "
+                        f"lr={float(m['lr']):.2e} "
+                        f"{toks / (dt_ms / 1000):.0f} tok/s "
+                        + (f"mfu={mfu:.3f} " if mfu is not None else "")
+                        + f"host_wait={wait_ms:.1f}ms")
             buffered.clear()
             slept_ms = 0.0
             waited_ms = 0.0
             t_interval = time.perf_counter()
+            # the device has been drained since t_sync; the loop closes
+            # this period after the next dispatch (drained_record)
+            drain, drained = (t_sync, t_sensors, t_emit, t_interval), \
+                (None, None)
+
+        def flush_metrics(emit_log=True):
+            """Flush what is buffered (flush_buffered), as one
+            `train.flush` span; nothing buffered, nothing done."""
+            if buffered:
+                with tracer.span("train.flush", "loop"):
+                    flush_buffered(emit_log)
 
         def attempt_rollback(reason: str, at_step: int):
             """Close the sensors→recovery loop IN PROCESS (DESIGN.md
@@ -2182,9 +2239,11 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                 # dependency, and the time it blocks is the host/device
                 # breakdown's host_wait_ms
                 meter.enter("input_wait")
-                t_wait = time.perf_counter()
-                step_i, epoch, batch = next(stream)
-                waited_ms += (time.perf_counter() - t_wait) * 1000
+                with tracer.span("train.input_wait", "loop"):
+                    t_wait = time.perf_counter()
+                    step_i, epoch, batch = next(stream)
+                    t_input = time.perf_counter()
+                waited_ms += (t_input - t_wait) * 1000
                 meter.enter("step")
                 assert step_i == step  # strict order preservation
                 maybe_profile(step)
@@ -2196,20 +2255,34 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                 # degradation ladder and retries the SAME batch (the
                 # batch is not donated — only trainable/opt are, and
                 # recover_dispatch_oom restores those)
-                while True:
-                    try:
-                        if injector.kind == "hbm_pressure":
-                            injector.maybe_oom_dispatch(step)
-                        trainable, opt_state, metrics = compiled_step(
-                            trainable, frozen, opt_state, batch,
-                            jnp.int32(step))
-                        break
-                    except (KeyboardInterrupt, SystemExit):
-                        raise
-                    except Exception as e:
-                        if not mg.is_resource_exhausted(e):
+                if drain is not None:
+                    t_dispatch = time.perf_counter()
+                with tracer.span("train.dispatch", "loop"):
+                    while True:
+                        try:
+                            if injector.kind == "hbm_pressure":
+                                injector.maybe_oom_dispatch(step)
+                            trainable, opt_state, metrics = compiled_step(
+                                trainable, frozen, opt_state, batch,
+                                jnp.int32(step))
+                            break
+                        except (KeyboardInterrupt, SystemExit):
                             raise
-                        recover_dispatch_oom(e, step)
+                        except Exception as e:
+                            if not mg.is_resource_exhausted(e):
+                                raise
+                            recover_dispatch_oom(e, step)
+                if drain is not None:
+                    # the first dispatch after a flush: the device has
+                    # work again, the drained period is closed. Whatever
+                    # restarted the flush interval since (an eval, a
+                    # save, a rollback: "that time is not step time")
+                    # owns the time in between: no record then
+                    if drain[-1] == t_interval:
+                        drained = drained_record(
+                            drain, t_wait, t_input, t_dispatch,
+                            time.perf_counter())
+                    drain = None
                 oom_snap = None  # a retired step ends the retry window
                 toks = batch["input_ids"].shape[0] * batch["input_ids"].shape[1]
                 buffered.append((step, epoch, toks, metrics))
@@ -2262,7 +2335,8 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                         and (step + 1) % args.eval_interval == 0):
                     flush_metrics(emit_log=False)  # off-cadence boundary flush
                     meter.enter("eval")
-                    with pause():  # an eval may exceed any step deadline
+                    # pause(): an eval may exceed any step deadline
+                    with tracer.span("train.eval", "loop"), pause():
                         ev = evaluate(eval_step, trainable, frozen,
                                       valid_ds, args.eval_batches,
                                       mesh=eval_mesh,
@@ -2291,7 +2365,8 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                     # event (with the snapshot/write split) is emitted
                     # by the checkpointer when the write completes.
                     meter.enter("checkpoint")
-                    with pause():  # a slow save is not a hang
+                    # pause(): a slow save is not a hang
+                    with tracer.span("train.checkpoint", "loop"), pause():
                         save_hook(step + 1, trainable, opt_state,
                                   final=False, ckpt=ckpt)
                     meter.enter("step")
@@ -2307,7 +2382,8 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                         injector.maybe_corrupt_ckpt(ckpt_path)
 
                 meter.enter("governor_sleep")
-                slept_ms += governor.throttle(step)
+                with tracer.span("train.governor_sleep", "loop"):
+                    slept_ms += governor.throttle(step)
                 meter.enter("step")
                 done_steps = step + 1 - start_step
                 cur_step["step"] = step + 1
@@ -2333,7 +2409,9 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                              signal=guard.signal_name or "SIGTERM")
                     if save_hook is not None:
                         meter.enter("checkpoint")
-                        with pause():  # a slow drain save is not a hang
+                        # pause(): a slow drain save is not a hang
+                        with tracer.span("train.checkpoint", "loop"), \
+                                pause():
                             save_hook(step + 1, trainable, opt_state,
                                       final=True, ckpt=ckpt)
                     meter.enter("shutdown")
@@ -2383,7 +2461,7 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
             # step loop. Closing here makes the trace land on EVERY exit
             # path (regression: tests/test_cli.py short-run profile test).
             if prof_active:
-                maybe_profile(prof_end)
+                maybe_profile(step, closing=True)
 
         # the post-loop tail (final flush/eval/save) carries the same
         # run_end-on-exception contract as the loop: a disk-full save or a
@@ -2393,7 +2471,8 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
             flush_metrics()
             if valid_ds is not None and args.eval_interval:
                 meter.enter("eval")
-                with pause():  # unbounded legitimate pause
+                # pause(): unbounded legitimate pause
+                with tracer.span("train.eval", "loop"), pause():
                     ev = evaluate(eval_step, trainable, frozen, valid_ds,
                                   args.eval_batches, mesh=eval_mesh,
                                   sequence_parallel=eval_sp,
@@ -2414,7 +2493,7 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                 # disk, so this blocking span (snapshot + any queued
                 # writes) honestly lands in the checkpoint bucket
                 meter.enter("checkpoint")
-                with pause():
+                with tracer.span("train.checkpoint", "loop"), pause():
                     save_hook(total_steps, trainable, opt_state,
                               final=True, ckpt=ckpt)
                 meter.enter("shutdown")

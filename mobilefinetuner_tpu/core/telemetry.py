@@ -50,6 +50,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from mobilefinetuner_tpu.core.trace import NULL_TRACER
+
 # --------------------------- event taxonomy ---------------------------------
 
 _NUM = (int, float)
@@ -111,6 +113,18 @@ EVENT_SCHEMA: Dict[str, Dict[str, tuple]] = {
         "host_step_ms": (dict, type(None)),  # {host: per-step ms} from the
                                     # last straggler-cadence gather; None
                                     # when --straggler_cadence is off
+        # the flush counter (optional on read: older streams). A flush
+        # fetches the NEWEST step, so the device has nothing queued from
+        # the device_get's return until the next dispatch returns:
+        # drained_ms is that host time for the PREVIOUS flush (a line
+        # cannot hold the time of its own write), drained_parts_ms its
+        # split {sensors, emit, log, loop_tail, input_wait, dispatch}
+        # at the boundaries of the train.flush.* / train.input_wait /
+        # train.dispatch spans; the parts sum to drained_ms. None on
+        # the first flush and where an eval, a save or a rollback
+        # restarted the flush interval in between (that time is theirs).
+        "drained_ms": _OPT_NUM,
+        "drained_parts_ms": (dict, type(None)),
         "tenants": (dict, type(None)),  # round-18 multi-tenant engine:
                                     # {name: {slot, step, loss, tokens,
                                     # wait_ms}} per resident adapter job
@@ -499,7 +513,8 @@ RUN_PHASES = ("start", "end")
 # ABSENCE so pre-fleet (round-8) streams still validate and render —
 # when present they are type-checked as usual.
 OPTIONAL_FIELDS: Dict[str, frozenset] = {
-    "step_stats": frozenset({"host_step_ms", "skipped", "tenants"}),
+    "step_stats": frozenset({"host_step_ms", "skipped", "tenants",
+                             "drained_ms", "drained_parts_ms"}),
     "serve_stats": frozenset({"hbm_mb", "pool_mb", "mesh",
                               "prefix_hit_rate", "cow_copies",
                               "blocks_in_use"}),
@@ -982,13 +997,15 @@ class GoodputMeter:
     acceptance criterion's within-1% identity is structural, not
     approximate). `summary()` is the run_end `goodput` payload.
 
-    With a `tracer` (core/trace.py, --trace_spans) every phase SEGMENT
-    additionally lands as a `span` event on the "phase" track — the
-    same transition that charges the bucket emits the span, so the
-    exported timeline's per-bucket span sums reconcile with run_end's
-    goodput buckets by construction (trace_export prints the check)."""
+    Under --trace_spans (a `tracer` whose JSONL sink is on,
+    core/trace.py) every phase SEGMENT additionally lands as a `span`
+    event on the "phase" track — the same transition that charges the
+    bucket emits the span, so the exported timeline's per-bucket span
+    sums reconcile with run_end's goodput buckets by construction
+    (trace_export prints the check). Bookkeeping over stored stamps:
+    JSONL only, the profiler's trace gets the loop's own spans."""
 
-    def __init__(self, tracer=None):
+    def __init__(self, tracer=NULL_TRACER):
         self.buckets = {b: 0.0 for b in GOODPUT_BUCKETS}
         self._phase = "init"
         self._mark = time.perf_counter()
@@ -1002,9 +1019,8 @@ class GoodputMeter:
         assert phase in self.buckets, phase
         now = time.perf_counter()
         self.buckets[self._phase] += now - self._mark
-        if self._tracer is not None:
-            self._tracer.emit_span(self._phase, "phase", self._mark,
-                                   (now - self._mark) * 1000.0)
+        self._tracer.emit_span(self._phase, "phase", self._mark,
+                               (now - self._mark) * 1000.0)
         self._mark = now
         self._phase = phase
 

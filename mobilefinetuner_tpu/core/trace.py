@@ -1,27 +1,39 @@
-"""Host-span tracing + anomaly-triggered profiler capture (DESIGN.md §22).
+"""Program spans + anomaly-triggered profiler capture (DESIGN.md §22).
 
 The telemetry stream (core/telemetry.py) records WHAT happened; this
-module records WHEN, precisely enough to draw: `span` events carry a
-monotonic begin stamp and a duration on a named TRACK, so a whole run —
-the GoodputMeter's exclusive phases, the serve loop's per-request
-queue/prefill/decode lifecycle, the async-checkpoint writer thread, the
-prefetch producer — renders as one timeline in ui.perfetto.dev after
-`tools/trace_export.py` converts the stream. Spans ride the SAME
-crash-durable JSONL stream as every other event (one `span` record per
-completed span, emitted at span END), so a killed run keeps every span
-that finished before the kill and the exporter needs no second file.
+module records WHEN. `Tracer.span` is the repo's ONE span primitive and
+it has two sinks:
 
-Clock discipline: span `t0` uses time.perf_counter() — the same
-monotonic clock the telemetry envelope's `t_mono` stamp uses — so the
-exporter can place spans on the wall-clock timeline via the per-host
-(t - t_mono) offset without NTP-step jitter corrupting durations.
+  - the profiler's own trace. Every lexical span is also a
+    `jax.profiler.TraceAnnotation`, so whenever a profiler session is
+    live (--profile_dir, the AutoProfiler below, the benchmark's
+    --trace 1) the span sits in the `.xplane.pb` host plane, on the
+    line of the thread that ran it and on the DEVICE trace's clock:
+    an idle gap on the chip is attributed to the phase the host was in
+    by reading one file. With no session the annotation is TraceMe's
+    inactive check (a fraction of a microsecond), so the spans stay in
+    the train loop and the serve loop always.
+  - the telemetry JSONL, OPT-IN (--trace_spans /
+    ServeConfig.trace_spans): one `span` record per completed span,
+    emitted at span END on a named TRACK, which tools/trace_export.py
+    converts to one Perfetto timeline. `Tracer.enabled` gates this sink
+    ONLY; while it is off a span formats no string, builds no record
+    and reads no clock.
 
-Span emission is OPT-IN (--trace_spans / ServeConfig.trace_spans): a
-traced step loop emits a handful of spans per step, which is exactly
-what you want while looking at a problem and more than you want in a
-month-long stream. Everything here is host-side: no device access, no
-jax import on the Tracer path (the zero-sync invariant extends to the
-trace layer — tests pin it structurally).
+`emit_span` stays for spans that are pure bookkeeping over stamps the
+caller already holds (the GoodputMeter's phase track, the serve
+engine's `req:<id>` lifecycle): those are JSONL-only. Spans ride the
+SAME crash-durable stream as every other event, so a killed run keeps
+every span that finished before the kill.
+
+Clock discipline: a JSONL span's `t0` is time.perf_counter() — the
+clock of the envelope's `t_mono` — so the exporter places spans on the
+wall timeline via the per-host (t - t_mono) offset without NTP-step
+jitter corrupting durations.
+
+Everything here is host-side: no device access, no top-level jax
+import (core/static_checks.py pins it); `jax.profiler` is bound on the
+first span.
 
 The second half is the flight recorder (`AutoProfiler`, --auto_profile):
 a manually pre-scheduled --profile_dir window is useless for the
@@ -36,23 +48,63 @@ Every capture decision is a `profile_capture` telemetry event.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
 from typing import Callable, Optional
 
+_annotation: Optional[Callable] = None  # bound by _bind_annotation
+
+
+def _bind_annotation() -> Callable:
+    """`jax.profiler.TraceAnnotation`, bound on the first span: this
+    module may not import jax at load (core/static_checks.py), and an
+    annotation touches no device."""
+    global _annotation
+    import jax
+    _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+class _Span:
+    """One lexical span (`Tracer.span`): a TraceAnnotation always, a
+    `span` record on exit while the tracer's JSONL sink is on."""
+
+    __slots__ = ("_tracer", "_name", "_track", "_extra", "_ann", "_t0")
+
+    def __init__(self, tracer, name, track, extra):
+        self._tracer, self._name = tracer, name
+        self._track, self._extra = track, extra
+
+    def __enter__(self):
+        self._ann = (_annotation or _bind_annotation())(self._name)
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)    # first: writing the record is not
+        if self._tracer.enabled:    # part of what the span measures
+            self._tracer.emit_span(
+                self._name, self._track, self._t0,
+                (time.perf_counter() - self._t0) * 1000.0, **self._extra)
+        return False
+
 
 class Tracer:
-    """Span emitter over a telemetry sink (`Telemetry.emit` signature).
+    """The span primitive over a telemetry sink (`Telemetry.emit`
+    signature).
 
-    One emit site for the whole repo: every producer — the goodput
-    meter, the serve engine, the checkpoint writer, the prefetch
-    producer — routes through `emit_span`, so the `span` event shape
-    cannot fork between threads or subsystems. Thread-safety is the
-    sink's problem (Telemetry.emit is lock-serialized), which is what
-    lets the checkpoint writer and prefetch producer threads trace
-    through the same stream as the step loop.
+    One emit site for the whole repo: every producer — the train loop,
+    the serve loop, the goodput meter, the checkpoint writer, the
+    prefetch producer — routes through `span` / `emit_span`, so the
+    `span` event shape cannot fork between threads or subsystems.
+    Thread-safety is the sink's problem (Telemetry.emit is
+    lock-serialized), which is what lets the checkpoint writer and
+    prefetch producer threads trace through the same stream as the
+    step loop. `enabled` gates the JSONL sink only: a Tracer with no
+    sink still annotates the profiler's trace.
     """
 
     def __init__(self, sink: Optional[Callable] = None,
@@ -70,16 +122,17 @@ class Tracer:
         self._sink(event="span", name=name, track=track,
                    t0=round(t0, 6), dur_ms=round(dur_ms, 3), **extra)
 
-    @contextlib.contextmanager
-    def span(self, name: str, track: str = "main", **extra):
-        """Lexical span: emits on exit, exception or not (the span that
-        raised is exactly the one a post-mortem wants on the timeline)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.emit_span(name, track, t0,
-                           (time.perf_counter() - t0) * 1000.0, **extra)
+    def span(self, name: str, track: str = "main", **extra) -> _Span:
+        """Lexical span: in the profiler's trace whenever a session is
+        live, and a `span` record on exit while `enabled` — exception
+        or not (the span that raised is exactly the one a post-mortem
+        wants on the timeline)."""
+        return _Span(self, name, track, extra)
+
+
+#: the default of every component that takes a tracer: no JSONL sink,
+#: so its spans reach only the profiler's trace (it holds no state)
+NULL_TRACER = Tracer()
 
 
 class AutoProfiler:

@@ -42,6 +42,8 @@ import queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, Optional
 
+from mobilefinetuner_tpu.core.trace import NULL_TRACER
+
 _DONE = object()
 
 # lock-discipline declaration (core/static_checks.py, DESIGN.md §24):
@@ -93,15 +95,15 @@ class Prefetcher:
                  place_fn: Optional[Callable[[Any], Any]] = None,
                  lookahead: int = 1, rss_limit_mb: float = 0,
                  rss_fn: Optional[Callable[[], Optional[float]]] = None,
-                 tracer=None):
+                 tracer=NULL_TRACER):
         if depth < 0:
             raise ValueError(f"depth must be >= 0, got {depth}")
-        # span tracing (core/trace.py, --trace_spans): each batch the
-        # producer thread assembles lands as a `span` on the "prefetch"
-        # track, so the exported timeline shows host batch assembly
-        # overlapping device steps — the overlap IS this module's
-        # claim, and the trace makes it visible instead of inferred
-        # from host_wait_ms
+        # each batch the producer thread assembles is a
+        # `prefetch.produce` span (core/trace.py) on the producer's own
+        # line of a profiler trace — and on the "prefetch" track of the
+        # stream under --trace_spans — so host batch assembly is SEEN
+        # overlapping device steps (the overlap is this module's claim)
+        # instead of inferred from host_wait_ms
         self._tracer = tracer
         self._place = place_fn if place_fn is not None else (lambda x: x)
         self._lookahead = max(lookahead, 0) if depth > 0 else 0
@@ -179,24 +181,17 @@ class Prefetcher:
 
     def _produce(self, source):
         try:
-            import time as _time
             it = iter(source)
-            n = 0
             while True:
                 self._shed_on_rss()
                 if self._stop.is_set():
                     return
-                t0 = _time.perf_counter()
                 try:
-                    item = next(it)
+                    with self._tracer.span("prefetch.produce", "prefetch"):
+                        item = next(it)
                 except StopIteration:
                     self._put(_DONE)
                     return
-                if self._tracer is not None:
-                    self._tracer.emit_span(
-                        f"produce[{n}]", "prefetch", t0,
-                        (_time.perf_counter() - t0) * 1000.0)
-                n += 1
                 if not self._put(item):
                     return
         except BaseException as e:  # noqa: BLE001 — carried to the consumer

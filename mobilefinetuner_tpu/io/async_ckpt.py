@@ -52,6 +52,8 @@ from typing import Callable, Iterable, Optional
 
 import numpy as np
 
+from mobilefinetuner_tpu.core.trace import NULL_TRACER
+
 # lock-discipline declaration (core/static_checks.py, DESIGN.md §24):
 # the cross-thread contract graftlint enforces mechanically — every
 # guarded field may be touched only under the declared lock.
@@ -157,14 +159,15 @@ class AsyncCheckpointer:
     """
 
     def __init__(self, enabled: bool = True,
-                 event_sink: Optional[Callable] = None, tracer=None):
+                 event_sink: Optional[Callable] = None,
+                 tracer=NULL_TRACER):
         self.enabled = bool(enabled)
         self._sink = event_sink
-        # span tracing (core/trace.py, --trace_spans): each disk write
-        # lands as a `span` on the "ckpt" track — emitted from the
-        # writer THREAD, so the exported timeline shows the background
-        # write overlapping `step` time (the overlap is this module's
-        # whole point; the trace draws it)
+        # each disk write is a `ckpt.write` span (core/trace.py) taken
+        # on the writer THREAD: on that thread's line of a profiler
+        # trace, and on the "ckpt" track of the stream under
+        # --trace_spans, the background write is drawn overlapping
+        # `step` time (the overlap is this module's whole point)
         self._tracer = tracer
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -285,7 +288,8 @@ class AsyncCheckpointer:
     def _write(self, item: _SaveItem) -> None:
         t0 = time.perf_counter()
         try:
-            paths = list(item.write_fn() or ())
+            with self._tracer.span("ckpt.write", "ckpt", step=item.step):
+                paths = list(item.write_fn() or ())
         except BaseException as e:  # surfaced at the next save()/drain()
             with self._lock:
                 self._error = e
@@ -293,9 +297,6 @@ class AsyncCheckpointer:
         finally:
             item.done.set()
         write_ms = (time.perf_counter() - t0) * 1000.0
-        if self._tracer is not None:
-            self._tracer.emit_span(f"ckpt_write(step {item.step})",
-                                   "ckpt", t0, write_ms, step=item.step)
         nbytes = 0
         for p in paths:
             try:

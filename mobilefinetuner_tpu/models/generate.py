@@ -653,6 +653,41 @@ def gemma3_prefill(config: Gemma3TextConfig, params, input_ids,
     return logits.astype(jnp.float32), (pk, pv)
 
 
+def _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd):
+    """q, k, v [R, H, D] of the R rows `h` [R, E] (already layer-normed)
+    for the paged programs (decode step: R slots; prefill chunk: R
+    chunk rows), adapters applied."""
+    R, E = h.shape
+    qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
+    qkv = apply_lora(qkv, h, "attn_qkv", i)
+    if lora_b is not None:
+        from mobilefinetuner_tpu.lora.lora import GPT2_SPLIT_QKV_SLOTS
+        for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
+            if name in lora_b:
+                sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
+                qkv = qkv.at[sl].set(apply_lora(qkv[sl], h, name, i))
+    q, k, v = (z.reshape(R, H, E // H)
+               for z in jnp.split(qkv, 3, axis=-1))
+    if shd is not None:
+        q, k, v = shd.kv_rows(q), shd.kv_rows(k), shd.kv_rows(v)
+    return q, k, v
+
+
+def _gpt2_proj_mlp(x, ctx, bp, i, eps, apply_lora, shd):
+    """The rest of a GPT-2 block after attention, for the paged
+    programs: output projection, residual, MLP, residual."""
+    proj = ctx @ bp["attn"]["proj_w"] + bp["attn"]["proj_b"]
+    proj = apply_lora(proj, ctx, "attn_proj", i)
+    x = x + proj
+    h2 = gpt2.layer_norm(x, bp["ln_2"]["g"], bp["ln_2"]["b"], eps)
+    fc = h2 @ bp["mlp"]["fc_w"] + bp["mlp"]["fc_b"]
+    if shd is not None:
+        fc = shd.hidden(fc)
+    fc = gpt2.gelu_new(apply_lora(fc, h2, "mlp_fc_in", i))
+    out = fc @ bp["mlp"]["proj_w"] + bp["mlp"]["proj_b"]
+    return x + apply_lora(out, fc, "mlp_fc_out", i)
+
+
 def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
                        start, n_tok, tbl, lora=None,
                        compute_dtype=jnp.float32,
@@ -695,7 +730,8 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
     lora_b = None if lora is None else lora.get("blocks")
     cast = lambda t: (t.astype(compute_dtype)
                       if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb = jax.tree.map(cast, params["blocks"])
+    with jax.named_scope("decode_weight_cast"):
+        wb = jax.tree.map(cast, params["blocks"])
     shd = shardings
 
     rows = jnp.arange(W, dtype=jnp.int32)
@@ -724,73 +760,59 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
 
     def layer(x, inp):
         bp, i = inp
-        h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
-        qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
-        qkv = apply_lora(qkv, h, "attn_qkv", i)
-        if lora_b is not None:
-            from mobilefinetuner_tpu.lora.lora import GPT2_SPLIT_QKV_SLOTS
-            for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
-                if name in lora_b:
-                    sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
-                    qkv = qkv.at[sl].set(apply_lora(qkv[sl], h, name, i))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        hd = lambda z: z.reshape(W, H, D)
-        q, k, v = hd(q), hd(k), hd(v)
-        if shd is not None:
-            q, k, v = shd.kv_rows(q), shd.kv_rows(k), shd.kv_rows(v)
-        # pool-dtype roundtrip: within-chunk attention must read the
-        # same values the pages will hold, or chunked-vs-one-shot
-        # token parity drifts at low pool precision
-        kq = k.astype(pool_k.dtype)
-        vq = v.astype(pool_v.dtype)
-        # joint softmax over [prefix pages | chunk rows]: the pools
-        # are closed over READ-ONLY here (gather, never scatter), so
-        # they are not scan carries — dtype discipline mirrors
-        # ops.decode_attention.paged_attention
-        kc = pool_k[tbl[0], i]                    # [M, H, bT, D]
-        vc = pool_v[tbl[0], i]
-        s1 = jnp.einsum("whd,mhtd->whmt", q, kc,
-                        preferred_element_type=jnp.float32) * scale
-        s1 = jnp.where(pre_ok[None, None, :],
-                       s1.reshape(W, H, M * bT), NEG_INF)
-        s2 = jnp.einsum("whd,jhd->whj", q, kq,
-                        preferred_element_type=jnp.float32) * scale
-        s2 = jnp.where(causal[:, None, :], s2, NEG_INF)
-        p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-        ctx = jnp.einsum("whmt,mhtd->whd",
-                         p[..., :M * bT].reshape(W, H, M, bT)
-                         .astype(vc.dtype), vc,
-                         preferred_element_type=jnp.float32) \
-            + jnp.einsum("whj,jhd->whd",
-                         p[..., M * bT:].astype(vq.dtype), vq,
-                         preferred_element_type=jnp.float32)
+        with jax.named_scope("decode_proj_mlp"):
+            h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
+            q, k, v = _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd)
+            # pool-dtype roundtrip: within-chunk attention must read the
+            # same values the pages will hold, or chunked-vs-one-shot
+            # token parity drifts at low pool precision
+            kq = k.astype(pool_k.dtype)
+            vq = v.astype(pool_v.dtype)
+        with jax.named_scope("decode_paged_attn"):
+            # joint softmax over [prefix pages | chunk rows]: the pools
+            # are closed over READ-ONLY here (gather, never scatter), so
+            # they are not scan carries — dtype discipline mirrors
+            # ops.decode_attention.paged_attention
+            kc = pool_k[tbl[0], i]                    # [M, H, bT, D]
+            vc = pool_v[tbl[0], i]
+            s1 = jnp.einsum("whd,mhtd->whmt", q, kc,
+                            preferred_element_type=jnp.float32) * scale
+            s1 = jnp.where(pre_ok[None, None, :],
+                           s1.reshape(W, H, M * bT), NEG_INF)
+            s2 = jnp.einsum("whd,jhd->whj", q, kq,
+                            preferred_element_type=jnp.float32) * scale
+            s2 = jnp.where(causal[:, None, :], s2, NEG_INF)
+            p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
+            ctx = jnp.einsum("whmt,mhtd->whd",
+                             p[..., :M * bT].reshape(W, H, M, bT)
+                             .astype(vc.dtype), vc,
+                             preferred_element_type=jnp.float32) \
+                + jnp.einsum("whj,jhd->whd",
+                             p[..., M * bT:].astype(vq.dtype), vq,
+                             preferred_element_type=jnp.float32)
         if shd is not None:
             ctx = shd.heads4(ctx[:, :, None, :]).reshape(W, H, D)
-        ctx = ctx.reshape(W, E).astype(compute_dtype)
-        proj = ctx @ bp["attn"]["proj_w"] + bp["attn"]["proj_b"]
-        proj = apply_lora(proj, ctx, "attn_proj", i)
-        x = x + proj
-        h2 = gpt2.layer_norm(x, bp["ln_2"]["g"], bp["ln_2"]["b"], eps)
-        fc = h2 @ bp["mlp"]["fc_w"] + bp["mlp"]["fc_b"]
-        if shd is not None:
-            fc = shd.hidden(fc)
-        fc = gpt2.gelu_new(apply_lora(fc, h2, "mlp_fc_in", i))
-        out = fc @ bp["mlp"]["proj_w"] + bp["mlp"]["proj_b"]
-        out = apply_lora(out, fc, "mlp_fc_out", i)
-        return x + out, (kq, vq)
+        with jax.named_scope("decode_proj_mlp"):
+            x = _gpt2_proj_mlp(x, ctx.reshape(W, E).astype(compute_dtype),
+                               bp, i, eps, apply_lora, shd)
+        return x, (kq, vq)
 
     x, (ks, vs) = jax.lax.scan(
         layer, x, (wb, jnp.arange(L, dtype=jnp.int32)))
     # one scatter for all L layers' chunk rows (padded rows -> trash):
     # [L, W, H, D] -> assignment shape [W, L, H, D]
-    pool_k = pool_k.at[blk, :, :, off, :].set(ks.transpose(1, 0, 2, 3))
-    pool_v = pool_v.at[blk, :, :, off, :].set(vs.transpose(1, 0, 2, 3))
-    x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
-                        params["ln_f"]["b"].astype(compute_dtype), eps)
-    last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
-                                        keepdims=True)        # [1, E]
-    logits = last @ params["wte"].astype(compute_dtype).T
-    logits = _head_lora(logits, last, lora_b, lora_impl)
+    with jax.named_scope("decode_kv_write"):
+        pool_k = pool_k.at[blk, :, :, off, :].set(
+            ks.transpose(1, 0, 2, 3))
+        pool_v = pool_v.at[blk, :, :, off, :].set(
+            vs.transpose(1, 0, 2, 3))
+    with jax.named_scope("decode_head"):
+        x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
+                            params["ln_f"]["b"].astype(compute_dtype), eps)
+        last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
+                                            keepdims=True)    # [1, E]
+        logits = last @ params["wte"].astype(compute_dtype).T
+        logits = _head_lora(logits, last, lora_b, lora_impl)
     return logits.astype(jnp.float32), pool_k, pool_v
 
 
@@ -820,7 +842,8 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
     lora_b = None if lora is None else lora.get("blocks")
     cast = lambda t: (t.astype(compute_dtype)
                       if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb = jax.tree.map(cast, params["blocks"])
+    with jax.named_scope("decode_weight_cast"):
+        wb = jax.tree.map(cast, params["blocks"])
     is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
     normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
     shd = shardings
@@ -849,70 +872,78 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
 
     def layer(x, inp):
         bp, glob, i = inp
-        a = bp["attn"]
-        h = gemma3.rms_norm(x, bp["input_ln"], eps)
-        q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(W, nq, D)
-        k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(W, KV, D)
-        v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(W, KV, D)
-        q = gemma3.rms_norm(q, a["q_norm"], eps)
-        k = gemma3.rms_norm(k, a["k_norm"], eps)
-        cos = jnp.where(glob, cos_g, cos_l)
-        sin = jnp.where(glob, sin_g, sin_l)
-        q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
-        k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
-        if shd is not None:
-            k, v = shd.kv_rows(k), shd.kv_rows(v)
-        kq = k.astype(pool_k.dtype)               # pool-dtype roundtrip
-        vq = v.astype(pool_v.dtype)
-        ok1 = jnp.where(glob, pre_valid, pre_valid & win_ok)
-        ok2 = jnp.where(glob, causal, causal & win_in)        # [W, W]
-        q4 = q.reshape(W, KV, G, D)
-        if shd is not None:
-            q4 = shd.heads4(q4)
-        kc = pool_k[tbl[0], i]                    # [M, KV, bT, D]
-        vc = pool_v[tbl[0], i]
-        s1 = jnp.einsum("wkgd,mktd->wkgmt", q4, kc,
-                        preferred_element_type=jnp.float32) * scale
-        s1 = jnp.where(ok1[:, None, None, :],
-                       s1.reshape(W, KV, G, M * bT), NEG_INF)
-        s2 = jnp.einsum("wkgd,jkd->wkgj", q4, kq,
-                        preferred_element_type=jnp.float32) * scale
-        s2 = jnp.where(ok2[:, None, None, :], s2, NEG_INF)
-        p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-        ctx = jnp.einsum("wkgmt,mktd->wkgd",
-                         p[..., :M * bT].reshape(W, KV, G, M, bT)
-                         .astype(vc.dtype), vc,
-                         preferred_element_type=jnp.float32) \
-            + jnp.einsum("wkgj,jkd->wkgd",
-                         p[..., M * bT:].astype(vq.dtype), vq,
-                         preferred_element_type=jnp.float32)
-        if shd is not None:
-            ctx = shd.heads4(ctx)
-        ctx = ctx.reshape(W, nq * D).astype(compute_dtype)
-        attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
-        attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
-        x = x + attn_out
-        h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
-        act = gemma3.gelu_tanh(
-            apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
-            * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
-        if shd is not None:
-            act = shd.hidden(act)
-        down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
-        down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
-        return x + down, (kq, vq)
+        with jax.named_scope("decode_proj_mlp"):
+            a = bp["attn"]
+            h = gemma3.rms_norm(x, bp["input_ln"], eps)
+            q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(W, nq, D)
+            k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(W, KV, D)
+            v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(W, KV, D)
+            q = gemma3.rms_norm(q, a["q_norm"], eps)
+            k = gemma3.rms_norm(k, a["k_norm"], eps)
+            cos = jnp.where(glob, cos_g, cos_l)
+            sin = jnp.where(glob, sin_g, sin_l)
+            q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
+            k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
+            if shd is not None:
+                k, v = shd.kv_rows(k), shd.kv_rows(v)
+            kq = k.astype(pool_k.dtype)               # pool-dtype roundtrip
+            vq = v.astype(pool_v.dtype)
+        with jax.named_scope("decode_paged_attn"):
+            ok1 = jnp.where(glob, pre_valid, pre_valid & win_ok)
+            ok2 = jnp.where(glob, causal, causal & win_in)        # [W, W]
+            q4 = q.reshape(W, KV, G, D)
+            if shd is not None:
+                q4 = shd.heads4(q4)
+            kc = pool_k[tbl[0], i]                    # [M, KV, bT, D]
+            vc = pool_v[tbl[0], i]
+            s1 = jnp.einsum("wkgd,mktd->wkgmt", q4, kc,
+                            preferred_element_type=jnp.float32) * scale
+            s1 = jnp.where(ok1[:, None, None, :],
+                           s1.reshape(W, KV, G, M * bT), NEG_INF)
+            s2 = jnp.einsum("wkgd,jkd->wkgj", q4, kq,
+                            preferred_element_type=jnp.float32) * scale
+            s2 = jnp.where(ok2[:, None, None, :], s2, NEG_INF)
+            p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
+            ctx = jnp.einsum("wkgmt,mktd->wkgd",
+                             p[..., :M * bT].reshape(W, KV, G, M, bT)
+                             .astype(vc.dtype), vc,
+                             preferred_element_type=jnp.float32) \
+                + jnp.einsum("wkgj,jkd->wkgd",
+                             p[..., M * bT:].astype(vq.dtype), vq,
+                             preferred_element_type=jnp.float32)
+            if shd is not None:
+                ctx = shd.heads4(ctx)
+        with jax.named_scope("decode_proj_mlp"):
+            ctx = ctx.reshape(W, nq * D).astype(compute_dtype)
+            attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
+            attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
+            x = x + attn_out
+            h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
+            act = gemma3.gelu_tanh(
+                apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
+                * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
+            if shd is not None:
+                act = shd.hidden(act)
+            down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
+            down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
+            x = x + down
+        return x, (kq, vq)
 
     x, (ks, vs) = jax.lax.scan(
         layer, x, (wb, is_global, jnp.arange(L, dtype=jnp.int32)))
     # one scatter for all L layers' chunk rows (padded rows -> trash)
-    pool_k = pool_k.at[blk, :, :, off, :].set(ks.transpose(1, 0, 2, 3))
-    pool_v = pool_v.at[blk, :, :, off, :].set(vs.transpose(1, 0, 2, 3))
-    x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
-                        eps)
-    last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
-                                        keepdims=True)        # [1, E]
-    logits = last @ params["embed"].astype(compute_dtype).T
-    logits = _head_lora(logits, last, lora_b, lora_impl)
+    with jax.named_scope("decode_kv_write"):
+        pool_k = pool_k.at[blk, :, :, off, :].set(
+            ks.transpose(1, 0, 2, 3))
+        pool_v = pool_v.at[blk, :, :, off, :].set(
+            vs.transpose(1, 0, 2, 3))
+    with jax.named_scope("decode_head"):
+        x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
+                            eps)
+        last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
+                                            keepdims=True)    # [1, E]
+        logits = last @ params["embed"].astype(compute_dtype).T
+        logits = _head_lora(logits, last, lora_b, lora_impl)
     return logits.astype(jnp.float32), pool_k, pool_v
 
 
@@ -965,7 +996,8 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     lora_b = None if lora is None else lora.get("blocks")
     cast = lambda t: (t.astype(compute_dtype)
                       if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb = jax.tree.map(cast, params["blocks"])
+    with jax.named_scope("decode_weight_cast"):
+        wb = jax.tree.map(cast, params["blocks"])
     shd = shardings
     attend = _paged_attend(attn_impl, H, 1, pool_k, shd)
 
@@ -985,46 +1017,30 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     def layer(inner, inp):
         x, pk, pv = inner
         bp, i = inp
-        h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
-        qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
-        qkv = apply_lora(qkv, h, "attn_qkv", i)
-        if lora_b is not None:
-            from mobilefinetuner_tpu.lora.lora import GPT2_SPLIT_QKV_SLOTS
-            for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
-                if name in lora_b:
-                    sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
-                    qkv = qkv.at[sl].set(apply_lora(qkv[sl], h, name, i))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        hd = lambda z: z.reshape(S, H, D)
-        q, k, v = hd(q), hd(k), hd(v)
-        if shd is not None:
-            q, k, v = shd.kv_rows(q), shd.kv_rows(k), shd.kv_rows(v)
+        with jax.named_scope("decode_proj_mlp"):
+            h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
+            q, k, v = _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd)
         # scatter the fed token's K/V into its slot's current page; idle
         # slots land in the reserved trash block (never attended)
-        pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
-        pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
-        ctx = attend(q[:, :, None, :], pk, pv, tbl, i, ok, D ** -0.5)
+        with jax.named_scope("decode_kv_write"):
+            pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
+            pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
+        with jax.named_scope("decode_paged_attn"):
+            ctx = attend(q[:, :, None, :], pk, pv, tbl, i, ok, D ** -0.5)
         if shd is not None:
             ctx = shd.heads4(ctx)
-        ctx = ctx.reshape(S, E).astype(compute_dtype)
-        proj = ctx @ bp["attn"]["proj_w"] + bp["attn"]["proj_b"]
-        proj = apply_lora(proj, ctx, "attn_proj", i)
-        x = x + proj
-        h2 = gpt2.layer_norm(x, bp["ln_2"]["g"], bp["ln_2"]["b"], eps)
-        fc = h2 @ bp["mlp"]["fc_w"] + bp["mlp"]["fc_b"]
-        if shd is not None:
-            fc = shd.hidden(fc)
-        fc = gpt2.gelu_new(apply_lora(fc, h2, "mlp_fc_in", i))
-        out = fc @ bp["mlp"]["proj_w"] + bp["mlp"]["proj_b"]
-        out = apply_lora(out, fc, "mlp_fc_out", i)
-        return (x + out, pk, pv), None
+        with jax.named_scope("decode_proj_mlp"):
+            x = _gpt2_proj_mlp(x, ctx.reshape(S, E).astype(compute_dtype),
+                               bp, i, eps, apply_lora, shd)
+        return (x, pk, pv), None
 
     (x, pool_k, pool_v), _ = jax.lax.scan(
         layer, (x, pool_k, pool_v), (wb, jnp.arange(L, dtype=jnp.int32)))
-    x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
-                        params["ln_f"]["b"].astype(compute_dtype), eps)
-    logits = x @ params["wte"].astype(compute_dtype).T
-    logits = _head_lora(logits, x, lora_b, lora_impl)
+    with jax.named_scope("decode_head"):
+        x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
+                            params["ln_f"]["b"].astype(compute_dtype), eps)
+        logits = x @ params["wte"].astype(compute_dtype).T
+        logits = _head_lora(logits, x, lora_b, lora_impl)
     return logits.astype(jnp.float32), pool_k, pool_v
 
 
@@ -1053,7 +1069,8 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     lora_b = None if lora is None else lora.get("blocks")
     cast = lambda t: (t.astype(compute_dtype)
                       if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb = jax.tree.map(cast, params["blocks"])
+    with jax.named_scope("decode_weight_cast"):
+        wb = jax.tree.map(cast, params["blocks"])
     is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
     normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
     shd = shardings
@@ -1077,49 +1094,55 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     def layer(inner, inp):
         x, pk, pv = inner
         bp, glob, i = inp
-        a = bp["attn"]
-        h = gemma3.rms_norm(x, bp["input_ln"], eps)
-        q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(S, nq, D)
-        k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(S, KV, D)
-        v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(S, KV, D)
-        q = gemma3.rms_norm(q, a["q_norm"], eps)
-        k = gemma3.rms_norm(k, a["k_norm"], eps)
-        cos = jnp.where(glob, cos_g, cos_l)
-        sin = jnp.where(glob, sin_g, sin_l)
-        q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
-        k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
-        if shd is not None:
-            k, v = shd.kv_rows(k), shd.kv_rows(v)
-        pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
-        pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
-        ok = jnp.where(glob, valid, valid & win_ok)           # [S, M*bT]
-        q4 = q.reshape(S, KV, G, D)
-        if shd is not None:
-            q4 = shd.heads4(q4)
-        ctx = attend(q4, pk, pv, tbl, i, ok, scale)
-        if shd is not None:
-            ctx = shd.heads4(ctx)
-        ctx = ctx.reshape(S, nq * D).astype(compute_dtype)
-        attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
-        attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
-        x = x + attn_out
-        h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
-        act = gemma3.gelu_tanh(
-            apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
-            * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
-        if shd is not None:
-            act = shd.hidden(act)
-        down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
-        down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
-        return (x + down, pk, pv), None
+        with jax.named_scope("decode_proj_mlp"):
+            a = bp["attn"]
+            h = gemma3.rms_norm(x, bp["input_ln"], eps)
+            q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(S, nq, D)
+            k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(S, KV, D)
+            v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(S, KV, D)
+            q = gemma3.rms_norm(q, a["q_norm"], eps)
+            k = gemma3.rms_norm(k, a["k_norm"], eps)
+            cos = jnp.where(glob, cos_g, cos_l)
+            sin = jnp.where(glob, sin_g, sin_l)
+            q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
+            k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
+            if shd is not None:
+                k, v = shd.kv_rows(k), shd.kv_rows(v)
+        with jax.named_scope("decode_kv_write"):
+            pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
+            pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
+        with jax.named_scope("decode_paged_attn"):
+            ok = jnp.where(glob, valid, valid & win_ok)       # [S, M*bT]
+            q4 = q.reshape(S, KV, G, D)
+            if shd is not None:
+                q4 = shd.heads4(q4)
+            ctx = attend(q4, pk, pv, tbl, i, ok, scale)
+            if shd is not None:
+                ctx = shd.heads4(ctx)
+        with jax.named_scope("decode_proj_mlp"):
+            ctx = ctx.reshape(S, nq * D).astype(compute_dtype)
+            attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
+            attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
+            x = x + attn_out
+            h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
+            act = gemma3.gelu_tanh(
+                apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
+                * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
+            if shd is not None:
+                act = shd.hidden(act)
+            down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
+            down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
+            x = x + down
+        return (x, pk, pv), None
 
     (x, pool_k, pool_v), _ = jax.lax.scan(
         layer, (x, pool_k, pool_v),
         (wb, is_global, jnp.arange(L, dtype=jnp.int32)))
-    x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
-                        eps)
-    logits = x @ params["embed"].astype(compute_dtype).T
-    logits = _head_lora(logits, x, lora_b, lora_impl)
+    with jax.named_scope("decode_head"):
+        x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
+                            eps)
+        logits = x @ params["embed"].astype(compute_dtype).T
+        logits = _head_lora(logits, x, lora_b, lora_impl)
     return logits.astype(jnp.float32), pool_k, pool_v
 
 

@@ -658,9 +658,12 @@ class ServeEngine:
             self.dtype.itemsize, tp=cfg.mesh_tp)
         self.lora_impl_resolved = lora_impl_resolved
         self.telemetry = telemetry or Telemetry("")
-        # request-lifecycle span tracing (core/trace.py): queue/prefill/
-        # decode spans per request on its own "req:<id>" track. Pure
-        # host bookkeeping over stamps the engine already takes.
+        # span tracing (core/trace.py). step() is split into `serve.*`
+        # spans, which are in any profiler trace taken of this process;
+        # `trace_spans` additionally writes them to the stream, with the
+        # request lifecycle — queue/prefill/decode per request on its
+        # own "req:<id>" track, pure host bookkeeping over stamps the
+        # engine already takes (stream only).
         from mobilefinetuner_tpu.core.trace import Tracer
         self.tracer = Tracer(self.telemetry.emit,
                              enabled=cfg.trace_spans)
@@ -1013,23 +1016,26 @@ class ServeEngine:
         ids[0, :P], mask[0, :P] = req.prompt, 1
         bank_tree = self.bank.tree if self.bank else None
         t_prefill = time.perf_counter()
-        tok0, k, v = self._prefill(
-            self.params, bank_tree, self._dev(ids), self._dev(mask),
-            # graftlint: disable=sync-hazard(host int wrapped for the device; nothing is pulled back)
-            self._dev(np.asarray([req.aid], np.int32)),
-            *self._samp_args(req))
-        # scatter the prompt pages; table rows past the prompt stay trash
-        block_ids = np.full(cfg.max_prompt // cfg.block_T, TRASH_BLOCK,
-                            np.int32)
-        block_ids[:len(req.blocks)] = req.blocks
-        # the write DONATES the pools (non-CPU): if it raises, the old
-        # buffers may already be consumed — flag the window so the
-        # admission containment knows one-victim recovery is not enough
-        self._pools_at_risk = True
-        self.pool_k, self.pool_v = self._write(
-            self.pool_k, self.pool_v, k, v, self._dev(block_ids))
-        self._pools_at_risk = False
-        tok0 = int(tok0)                 # host sync: the first token
+        with self.tracer.span("serve.prefill", "loop"):
+            tok0, k, v = self._prefill(
+                self.params, bank_tree, self._dev(ids), self._dev(mask),
+                # graftlint: disable=sync-hazard(host int wrapped for the device; nothing is pulled back)
+                self._dev(np.asarray([req.aid], np.int32)),
+                *self._samp_args(req))
+            # scatter the prompt pages; table rows past the prompt stay
+            # trash
+            block_ids = np.full(cfg.max_prompt // cfg.block_T, TRASH_BLOCK,
+                                np.int32)
+            block_ids[:len(req.blocks)] = req.blocks
+            # the write DONATES the pools (non-CPU): if it raises, the
+            # old buffers may already be consumed — flag the window so
+            # the admission containment knows one-victim recovery is not
+            # enough
+            self._pools_at_risk = True
+            self.pool_k, self.pool_v = self._write(
+                self.pool_k, self.pool_v, k, v, self._dev(block_ids))
+            self._pools_at_risk = False
+            tok0 = int(tok0)             # host sync: the first token
         now = time.perf_counter()
         req.admit_t = req.first_token_t = now
         if self.tracer.enabled:
@@ -1254,19 +1260,30 @@ class ServeEngine:
         step for every active slot. Returns every request that reached
         a TERMINAL state on this iteration (finished, and since round
         14: timeout, error, and shutdown-rejected) — filter on
-        `.state` when only completions matter."""
-        cfg = self.cfg
+        `.state` when only completions matter.
+
+        The iteration is one `serve.step` span (core/trace.py) whose
+        children name its phases: serve.expire, serve.admit (one per
+        admitted request; serve.prefill inside), serve.prefill_chunk,
+        serve.page_append, serve.upload, serve.dispatch, serve.sync,
+        serve.emit, serve.stats."""
+        with self.tracer.span("serve.step", "loop"):
+            return self._step_phases()
+
+    def _step_phases(self) -> List[Request]:
+        cfg, span = self.cfg, self.tracer.span
         done: List[Request] = []
-        # a preemption signal is observed at the step boundary (never
-        # inside a dispatch): stop admissions, reject the queued
-        # remainder, let the in-flight requests finish
-        if self.guard is not None and self.guard.triggered \
-                and not self.draining:
-            self.telemetry.emit("preempt", step=self.decode_steps,
-                                signal=self.guard.signal_name or "SIGTERM")
-            done.extend(self.begin_shutdown())
-        now = time.perf_counter()
-        done.extend(self._expire(now))
+        with span("serve.expire", "loop"):
+            # a preemption signal is observed at the step boundary
+            # (never inside a dispatch): stop admissions, reject the
+            # queued remainder, let the in-flight requests finish
+            if self.guard is not None and self.guard.triggered \
+                    and not self.draining:
+                self.telemetry.emit(
+                    "preempt", step=self.decode_steps,
+                    signal=self.guard.signal_name or "SIGTERM")
+                done.extend(self.begin_shutdown())
+            done.extend(self._expire(time.perf_counter()))
         # FCFS admission under the worst-case page reservation
         while self.queue and not self.draining:
             free = [i for i, r in enumerate(self._slots) if r is None]
@@ -1279,7 +1296,8 @@ class ServeEngine:
                 break
             self.queue.popleft()
             try:
-                self._admit(req, free[0])
+                with span("serve.admit", "loop"):
+                    self._admit(req, free[0])
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -1323,7 +1341,8 @@ class ServeEngine:
             if W > budget:
                 break                    # next chunk outlives the budget
             try:
-                self._prefill_chunk(req)
+                with span("serve.prefill_chunk", "loop"):
+                    self._prefill_chunk(req)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except Exception as e:
@@ -1345,24 +1364,29 @@ class ServeEngine:
             return done
         # a slot crossing a page boundary this step takes its next page
         # (guaranteed by the admission reservation)
-        for req in live:
-            j = int(self._pos[req.slot]) // cfg.block_T
-            if j == len(req.blocks):
-                try:
-                    req.blocks.append(self.alloc.append())
-                except OutOfBlocks as e:  # pragma: no cover — invariant
-                    raise OutOfBlocks(
-                        f"reservation accounting failed for request "
-                        f"{req.id}: {e}") from e
-                self._tbl[req.slot, j] = req.blocks[-1]
+        with span("serve.page_append", "loop"):
+            for req in live:
+                j = int(self._pos[req.slot]) // cfg.block_T
+                if j == len(req.blocks):
+                    try:
+                        req.blocks.append(self.alloc.append())
+                    except OutOfBlocks as e:  # pragma: no cover — invariant
+                        raise OutOfBlocks(
+                            f"reservation accounting failed for request "
+                            f"{req.id}: {e}") from e
+                    self._tbl[req.slot, j] = req.blocks[-1]
 
         t_step = time.perf_counter()
         try:
             if self.step_hook is not None:
                 self.step_hook(self.decode_steps)
-            nxt, pool_k, pool_v = self._step(*self._step_args())
-            # graftlint: disable=sync-hazard(the serve loop's ONE host sync per decode step: this step's tokens drive host-side scheduling)
-            nxt = np.asarray(nxt)
+            with span("serve.upload", "loop"):
+                args = self._step_args()
+            with span("serve.dispatch", "loop"):
+                nxt, pool_k, pool_v = self._step(*args)
+            with span("serve.sync", "loop"):
+                # graftlint: disable=sync-hazard(the serve loop's ONE host sync per decode step: this step's tokens drive host-side scheduling)
+                nxt = np.asarray(nxt)
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
@@ -1370,30 +1394,35 @@ class ServeEngine:
             if cfg.on_step_error == "raise":
                 raise
             return done
-        self.pool_k, self.pool_v = pool_k, pool_v
-        self.decode_steps += 1
-        with self._health_lock:
-            self._step_ms.append((time.perf_counter() - t_step) * 1000.0)
-        if self.watchdog is not None:
-            self.watchdog.pet(self.decode_steps,
-                              time.perf_counter() - t_step)
-        for req in live:
-            s = req.slot
-            self._pos[s] += 1
-            self._tok[s] = int(nxt[s])
-            req.tokens.append(int(nxt[s]))
-            if req.awaiting_first:
-                # full-hit re-feed: THIS decode emitted the request's
-                # first token (the prompt never prefilled at all)
-                req.awaiting_first = False
-                req.first_token_t = time.perf_counter()
-                self._emit_request(req, phase="first_token")
-            if (self.eos_id is not None and req.tokens[-1] == self.eos_id) \
-                    or len(req.tokens) >= req.max_new_tokens:
-                self._finish(req)
-                done.append(req)
+        with span("serve.emit", "loop"):
+            self.pool_k, self.pool_v = pool_k, pool_v
+            self.decode_steps += 1
+            with self._health_lock:
+                self._step_ms.append(
+                    (time.perf_counter() - t_step) * 1000.0)
+            if self.watchdog is not None:
+                self.watchdog.pet(self.decode_steps,
+                                  time.perf_counter() - t_step)
+            for req in live:
+                s = req.slot
+                self._pos[s] += 1
+                self._tok[s] = int(nxt[s])
+                req.tokens.append(int(nxt[s]))
+                if req.awaiting_first:
+                    # full-hit re-feed: THIS decode emitted the
+                    # request's first token (the prompt never prefilled
+                    # at all)
+                    req.awaiting_first = False
+                    req.first_token_t = time.perf_counter()
+                    self._emit_request(req, phase="first_token")
+                if (self.eos_id is not None
+                        and req.tokens[-1] == self.eos_id) \
+                        or len(req.tokens) >= req.max_new_tokens:
+                    self._finish(req)
+                    done.append(req)
         if cfg.stats_every and self.decode_steps % cfg.stats_every == 0:
-            self.emit_stats()
+            with span("serve.stats", "loop"):
+                self.emit_stats()
         return done
 
     def _step_args(self) -> list:

@@ -602,7 +602,7 @@ def test_trace_export_synthesizes_request_spans_without_tracing():
     assert xs["decode"]["args"]["outcome"] == "finish"
     # checkpoint write span derived from the write_ms on the event
     ck = next(e for e in trace["traceEvents"]
-              if e["ph"] == "X" and e["name"].startswith("ckpt_write"))
+              if e["ph"] == "X" and e["name"] == "ckpt.write")
     assert ck["dur"] == pytest.approx(500e3, rel=1e-6)
 
 

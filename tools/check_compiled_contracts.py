@@ -44,6 +44,12 @@ DEFAULT_CONTRACTS = os.path.join(REPO, "tools", "compiled_contracts.json")
 
 # the phase scopes the telemetry layer pins (DESIGN.md §13)
 TRAIN_SCOPES = ("embed", "attention", "mlp", "loss", "optimizer")
+# the paged decode step's and prefill chunk's scopes (models/generate.py):
+# how a device trace tells the KV scatter from the paged read from the
+# block's matmuls. `decode_weight_cast` is not pinned here: these
+# programs compute in float32, where the cast is no operation at all
+DECODE_SCOPES = ("decode_kv_write", "decode_paged_attn",
+                 "decode_proj_mlp", "decode_head")
 
 
 def _ensure_cpu_devices() -> None:
@@ -187,7 +193,7 @@ def prog_decode_gpt2_paged():
     pos = jnp.asarray([4, 5], jnp.int32)
     text = step.lower(params, pool_k, pool_v, tok, pos,
                       tbl).compile().as_text()
-    return text, retraces, ()
+    return text, retraces, DECODE_SCOPES
 
 
 def prog_decode_gpt2_paged_tp():
@@ -253,7 +259,7 @@ def prog_decode_gpt2_paged_tp():
     pos = dev(np.array([4, 5], np.int32))
     text = step.lower(params, bank, pool_k, pool_v, tok, pos, tbl,
                       aid).compile().as_text()
-    return text, retraces, ()
+    return text, retraces, DECODE_SCOPES
 
 
 def prog_prefill_chunk_gpt2_tp():
@@ -310,7 +316,7 @@ def prog_prefill_chunk_gpt2_tp():
     ids = dev(np.full((1, W), 5, np.int32))
     text = chunk.lower(params, pool_k, pool_v, ids, dev(np.int32(0)),
                        dev(np.int32(W)), tbl).compile().as_text()
-    return text, retraces, ()
+    return text, retraces, DECODE_SCOPES
 
 
 def prog_multitenant_gpt2():

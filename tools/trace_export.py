@@ -2,8 +2,7 @@
 
 A fleet run (or serve session) leaves per-host JSONL telemetry shards;
 this tool converts a coordinator stream — plus every `.host<k>` shard
-found next to it, plus optionally a `jax.profiler` device trace — into
-a single trace-event JSON file that opens in ui.perfetto.dev (or
+found next to it — into a single trace-event JSON file that opens in ui.perfetto.dev (or
 chrome://tracing): one PROCESS row per host, one THREAD row per track
 ("phase" for the goodput buckets, "ckpt" for the async writer thread,
 "prefetch" for the producer, "req:<id>" per serve request), counter
@@ -25,23 +24,20 @@ buckets, so per-phase span sums match `run_end.goodput` by
 construction — the tool prints the check (and `phase_reconcile` is the
 test's oracle).
 
-Device-trace merge (`--profile DIR|FILE`): jax.profiler writes a
-Chrome-trace `*.trace.json.gz` under its log dir; its events are
-appended under their own process rows. Alignment is BEST-EFFORT (the
-profiler's clock zero is its own): the profiler timeline is shifted so
-its start coincides with the stream's first `profile_capture` event
-when one exists, else with the stream's start.
+Device traces are not merged here: every `Tracer.span` is also a
+`jax.profiler.TraceAnnotation` (core/trace.py), so a profiler trace
+(--profile_dir, --auto_profile) already holds the program's spans on
+the device's own clock — open its `.xplane.pb` directory with the
+profiler's viewer, or reduce it with benchmark/harness/xplane.py. The
+stream's `profile_capture` events say where each trace of a run lies.
 
 Usage:
   python tools/trace_export.py run.jsonl -o trace.json
-  python tools/trace_export.py run.jsonl --profile prof_dir -o all.json
 """
 
 from __future__ import annotations
 
 import argparse
-import glob as globmod
-import gzip
 import json
 import os
 import statistics
@@ -188,7 +184,7 @@ def host_trace_events(host, events, t_base):
                     "ts": round((t_end - e["write_ms"] / 1000.0
                                  - t_base) * 1e6, 3),
                     "dur": round(e["write_ms"] * 1000.0, 3),
-                    "name": f"ckpt_write(step {e['step']})",
+                    "name": "ckpt.write",
                     "cat": "checkpoint",
                     "args": {"step": e["step"], "bytes": e.get("bytes"),
                              "async": e.get("async")}})
@@ -232,44 +228,6 @@ def host_trace_events(host, events, t_base):
         meta.append({"ph": "M", "pid": host, "tid": tid,
                      "name": "thread_name", "args": {"name": track}})
     return meta + out, set(tracks)
-
-
-def find_profiler_trace(path):
-    """Locate a jax.profiler Chrome trace: the path itself when it is a
-    .json/.json.gz file, else the newest *.trace.json.gz under it."""
-    if os.path.isfile(path):
-        return path
-    hits = sorted(globmod.glob(os.path.join(
-        globmod.escape(path), "**", "*.trace.json.gz"), recursive=True),
-        key=os.path.getmtime)
-    return hits[-1] if hits else None
-
-
-def load_profiler_events(path):
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt", encoding="utf-8", errors="replace") as f:
-        data = json.load(f)
-    evs = data.get("traceEvents", data) or []
-    return [e for e in evs if isinstance(e, dict)]
-
-
-def merge_profiler(trace_events, prof_events, anchor_us):
-    """Append the device trace under its own process rows (pids offset
-    by 9000), shifted so its earliest timestamp lands at `anchor_us` —
-    best-effort alignment (the profiler's epoch is its own)."""
-    ts0 = min((e["ts"] for e in prof_events
-               if isinstance(e.get("ts"), (int, float))), default=0.0)
-    out = []
-    for e in prof_events:
-        e = dict(e)
-        if isinstance(e.get("pid"), int):
-            e["pid"] = 9000 + e["pid"]
-        else:
-            e["pid"] = 9000
-        if isinstance(e.get("ts"), (int, float)):
-            e["ts"] = round(e["ts"] - ts0 + anchor_us, 3)
-        out.append(e)
-    trace_events.extend(out)
 
 
 def phase_sums(trace, pid: int = 0) -> dict:
@@ -384,7 +342,7 @@ def router_reconcile(shards) -> dict | None:
             "max_gap_frac": (worst / wall) if wall else 0.0}
 
 
-def export(shards, profile=None, router=False) -> dict:
+def export(shards, router=False) -> dict:
     """shards: {host: events}. Returns the trace-event JSON dict.
     Each shard is scoped to its latest run first (see latest_run).
     With router=True the process rows are named for the serve-fleet
@@ -403,11 +361,6 @@ def export(shards, profile=None, router=False) -> dict:
                 pid = e.get("pid")
                 e["args"]["name"] = ("router" if pid == 0
                                      else f"replica {pid}")
-    if profile:
-        caps = [e for e in all_events
-                if e["event"] == "profile_capture"]
-        anchor = ((caps[0]["t"] - t_base) * 1e6) if caps else 0.0
-        merge_profiler(trace_events, profile, anchor)
     return {"traceEvents": trace_events, "displayTimeUnit": "ms",
             "otherData": {"source": "mobilefinetuner_tpu trace_export",
                           "hosts": len(shards),
@@ -422,9 +375,6 @@ def main(argv=None) -> int:
                                   "discovered and merged)")
     ap.add_argument("-o", "--out", default="",
                     help="output file (default: <stream>.trace.json)")
-    ap.add_argument("--profile", default="",
-                    help="jax.profiler log dir (or trace.json[.gz]) to "
-                         "merge as device-trace process rows")
     ap.add_argument("--router", action="store_true",
                     help="serve-fleet stream: name host 0 'router' and "
                          "host k 'replica k', and check the per-rid "
@@ -450,16 +400,7 @@ def main(argv=None) -> int:
         print(f"error: no valid telemetry events in "
               f"{sorted(paths.values())}", file=sys.stderr)
         return 1
-    prof = None
-    if args.profile:
-        found = find_profiler_trace(args.profile)
-        if found is None:
-            print(f"error: no *.trace.json.gz under {args.profile}",
-                  file=sys.stderr)
-            return 1
-        prof = load_profiler_events(found)
-        print(f"device trace: {found} ({len(prof)} events)")
-    trace = export(shards, profile=prof, router=args.router)
+    trace = export(shards, router=args.router)
     out = args.out or (args.jsonl + ".trace.json")
     tmp = out + ".tmp"
     with open(tmp, "w") as f:

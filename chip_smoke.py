@@ -507,11 +507,13 @@ def _kernel_vs_oracle(seed, S, KV, G, D, bT, M, NB, L, dtype):
     import numpy as np
     from mobilefinetuner_tpu.ops.decode_attention import (
         paged_attention, paged_decode_attention)
+    from mobilefinetuner_tpu.serve.paged_kv import pool_shape
     rng = np.random.default_rng(seed)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (S, KV, G, D), dtype)
-    pk = jax.random.normal(kk, (NB, L, KV, bT, D), dtype)
-    pv = jax.random.normal(kv, (NB, L, KV, bT, D), dtype)
+    shape = pool_shape(NB, L, KV, bT, D)
+    pk = jax.random.normal(kk, shape, dtype)
+    pv = jax.random.normal(kv, shape, dtype)
     tbl = jnp.asarray(rng.integers(1, NB, (S, M)), jnp.int32)
     lens = rng.integers(1, M * bT + 1, S)
     ok = jnp.asarray(np.arange(M * bT)[None, :] < lens[:, None])
@@ -658,6 +660,7 @@ def child_serve(args) -> int:
                            "lora": eng.lora_impl_resolved,
                            "prefill_attention": "xla (S=%d < 512)"
                            % sz["max_prompt"]},
+            pool_layout=eng.pool_layout,
             tpu_custom_calls_in_decode_step=kernels,
             kernel_vs_oracle_max_abs_diff=kernel_err,
             kernel_tol=KERNEL_TOL,
@@ -723,7 +726,6 @@ def child_tp(args) -> int:
     # logits, not argmax tokens: ONE decode step of the model function
     # the engine jits, through each engine's own placement, on the same
     # seeded pool contents and slot state
-    L, KV, D = one._pool_dims
     S, M, bT, NB = sz["slots"], one.M, sz["block_T"], sz["blocks"]
     rng = np.random.default_rng(args.seed)
     tbl = rng.integers(1, NB, (S, M)).astype(np.int32)
@@ -731,8 +733,8 @@ def child_tp(args) -> int:
     tok = rng.integers(1, one.config.vocab_size, S).astype(np.int32)
     aid = rng.integers(0, 3, S).astype(np.int32)
     kk, kv = jax.random.split(jax.random.PRNGKey(args.seed))
-    pk = jax.random.normal(kk, (NB, L, KV, bT, D), jnp.bfloat16)
-    pv = jax.random.normal(kv, (NB, L, KV, bT, D), jnp.bfloat16)
+    pk = jax.random.normal(kk, one.pool_k.shape, jnp.bfloat16)
+    pv = jax.random.normal(kv, one.pool_k.shape, jnp.bfloat16)
 
     def logits_of(eng):
         shd = eng.sharding

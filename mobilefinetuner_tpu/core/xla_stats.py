@@ -10,6 +10,9 @@ reports no memory_stats at all).
 
 from __future__ import annotations
 
+import math
+import re
+
 import jax
 
 
@@ -27,6 +30,26 @@ def compiled_peak_bytes(compiled) -> int:
 
 def compiled_peak_mb(compiled) -> float:
     return compiled_peak_bytes(compiled) / 2 ** 20
+
+
+_HLO_RESULT = re.compile(
+    r"= \w+\[([\d,]*)\]\S* (copy|copy-start|transpose)\(")
+
+
+def sized_copies(compiled, min_elems: int) -> list:
+    """HLO lines of `compiled` (fused computations included) where a
+    copy or transpose PRODUCES at least `min_elems` elements, whatever
+    the layout or the dims a bitcast gave it — the "no program converts
+    a whole KV pool between layouts" primitive (serve/paged_kv.py's
+    contract; asserted by tests/test_aot_tpu.py, entry and exit copies
+    included: they are ordinary `copy` instructions of the module)."""
+    out = []
+    for ln in compiled.as_text().splitlines():
+        m = _HLO_RESULT.search(ln)
+        if m and math.prod(int(d) for d in m.group(1).split(",") if d) \
+                >= min_elems:
+            out.append(ln.strip())
+    return out
 
 
 def shaped_all_gathers(compiled, shape, dtypes=("f32", "bf16")) -> list:

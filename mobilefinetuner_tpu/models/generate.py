@@ -592,7 +592,9 @@ def gemma3_generate(config: Gemma3TextConfig, params, input_ids,
 #                        at the last REAL position plus every layer's
 #                        K/V for the host to scatter into pool blocks;
 #   *_decode_step_paged  one token step for ALL slots against the shared
-#                        block pool [NB, L, KV, bT, D]: write the fed
+#                        block pool (its layout is serve/paged_kv.py's
+#                        contract; these programs only go through that
+#                        module's writers and readers): write the fed
 #                        token's K/V at (tbl[s, pos//bT], pos%bT), read
 #                        each slot's pages through its block table
 #                        (ops/decode_attention.paged_attention — the
@@ -720,11 +722,13 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
     under `shardings` like any dense op (a chunk-shaped Pallas kernel
     is future work, gated behind the same benched decision)."""
     from mobilefinetuner_tpu.ops.decode_attention import NEG_INF
-    from mobilefinetuner_tpu.serve.paged_kv import TRASH_BLOCK
+    from mobilefinetuner_tpu.serve.paged_kv import (TRASH_BLOCK,
+                                                    gather_pages,
+                                                    write_chunk_rows)
     W = ids.shape[1]
     M = tbl.shape[1]
-    NB, L, H, bT, D = pool_k.shape
-    E = config.n_embd
+    L, bT = pool_k.shape[1:3]
+    E, H, D = config.n_embd, config.n_head, config.head_dim
     eps = config.layer_norm_epsilon
     params = jax.tree.map(jnp.asarray, params)
     lora_b = None if lora is None else lora.get("blocks")
@@ -773,9 +777,9 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
             # are closed over READ-ONLY here (gather, never scatter), so
             # they are not scan carries — dtype discipline mirrors
             # ops.decode_attention.paged_attention
-            kc = pool_k[tbl[0], i]                    # [M, H, bT, D]
-            vc = pool_v[tbl[0], i]
-            s1 = jnp.einsum("whd,mhtd->whmt", q, kc,
+            kc = gather_pages(pool_k, tbl[0], i, H)   # [M, bT, H, D]
+            vc = gather_pages(pool_v, tbl[0], i, H)
+            s1 = jnp.einsum("whd,mthd->whmt", q, kc,
                             preferred_element_type=jnp.float32) * scale
             s1 = jnp.where(pre_ok[None, None, :],
                            s1.reshape(W, H, M * bT), NEG_INF)
@@ -783,7 +787,7 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
                             preferred_element_type=jnp.float32) * scale
             s2 = jnp.where(causal[:, None, :], s2, NEG_INF)
             p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-            ctx = jnp.einsum("whmt,mhtd->whd",
+            ctx = jnp.einsum("whmt,mthd->whd",
                              p[..., :M * bT].reshape(W, H, M, bT)
                              .astype(vc.dtype), vc,
                              preferred_element_type=jnp.float32) \
@@ -799,13 +803,10 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
 
     x, (ks, vs) = jax.lax.scan(
         layer, x, (wb, jnp.arange(L, dtype=jnp.int32)))
-    # one scatter for all L layers' chunk rows (padded rows -> trash):
-    # [L, W, H, D] -> assignment shape [W, L, H, D]
+    # one write for all L layers' chunk rows (padded rows -> trash)
     with jax.named_scope("decode_kv_write"):
-        pool_k = pool_k.at[blk, :, :, off, :].set(
-            ks.transpose(1, 0, 2, 3))
-        pool_v = pool_v.at[blk, :, :, off, :].set(
-            vs.transpose(1, 0, 2, 3))
+        pool_k = write_chunk_rows(pool_k, blk, off, ks)
+        pool_v = write_chunk_rows(pool_v, blk, off, vs)
     with jax.named_scope("decode_head"):
         x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
                             params["ln_f"]["b"].astype(compute_dtype), eps)
@@ -829,11 +830,14 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
     chunk, the pools ride closed-over (reads only) and one post-scan
     scatter lands every layer's rows."""
     from mobilefinetuner_tpu.ops.decode_attention import NEG_INF
-    from mobilefinetuner_tpu.serve.paged_kv import TRASH_BLOCK
+    from mobilefinetuner_tpu.serve.paged_kv import (TRASH_BLOCK,
+                                                    gather_pages,
+                                                    write_chunk_rows)
     c = config
     W = ids.shape[1]
     M = tbl.shape[1]
-    NB, L, KV, bT, D = pool_k.shape
+    L, bT = pool_k.shape[1:3]
+    KV, D = c.num_key_value_heads, c.head_dim
     nq = c.num_attention_heads
     G = nq // KV
     eps = c.rms_norm_eps
@@ -894,9 +898,9 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
             q4 = q.reshape(W, KV, G, D)
             if shd is not None:
                 q4 = shd.heads4(q4)
-            kc = pool_k[tbl[0], i]                    # [M, KV, bT, D]
-            vc = pool_v[tbl[0], i]
-            s1 = jnp.einsum("wkgd,mktd->wkgmt", q4, kc,
+            kc = gather_pages(pool_k, tbl[0], i, KV)  # [M, bT, KV, D]
+            vc = gather_pages(pool_v, tbl[0], i, KV)
+            s1 = jnp.einsum("wkgd,mtkd->wkgmt", q4, kc,
                             preferred_element_type=jnp.float32) * scale
             s1 = jnp.where(ok1[:, None, None, :],
                            s1.reshape(W, KV, G, M * bT), NEG_INF)
@@ -904,7 +908,7 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
                             preferred_element_type=jnp.float32) * scale
             s2 = jnp.where(ok2[:, None, None, :], s2, NEG_INF)
             p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-            ctx = jnp.einsum("wkgmt,mktd->wkgd",
+            ctx = jnp.einsum("wkgmt,mtkd->wkgd",
                              p[..., :M * bT].reshape(W, KV, G, M, bT)
                              .astype(vc.dtype), vc,
                              preferred_element_type=jnp.float32) \
@@ -931,12 +935,10 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
 
     x, (ks, vs) = jax.lax.scan(
         layer, x, (wb, is_global, jnp.arange(L, dtype=jnp.int32)))
-    # one scatter for all L layers' chunk rows (padded rows -> trash)
+    # one write for all L layers' chunk rows (padded rows -> trash)
     with jax.named_scope("decode_kv_write"):
-        pool_k = pool_k.at[blk, :, :, off, :].set(
-            ks.transpose(1, 0, 2, 3))
-        pool_v = pool_v.at[blk, :, :, off, :].set(
-            vs.transpose(1, 0, 2, 3))
+        pool_k = write_chunk_rows(pool_k, blk, off, ks)
+        pool_v = write_chunk_rows(pool_v, blk, off, vs)
     with jax.named_scope("decode_head"):
         x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
                             eps)
@@ -947,15 +949,15 @@ def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
     return logits.astype(jnp.float32), pool_k, pool_v
 
 
-def _paged_attend(attn_impl: str, KV: int, G: int, pool_k, shardings):
+def _paged_attend(attn_impl: str, KV: int, G: int, D: int, pool_k,
+                  shardings):
     """The decode steps' attend(q, pool_k, pool_v, tbl, layer, ok, scale):
     the Pallas paged kernel (under shard_map on a serve mesh) or the
     gather oracle, by ops/decode_attention.resolve_paged_impl."""
     from mobilefinetuner_tpu.ops.decode_attention import (
         paged_attention, paged_decode_attention, resolve_paged_impl,
         sharded_paged_attend)
-    _, _, _, bT, D = pool_k.shape
-    impl = resolve_paged_impl(attn_impl, KV, G, bT, D,
+    impl = resolve_paged_impl(attn_impl, KV, G, pool_k.shape[2], D,
                               pool_k.dtype.itemsize,
                               tp=1 if shardings is None else shardings.tp)
     if impl != "pallas":
@@ -972,11 +974,11 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
                            lora_impl: str = "auto", shardings=None):
     """One continuous-batching decode step over a block-paged KV pool.
 
-    pool_k/pool_v [NB, L, H, bT, D]; tok [S] the token each slot feeds;
-    pos [S] its cache position (= tokens already cached); tbl [S, M]
-    per-slot block tables (idle slots -> trash block 0). Returns
-    (logits [S, V] f32, pool_k, pool_v) with the fed tokens' K/V
-    scattered in at (tbl[s, pos//bT], pos%bT).
+    pool_k/pool_v the block pools (serve/paged_kv.py's layout); tok [S]
+    the token each slot feeds; pos [S] its cache position (= tokens
+    already cached); tbl [S, M] per-slot block tables (idle slots ->
+    trash block 0). Returns (logits [S, V] f32, pool_k, pool_v) with
+    the fed tokens' K/V written in at (tbl[s, pos//bT], pos%bT).
 
     attn_impl: "xla" = gather-based paged_attention (every backend),
     "pallas" = the scalar-prefetch paged kernel, "auto" = pallas on TPU
@@ -988,9 +990,10 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     (GSPMD inserts the collectives; check_compiled_contracts pins the
     census), the Pallas gate charges per-shard head counts, and the
     kernel path routes through sharded_paged_attend's shard_map."""
+    from mobilefinetuner_tpu.serve.paged_kv import write_token_rows
     S, M = tbl.shape
-    NB, L, H, bT, D = pool_k.shape
-    E = config.n_embd
+    L, bT = pool_k.shape[1:3]
+    E, H, D = config.n_embd, config.n_head, config.head_dim
     eps = config.layer_norm_epsilon
     params = jax.tree.map(jnp.asarray, params)
     lora_b = None if lora is None else lora.get("blocks")
@@ -999,7 +1002,7 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     with jax.named_scope("decode_weight_cast"):
         wb = jax.tree.map(cast, params["blocks"])
     shd = shardings
-    attend = _paged_attend(attn_impl, H, 1, pool_k, shd)
+    attend = _paged_attend(attn_impl, H, 1, D, pool_k, shd)
 
     x = params["wte"][tok].astype(compute_dtype) \
         + params["wpe"][pos].astype(compute_dtype)            # [S, E]
@@ -1020,11 +1023,11 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
         with jax.named_scope("decode_proj_mlp"):
             h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
             q, k, v = _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd)
-        # scatter the fed token's K/V into its slot's current page; idle
-        # slots land in the reserved trash block (never attended)
+        # write the fed token's K/V row into its slot's current page;
+        # idle slots land in the reserved trash block (never attended)
         with jax.named_scope("decode_kv_write"):
-            pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
-            pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
+            pk = write_token_rows(pk, blk, i, off, k)
+            pv = write_token_rows(pv, blk, i, off, v)
         with jax.named_scope("decode_paged_attn"):
             ctx = attend(q[:, :, None, :], pk, pv, tbl, i, ok, D ** -0.5)
         if shd is not None:
@@ -1050,7 +1053,7 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
                              attn_impl: str = "auto",
                              lora_impl: str = "auto", shardings=None):
     """Gemma-3 paged decode step (see gpt2_decode_step_paged): GQA pool
-    [NB, L, Hkv, bT, D], per-layer global/local RoPE, sliding-window
+    (Hkv heads to a page row), per-layer global/local RoPE, sliding-window
     validity over absolute positions (serve sequences are unpadded, so
     the column index IS the position).
 
@@ -1058,9 +1061,11 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     pool's KV axis shards when Hkv % tp == 0, otherwise the query-group
     axis does (pools replicated) — either way the gate charges per-shard
     head counts and constraints pin the 4D [S, KV, G, D] layout."""
+    from mobilefinetuner_tpu.serve.paged_kv import write_token_rows
     c = config
     S, M = tbl.shape
-    NB, L, KV, bT, D = pool_k.shape
+    L, bT = pool_k.shape[1:3]
+    KV, D = c.num_key_value_heads, c.head_dim
     nq = c.num_attention_heads
     G = nq // KV
     eps = c.rms_norm_eps
@@ -1074,7 +1079,7 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
     is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
     normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
     shd = shardings
-    attend = _paged_attend(attn_impl, KV, G, pool_k, shd)
+    attend = _paged_attend(attn_impl, KV, G, D, pool_k, shd)
 
     x = params["embed"][tok].astype(compute_dtype) * normalizer
     if shd is not None:
@@ -1109,8 +1114,8 @@ def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
             if shd is not None:
                 k, v = shd.kv_rows(k), shd.kv_rows(v)
         with jax.named_scope("decode_kv_write"):
-            pk = pk.at[blk, i, :, off, :].set(k.astype(pk.dtype))
-            pv = pv.at[blk, i, :, off, :].set(v.astype(pv.dtype))
+            pk = write_token_rows(pk, blk, i, off, k)
+            pv = write_token_rows(pv, blk, i, off, v)
         with jax.named_scope("decode_paged_attn"):
             ok = jnp.where(glob, valid, valid & win_ok)       # [S, M*bT]
             q4 = q.reshape(S, KV, G, D)

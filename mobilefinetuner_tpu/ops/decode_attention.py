@@ -59,6 +59,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 _VMEM_BUDGET = 12 * 2 ** 20
+_LANE = 128
 
 
 from mobilefinetuner_tpu.ops import pallas_util
@@ -157,11 +158,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, ok_ref, o_ref, *, scale, kvb):
 #
 # The serving subsystem (serve/engine.py, DESIGN.md §16) replaces the
 # per-request contiguous [B, KV, T, D] cache with one shared block pool
-# [NB, L, KV, bT, D]: request r's logical column t lives at physical
-# block tbl[r, t // bT], offset t % bT. Two readers of that layout:
+# of token-major pages (the layout contract: serve/paged_kv.py's module
+# docstring): request r's logical column t lives at physical block
+# tbl[r, t // bT], row t % bT. Two readers of that layout:
 #
 #   paged_attention       XLA oracle: gather the slot's pages into a
-#                         contiguous [S, M, KV, bT, D] view, then the
+#                         contiguous [S, M, bT, KV, D] view, then the
 #                         same masked-softmax einsums as xla_reference.
 #                         The gather MATERIALIZES the active cache once
 #                         per layer per step — correct everywhere (it is
@@ -183,8 +185,8 @@ def paged_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
     """Block-paged decode attention, XLA path (the kernel's oracle).
 
     q       [S, KV, G, D]    current-token queries per slot
-    pool_k  [NB, L, KV, bT, D]  shared block pools (all layers)
-    pool_v  [NB, L, KV, bT, D]
+    pool_k  the shared block pools, all layers (serve/paged_kv.py's
+    pool_v  layout: token-major pages [bT, KV * D])
     tbl     [S, M] int32     per-slot block table (unused rows -> the
                              reserved trash block 0; masked by ok)
     layer   scalar int32     which layer's pages to read
@@ -192,23 +194,25 @@ def paged_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
                              any sliding window — caller composes)
     -> ctx  [S, KV, G, D] float32
     """
-    kc = pool_k[tbl, layer]                      # [S, M, KV, bT, D]
-    vc = pool_v[tbl, layer]
-    S, M, KV, bT, D = kc.shape
-    G = q.shape[2]
-    s = jnp.einsum("skgd,smktd->skgmt", q, kc,
+    from mobilefinetuner_tpu.serve.paged_kv import gather_pages
+    S, KV, G, D = q.shape
+    kc = gather_pages(pool_k, tbl, layer, KV)    # [S, M, bT, KV, D]
+    vc = gather_pages(pool_v, tbl, layer, KV)
+    M, bT = kc.shape[1:3]
+    s = jnp.einsum("skgd,smtkd->skgmt", q, kc,
                    preferred_element_type=jnp.float32) * scale
     s = s.reshape(S, KV, G, M * bT)
     s = jnp.where(ok[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).reshape(S, KV, G, M, bT)
-    return jnp.einsum("skgmt,smktd->skgd", p.astype(vc.dtype), vc,
+    return jnp.einsum("skgmt,smtkd->skgd", p.astype(vc.dtype), vc,
                       preferred_element_type=jnp.float32)
 
 
 def paged_eligible(KV: int, G: int, bT: int, D: int,
                    itemsize: int, tp: int = 1) -> bool:
-    """One page pair (K+V, double-buffered) + the per-slot q/ctx blocks
-    and [G, bT] score rows must fit VMEM; bT must be sublane-aligned.
+    """One page pair (K+V, double-buffered), the per-slot block-diagonal
+    queries and the [G*KV, KV*D] f32 accumulator with its temps must
+    fit VMEM; bT must be sublane-aligned.
     tp > 1 charges PER-SHARD head counts (shard_heads): the sharded
     serve path runs the kernel under shard_map on each shard's pool
     slice, so the VMEM bill is the local one — global counts would be
@@ -219,13 +223,16 @@ def paged_eligible(KV: int, G: int, bT: int, D: int,
     (tests/test_aot_tpu.py compiles both sides of it: every shape this
     admits compiles, and the over-budget shapes it refuses are refused
     by Mosaic with RESOURCE_EXHAUSTED). bT % 8 is POLICY, not a
-    compiler limit — Mosaic pads a ragged [bT, D] page, but so does the
-    pool's HBM tiling, and a page that wastes its tile is a bad
-    ServeConfig, not a fast path."""
+    compiler limit — Mosaic pads a ragged page, but so does the pool's
+    HBM tiling, and a page that wastes its tile is a bad ServeConfig,
+    not a fast path."""
     KV, G = shard_heads(KV, G, tp)
-    need = (4 * KV * bT * D * itemsize          # K+V page, double-buffered
-            + KV * G * D * (itemsize + 4)       # q block + f32 ctx block
-            + 3 * KV * G * max(D, bT) * 4)      # o/m/l accumulators + p
+    row, R = KV * D, KV * G
+    need = (4 * bT * row * itemsize             # K+V page, double-buffered
+            + R * row * itemsize                # block-diagonal queries
+            + 3 * R * row * 4                   # o accumulator + p@v temps
+            + 2 * G * row * (itemsize + 4)      # q rows in, f32 ctx rows out
+            + 3 * R * max(bT, _LANE) * 4)       # scores, p, m/l
     return bT % 8 == 0 and need <= _VMEM_BUDGET
 
 
@@ -245,100 +252,132 @@ def resolve_paged_impl(attn_impl: str, KV: int, G: int, bT: int, D: int,
 
 
 def _paged_kernel(tbl_ref, lyr_ref, q_ref, k_ref, v_ref, ok_ref, o_ref,
-                  o_acc, m_acc, l_acc, *, scale, kv):
+                  q_bd, o_acc, m_acc, l_acc, *, scale, kv, g, d):
     """Grid (S, M): slot-major, pages inner — the accumulators carry one
-    slot's online softmax across its pages. A fully-masked page (e.g.
-    beyond a sliding window) contributes exactly zero: probabilities are
-    re-masked after the exp, so the NEG_INF-vs-NEG_INF cancellation in
-    `s - m` cannot resurrect dead columns. ok_ref is the page's
-    (1, 1, 1, bT) block of the [S, M, 1, bT] mask."""
+    slot's online softmax across its pages. A page is [bT, KV*D] (all
+    heads of a token in one row), so the queries are laid out
+    BLOCK-DIAGONALLY against it, once per slot: row (gi, j) of q_bd
+    holds query group gi of head j in head j's lanes [j*D, (j+1)*D) and
+    zeros elsewhere (they add exact zeros to the f32 accumulation).
+    Every head's scores are then ONE matmul per page, with no per-head
+    slicing of a row, and row (gi, j) of the accumulator holds head j's
+    context in its own lanes (the rest is the cross-head product nobody
+    reads). A fully-masked page (e.g. beyond a sliding window)
+    contributes exactly zero: probabilities are re-masked after the
+    exp, so the NEG_INF-vs-NEG_INF cancellation in `s - m` cannot
+    resurrect dead columns. ok_ref is the page's (1, 1, 1, bT) block of
+    the [S, M, 1, bT] mask."""
     del tbl_ref, lyr_ref  # consumed by the index_maps, not the body
     m = pl.program_id(1)
+
+    def own(gi):
+        """Group gi's rows (gi, j), each in its own head j's lanes —
+        needed at a slot's first and last page only."""
+        row = jax.lax.broadcasted_iota(jnp.int32, o_acc.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, o_acc.shape, 1)
+        return ((row // kv) == gi) & ((col // d) == (row % kv))
 
     @pl.when(m == 0)
     def _init():
         o_acc[...] = jnp.zeros_like(o_acc)
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
+        # (selects run in f32: Mosaic will not relayout the 32-bit
+        # mask onto a packed 16-bit operand)
+        bd = jnp.zeros(o_acc.shape, jnp.float32)
+        for gi in range(g):                             # static unroll
+            q = q_ref[0, pl.ds(gi, 1), :].astype(jnp.float32)  # [1, KV*D]
+            bd = jnp.where(own(gi), q, bd)
+        q_bd[...] = bd.astype(q_bd.dtype)
 
     ok = ok_ref[0, 0] > 0                               # [1, bT] (lanes)
-    for j in range(kv):                                 # static unroll
-        k = k_ref[0, 0, j]                              # [bT, D] storage
-        v = v_ref[0, 0, j]
-        q = q_ref[0, j].astype(k.dtype)                 # [G, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [G, bT]
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_acc[j]                                # [G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)       # [G, bT]
-        o_acc[j] = alpha * o_acc[j] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        l_acc[j] = alpha * l_acc[j] + jnp.sum(p, axis=-1, keepdims=True)
-        m_acc[j] = m_new
+    k = k_ref[0, 0]                                     # [bT, KV*D] storage
+    v = v_ref[0, 0]
+    s = jax.lax.dot_general(
+        q_bd[...], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale     # [G*KV, bT]
+    s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_acc[...]                                 # [G*KV, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)          # [G*KV, bT]
+    o_acc[...] = alpha * o_acc[...] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)             # [G*KV, KV*D]
+    l_acc[...] = alpha * l_acc[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_acc[...] = m_new
 
     @pl.when(m == pl.num_programs(1) - 1)
     def _finish():
-        # the current token's own column is always attendable, so l > 0
-        o_ref[0] = o_acc[...] / l_acc[...]
+        # the current token's own column is always attendable, so l > 0.
+        # Keep each row's own head block and fold group gi's rows into
+        # ctx row gi: [G, KV*D], heads side by side like the queries
+        o = o_acc[...] / l_acc[...]
+        for gi in range(g):                             # static unroll
+            o_ref[0, pl.ds(gi, 1), :] = jnp.sum(
+                jnp.where(own(gi), o, 0.0), axis=0, keepdims=True)
 
 
 def paged_decode_attention(q, pool_k, pool_v, tbl, layer, ok, scale):
     """Pallas block-paged decode attention (shapes as paged_attention).
     The block table and layer index are scalar-prefetch operands: each
     (slot, page) grid step's index_map reads tbl to DMA the right
-    physical [bT, D] page out of the pool — the cache is read once, at
-    DMA rate, with no gathered per-slot copy. Caller must have checked
-    paged_eligible."""
+    physical page [bT, KV*D] out of the pool as it lies there
+    (serve/paged_kv.py's layout) — the cache is read once, at DMA rate,
+    with no gathered per-slot copy and no relayout. Queries go in and
+    contexts come out as rows of the same kind, [S, G, KV*D]. Caller
+    must have checked paged_eligible."""
     S, KV, G, D = q.shape
-    NB, L, _, bT, _ = pool_k.shape
+    bT, row = pool_k.shape[2:]
     M = tbl.shape[1]
     if q.dtype != pool_k.dtype:
         raise ValueError(
             f"paged_decode_attention requires q.dtype == pool dtype "
             f"(got {q.dtype} vs {pool_k.dtype})")
+    if row != KV * D:
+        raise ValueError(
+            f"pool rows hold {row} values, the queries want KV*D = "
+            f"{KV}*{D} (pool layout: serve/paged_kv.py)")
     if not paged_eligible(KV, G, bT, D, pool_k.dtype.itemsize):
         raise ValueError(
             f"paged_decode_attention ineligible for KV={KV}, G={G}, "
             f"bT={bT}, D={D} (check paged_eligible before calling)")
-    kernel = functools.partial(_paged_kernel, scale=scale, kv=KV)
+    kernel = functools.partial(_paged_kernel, scale=scale, kv=KV, g=G, d=D)
     # the mask rides as [S, M, 1, bT] so a page's (1, 1, 1, bT) block
     # spans the array's last two dims whole — the TPU lowering takes a
     # block whose trailing dims are (8k, 128k) or the full extent, and a
     # (1, bT) window into [S, M*bT] is neither
     ok4 = ok.astype(jnp.int32).reshape(S, M, 1, bT)
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    rows = pl.BlockSpec((1, G, row), lambda s, m, tbl, l: (s, 0, 0))
+    page = pl.BlockSpec((1, 1, bT, row),
+                        lambda s, m, tbl, l: (tbl[s, m], l[0], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,        # tbl, layer
         grid=(S, M),
         in_specs=[
-            pl.BlockSpec((1, KV, G, D), lambda s, m, tbl, l: (s, 0, 0, 0)),
-            pl.BlockSpec((1, 1, KV, bT, D),
-                         lambda s, m, tbl, l: (tbl[s, m], l[0], 0, 0, 0)),
-            pl.BlockSpec((1, 1, KV, bT, D),
-                         lambda s, m, tbl, l: (tbl[s, m], l[0], 0, 0, 0)),
+            rows, page, page,
             pl.BlockSpec((1, 1, 1, bT), lambda s, m, tbl, l: (s, m, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, KV, G, D),
-                               lambda s, m, tbl, l: (s, 0, 0, 0)),
+        out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((KV, G, D), jnp.float32),   # o accumulator
-            pltpu.VMEM((KV, G, 1), jnp.float32),   # running max
-            pltpu.VMEM((KV, G, 1), jnp.float32),   # running sum
+            pltpu.VMEM((G * KV, row), pool_k.dtype),  # block-diagonal q
+            pltpu.VMEM((G * KV, row), jnp.float32),   # o accumulator
+            pltpu.VMEM((G * KV, 1), jnp.float32),     # running max
+            pltpu.VMEM((G * KV, 1), jnp.float32),     # running sum
         ],
     )
     # no dimension_semantics here: the page dimension must stay
     # sequential (the accumulators carry across it), which is the
     # compiler's default for grid_spec-style calls
-    return pl.pallas_call(
+    ctx = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, G, row), jnp.float32),
         interpret=pallas_util.interpret_mode(),
-    )(tbl.astype(jnp.int32), lyr, q, pool_k, pool_v, ok4)
+    )(tbl.astype(jnp.int32), lyr,
+      q.transpose(0, 2, 1, 3).reshape(S, G, row), pool_k, pool_v, ok4)
+    return ctx.reshape(S, G, KV, D).transpose(0, 2, 1, 3)
 
 
 def sharded_paged_attend(shardings):
@@ -348,9 +387,10 @@ def sharded_paged_attend(shardings):
     serve path wraps the UNCHANGED kernel in jax.shard_map and
     hands each shard its own operands:
 
-      pool_k/pool_v  [NB, L, KV/tp, bT, D] per-shard head slice when
-                     the KV axis shards (each shard DMAs only its own
-                     pages), the whole pool otherwise (replicated);
+      pool_k/pool_v  KV/tp whole heads of every page row when the KV
+                     axis shards (serve/paged_kv.pool_partition_spec:
+                     each shard DMAs only its own lanes of a page), the
+                     whole pool otherwise (replicated);
       q / ctx        [S/dp, KV', G', D] — whichever head axis the
                      engine shards (shard_heads), slots split over dp;
       tbl / ok       replicated across tp (every shard walks the same
@@ -366,13 +406,14 @@ def sharded_paged_attend(shardings):
     tbl, layer, ok, scale) drop-in for the paged_attention signature.
     """
     from jax.sharding import PartitionSpec as P
+    from mobilefinetuner_tpu.serve.paged_kv import pool_partition_spec
 
     sh = shardings
     dp = "dp" if sh.dp > 1 else None
     kv_ax = "tp" if sh.kv_shards > 1 else None
     g_ax = "tp" if sh.g_shards > 1 else None
     q_spec = P(dp, kv_ax, g_ax, None)
-    pool_spec = P(None, None, kv_ax, None, None)
+    pool_spec = pool_partition_spec(sh.kv_shards > 1)
 
     def attend(q, pool_k, pool_v, tbl, layer, ok, scale):
         def local(q_, pk_, pv_, tbl_, lyr_, ok_):

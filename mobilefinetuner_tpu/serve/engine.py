@@ -117,7 +117,7 @@ from mobilefinetuner_tpu.models.generate import (gemma3_decode_step_paged,
 from mobilefinetuner_tpu.serve.adapters import AdapterBank
 from mobilefinetuner_tpu.serve.paged_kv import (TRASH_BLOCK, BlockAllocator,
                                                 OutOfBlocks, blocks_for,
-                                                init_pools,
+                                                init_pools, pool_layout,
                                                 write_prompt_blocks)
 from mobilefinetuner_tpu.serve.prefix_cache import PrefixCache, chain_keys
 
@@ -657,6 +657,10 @@ class ServeEngine:
             cfg.attn_impl, KV, nq // KV, cfg.block_T, D,
             self.dtype.itemsize, tp=cfg.mesh_tp)
         self.lora_impl_resolved = lora_impl_resolved
+        # and the pools' resident layout (serve/paged_kv.py): computed
+        # from shapes alone, so a stream says which layout served
+        self.pool_layout = pool_layout(cfg.num_blocks, L, KV,
+                                       cfg.block_T, D)
         self.telemetry = telemetry or Telemetry("")
         # span tracing (core/trace.py). step() is split into `serve.*`
         # spans, which are in any profiler trace taken of this process;
@@ -674,6 +678,7 @@ class ServeEngine:
             "max_new_tokens": cfg.max_new_tokens, "dtype": cfg.dtype,
             "attn_impl": cfg.attn_impl,
             "attn_impl_resolved": self.attn_impl_resolved,
+            **self.pool_layout,
             "lora_impl": cfg.lora_impl,
             "lora_impl_resolved": lora_impl_resolved,
             "adapter_slots": bank.capacity if bank else 0,

@@ -3,15 +3,44 @@
 The generate() path gives every request a contiguous [L, B, KV, T, D]
 cache sized for its worst case — at serving batch sizes that fragments
 HBM (a 2-token health-check ping reserves as much cache as a 2k-token
-completion). Here the cache is ONE pool of fixed-size pages,
-
-    pool_k / pool_v : [num_blocks, L, KV, block_T, D]
-
-and request r's logical column t lives at physical page
+completion). Here the cache is ONE pool of fixed-size pages, and
+request r's logical column t lives at physical page
 `tbl[r, t // block_T]`, offset `t % block_T` — the vLLM PagedAttention
-layout, TPU-shaped: block_T is sublane-aligned so a page is a clean
-[bT, D] tile, and every page holds ALL layers' K/V for its span (one
-allocator decision covers L scatters).
+scheme. Every page holds ALL layers' K/V for its span (one allocator
+decision covers L writes).
+
+THE POOL'S LAYOUT — stated here once; everything that touches a pool
+goes through the functions of this module or names this contract:
+
+    pool_k / pool_v : [num_blocks, L, block_T, KV * D]      (token-major)
+
+A page of one layer is [block_T, KV * D]: one ROW per cached token,
+holding that token's K (or V) of every KV head side by side, head j in
+lanes [j * D, (j + 1) * D) — the row the qkv projection produces, as it
+lies. It is the one layout on which the three parties agree, so no
+compiled program converts the pool between layouts (PERF.md §6, PR 25:
+with the head-major [NB, L, KV, bT, D] pool and D = 64 the decode step
+copied both pools 52 times a token):
+
+  (a) the runtime's buffer between steps: the minor dimension KV * D
+      fills the TPU's 128 lanes for every served model (a D = 64 minor
+      dimension leaves half of each lane tile empty, and the runtime
+      then stores the buffer block-index-minor), so the donated buffer
+      is plain row-major from parameter to result;
+  (b) the token write: a fed token's K/V is one contiguous row per slot
+      (`write_token_rows`), written in place; a prefill chunk's rows
+      (`write_chunk_rows`) and a prompt's whole pages
+      (`write_prompt_blocks`) likewise;
+  (c) the paged kernel (ops/decode_attention.paged_decode_attention)
+      DMAs the page [block_T, KV * D] as it lies and contracts it
+      against block-diagonal queries it lays out in VMEM: no per-head
+      slicing of a row, no relayout.
+
+Nothing here depends on the model's name or on a flag: KV and D come
+from the K/V rows and queries the callers hold, block_T and KV * D from
+the pool's own shape (`pool_layout` is what the engine stamps in its
+run_start manifest). Under tensor parallelism the row shards on its
+lanes (`pool_partition_spec`): KV/tp whole heads per shard, contiguous.
 
 The allocator is deliberately host-side and trivial (a free list over
 ints): allocation happens at most once per admitted request plus once
@@ -57,39 +86,97 @@ def blocks_for(tokens: int, block_T: int) -> int:
     return max(0, -(-int(tokens) // block_T))
 
 
+def pool_shape(num_blocks: int, L: int, KV: int, block_T: int, D: int
+               ) -> tuple:
+    """The module's layout as a shape — the one place it is spelled."""
+    return (num_blocks, L, block_T, KV * D)
+
+
 def init_pools(num_blocks: int, L: int, KV: int, block_T: int, D: int,
                dtype=jnp.float32):
-    """The two device pools, zero-filled (the trash page must hold
-    finite values: idle slots attend their own zero column)."""
-    shape = (num_blocks, L, KV, block_T, D)
+    """The two device pools in the module's layout, zero-filled (the
+    trash page must hold finite values: idle slots attend their own
+    zero column)."""
+    shape = pool_shape(num_blocks, L, KV, block_T, D)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+_LANES = 128
+
+
+def pool_layout(num_blocks: int, L: int, KV: int, block_T: int, D: int
+                ) -> dict:
+    """What the engine stamps into run_start next to attn_impl_resolved
+    (scalars: the manifest keeps nothing else): the pool's axes and
+    shape, and how heads fold into a page row. pool_lane_fill is the
+    share of the TPU's lane tiles a row fills (1.0 unless KV * D is no
+    multiple of 128: gpt2-xl's 25 x 64 = 1600 fills 12.5 of 13)."""
+    shape = pool_shape(num_blocks, L, KV, block_T, D)
+    row = shape[-1]
+    return {"pool_layout": "block,layer,token,kv_head*head_dim",
+            "pool_shape": "x".join(map(str, shape)),
+            "pool_heads_per_row": KV, "pool_head_dim": D,
+            "pool_lane_fill": round(row / (-(-row // _LANES) * _LANES), 4)}
+
+
 def pool_partition_spec(kv_sharded: bool):
-    """PartitionSpec for the pools under the serve ("dp", "tp") mesh
-    (serve/sharding.py). The KV-head axis is the ONLY shardable one:
-    page identity (NB) must stay whole so one host-side block table
-    serves every shard, L is scanned over, and [bT, D] is the page tile
-    the Pallas kernel DMAs. kv_sharded gives each tp shard a per-shard
-    head slice [NB, L, KV/tp, bT, D] of every page; otherwise (GQA
-    head counts indivisible by tp) the pools replicate and the query
-    groups shard instead (ops/decode_attention.shard_heads)."""
+    """PartitionSpec for the pools (module docstring) under the serve
+    ("dp", "tp") mesh (serve/sharding.py). The KV-head axis is the ONLY
+    shardable one, and in a page row it is the lanes: page identity (NB)
+    must stay whole so one host-side block table serves every shard, L
+    is scanned over, and a page's tokens stay together for the kernel's
+    DMA. kv_sharded gives each tp shard KV/tp whole heads of every row
+    (a contiguous lane range); otherwise (GQA head counts indivisible
+    by tp) the pools replicate and the query groups shard instead
+    (ops/decode_attention.shard_heads)."""
     from jax.sharding import PartitionSpec as P
-    return P(None, None, "tp", None, None) if kv_sharded else P()
+    return P(None, None, None, "tp") if kv_sharded else P()
+
+
+def token_rows(x, dtype):
+    """[..., KV, D] per-token K or V -> [..., KV * D] page rows."""
+    return x.reshape(*x.shape[:-2], -1).astype(dtype)
+
+
+def write_token_rows(pool, blk, layer, off, x):
+    """The decode step's write: slot s's fed-token K or V x[s] [KV, D]
+    lands in row off[s] of layer `layer` of page blk[s] — one
+    contiguous row per slot, in place."""
+    return pool.at[blk, layer, off, :].set(token_rows(x, pool.dtype))
+
+
+def write_chunk_rows(pool, blk, off, x):
+    """A prefill chunk's write, all layers at once: x [L, W, KV, D],
+    row w of every layer lands at (blk[w], :, off[w])."""
+    rows = token_rows(x, pool.dtype)                       # [L, W, KV*D]
+    # every (layer, row) is its own index: the update window is one
+    # contiguous page row, as in the decode step's write — a window
+    # over the layer axis would ask for a pool with layers minor
+    layers = jnp.arange(rows.shape[0], dtype=jnp.int32)[:, None]
+    return pool.at[blk[None, :], layers, off[None, :], :].set(rows)
+
+
+def gather_pages(pool, tbl, layer, KV: int):
+    """Layer `layer` of the pages a block table names, heads split out
+    for the XLA attention paths: tbl [..., M] -> [..., M, bT, KV, D]."""
+    pages = pool[tbl, layer]                               # [..., M, bT, KV*D]
+    return pages.reshape(*pages.shape[:-1], KV, -1)
 
 
 def write_prompt_blocks(pool_k, pool_v, k, v, block_ids):
-    """Scatter one prefilled request's K/V into its allocated pages.
+    """Write one prefilled request's K/V into its allocated pages.
 
     k/v: [L, KV, Ppad, D] from *_prefill (B squeezed), Ppad a block_T
     multiple; block_ids: [Ppad // block_T] physical pages, TRASH-padded
     past the prompt's real pages (their garbage columns are never
     attendable). Pure — the engine jits this with the pools donated.
     """
-    NB, L, KV, bT, D = pool_k.shape
-    M = k.shape[2] // bT
-    # [L, KV, M, bT, D] -> [M, L, KV, bT, D]: one row per physical page
-    pages = lambda t: t.reshape(L, KV, M, bT, D).transpose(2, 0, 1, 3, 4)
+    bT = pool_k.shape[2]
+    L, KV, Ppad, D = k.shape
+    M = Ppad // bT
+    # [L, KV, M, bT, D] -> [M, L, bT, KV * D]: one row per physical page
+    pages = lambda t: t.reshape(L, KV, M, bT, D).transpose(
+        2, 0, 3, 1, 4).reshape(M, L, bT, KV * D)
     pool_k = pool_k.at[block_ids].set(pages(k).astype(pool_k.dtype))
     pool_v = pool_v.at[block_ids].set(pages(v).astype(pool_v.dtype))
     return pool_k, pool_v

@@ -2,7 +2,7 @@
 
 The serving step's arrays are tiny on the activation side (one [S, E]
 row per slot) and huge on the weight side (every layer's matrices, the
-[NB, L, KV, bT, D] page pools, the stacked [k, ...] adapter bank). So
+KV page pools, the stacked [k, ...] adapter bank). So
 the sharded serve plane is weight-parallel, GSPMD-style: this module
 PLACES the big buffers with NamedShardings and pins a handful of
 `with_sharding_constraint`s at the head/hidden boundaries inside the
@@ -168,7 +168,7 @@ class ServeSharding:
         return jax.device_put(tree, self.repl)
 
     def pool_sharding(self) -> NamedSharding:
-        """The [NB, L, KV, bT, D] page pools (layout: serve/paged_kv)."""
+        """The page pools (layout and spec: serve/paged_kv)."""
         return self.named(pool_partition_spec(self.kv_shards > 1))
 
     def cache_sharding(self) -> NamedSharding:

@@ -38,10 +38,12 @@ import pytest  # noqa: E402
 from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from mobilefinetuner_tpu.core.config import GPT2Config  # noqa: E402
+from mobilefinetuner_tpu.core.config import (  # noqa: E402
+    Gemma3TextConfig, GPT2Config)
 from mobilefinetuner_tpu.core.xla_stats import (  # noqa: E402
-    compiled_peak_bytes, tpu_custom_calls)
+    compiled_peak_bytes, sized_copies, tpu_custom_calls)
 from mobilefinetuner_tpu.ops import decode_attention as da  # noqa: E402
+from mobilefinetuner_tpu.serve import paged_kv  # noqa: E402
 
 BF16 = jnp.bfloat16
 V5E_HBM = 16 * 2 ** 30
@@ -147,11 +149,19 @@ PAGED_SHAPES = {
     "kv25": (8, 25, 1, 64, 16, 6, 128, 4),
     "gqa_d256": (4, 1, 4, 256, 16, 6, 64, 2),
 }
-# over the VMEM gate: the rule says no AND the compiler says no
+# over the VMEM gate: the rule says no AND the compiler says no (a page
+# is [bT, KV*D] with no lane padding, so it takes twice the tokens the
+# head-major pages took to get there)
 PAGED_REFUSED = {
-    "kv25_bT1024": (8, 25, 1, 64, 1024, 2, 16, 2),
-    "kv12_bT2048": (8, 12, 1, 64, 2048, 2, 16, 2),
+    "kv25_bT2048": (8, 25, 1, 64, 2048, 2, 16, 2),
+    "kv12_bT4096": (8, 12, 1, 64, 4096, 2, 16, 2),
 }
+
+
+def pool_sds(NB, L, KV, bT, D, sharding):
+    """A pool as a shape: whatever serve/paged_kv.py says it is."""
+    return jax.ShapeDtypeStruct(paged_kv.pool_shape(NB, L, KV, bT, D), BF16,
+                                sharding=sharding)
 
 
 def paged_operands(topo, shape, shard=lambda name: None):
@@ -159,9 +169,8 @@ def paged_operands(topo, shape, shard=lambda name: None):
     one = SingleDeviceSharding(topo.devices[0])
     mk = lambda name, shp, dt: jax.ShapeDtypeStruct(
         shp, dt, sharding=shard(name) or one)
-    return (mk("q", (S, KV, G, D), BF16),
-            mk("pool", (NB, L, KV, bT, D), BF16),
-            mk("pool", (NB, L, KV, bT, D), BF16),
+    pool = pool_sds(NB, L, KV, bT, D, shard("pool") or one)
+    return (mk("q", (S, KV, G, D), BF16), pool, pool,
             mk("tbl", (S, M), jnp.int32),
             mk("ok", (S, M * bT), jnp.bool_))
 
@@ -205,7 +214,7 @@ def test_sharded_paged_attend_compiles_on_four_chips(topo, as_tpu, dp, tp):
                              devices=topo.devices)
     dpn = "dp" if dp > 1 else None
     specs = {"q": P(dpn, "tp", None, None),
-             "pool": P(None, None, "tp", None, None),
+             "pool": paged_kv.pool_partition_spec(True),
              "tbl": P(dpn, None), "ok": P(dpn, None)}
     ops = paged_operands(
         topo, PAGED_SHAPES["smoke_engine"],
@@ -245,6 +254,124 @@ def test_lora_epilogue_compiles(topo, as_tpu, N, d_out, r):
         lora_epilogue_add, sds(topo, (N, d_out), BF16),
         sds(topo, (N, r), BF16), sds(topo, (r, d_out), BF16),
         sds(topo, (), jnp.float32)))
+
+
+# --------------------------- the pool is never converted --------------------
+
+def _gpt2_pool_case(cfg, S, NB, P):
+    from mobilefinetuner_tpu.models import generate as g
+    from mobilefinetuner_tpu.models import gpt2
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    return dict(params=params, L=cfg.n_layer, KV=cfg.n_head,
+                D=cfg.head_dim, S=S, NB=NB, P=P,
+                step=functools.partial(g.gpt2_decode_step_paged, cfg,
+                                       compute_dtype=BF16),
+                chunk=functools.partial(g.gpt2_prefill_chunk, cfg,
+                                        compute_dtype=BF16))
+
+
+def _gemma_pool_case(cfg, S, NB, P):
+    from mobilefinetuner_tpu.models import gemma3
+    from mobilefinetuner_tpu.models import generate as g
+    params = jax.eval_shape(
+        lambda: gemma3.init_params(cfg, jax.random.PRNGKey(0)))
+    return dict(params=params, L=cfg.num_hidden_layers,
+                KV=cfg.num_key_value_heads, D=cfg.head_dim, S=S, NB=NB,
+                P=P,
+                step=functools.partial(g.gemma3_decode_step_paged, cfg,
+                                       compute_dtype=BF16),
+                chunk=functools.partial(g.gemma3_prefill_chunk, cfg,
+                                        compute_dtype=BF16))
+
+
+def test_sized_copies_sees_what_the_head_major_pool_compiled_to():
+    """The witness below must not go blind: these are the three kinds of
+    pool-sized instruction the head-major pool compiled to (the in-loop
+    relayout, the entry copy out of the runtime's block-minor buffer,
+    a copy of the pool as a bitcast flattened it), next to lines it has
+    to leave alone."""
+    class Text:
+        def __init__(self, *lines):
+            self.as_text = lambda: "\n".join(lines)
+    n = 1089 * 24 * 16 * 16 * 64
+    hits = [
+        "  %copy.36 = bf16[1089,24,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} "
+        "copy(%get-tuple-element.5), metadata={op_name=\"x\"}",
+        "  ROOT %copy.22 = bf16[1089,24,16,16,64]{0,4,3,2,1:T(8,128)(2,1)}"
+        " copy(%param_1), sharding={replicated}",
+        "  %copy.9 = bf16[418176,1024]{1,0:T(8,128)(2,1)} copy(%bitcast.4)",
+        "  %transpose.3 = bf16[24,1089,16,16,64]{4,3,2,1,0} "
+        "transpose(%p), dimensions={1,0,2,3,4}",
+    ]
+    leave = [
+        "  %copy.2 = bf16[16,1024]{1,0:T(8,128)(2,1)} copy(%fusion.7)",
+        "  %scatter.1 = bf16[1089,24,16,1024]{3,2,1,0:T(8,128)(2,1)} "
+        "scatter(%p, %i, %u), to_apply=%region",
+        "  %fusion.141 = bf16[1089,24,16,1024]{3,2,1,0} fusion(%p, %u), "
+        "kind=kLoop, calls=%fused_computation",
+    ]
+    assert sized_copies(Text(*hits, *leave), n) == [h.strip() for h in hits]
+    assert sized_copies(Text(*leave), n) == []
+
+
+# md_batchgen_long's engine (gpt2-medium: 24 layers, 16 heads of 64; 16
+# slots, 1,089 blocks of 16, prompts to 512, f32 weights as build_engine
+# hands them); gpt2-xl's 25 heads of 64 (a row of 1600 lanes, 12.5 lane
+# tiles) at four layers; Gemma-3 270M's GQA heads (1 KV head of 256, 4
+# query heads) at six layers, so one of them is global. Each pool holds
+# more elements than the model's largest table, so "pool-sized" names
+# the pools alone
+POOL_CASES = {
+    "cell_md": lambda: _gpt2_pool_case(GPT2Config.gpt2_medium(),
+                                       16, 1089, 512),
+    "kv25_d64": lambda: _gpt2_pool_case(
+        dataclasses.replace(GPT2Config.gpt2_xl(), n_layer=4), 8, 1025, 256),
+    "gqa_d256": lambda: _gemma_pool_case(
+        Gemma3TextConfig(num_hidden_layers=6), 8, 8193, 256),
+}
+
+
+@pytest.mark.parametrize("program", ["decode_step", "write_prompt_blocks",
+                                     "prefill_chunk"])
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_no_program_converts_the_pool(topo, as_tpu, case, program):
+    """serve/paged_kv.py's layout contract, witnessed at compile time:
+    with the pools donated (as ServeEngine jits them) no program that
+    touches a pool holds a pool-sized copy or transpose — not in the
+    layer loop, not at the program's entry or exit, where the runtime's
+    own buffer layout shows — and its compiled peak is the pools, the
+    weights and under 1 GB of everything else. The head-major pool this
+    replaced held 6 such instructions in the decode step (52 executed a
+    token at 24 layers) and peaked at 13.7 GB where this asserts 4.1."""
+    c = POOL_CASES[case]()
+    L, KV, D, S, NB, P = (c[k] for k in ("L", "KV", "D", "S", "NB", "P"))
+    bT = 16
+    M = paged_kv.blocks_for(2 * P - 1, bT)
+    params = on_chip0(topo, c["params"])
+    pool = pool_sds(NB, L, KV, bT, D, SingleDeviceSharding(topo.devices[0]))
+    i32 = lambda *shape: sds(topo, shape, jnp.int32)
+    if program == "decode_step":
+        fn, ops, donate = c["step"], (params, pool, pool, i32(S), i32(S),
+                                      i32(S, M)), (1, 2)
+    elif program == "prefill_chunk":
+        fn, ops, donate = c["chunk"], (params, pool, pool, i32(1, P // 2),
+                                       i32(), i32(), i32(1, M)), (1, 2)
+    else:
+        kv = sds(topo, (L, KV, P, D), BF16)
+        fn, ops, donate = paged_kv.write_prompt_blocks, \
+            (pool, pool, kv, kv, i32(P // bT)), (0, 1)
+    compiled = compile_for(fn, *ops, donate_argnums=donate)
+    n_pool = int(np.prod(pool.shape))
+    assert sized_copies(compiled, n_pool) == []
+    nbytes = lambda t: sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                           for x in jax.tree.leaves(t))
+    held = 2 * nbytes(pool) + (nbytes(params) if fn is not
+                               paged_kv.write_prompt_blocks else 0)
+    assert compiled_peak_bytes(compiled) < held + 2 ** 30, (
+        compiled_peak_bytes(compiled) / 1e9, held / 1e9)
+    if program == "decode_step":
+        assert_kernel(compiled)
 
 
 # --------------------------- whole programs (slow) --------------------------
@@ -378,9 +505,8 @@ def test_tp_decode_step_compiles_on_four_chips(topo, as_tpu):
     params = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         params, sh.param_shardings(params))
-    pool = jax.ShapeDtypeStruct(
-        (NB, cfg.n_layer, cfg.n_head, bT, cfg.head_dim), BF16,
-        sharding=sh.pool_sharding())
+    pool = pool_sds(NB, cfg.n_layer, cfg.n_head, bT, cfg.head_dim,
+                    sh.pool_sharding())
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=sh.repl)
     compiled = compile_for(
@@ -452,7 +578,7 @@ def test_gpt2_xl_serve_programs_compile_and_fit(topo, as_tpu, program):
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, BF16),
         jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
-    pool = jax.ShapeDtypeStruct((NB, L, H, bT, D), BF16)
+    pool = pool_sds(NB, L, H, bT, D, None)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     if program == "decode_step":
         fn = functools.partial(g.gpt2_decode_step_paged, cfg,

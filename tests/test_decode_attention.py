@@ -20,6 +20,7 @@ from mobilefinetuner_tpu.ops.decode_attention import (decode_attention,
                                                       paged_eligible,
                                                       pick_kvb,
                                                       xla_reference)
+from mobilefinetuner_tpu.serve.paged_kv import gather_pages, pool_shape
 
 
 def make(B, KV, G, T, D, dtype, seed=0):
@@ -94,8 +95,10 @@ def make_paged(S, KV, G, D, bT, M, NB, L, dtype, seed=0):
     rng = np.random.default_rng(seed)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (S, KV, G, D), dtype)
-    pool_k = jax.random.normal(kk, (NB, L, KV, bT, D), dtype)
-    pool_v = jax.random.normal(kv, (NB, L, KV, bT, D), dtype)
+    # the pool's shape is serve/paged_kv.py's to say
+    shape = pool_shape(NB, L, KV, bT, D)
+    pool_k = jax.random.normal(kk, shape, dtype)
+    pool_v = jax.random.normal(kv, shape, dtype)
     # block tables over non-trash pages; ragged per-slot lengths, plus a
     # sliding-window hole on slot 0 so FULLY-masked pages occur
     tbl = jnp.asarray(rng.integers(1, NB, (S, M)), jnp.int32)
@@ -111,6 +114,10 @@ def make_paged(S, KV, G, D, bT, M, NB, L, dtype, seed=0):
     ((3, 12, 1, 64, 8, 4, 9, 2), jnp.bfloat16),
     ((2, 1, 4, 64, 16, 3, 7, 3), jnp.float32),    # Gemma GQA layout
     ((4, 2, 2, 32, 8, 5, 11, 2), jnp.bfloat16),
+    ((2, 5, 1, 64, 16, 3, 7, 2), jnp.float32),    # odd head count, D=64:
+    ((2, 25, 1, 64, 16, 2, 5, 1), jnp.bfloat16),  # rows of 320 / 1600 lanes
+    ((2, 2, 2, 128, 16, 3, 7, 2), jnp.float32),   # D=128 GQA
+    ((3, 1, 4, 128, 8, 3, 6, 2), jnp.bfloat16),
 ])
 def test_paged_matches_gathered_contiguous(shape, dtype):
     """paged_attention == xla_reference over the gathered contiguous
@@ -123,9 +130,9 @@ def test_paged_matches_gathered_contiguous(shape, dtype):
     assert paged_eligible(KV, G, bT, D, jnp.dtype(dtype).itemsize)
     for layer in range(L):
         got = paged_attention(q, pk, pv, tbl, layer, ok, scale)
-        kc = pk[tbl, layer].transpose(0, 2, 1, 3, 4) \
+        kc = gather_pages(pk, tbl, layer, KV).transpose(0, 3, 1, 2, 4) \
             .reshape(S, KV, M * bT, D)
-        vc = pv[tbl, layer].transpose(0, 2, 1, 3, 4) \
+        vc = gather_pages(pv, tbl, layer, KV).transpose(0, 3, 1, 2, 4) \
             .reshape(S, KV, M * bT, D)
         want = xla_reference(q, kc, vc, ok, scale)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -101,6 +101,90 @@ def test_block_allocator_lifecycle():
     assert blocks_for(8, 8) == 1 and blocks_for(9, 8) == 2
 
 
+@pytest.mark.parametrize("KV,D,dtype", [
+    (5, 64, jnp.bfloat16),     # odd head count at D=64: a row of 320
+    (2, 128, jnp.bfloat16),    # D=128 GQA pool
+    (4, 16, jnp.float32),      # the tiny engines' widths
+])
+def test_pool_round_trip_is_bit_exact(KV, D, dtype):
+    """serve/paged_kv.py's writers against its reader, with no model in
+    between: a prompt's pages (write_prompt_blocks), a prefill chunk's
+    rows (write_chunk_rows) and a decode step's fed-token rows
+    (write_token_rows) come back from gather_pages bit for bit in the
+    pool's dtype, at the column the block table says — and the trash
+    page takes the padding."""
+    from mobilefinetuner_tpu.serve.paged_kv import (gather_pages,
+                                                    init_pools,
+                                                    write_chunk_rows,
+                                                    write_prompt_blocks,
+                                                    write_token_rows)
+    L, bT, NB, P = 3, 8, 9, 20            # 2.5 pages of prompt
+    Ppad = blocks_for(P, bT) * bT
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(KV * D), 3)
+    k = jax.random.normal(kk, (L, KV, Ppad, D), dtype)
+    v = jax.random.normal(kv, (L, KV, Ppad, D), dtype)
+    pages = np.asarray([7, 2, 5], np.int32)            # not in order
+    pk, pv = init_pools(NB, L, KV, bT, D, dtype)
+    pk, pv = write_prompt_blocks(pk, pv, k, v, pages)
+
+    def columns(pool):
+        """[L, KV, 3 * bT, D]: the request's columns as the table
+        orders them."""
+        got = jnp.stack([gather_pages(pool, pages, i, KV)
+                         for i in range(L)])            # [L, M, bT, KV, D]
+        return np.asarray(got.transpose(0, 3, 1, 2, 4).reshape(
+            L, KV, -1, D).astype(jnp.float32))
+
+    np.testing.assert_array_equal(columns(pk), np.asarray(k, np.float32))
+    np.testing.assert_array_equal(columns(pv), np.asarray(v, np.float32))
+    # the same prompt written as ONE chunk (rows past P go to trash)
+    rows = jnp.arange(Ppad)
+    blk = jnp.where(rows < P, jnp.asarray(pages)[rows // bT], TRASH_BLOCK)
+    ck, _ = init_pools(NB, L, KV, bT, D, dtype)
+    ck = write_chunk_rows(ck, blk, rows % bT, k.transpose(0, 2, 1, 3))
+    np.testing.assert_array_equal(columns(ck)[:, :, :P],
+                                  np.asarray(k, np.float32)[:, :, :P])
+    assert not np.asarray(ck)[[1, 3, 4, 6, 8]].any()   # untouched pages
+    # a decode step's write at position P of two slots (one idle: trash)
+    tok = jax.random.normal(kt, (L, 2, KV, D), dtype)
+    for i in range(L):
+        pk = write_token_rows(pk, jnp.asarray([pages[P // bT], TRASH_BLOCK]),
+                              i, jnp.asarray([P % bT, 0]), tok[i])
+    want = np.array(k, np.float32)
+    want[:, :, P] = np.asarray(tok[:, 0], np.float32)
+    np.testing.assert_array_equal(columns(pk), want)
+    np.testing.assert_array_equal(
+        np.asarray(gather_pages(pk, jnp.asarray([TRASH_BLOCK]), L - 1,
+                                KV)[0, 0], np.float32),
+        np.asarray(tok[L - 1, 1], np.float32))
+
+
+def test_run_start_names_the_pool_layout(gpt2_params, tmp_path):
+    """The stream says which pool layout served, next to the resolved
+    attention path: the shape the pools really have, and how many heads
+    fold into a page row."""
+    path = str(tmp_path / "serve.jsonl")
+    eng = ServeEngine("gpt2", GPT2_CFG, gpt2_params,
+                      ServeConfig(num_slots=2, block_T=8, num_blocks=8,
+                                  max_prompt=16, max_new_tokens=8),
+                      telemetry=Telemetry(path))
+    try:
+        L, KV, D = eng._pool_dims
+        shape = list(eng.pool_k.shape)
+        assert shape == list(eng.pool_v.shape)
+    finally:
+        eng.close()
+    start = next(e for e in map(json.loads, open(path))
+                 if e["event"] == "run_start")
+    assert validate_event(start) is None
+    conf = start["config"]
+    assert conf["pool_layout"] == "block,layer,token,kv_head*head_dim"
+    assert conf["pool_shape"] == "x".join(map(str, shape))
+    assert (conf["pool_heads_per_row"], conf["pool_head_dim"]) == (KV, D)
+    assert shape[-1] == KV * D and conf["pool_lane_fill"] == 0.5  # 64 lanes
+    assert conf["attn_impl_resolved"] == eng.attn_impl_resolved
+
+
 def test_serve_config_validation(gpt2_params):
     with pytest.raises(ValueError, match="multiple"):
         ServeConfig(max_prompt=12, block_T=8).validate()

@@ -188,6 +188,31 @@ def test_serve_sharding_build_and_validation():
         make_serve_mesh(0, 2)
 
 
+@pytest.mark.parametrize("family,tp,frac", [
+    ("gpt2", 2, 0.5), ("gpt2", 4, 0.25),   # MHA: the KV heads shard
+    ("gemma", 2, 0.5),                     # KV=2 shards at tp=2
+    ("gemma", 4, 1.0),                     # nq=4, kv=2: pools replicate
+])
+def test_pool_shard_is_whole_heads_of_every_row(family, tp, frac, params):
+    """Each tp shard holds 1/tp of the pool (chip_smoke.py's fraction
+    check), and what it holds is KV/tp WHOLE heads of every page row —
+    a contiguous lane range (serve/paged_kv.py's layout), so the
+    per-shard kernel reads its own heads and the token write stays
+    collective-free."""
+    cfg = ServeConfig(num_slots=4, block_T=8, num_blocks=16,
+                      max_prompt=16, max_new_tokens=8, mesh_tp=tp)
+    eng = ServeEngine(family, FAMS[family][0], params[family], cfg)
+    try:
+        L, KV, D = eng._pool_dims
+        for pool in (eng.pool_k, eng.pool_v):
+            shard = pool.addressable_shards[0].data
+            assert shard.size / pool.size == frac
+            assert shard.shape == (*pool.shape[:3],
+                                   round(KV * frac) * D)
+    finally:
+        eng.close()
+
+
 def test_serve_config_mesh_validation(params):
     with pytest.raises(ValueError, match="mesh"):
         ServeConfig(mesh_dp=0).validate()

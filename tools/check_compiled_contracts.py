@@ -171,7 +171,7 @@ def prog_decode_gpt2_paged():
     L, H = cfg.n_layer, cfg.n_head
     D = cfg.n_embd // cfg.n_head
     bT, NB = 8, 8
-    # serve/engine.py KV pool layout: [NB, L, H, bT, D] per head-pool
+    # the pool's layout is init_pools' to say (serve/paged_kv.py)
     pool_k, pool_v = init_pools(NB, L, H, bT, D)
     traces = {"n": 0}
 

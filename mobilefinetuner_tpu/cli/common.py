@@ -2112,7 +2112,15 @@ def run_training(args, *, trainable, frozen, loss_fn, nll_fn,
                     host_step_ms=host_step_ms["latest"],
                     # the PREVIOUS flush's drained period (a line cannot
                     # hold the time of its own write)
-                    drained_ms=drained[0], drained_parts_ms=drained[1])
+                    drained_ms=drained[0], drained_parts_ms=drained[1],
+                    # the model's own device counters (make_train_step):
+                    # mean / max over the flush's steps, under their names
+                    **{k: sum(float(fm["counters_sum"][k])
+                              for fm in fetched) / len(fetched)
+                       for k in m.get("counters_sum", ())},
+                    **{k: max(float(fm["counters_max"][k])
+                              for fm in fetched)
+                       for k in m.get("counters_max", ())})
             t_emit = time.perf_counter()
             with tracer.span("train.flush.log", "loop"):
                 if emit_log and args.log_interval:

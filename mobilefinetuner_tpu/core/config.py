@@ -169,3 +169,99 @@ class Gemma3TextConfig:
                    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
                    max_position_embeddings=128, sliding_window=16,
                    query_pre_attn_scalar=8.0, sliding_window_pattern=3)
+
+
+@dataclasses.dataclass
+class AfmoeConfig:
+    """AFMoE text decoder (Arcee Trinity; HF `model_type: afmoe`) — the
+    published `config.json` keys, plus what this chip holds of each
+    expert layer.
+
+    `num_experts` is the router's width (every expert of the model);
+    `experts_held = (first, count)` says which of them live here
+    (models/afmoe.py computes their part only, ops/moe.py). Defaults to
+    all. `vocab_size` is the rows of the embedding and of the untied head
+    that live here: a sliced vocabulary is a smaller vocabulary.
+    Defaults are Trinity-Large-Preview's widths at a depth of one period.
+    """
+
+    vocab_size: int = 200192
+    hidden_size: int = 3072
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 3072
+    num_hidden_layers: int = 5
+    num_dense_layers: int = 1
+    num_attention_heads: int = 48
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    num_experts: int = 256
+    num_experts_per_tok: int = 4
+    num_shared_experts: int = 1
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 2.448
+    sliding_window: int = 4096
+    global_attn_every_n_layers: int = 4
+    layer_types: Optional[List[str]] = None
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True
+    tie_word_embeddings: bool = False
+    max_position_embeddings: int = 262144
+    attention_impl: str = "auto"
+    experts_held: Optional[tuple] = None
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            p = self.global_attn_every_n_layers
+            self.layer_types = [
+                "full_attention" if (i + 1) % p == 0 else "sliding_attention"
+                for i in range(self.num_hidden_layers)]
+        self.layer_types = list(self.layer_types)[:self.num_hidden_layers]
+        if self.experts_held is None:
+            self.experts_held = (0, self.num_experts)
+        self.experts_held = tuple(int(x) for x in self.experts_held)
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(f"experts_held={self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+        if self.score_func != "sigmoid" or self.tie_word_embeddings \
+                or self.num_shared_experts != 1:
+            raise ValueError(
+                "AfmoeConfig: only score_func=sigmoid, an untied head and "
+                "one shared expert are implemented (the published Trinity "
+                "configurations)")
+
+    def is_global_layer(self, i: int) -> bool:
+        return self.layer_types[i] == "full_attention"
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+    def with_expert_parallel(self, n: int, rank: int) -> "AfmoeConfig":
+        """This chip as rank `rank` of `n` that share each expert layer:
+        experts [rank·E/n, (rank+1)·E/n)."""
+        if n < 1 or self.num_experts % n or not 0 <= rank < n:
+            raise ValueError(f"expert_parallel={n} rank={rank} does not "
+                             f"divide {self.num_experts} experts")
+        per = self.num_experts // n
+        return dataclasses.replace(self, experts_held=(rank * per, per))
+
+    @classmethod
+    def from_pretrained(cls, model_dir: str) -> "AfmoeConfig":
+        raw = _load_config_json(model_dir)
+        return cls(**{f.name: raw[f.name] for f in dataclasses.fields(cls)
+                      if f.name in raw})
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 300) -> "AfmoeConfig":
+        """Tiny config for tests: one dense layer, then one whole period
+        (s, s, f, s) of expert layers, window < S, GQA, 8 experts top-2."""
+        return cls(vocab_size=vocab_size, hidden_size=64,
+                   intermediate_size=128, moe_intermediate_size=32,
+                   num_hidden_layers=5, num_dense_layers=1,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=16, num_experts=8, num_experts_per_tok=2,
+                   sliding_window=8, max_position_embeddings=128)

@@ -16,6 +16,7 @@ applied — the same reason the reference CLI disables its loader transpose
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import glob as _glob
 import json
 import os
@@ -23,7 +24,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from mobilefinetuner_tpu.core.config import GPT2Config, Gemma3TextConfig
+from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
+                                              Gemma3TextConfig)
 from mobilefinetuner_tpu.io.safetensors_io import (CheckpointIntegrityError,
                                                    SafeTensorsReader,
                                                    atomic_publish,
@@ -256,6 +258,148 @@ def save_gemma3(path: str, params, metadata: Optional[dict] = None):
     transformers)."""
     save_safetensors(path, gemma3_params_to_hf(jax_to_numpy(params)),
                      metadata=metadata or {"format": "pt"})
+
+
+# ----------------------------- AFMoE ---------------------------------------
+#
+# HF `afmoe` key scheme (the family's public modelling code):
+#   model.embed_tokens.weight, lm_head.weight (untied), model.norm.weight,
+#   model.layers.{i}.self_attn.{q,k,v,o,gate}_proj.weight, .q_norm.weight,
+#   .k_norm.weight, .input_layernorm / post_attention_layernorm /
+#   pre_mlp_layernorm / post_mlp_layernorm .weight; dense layers
+#   .mlp.{gate,up,down}_proj.weight; expert layers .mlp.router.gate.weight
+#   [E, H], .mlp.expert_bias [E], .mlp.shared_experts.{gate,up,down}_proj,
+#   .mlp.experts.{e}.{gate,up,down}_proj. Linear weights are [out, in].
+
+_AFMOE_LAYER = "model.layers.{}."
+_AFMOE_NORMS = (("input_ln", "input_layernorm"),
+                ("post_attn_ln", "post_attention_layernorm"),
+                ("pre_mlp_ln", "pre_mlp_layernorm"),
+                ("post_mlp_ln", "post_mlp_layernorm"))
+_AFMOE_ATTN = (("q_w", "q_proj"), ("k_w", "k_proj"), ("v_w", "v_proj"),
+               ("o_w", "o_proj"), ("gate_w", "gate_proj"))
+_SWIGLU = (("gate_w", "gate_proj"), ("up_w", "up_proj"),
+           ("down_w", "down_proj"))
+
+
+def _tensor_readers(model_dir: str) -> Dict[str, SafeTensorsReader]:
+    """key -> the reader of the file that holds it (single-file or
+    sharded layout), nothing loaded yet."""
+    index = os.path.join(model_dir, "model.safetensors.index.json")
+    if os.path.exists(index):
+        with open(index) as f:
+            weight_map = json.load(f)["weight_map"]
+        readers = {sh: SafeTensorsReader(os.path.join(model_dir, sh))
+                   for sh in sorted(set(weight_map.values()))}
+        return {k: readers[sh] for k, sh in weight_map.items()}
+    r = SafeTensorsReader(_find_weights_file(model_dir))
+    return {k: r for k in r.keys()}
+
+
+def load_afmoe(model_dir: str, experts_held: Optional[tuple] = None,
+               config: Optional[AfmoeConfig] = None):
+    """(config, params in models/afmoe.py's layout) from an HF-layout
+    directory. Of each expert layer only the `experts_held = (first,
+    count)` experts are READ (default: all); the absent ones' bytes are
+    never touched. A BF16 tensor stays bf16 (`ml_dtypes.bfloat16`), so a
+    frozen base published in bf16 is resident at 2 bytes a parameter."""
+    if config is None:
+        config = AfmoeConfig.from_pretrained(model_dir)
+    if experts_held is not None:
+        config = dataclasses.replace(config,
+                                     experts_held=tuple(experts_held))
+    c = config
+    readers = _tensor_readers(model_dir)
+
+    def get(key, linear=False):
+        a = readers[key].load(key, keep_bf16=True)
+        return a.T if linear else a      # [out, in] -> [in, out]
+
+    def stack(keys, linear=False):
+        return np.stack([get(k, linear) for k in keys])
+
+    def layers(ids):
+        out = {name: stack([_AFMOE_LAYER.format(i) + hf + ".weight"
+                            for i in ids]) for name, hf in _AFMOE_NORMS}
+        a = _AFMOE_LAYER + "self_attn.{}.weight"
+        out["attn"] = {name: stack([a.format(i, hf) for i in ids], True)
+                       for name, hf in _AFMOE_ATTN}
+        out["attn"].update({n: stack([a.format(i, n) for i in ids])
+                            for n in ("q_norm", "k_norm")})
+        return out
+
+    def swiglu(fmt, ids):
+        return {name: stack([fmt.format(i, hf) for i in ids], True)
+                for name, hf in _SWIGLU}
+
+    nd = c.num_dense_layers
+    dense_ids, moe_ids = range(nd), range(nd, c.num_hidden_layers)
+    first, n = c.experts_held
+    mlp = _AFMOE_LAYER + "mlp."
+    experts = {}
+    for name, hf in _SWIGLU:
+        rows = [np.stack([get(f"{mlp.format(i)}experts.{e}.{hf}.weight",
+                              True) for e in range(first, first + n)])
+                for i in moe_ids]
+        experts[name] = np.stack(rows)
+    params = {
+        "embed": get("model.embed_tokens.weight"),
+        "dense": {**layers(dense_ids),
+                  "mlp": swiglu(mlp + "{}.weight", dense_ids)},
+        "moe": {**layers(moe_ids),
+                "router_w": stack([mlp.format(i) + "router.gate.weight"
+                                   for i in moe_ids], True),
+                "expert_bias": stack([mlp.format(i) + "expert_bias"
+                                      for i in moe_ids]),
+                "shared": swiglu(mlp + "shared_experts.{}.weight",
+                                 moe_ids),
+                "experts": experts},
+        "final_norm": get("model.norm.weight"),
+        "lm_head": get("lm_head.weight"),
+    }
+    return config, params
+
+
+def afmoe_params_to_hf(params, first_expert: int = 0) -> Dict[str, np.ndarray]:
+    """models/afmoe.py's tree -> the HF key scheme above (the inverse of
+    load_afmoe; linear weights back to [out, in]). The held experts are
+    written under their own numbers, `first_expert` onwards."""
+    p = {"model.embed_tokens.weight": np.asarray(params["embed"]),
+         "lm_head.weight": np.asarray(params["lm_head"]),
+         "model.norm.weight": np.asarray(params["final_norm"])}
+    nd = np.asarray(params["dense"]["input_ln"]).shape[0]
+
+    def put_layers(stack, base):
+        for j in range(np.asarray(stack["input_ln"]).shape[0]):
+            pre = _AFMOE_LAYER.format(base + j)
+            for name, hf in _AFMOE_NORMS:
+                p[pre + hf + ".weight"] = np.asarray(stack[name][j])
+            for name, hf in _AFMOE_ATTN:
+                p[f"{pre}self_attn.{hf}.weight"] = \
+                    np.asarray(stack["attn"][name][j]).T
+            for nme in ("q_norm", "k_norm"):
+                p[f"{pre}self_attn.{nme}.weight"] = \
+                    np.asarray(stack["attn"][nme][j])
+            for name, hf in _SWIGLU:
+                if "mlp" in stack:
+                    p[f"{pre}mlp.{hf}.weight"] = \
+                        np.asarray(stack["mlp"][name][j]).T
+                    continue
+                p[f"{pre}mlp.shared_experts.{hf}.weight"] = \
+                    np.asarray(stack["shared"][name][j]).T
+                ex = np.asarray(stack["experts"][name][j])
+                for e in range(ex.shape[0]):
+                    p[f"{pre}mlp.experts.{first_expert + e}.{hf}.weight"] \
+                        = ex[e].T
+            if "mlp" not in stack:
+                p[pre + "mlp.router.gate.weight"] = \
+                    np.asarray(stack["router_w"][j]).T
+                p[pre + "mlp.expert_bias"] = \
+                    np.asarray(stack["expert_bias"][j])
+
+    put_layers(params["dense"], 0)
+    put_layers(params["moe"], nd)
+    return p
 
 
 # --------------------------- checkpoint lineage ------------------------------

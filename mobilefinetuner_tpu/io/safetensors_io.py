@@ -163,11 +163,16 @@ class SafeTensorsReader:
         e = self.entries[name]
         return tuple(e["shape"]), e["dtype"]
 
-    def load(self, name: str, promote_to_f32: bool = False) -> np.ndarray:
+    def load(self, name: str, promote_to_f32: bool = False,
+             keep_bf16: bool = False) -> np.ndarray:
         """Load one tensor as a numpy array (copy).
 
-        BF16 always decodes to float32 (numpy can't hold bf16); other dtypes
-        keep their storage dtype unless promote_to_f32.
+        BF16 decodes to float32 (plain numpy has no bf16) unless
+        `keep_bf16`, which returns the stored 16 bits as an
+        `ml_dtypes.bfloat16` array — half the host memory, and the dtype a
+        device array keeps (io/checkpoints.load_afmoe: a frozen base that
+        stays bf16 from disk to the MXU); other dtypes keep their storage
+        dtype unless promote_to_f32.
         """
         e = self.entries[name]
         tag = e["dtype"]
@@ -178,7 +183,10 @@ class SafeTensorsReader:
         else:
             begin, end = e["data_offsets"]
             raw = np.frombuffer(self._blob[begin:end], dtype=_DTYPES[tag])
-        if tag == "BF16":
+        if tag == "BF16" and keep_bf16 and not promote_to_f32:
+            import ml_dtypes
+            arr = raw.copy().view(ml_dtypes.bfloat16)
+        elif tag == "BF16":
             arr = _bf16_to_f32(raw)
         else:
             arr = raw.copy()
@@ -290,7 +298,10 @@ def _tensor_spec(name, arr, bf16_keys):
     is_bf16_input = arr.dtype.name == "bfloat16"
     shape = arr.shape
     n = int(np.prod(shape, dtype=np.int64))
-    if is_bf16_input or (bf16_keys and name in bf16_keys):
+    if is_bf16_input:   # already the 16 bits to store
+        encode = lambda: np.ascontiguousarray(arr).view(np.uint16).tobytes()
+        return "BF16", shape, n * 2, encode
+    if bf16_keys and name in bf16_keys:
         encode = lambda: _f32_to_bf16_u16(arr.astype(np.float32)).tobytes()
         return "BF16", shape, n * 2, encode
     dtype = arr.dtype if arr.dtype in _TO_TAG else np.dtype(np.float32)

@@ -72,6 +72,11 @@ GEMMA_TARGETS = {
     "down_proj": lambda c: (c.intermediate_size, c.hidden_size),
     "lm_head": lambda c: (c.hidden_size, c.vocab_size),  # tied embed head
 }
+# AFMoE (Arcee Trinity): the attention projections of every layer, dense
+# and expert alike — the usual set for expert models. The output gate,
+# the router, the shared and the routed experts stay frozen.
+AFMOE_TARGETS = {t: GEMMA_TARGETS[t]
+                 for t in ("q_proj", "k_proj", "v_proj", "o_proj")}
 # targets with ONE site instead of a per-layer stack: A [in, r],
 # B [r, out] (no leading L axis; maybe_lora's ndim checks skip the
 # layer_idx slice for them)
@@ -163,6 +168,14 @@ def init_lora_gemma3(config, spec: LoRASpec, key: jax.Array,
     if isinstance(targets, str):
         targets = GEMMA_PRESETS[targets]
     dims = {t: GEMMA_TARGETS[t](config) for t in targets}
+    return init_lora(dims, config.num_hidden_layers, spec, key, dtype)
+
+
+def init_lora_afmoe(config, spec: LoRASpec, key: jax.Array,
+                    dtype=jnp.float32) -> dict:
+    """Stacks over ALL layers, the dense ones first (models/afmoe.py)."""
+    dims = {t: AFMOE_TARGETS[t](config)
+            for t in spec.targets or list(AFMOE_TARGETS)}
     return init_lora(dims, config.num_hidden_layers, spec, key, dtype)
 
 

@@ -40,6 +40,11 @@ GEMMA_PEFT_MODULES.update({
     t: "model.layers.{}.mlp." + t for t in
     ("gate_proj", "up_proj", "down_proj")
 })
+# AFMoE (HF `afmoe`): the same attention module names as Gemma's
+AFMOE_PEFT_MODULES = {t: GEMMA_PEFT_MODULES[t] for t in
+                      ("q_proj", "k_proj", "v_proj", "o_proj")}
+PEFT_MODULES = {"gpt2": GPT2_PEFT_MODULES, "gemma": GEMMA_PEFT_MODULES,
+                "afmoe": AFMOE_PEFT_MODULES}
 # For adapter_config.json target_modules. PEFT suffix-matches these against
 # full module paths, so they must be path-qualified: a bare "c_proj" would
 # match BOTH attn.c_proj and mlp.c_proj and make PEFT instantiate phantom
@@ -94,8 +99,7 @@ def export_peft(out_dir: str, lora_tree, spec: LoRASpec, family: str,
     PEFT. A/B are stored in torch nn.Linear layout: lora_A.weight [r, in],
     lora_B.weight [out, r] (our stacked layout is A [L, in, r], B [L, r, out]
     → transpose per layer)."""
-    modules = (GPT2_PEFT_MODULES if family == "gpt2"
-               else GEMMA_PEFT_MODULES)
+    modules = PEFT_MODULES[family]
     unsupported = sorted(set(lora_tree["blocks"]) - set(modules))
     if unsupported:
         raise ValueError(
@@ -147,8 +151,7 @@ def import_peft(adapter_dir: str, family: str) -> Tuple[dict, LoRASpec]:
                     dropout=cfg.get("lora_dropout", 0.0), init="peft")
     path = os.path.join(adapter_dir, "adapter_model.safetensors")
     raw = SafeTensorsReader(path).load_all(promote_to_f32=True)
-    modules = (GPT2_PEFT_MODULES if family == "gpt2"
-               else GEMMA_PEFT_MODULES)
+    modules = PEFT_MODULES[family]
     blocks: dict = {}
     for name, fmt in modules.items():
         per_layer_A, per_layer_B = [], []

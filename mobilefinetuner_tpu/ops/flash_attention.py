@@ -477,13 +477,58 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, pad_ref, seed_ref, lse_ref, delta_ref,
 
 
 _BWD_VMEM_BUDGET = 12 * 2 ** 20
+# from LONG_S on, the backward kernels' whole-S operands outgrow the
+# 16 MiB of scoped VMEM a kernel gets by default (see merged_bwd_fits)
+_LONG_S = 4096
+_BWD_VMEM_LONG = 48 * 2 ** 20
+
+
+def _bwd_laid_out(S: int, D: int, block_k: int, itemsize: int) -> int:
+    """Bytes the merged backward's operands take in VMEM as the compiler
+    lays them out: widths padded to 128 lanes, an [S, 1] float32 column
+    (lse, delta) one 128-lane row per query, and every pipelined operand
+    twice for its two buffers. The split pair's dK/dV kernel holds the
+    same whole-S q, dO, lse and delta, less the dQ slabs."""
+    W = -(-D // 128) * 128
+    return (2 * 2 * S * W * itemsize      # q + dO slabs
+            + S * W * 4                   # dq f32 scratch accumulator
+            + 2 * S * W * itemsize        # dq output block
+            + 2 * 2 * S * 128 * 4         # lse + delta columns
+            + 2 * 2 * block_k * W * (itemsize + 4))   # K/V in, dK/dV out
+
+
+def _bwd_vmem_limit(S: int, D: int, block_k: int, itemsize: int):
+    """vmem_limit_bytes of a backward kernel that holds whole-S operands:
+    None below LONG_S (the default every shape there has compiled and run
+    with), else the operands as laid out plus 12 MiB for the [BQ, BK]
+    float32 tile temporaries (s, p, dp, ds, the matmul results), capped
+    at 96 of the chip's 128 MiB."""
+    if S < _LONG_S:
+        return None
+    return min(_bwd_laid_out(S, D, block_k, itemsize) + 12 * 2 ** 20,
+               96 * 2 ** 20)
 
 
 def merged_bwd_fits(S: int, D: int, block_k: int, itemsize: int) -> bool:
     """VMEM accounting for one merged-backward program: whole-S q/dO
     slabs + the f32 dQ accumulator + the dQ output block + lse/Δ rows
     resident for the whole (b, h) sweep, plus double-buffered K/V input
-    and dK/dV output blocks."""
+    and dK/dV output blocks.
+
+    Below S = 4096 this is the plain count every shape so far has
+    compiled and run under (S ≤ 2048, D ≤ 256): verdicts and compiler
+    params unchanged. From 4096 on that count admits what the compiler
+    refuses — compiled for a described v5e inside the AFMoE train step
+    at S = 8192, D = 128 (PR 27), the merged kernel "ran out of memory
+    in memory space vmem", and the split pair's dK/dV kernel, which
+    holds the same slabs, would too: the [S, 1] columns alone are 2 x 2
+    x 4 MiB as laid out. There the operands are counted as laid out
+    (`_bwd_laid_out`), the backward kernels ask for that much VMEM plus
+    their tile temporaries (`_bwd_vmem_limit`), and the merged kernel is
+    taken while the count stays under 48 MiB — 33.5 MiB at S = 8192,
+    D = 128 in bf16."""
+    if S >= _LONG_S:
+        return _bwd_laid_out(S, D, block_k, itemsize) <= _BWD_VMEM_LONG
     need = (2 * S * D * itemsize          # q + dO slabs
             + S * D * 4                   # dq f32 scratch accumulator
             + S * D * itemsize            # dq output block
@@ -548,7 +593,9 @@ def _bwd_merged(scale, causal, window, block_q, block_k, p_drop, q, k, v,
             jax.ShapeDtypeStruct((B, Hq, S, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
-        **tpu_call_params("parallel", "parallel", "arbitrary"),
+        **tpu_call_params("parallel", "parallel", "arbitrary",
+                          vmem_limit_bytes=_bwd_vmem_limit(
+                              S, D, block_k, q.dtype.itemsize)),
     )
     with jax.named_scope("attention"), jax.named_scope("flash_bwd_merged"):
         dq, dk_p, dv_p = call(q, k, v, pad3, seed, lse, delta, do)
@@ -660,7 +707,9 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
             jax.ShapeDtypeStruct((B, Hkv, S, D), jnp.float32),
         ],
         **tpu_call_params("parallel", "parallel",
-                          "parallel" if G == 1 else "arbitrary"),
+                          "parallel" if G == 1 else "arbitrary",
+                          vmem_limit_bytes=_bwd_vmem_limit(
+                              S, D, block_k, q.dtype.itemsize)),
     )
     with jax.named_scope("attention"), jax.named_scope("flash_bwd_dkv"):
         dk, dv = dkv_call(q, k, v, pad3, seed, lse, delta, do)

@@ -17,14 +17,20 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_call_params(*dimension_semantics: str) -> dict:
+def tpu_call_params(*dimension_semantics: str,
+                    vmem_limit_bytes=None) -> dict:
     """The compiler_params + interpret kwargs every gridded pallas_call
     in this tree passes — one copy of the dimension-semantics plumbing
     so a kernel cannot set semantics without also consulting the
-    interpret policy. Returns a dict to splat into pl.pallas_call."""
+    interpret policy. Returns a dict to splat into pl.pallas_call.
+    vmem_limit_bytes: ask for more than the 16 MiB of scoped VMEM a
+    kernel gets by default (None: the default, the params every kernel
+    had before one needed more)."""
+    extra = {} if vmem_limit_bytes is None \
+        else {"vmem_limit_bytes": int(vmem_limit_bytes)}
     return dict(
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=dimension_semantics),
+            dimension_semantics=dimension_semantics, **extra),
         interpret=interpret_mode())
 
 

@@ -83,7 +83,13 @@ def make_train_step(loss_fn: Callable[[Any, Any, dict], tuple],
     """Build the jitted optimizer step.
 
     loss_fn(trainable, frozen, micro_batch) -> (sum_loss, weight): the SUM
-    of per-token losses and the token count (or any weight). Accumulation
+    of per-token losses and the token count (or any weight). A third
+    element, {"sum": {name: scalar}, "max": {name: scalar}}, carries the
+    model's own device counters (models/afmoe.py: assignments to the held
+    experts, their load imbalance): summed or maximised over the
+    micro-batches, they ride the metrics (`counters_sum`, `counters_max`)
+    and reach `step_stats` with the loss at the flush, at no sync of
+    their own. Accumulation
     sums both across micro-batches and divides once at the end, so the
     update equals the gradient of total_loss/total_weight over the whole
     batch — exact even when micro-batches have unequal valid-token counts
@@ -125,21 +131,21 @@ def make_train_step(loss_fn: Callable[[Any, Any, dict], tuple],
         micro = reshape_for_accum(batch, accum)
 
         def sum_fn(tr, mb):
-            s, w = loss_fn(tr, frozen, mb)
-            return s, w
+            s, w, *counters = loss_fn(tr, frozen, mb)
+            return s, (w, counters[0] if counters else {})
 
         vg = jax.value_and_grad(sum_fn, has_aux=True)
 
         def body(carry, mb):
             g_acc, loss_acc, w_acc = carry
-            (s, w), g = vg(trainable, mb)
+            (s, (w, counters)), g = vg(trainable, mb)
             g_acc = jax.tree.map(jnp.add, g_acc, g)
             return (g_acc, loss_acc + s,
-                    w_acc + w.astype(jnp.float32)), None
+                    w_acc + w.astype(jnp.float32)), counters
 
         g0 = jax.tree.map(lambda t: jnp.zeros(t.shape, jnp.float32),
                           trainable)
-        (g_sum, loss_sum, w_sum), _ = jax.lax.scan(
+        (g_sum, loss_sum, w_sum), counters = jax.lax.scan(
             body, (g0, jnp.float32(0.0), jnp.float32(0.0)), micro)
         inv = 1.0 / jnp.maximum(w_sum, 1.0)
         grads = jax.tree.map(lambda g: g * inv, g_sum)
@@ -192,6 +198,11 @@ def make_train_step(loss_fn: Callable[[Any, Any, dict], tuple],
                    "update_ratio": upd_norm / jnp.maximum(w_norm, 1e-20),
                    "nonfinite_count": nonfinite.astype(jnp.int32),
                    "skipped": skipped}
+        if counters:    # stacked over the micro-batches by the scan
+            metrics["counters_sum"] = {
+                k: v.sum() for k, v in counters.get("sum", {}).items()}
+            metrics["counters_max"] = {
+                k: v.max() for k, v in counters.get("max", {}).items()}
         return trainable2, opt_state2, metrics
 
     donate_argnums = (0, 2) if donate else ()

@@ -13,10 +13,12 @@ import os
 import jax
 import numpy as np
 
-from mobilefinetuner_tpu.core.config import GPT2Config, Gemma3TextConfig
-from mobilefinetuner_tpu.io.checkpoints import gpt2_params_to_hf
+from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
+                                              Gemma3TextConfig)
+from mobilefinetuner_tpu.io.checkpoints import (afmoe_params_to_hf,
+                                                gpt2_params_to_hf)
 from mobilefinetuner_tpu.io.safetensors_io import save_safetensors
-from mobilefinetuner_tpu.models import gemma3, gpt2
+from mobilefinetuner_tpu.models import afmoe, gemma3, gpt2
 
 WIKI_LINES = [
     " = Heading = ",
@@ -136,6 +138,27 @@ MMLU_ROWS = [
     ("Which animal barks ?", "dog", "cat", "fish", "bird", "A"),
     ("How many days in a week ?", "five", "six", "eight", "seven", "D"),
 ]
+
+
+def write_tiny_afmoe_dir(d: str, seed: int = 0, dtype=None):
+    """HF-layout AFMoE checkpoint dir (config.json with the published key
+    names, model.safetensors in the afmoe key scheme, the tiny GPT-2
+    byte-level tokenizer). dtype: the matrices' storage dtype (e.g.
+    jnp.bfloat16: stored BF16). Returns (config, params as written)."""
+    import dataclasses
+    import jax.numpy as jnp
+    os.makedirs(d, exist_ok=True)
+    config = AfmoeConfig.tiny(vocab_size=train_tiny_gpt2_tokenizer(d))
+    with open(os.path.join(d, "config.json"), "w") as f:
+        raw = dataclasses.asdict(config)
+        del raw["experts_held"], raw["attention_impl"]
+        json.dump({"model_type": "afmoe", **raw}, f)
+    params = afmoe.init_params(config, jax.random.PRNGKey(seed),
+                               dtype or jnp.float32)
+    save_safetensors(os.path.join(d, "model.safetensors"),
+                     afmoe_params_to_hf(jax.tree.map(np.asarray, params)),
+                     metadata={"format": "pt"})
+    return config, params
 
 
 def write_tiny_mmlu_dir(d: str, split: str = "test") -> str:

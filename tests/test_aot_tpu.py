@@ -138,6 +138,77 @@ def test_flash_backward_compiles(topo, as_tpu, shape, bwd_impl):
     assert n == (2 if bwd_impl == "merged" else 3)
 
 
+# --------------------------- AFMoE at S = 8192 -------------------------------
+
+# trinity_lora_s8192's attention: head size 128, GQA 48/8, with and
+# without the 4,096 window. From S = 4096 on the backward kernels hold
+# more whole-S operands than the default 16 MiB of scoped VMEM
+# (flash_attention.merged_bwd_fits): both implementations ask for more.
+AFMOE_FLASH = (1, 48, 8, 8192, 128)
+
+
+@pytest.mark.parametrize("bwd_impl", ["merged", "split"])
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_compiles_at_the_expert_cell_s_shapes(topo, as_tpu, window,
+                                                    bwd_impl):
+    from mobilefinetuner_tpu.ops.flash_attention import (flash_attention,
+                                                         resolve_bwd_impl)
+    B, nq, nkv, S, D = AFMOE_FLASH
+    q, kv = sds(topo, (B, nq, S, D), BF16), sds(topo, (B, nkv, S, D), BF16)
+    assert resolve_bwd_impl(S, D, 512, 2) == "merged"
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, is_causal=True,
+                               sliding_window=window, bwd_impl=bwd_impl
+                               ).astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert_kernel(c, 2 if bwd_impl == "merged" else 3)
+
+
+def test_grouped_product_compiles_and_skips_the_absent_tail(topo, as_tpu):
+    """The expert layer's three grouped products at the published widths
+    over the whole [4, 32, ...] stack: Mosaic kernels, forward and the
+    backward to x (no product for the frozen experts' own gradient), and
+    no copy of a layer's experts out of the stack."""
+    from mobilefinetuner_tpu.ops import moe
+    T, k, H, I, L, n = 8192, 4, 3072, 3072, 4, 32
+    ex = {"gate_w": sds(topo, (L, n, H, I), BF16),
+          "up_w": sds(topo, (L, n, H, I), BF16),
+          "down_w": sds(topo, (L, n, I, H), BF16)}
+
+    def f(m, idx, w, ex, layer):
+        return moe.expert_layer(m, idx, w, ex, (0, n), layer)[0] \
+            .astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(f, argnums=(0, 2)), sds(topo, (T, H), BF16),
+                    sds(topo, (T, k), jnp.int32),
+                    sds(topo, (T, k), jnp.float32), ex,
+                    sds(topo, (), jnp.int32))
+    assert assert_kernel(c, 6) == 6      # 3 forward + 3 to x, no tgmm
+    assert not sized_copies(c, n * H * I)
+
+
+@pytest.mark.slow
+def test_the_expert_cell_s_train_step_compiles_and_fits(topo, as_tpu):
+    """The LoRA step of trinity_lora_s8192 as benchmark/tools/size_afmoe.py
+    builds it, at the cell's batch size: it compiles for a v5e, holds the
+    flash and grouped-product kernels, and stays within 0.85 of
+    bytes_limit."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench, os.path.join(bench, "tools")]
+    import size_afmoe
+    from harness import common
+    from harness.traffic import argv_value
+    B = int(argv_value(common.load_cell("trinity_lora_s8192")
+                       ["traffic_file"]["argv"], "--batch_size"))
+    c = size_afmoe.lower_step(B, topo.devices[0]).compile()
+    assert_kernel(c, 30)
+    assert compiled_peak_bytes(c) <= 0.85 * size_afmoe.BYTES_LIMIT
+
+
 # --------------------------- paged decode attention -------------------------
 
 # (S, KV, G, D, bT, M, NB, L): the smoke's engine (build_engine("gpt2s"),

@@ -284,8 +284,38 @@ def test_span_reader(reader, obs, trace, want, notes_ok):
     bench_cases.check(reader, obs, trace, want, notes_ok)
 
 
-def test_new_layer_metrics_are_appended_with_their_readers():
-    bench_cases.test_new_metrics_are_listed_with_their_readers()
+def listed_with_its_reader(name: str) -> None:
+    """A per-layer metric of BENCHMARK.json, wherever it stands in the
+    list: its file names a callable reader and agrees with the entry."""
+    from harness import common
+    entries = {m["name"]: m for m in common.benchmark_json()["per_layer"]}
+    assert name in entries
+    spec = common.named("layer_metrics", name)
+    assert callable(common.resolve(spec["reader"]))
+    for key in ("unit", "better", "source", "layer", "moves", "workloads"):
+        assert entries[name][key] == spec[key], (name, key)
+
+
+@pytest.mark.parametrize("name", ["train_drained_share",
+                                  "train_drained_max_ms",
+                                  "serve_host_turn_ms_batch"])
+def test_new_layer_metrics_are_appended_with_their_readers(name):
+    """PR 24's three metrics (the benchmark's own hand-run test of this,
+    `benchmark/tests/test_span_readers.py`, also asks that they be the
+    LAST three, which no later metric can leave true)."""
+    listed_with_its_reader(name)
+
+
+@pytest.mark.parametrize("name", ["mfu_required_afmoe", "moe_share_of_step",
+                                  "expert_gmm_roofline",
+                                  "flash_mixed_roofline",
+                                  "moe_load_max_over_mean"])
+def test_the_expert_cell_s_layer_metrics_are_listed_with_their_readers(name):
+    """PR 27's five, each reported in `trinity_lora_s8192` alone."""
+    listed_with_its_reader(name)
+    from harness import common
+    assert common.named("layer_metrics", name)["workloads"] \
+        == ["trinity_lora_s8192"]
 
 
 # --------------------------- what the spans replaced -------------------------

@@ -52,6 +52,14 @@ DECODE_SCOPES = ("decode_kv_write", "decode_paged_attn",
                  "decode_proj_mlp", "decode_head")
 
 
+# an AFMoE step's scopes (models/afmoe.py, ops/moe.py): the families'
+# outer ones, and inside them what tells the router from the sort from
+# the grouped product, and windowed attention from full
+AFMOE_SCOPES = TRAIN_SCOPES + (
+    "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
+    "moe_combine", "attn_window", "attn_full")
+
+
 def _ensure_cpu_devices() -> None:
     """Force the 8-virtual-device CPU platform BEFORE jax initializes
     (same recipe as tests/conftest.py) so the fsdp program lowers at a
@@ -113,6 +121,49 @@ def prog_train_gpt2_lora():
     text = step.lower(tr, params, opt, batch,
                       jnp.int32(3)).compile().as_text()
     return text, retraces, TRAIN_SCOPES
+
+
+def prog_train_afmoe_lora():
+    """Single-device AFMoE LoRA optimizer step (one expert-parallel rank
+    of two), donate=True, the model's counters riding the metrics."""
+    import jax
+    import jax.numpy as jnp
+    from mobilefinetuner_tpu.core.config import AfmoeConfig
+    from mobilefinetuner_tpu.lora.lora import (LoRASpec, init_lora_afmoe,
+                                               trainable_mask)
+    from mobilefinetuner_tpu.models import afmoe
+    from mobilefinetuner_tpu.ops.loss import chunked_lm_cross_entropy_sum
+    from mobilefinetuner_tpu.train.trainer import (TrainConfig,
+                                                   init_optimizer,
+                                                   make_train_step)
+    cfg = AfmoeConfig.tiny().with_expert_parallel(2, 1)
+    params = afmoe.init_params(cfg, jax.random.PRNGKey(0))
+    lora = init_lora_afmoe(cfg, LoRASpec(rank=2, alpha=4.0, init="peft"),
+                           jax.random.PRNGKey(1))
+    mask = trainable_mask(lora)
+    tc = TrainConfig(total_steps=8, lr=1e-3, warmup_ratio=0.0,
+                     schedule="constant")
+    traces = {"n": 0}
+
+    def loss_fn(lo, p, mb):
+        traces["n"] += 1  # runs exactly when jax (re)traces
+        hidden, aux = afmoe.hidden_states(
+            cfg, p, mb["input_ids"], attention_mask=mb["attention_mask"],
+            lora=lo, remat=True, with_aux=True)
+        s, n = chunked_lm_cross_entropy_sum(hidden, p["lm_head"],
+                                            mb["labels"], num_chunks=2)
+        return s, n, aux["counters"]
+
+    step = make_train_step(loss_fn, tc, mask=mask, donate=True)
+    opt = init_optimizer(lora, tc, mask)
+    batch = _tiny_batch(cfg, 2, 32)
+    tr = lora
+    for i in range(3):
+        tr, opt, _ = step(tr, params, opt, batch, jnp.int32(i))
+    retraces = traces["n"]
+    text = step.lower(tr, params, opt, batch,
+                      jnp.int32(3)).compile().as_text()
+    return text, retraces, AFMOE_SCOPES
 
 
 def prog_train_gpt2_fsdp():
@@ -379,6 +430,7 @@ def prog_multitenant_gpt2():
 
 PROGRAMS = {
     "train_gpt2_lora": prog_train_gpt2_lora,
+    "train_afmoe_lora": prog_train_afmoe_lora,
     "train_gpt2_fsdp": prog_train_gpt2_fsdp,
     "decode_gpt2_paged": prog_decode_gpt2_paged,
     "decode_gpt2_paged_tp": prog_decode_gpt2_paged_tp,

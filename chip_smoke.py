@@ -22,8 +22,10 @@ timings, not metrics. The LAST line of stdout is exactly
 `{"ok": false, ...}` with a non-zero exit if any phase failed.
 
   --chips 4   runs ONLY what exists across chips, and what it is compared
-              with: FSDP LoRA training (--mesh_fsdp 4 vs a one-device mesh)
-              and tensor-parallel decode (mesh_tp=4 vs the (1,1) engine).
+              with: FSDP LoRA training (--mesh_fsdp 4 vs a one-device mesh,
+              at S = 128 and at S = 1024, where attention is the flash
+              kernels) and tensor-parallel decode (mesh_tp=4 vs the (1,1)
+              engine).
               Fails with fewer than four devices; the last line's count is 4.
   --rehearse  CPU rehearsal of the control flow at tiny sizes. Every line it
               prints says so, and it can never print the line above.
@@ -178,12 +180,12 @@ def sizes(rehearse: bool) -> dict:
     a tiny model for the CPU rehearsal (control flow only)."""
     if rehearse:
         return {"vocab": 300, "train": [(8, 32, 6), (2, 64, 4)],
-                "fsdp": (8, 32, 4), "eval": (2, 32, 3),
+                "fsdp": [(8, 32, 4), (4, 64, 3)], "eval": (2, 32, 3),
                 "serve_model": "tiny-gpt2", "slots": 4, "block_T": 8,
                 "blocks": 64, "max_prompt": 16, "max_new": 8,
                 "requests": 10, "numerics_S": 64, "tp": 2}
     return {"vocab": 50257, "train": [(64, 128, 12), (4, 1024, 6)],
-            "fsdp": (64, 128, 8), "eval": (8, 128, 4),
+            "fsdp": [(64, 128, 8), (8, 1024, 4)], "eval": (8, 128, 4),
             "serve_model": "gpt2s", "slots": 8, "block_T": 16,
             "blocks": 256, "max_prompt": 64, "max_new": 32,
             "requests": 36, "numerics_S": 1024, "tp": 4}
@@ -378,12 +380,21 @@ def phase_eval(smoke: Smoke) -> bool:
 
 def phase_fsdp(smoke: Smoke) -> bool:
     """FSDP LoRA training over four chips against the same seed and
-    global batch on a one-device mesh of the same machine."""
-    sz = sizes(smoke.rehearse)
-    B, S, n = sz["fsdp"]
+    global batch on a one-device mesh of the same machine, at each of
+    two sizes: the short one every record has, and S = 1024, where
+    `auto` is the flash kernels — under shard_map over the mesh's batch
+    axes on four chips (ops/attention.attention), plain on one."""
+    ok = True
+    for B, S, n in sizes(smoke.rehearse)["fsdp"]:
+        ok &= fsdp_against_one_device(smoke, B, S, n)
+    return ok
+
+
+def fsdp_against_one_device(smoke: Smoke, B: int, S: int, n: int) -> bool:
+    flash = not smoke.rehearse and S >= 512   # resolve_impl at D = 64
     runs = {}
     ok = True
-    for name, fsdp in (("fsdp4", 4), ("fsdp1", 1)):
+    for name, fsdp in ((f"fsdp4_S{S}", 4), (f"fsdp1_S{S}", 1)):
         cmd, tel, _ = train_cmd(smoke, name, B, S, n,
                                 ["--mesh_fsdp", str(fsdp)])
         rc, lines, secs = smoke.child(name, cmd)
@@ -391,18 +402,21 @@ def phase_fsdp(smoke: Smoke) -> bool:
         if ck(rc == 0, f"CLI exit code {rc}"):
             ev = read_events(ck, tel)
             rep = check_train(smoke, ck, ev, steps=range(1, n + 1),
-                              want_kernel=False,
+                              want_kernel=flash,
                               device_count=smoke.device["count"])
+            if flash:
+                ck(rep["attention_impl"] == "flash",
+                   f"attention auto -> {rep['attention_impl']!r} at S={S}")
             comp = (by_event(ev, "compile") or [{}])[0]
             rep["collectives"] = comp.get("collectives")
             rep["mesh_shape"] = by_event(ev, "run_start")[0].get(
                 "mesh_shape")
-        runs[name] = rep
+        runs[fsdp] = rep
         ok &= smoke.report(f"train_{name}", ck, rc, secs, lines, **rep)
     if not ok:
         return False
     ck = Check()
-    a, b = runs["fsdp4"], runs["fsdp1"]
+    a, b = runs[4], runs[1]
     rel = [abs(x - y) / abs(y) for x, y in zip(a["losses"], b["losses"])]
     ck(len(rel) == n and max(rel) <= LOSS_TOL_FSDP,
        f"per-step losses differ by up to {max(rel or [1]):.2e} "
@@ -428,9 +442,12 @@ def phase_fsdp(smoke: Smoke) -> bool:
            and all(0.15 <= s <= 0.40 for s in share),
            f"per-device share of the one-device bytes {share}, "
            f"want about 0.25 each")
-    return smoke.line("fsdp4_vs_1", not ck.bad, failures=ck.bad,
+    both = lambda key: {"fsdp4": a[key], "fsdp1": b[key]}
+    return smoke.line(f"fsdp4_vs_1_S{S}", not ck.bad, failures=ck.bad,
                       max_rel_loss_diff=max(rel) if rel else None,
                       loss_tol=LOSS_TOL_FSDP,
+                      attention_impl_resolved=both("attention_impl"),
+                      tpu_custom_calls=both("tpu_custom_calls"),
                       collectives_fsdp4=col, mesh_fsdp4=a["mesh_shape"],
                       live_mb_by_device_fsdp4=per4, live_mb_one_device=one,
                       share_of_one_device=share)

@@ -541,8 +541,13 @@ def build_mesh(args):
     """Returns (mesh, cp_mesh): cp_mesh is the mesh again when
     --sequence_parallel is set (pass it to the model forwards so ring
     attention engages), else None — deriving it HERE keeps every CLI's
-    wiring consistent. --multihost (or JAX_* env) first brings up the
-    distributed runtime and switches to the DCN-aware hybrid layout."""
+    wiring consistent. The mesh itself goes to the model forwards too
+    (mesh=): over a batch-parallel mesh the attention dispatcher runs
+    the flash kernels under shard_map, so --attention_impl means over a
+    mesh what it means on one chip; --lora_impl's `auto` still becomes
+    the naive path here (its kernel is not wrapped). --multihost (or
+    JAX_* env) first brings up the distributed runtime and switches to
+    the DCN-aware hybrid layout."""
     from mobilefinetuner_tpu.parallel.distributed import (initialize,
                                                           make_hybrid_mesh)
     # initialize() no-ops without --multihost / --dist_coordinator /
@@ -605,22 +610,21 @@ def build_mesh(args):
                 f"divisible by {b_axis} under multi-host (eval batches "
                 f"shard like train batches)")
         # the partitioned step cannot hold a Mosaic kernel outside
-        # shard_map (ops/pallas_util.partitioned_impl): `auto` becomes
-        # the XLA path HERE, where the mesh is known — the CLIs apply
-        # args.attention_impl to their config after this call. Under
-        # sequence parallelism attention is the ring (its flash
-        # partials sit inside shard_map) and the flag is not consulted.
-        from mobilefinetuner_tpu.ops.pallas_util import partitioned_impl
-        try:
-            if hasattr(args, "lora_impl"):
+        # shard_map (ops/pallas_util.partitioned_impl): the LoRA
+        # epilogue is not wrapped, so `auto` becomes the naive path HERE,
+        # where the mesh is known. --attention_impl is left as given:
+        # the attention dispatcher wraps the flash kernels over the
+        # batch axes itself (ops/attention.attention, which the model
+        # forwards hand this mesh), and under sequence parallelism
+        # attention is the ring.
+        if hasattr(args, "lora_impl"):
+            from mobilefinetuner_tpu.ops.pallas_util import \
+                partitioned_impl
+            try:
                 args.lora_impl = partitioned_impl(
                     "--lora_impl", args.lora_impl, "fused", "naive", size)
-            if hasattr(args, "attention_impl") and not sp:
-                args.attention_impl = partitioned_impl(
-                    "--attention_impl", args.attention_impl, "flash",
-                    "xla", size)
-        except ValueError as e:
-            raise SystemExit(str(e))
+            except ValueError as e:
+                raise SystemExit(str(e))
     return mesh, (mesh if sp else None)
 
 
@@ -756,13 +760,15 @@ def compute_dtype_from_args(args):
 
 def with_attention_impl(args, config):
     """`config` with --attention_impl applied, for a train CLI to call
-    AFTER build_mesh (which turns `auto` into the XLA path when the step
-    is partitioned over several devices) and after seq_len is clamped.
-    Also resolves `auto` for the run's (seq_len, head_dim) through the
-    model blocks' own rule (ops/attention.resolve_impl) and stamps it
-    into args, so the run_start manifest says which attention path
-    trained — flash means the Pallas kernels, and the `compile` event's
-    tpu_custom_calls then says they are really in the program."""
+    after seq_len is clamped. Also resolves `auto` for the run's
+    (seq_len, head_dim) through the model blocks' own rule
+    (ops/attention.resolve_impl — the same on one chip and over a
+    batch-parallel mesh, where the dispatcher wraps the kernels in
+    shard_map) and stamps it into args, so the run_start manifest says
+    which attention path trained — flash means the Pallas kernels, and
+    the `compile` event's tpu_custom_calls then says they are really in
+    the program (0 with `flash` stamped: the dispatcher fell back, e.g.
+    a batch the mesh does not divide)."""
     import dataclasses
     from mobilefinetuner_tpu.ops.attention import resolve_impl
     impl = args.attention_impl

@@ -197,7 +197,7 @@ def main(argv=None) -> int:
             config, params_t, mb["input_ids"],
             attention_mask=mb["attention_mask"],
             compute_dtype=compute_dtype, remat=args.remat,
-            cp_mesh=cp_mesh)
+            cp_mesh=cp_mesh, mesh=mesh)
         return chunked_lm_cross_entropy_sum(
             hidden, params_t["embed"], mb["labels"],
             num_chunks=args.loss_chunks, mesh=ce_mesh)
@@ -206,7 +206,7 @@ def main(argv=None) -> int:
         hidden = gemma3.hidden_states(
             config, params_t, mb["input_ids"],
             attention_mask=mb["attention_mask"],
-            compute_dtype=compute_dtype, cp_mesh=cp_mesh)
+            compute_dtype=compute_dtype, cp_mesh=cp_mesh, mesh=mesh)
         return chunked_lm_cross_entropy_sum(
             hidden, params_t["embed"], mb["labels"],
             num_chunks=args.loss_chunks, mesh=ce_mesh)

@@ -122,14 +122,15 @@ def main(argv=None) -> int:
         logits = gpt2.forward(config, params_t, mb["input_ids"],
                               attention_mask=mb["attention_mask"],
                               compute_dtype=compute_dtype, remat=args.remat,
-                              dropout_rng=rng, cp_mesh=cp_mesh)
+                              dropout_rng=rng, cp_mesh=cp_mesh,
+                              mesh=mesh)
         return lm_cross_entropy_sum(logits, mb["labels"])
 
     def nll_fn(params_t, _unused, mb):
         logits = gpt2.forward(config, params_t, mb["input_ids"],
                               attention_mask=mb["attention_mask"],
                               compute_dtype=compute_dtype,
-                              cp_mesh=cp_mesh)
+                              cp_mesh=cp_mesh, mesh=mesh)
         return lm_cross_entropy_sum(logits, mb["labels"])
 
     def save_hook(step, params_t, opt_st, final, ckpt=None):

@@ -181,7 +181,7 @@ def main(argv=None) -> int:
                               remat=args.remat, offload=offload_arg,
                               lora_dropout=args.lora_dropout,
                               dropout_rng=rng, cp_mesh=cp_mesh,
-                              lora_impl=args.lora_impl)
+                              lora_impl=args.lora_impl, mesh=mesh)
         return lm_cross_entropy_sum(logits, mb["labels"])
 
     def nll_fn(lora_t, frozen, mb):
@@ -190,7 +190,7 @@ def main(argv=None) -> int:
                               attention_mask=mb["attention_mask"],
                               lora=lora_t, compute_dtype=compute_dtype,
                               offload=offload_arg, cp_mesh=cp_mesh,
-                              lora_impl=args.lora_impl)
+                              lora_impl=args.lora_impl, mesh=mesh)
         return lm_cross_entropy_sum(logits, mb["labels"])
 
     def offload_rung():

@@ -197,7 +197,7 @@ def main(argv=None) -> int:
             compute_dtype=compute_dtype, remat=args.remat,
             lora_dropout=args.lora_dropout, dropout_rng=rng,
             block_stream=stream, cp_mesh=cp_mesh,
-            lora_impl=args.lora_impl)
+            lora_impl=args.lora_impl, mesh=mesh)
         # lm_head tied to embeddings; chunked CE avoids [B,S,262k]
         # logits — an opt-in "lm_head" adapter rides it as lora_head
         # (its delta stays chunk-local / in-kernel, DESIGN.md §17),
@@ -216,7 +216,7 @@ def main(argv=None) -> int:
             config, p, mb["input_ids"],
             attention_mask=mb["attention_mask"], lora=lora_t,
             compute_dtype=compute_dtype, block_stream=stream,
-            cp_mesh=cp_mesh, lora_impl=args.lora_impl)
+            cp_mesh=cp_mesh, lora_impl=args.lora_impl, mesh=mesh)
         return chunked_lm_cross_entropy_sum(
             hidden, p["embed"], mb["labels"], num_chunks=args.loss_chunks,
             mesh=ce_mesh, sequence_parallel=ce_sp,

@@ -94,7 +94,7 @@ from mobilefinetuner_tpu.models.lora_apply import maybe_lora
 def _block(c: Gemma3TextConfig, bp, x, padding_mask, masks, ropes,
            is_global, lora_b, i, lora_dropout=0.0, dropout_rng=None,
            cp_mesh=None, cp_axis="fsdp", collect_kv: bool = False,
-           lora_impl: str = "auto"):
+           lora_impl: str = "auto", mesh=None):
     """One Gemma-3 block; bp leaves are THIS layer's weights (sliced out of
     the [L, ...] stacks by the scan body); i (traced scalar) indexes the
     still-stacked LoRA leaves, RoPE tables, and masks. collect_kv: also
@@ -161,11 +161,11 @@ def _block(c: Gemma3TextConfig, bp, x, padding_mask, masks, ropes,
             # lax.cond (each branch compiles its own kernel variant).
             ctx = jax.lax.cond(
                 is_global[i],
-                lambda ops: attention(*ops, impl="flash", scale=scale,
-                                      is_causal=True,
+                lambda ops: attention(*ops, impl="flash", mesh=mesh,
+                                      scale=scale, is_causal=True,
                                       padding_mask=padding_mask),
-                lambda ops: attention(*ops, impl="flash", scale=scale,
-                                      is_causal=True,
+                lambda ops: attention(*ops, impl="flash", mesh=mesh,
+                                      scale=scale, is_causal=True,
                                       sliding_window=c.sliding_window,
                                       padding_mask=padding_mask),
                 (q, k, v))
@@ -199,8 +199,11 @@ def hidden_states(config: Gemma3TextConfig, params, input_ids,
                   offload=None, block_stream=None,
                   collect_layers: bool = False, collect_kv: bool = False,
                   cp_mesh=None, cp_axis: str = "fsdp",
-                  scan_unroll: int = 1, lora_impl: str = "auto"):
-    """offload: optional (plan, shardings) pair matching `params`; offloaded
+                  scan_unroll: int = 1, lora_impl: str = "auto",
+                  mesh=None):
+    """mesh: the mesh a batch-parallel step is partitioned over, for the
+    attention dispatcher (ops/attention.attention).
+    offload: optional (plan, shardings) pair matching `params`; offloaded
     block weights stream host->HBM per layer inside the scan (forces remat
     of the block body) — see parallel/offload.py. block_stream: pre-resolved
     stream fn for callers that already ran resolve_offload (so the fetched
@@ -257,7 +260,7 @@ def hidden_states(config: Gemma3TextConfig, params, input_ids,
         r = _block(c, slice_layer(i), x, attention_mask, masks, ropes,
                    is_global, lora_b, i, lora_dropout, dropout_rng,
                    cp_mesh, cp_axis, collect_kv=collect_kv,
-                   lora_impl=lora_impl)
+                   lora_impl=lora_impl, mesh=mesh)
         x2, kv = r if collect_kv else (r, None)
         return x2, (kv if collect_kv else (x2 if collect_layers else None))
     if remat or stream is not None:
@@ -281,7 +284,8 @@ def forward(config: Gemma3TextConfig, params, input_ids,
             attention_mask=None, lora=None, compute_dtype=jnp.float32,
             remat: bool = False, lora_dropout: float = 0.0,
             dropout_rng=None, offload=None, cp_mesh=None,
-            cp_axis: str = "fsdp", lora_impl: str = "auto") -> jnp.ndarray:
+            cp_axis: str = "fsdp", lora_impl: str = "auto",
+            mesh=None) -> jnp.ndarray:
     """Logits [B, S, V]; lm_head tied to the embedding table. An
     "lm_head" adapter entry adds its delta at the logits projection
     (training paths should prefer the chunked CE's lora_head= instead —
@@ -291,7 +295,7 @@ def forward(config: Gemma3TextConfig, params, input_ids,
     x = hidden_states(config, params, input_ids, attention_mask, lora,
                       compute_dtype, remat, lora_dropout, dropout_rng,
                       block_stream=stream, cp_mesh=cp_mesh,
-                      cp_axis=cp_axis, lora_impl=lora_impl)
+                      cp_axis=cp_axis, lora_impl=lora_impl, mesh=mesh)
     logits = x @ params["embed"].astype(compute_dtype).T
     lora_b = None if lora is None else lora.get("blocks")
     if lora_b is not None and "lm_head" in lora_b:

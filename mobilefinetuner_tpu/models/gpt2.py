@@ -86,12 +86,14 @@ def init_params(config: GPT2Config, key: jax.Array,
 def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
            lora_dropout=0.0, dropout_rng=None, cp_mesh=None,
            cp_axis="fsdp", collect_kv: bool = False,
-           lora_impl: str = "auto"):
+           lora_impl: str = "auto", mesh=None):
     """One pre-LN transformer block. bp leaves are THIS layer's weights
     (already sliced out of the [L, ...] stacks by the scan body); layer_idx
     (traced scalar) indexes the still-stacked LoRA leaves and salts
     dropout keys. cp_mesh: sequence-parallel mode — attention runs as
     ring attention over the mesh axis (parallel/ring_attention.py).
+    mesh: the mesh a batch-parallel step is partitioned over, for the
+    attention dispatcher (ops/attention.attention).
     collect_kv: also return this layer's (k, v) head tensors [B, H, S, D]
     (KV-cache prefill, models/generate.py)."""
     eps = config.layer_norm_epsilon
@@ -135,8 +137,8 @@ def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
                                  padding_mask=padding_mask)
         else:
             ctx = attention(to_heads(q), to_heads(k), to_heads(v),
-                            impl=config.attention_impl, is_causal=True,
-                            padding_mask=padding_mask,
+                            impl=config.attention_impl, mesh=mesh,
+                            is_causal=True, padding_mask=padding_mask,
                             attn_dropout=config.attn_pdrop,
                             attn_dropout_rng=attn_rng)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, E)
@@ -167,7 +169,7 @@ def hidden_states(config: GPT2Config, params, input_ids,
                   offload=None, block_stream=None,
                   collect_layers: bool = False, collect_kv: bool = False,
                   cp_mesh=None, cp_axis: str = "fsdp",
-                  lora_impl: str = "auto"):
+                  lora_impl: str = "auto", mesh=None):
     """Final-LN hidden states [B, S, E] (pre lm_head).
 
     offload: optional (plan, shardings) pytree pair matching `params`
@@ -214,7 +216,7 @@ def hidden_states(config: GPT2Config, params, input_ids,
     def body(x, i):
         r = _block(config, slice_layer(i), x, padding_mask, lora_b, i,
                    lora_dropout, dropout_rng, cp_mesh, cp_axis,
-                   collect_kv=collect_kv, lora_impl=lora_impl)
+                   collect_kv=collect_kv, lora_impl=lora_impl, mesh=mesh)
         x2, kv = r if collect_kv else (r, None)
         return x2, (kv if collect_kv else (x2 if collect_layers else None))
     if remat or stream is not None:
@@ -234,7 +236,7 @@ def forward(config: GPT2Config, params, input_ids, attention_mask=None,
             lora=None, compute_dtype=jnp.float32, remat: bool = False,
             lora_dropout: float = 0.0, dropout_rng=None,
             offload=None, cp_mesh=None, cp_axis: str = "fsdp",
-            lora_impl: str = "auto") -> jnp.ndarray:
+            lora_impl: str = "auto", mesh=None) -> jnp.ndarray:
     """Logits [B, S, V]. Tied lm_head: x @ wte^T (gpt2_model.cpp:421-440).
 
     The reference caches wte^T when embeddings are frozen (SURVEY.md
@@ -247,7 +249,7 @@ def forward(config: GPT2Config, params, input_ids, attention_mask=None,
     x = hidden_states(config, params, input_ids, attention_mask, lora,
                       compute_dtype, remat, lora_dropout, dropout_rng,
                       block_stream=stream, cp_mesh=cp_mesh,
-                      cp_axis=cp_axis, lora_impl=lora_impl)
+                      cp_axis=cp_axis, lora_impl=lora_impl, mesh=mesh)
     logits = x @ params["wte"].astype(compute_dtype).T
     lora_b = None if lora is None else lora.get("blocks")
     if lora_b is not None and "lm_head" in lora_b:

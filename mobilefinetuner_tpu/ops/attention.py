@@ -17,6 +17,7 @@ broadcasted iotas (no data-dependent control flow).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -118,11 +119,57 @@ def resolve_impl(S: int, D: int) -> str:
     return "flash" if S >= (512 if D <= 128 else 2048) else "xla"
 
 
-def attention(q, k, v, *, impl: str = "auto", **kwargs):
+def _flash_over_batch(mesh, axes, q, k, v, *, padding_mask=None,
+                      attn_dropout_rng=None, **kwargs):
+    """flash_attention under shard_map over the batch axes of `mesh`.
+
+    A Mosaic kernel cannot be GSPMD-partitioned, but attention is
+    independent per sequence and the training meshes shard activations on
+    the batch axis only, so every device runs the unchanged kernels on its
+    own sequences and no collective is needed. The custom VJP sits inside
+    the wrapped function: reverse mode transposes the shard_map and the
+    backward kernels run per shard the same way (as ring attention's flash
+    body does, whose check_vma choice this follows).
+
+    In-kernel dropout hashes (seed, b, h, row, col) with b the LOCAL batch
+    index, so the shard's position on the batch axes is folded into the
+    key: two shards never draw the same mask for their k-th sequence, and
+    the backward — same key, same position — regenerates the forward's."""
+    from jax.sharding import PartitionSpec as P
+    from mobilefinetuner_tpu.ops.flash_attention import flash_attention
+    B, _, S, _ = q.shape
+    if padding_mask is None:
+        padding_mask = jnp.ones((B, S), jnp.float32)
+    heads = P(axes, None, None, None)
+    operands, specs = [q, k, v, padding_mask], [heads] * 3 + [P(axes, None)]
+    if attn_dropout_rng is not None:
+        operands.append(attn_dropout_rng)
+        specs.append(P())
+
+    def per_shard(q, k, v, pad, *rng):
+        key = (jax.random.fold_in(rng[0], jax.lax.axis_index(axes))
+               if rng else None)
+        return flash_attention(q, k, v, padding_mask=pad,
+                               attn_dropout_rng=key, **kwargs)
+
+    return jax.shard_map(per_shard, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=heads, check_vma=False)(*operands)
+
+
+def attention(q, k, v, *, impl: str = "auto", mesh=None, **kwargs):
     """Dispatch between the XLA reference and the Pallas flash kernel.
 
     impl='auto' picks per shape (resolve_impl); 'flash' / 'xla' force the
     respective path.
+
+    mesh: the mesh the enclosing step is partitioned over, batch sharded
+    over its ("data", "fsdp") axes (None or one device: no partitioning).
+    Over more than one device the flash kernels run under shard_map on
+    each device's own sequences (_flash_over_batch) — taken only where
+    the kernels are eligible for (S, D), which batch sharding does not
+    change, and the batch divides by the product of the batch axes
+    (shard_map refuses an uneven split where GSPMD pads); otherwise the
+    XLA path, which GSPMD partitions like any other op.
     """
     if not (kwargs.get("attn_dropout", 0.0) > 0.0
             and kwargs.get("attn_dropout_rng") is not None):
@@ -141,7 +188,15 @@ def attention(q, k, v, *, impl: str = "auto", **kwargs):
             raise NotImplementedError(
                 "attention_impl='flash' requires the Pallas kernel "
                 "(ops/flash_attention.py); use attention_impl='xla'") from e
-        return flash_attention.flash_attention(q, k, v, **kwargs)
+        if mesh is None or mesh.size == 1:
+            return flash_attention.flash_attention(q, k, v, **kwargs)
+        # the batch axes as parallel/mesh.batch_sharding states them
+        from mobilefinetuner_tpu.parallel.mesh import batch_sharding
+        axes = tuple(batch_sharding(mesh).spec[0])
+        n = math.prod(mesh.shape[a] for a in axes)
+        if (q.shape[0] % n == 0 and kwargs.get("attn_mask") is None
+                and flash_attention.kernel_eligible(*q.shape[2:])):
+            return _flash_over_batch(mesh, axes, q, k, v, **kwargs)
     # backward-impl selection is a flash-kernel knob; the XLA path has
     # one backward (jax autodiff)
     kwargs.pop("bwd_impl", None)

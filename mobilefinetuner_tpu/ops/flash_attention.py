@@ -773,10 +773,12 @@ def _flash_lse_bwd(scale, causal, window, block_q, block_k, bwd_impl, res,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def flash_partial_eligible(S: int, D: int) -> bool:
-    """Can flash_attention_partial serve a [.., S, D] shard? (The ring
-    dispatcher asks this OUTSIDE shard_map, where the decision must be
-    static.)"""
+def kernel_eligible(S: int, D: int) -> bool:
+    """Do the kernels (at their default blocks) serve a [.., S, D]
+    operand, or would flash_attention / flash_attention_partial fall
+    back? The dispatchers that wrap them in shard_map ask this OUTSIDE
+    it, where the decision must be static: the ring on its shard's S,
+    ops/attention.attention on the whole S (it shards the batch only)."""
     return D in (64, 128, 256) and _valid_blocks(S, 512, 512) is not None
 
 

@@ -37,14 +37,19 @@ def tpu_call_params(*dimension_semantics: str,
 def partitioned_impl(flag: str, impl: str, kernel: str, plain: str,
                      n_devices: int) -> str:
     """An implementation switch's value for a step GSPMD partitions over
-    `n_devices`. A Mosaic kernel cannot be partitioned automatically
-    (the TPU lowering refuses: "wrap the call in a shard_map"), and
-    interpret mode never shows it — the CPU mesh tests partition the
-    interpreted kernel like any XLA op. So over more than one device
-    `auto` means the XLA path `plain`, and asking for `kernel` by name
-    is an error here rather than deep inside the compile. The kernels
-    that DO run on a mesh are wrapped where they are called
-    (sharded_paged_attend, ring attention's partials)."""
+    `n_devices`, for a kernel that is NOT wrapped in shard_map where it
+    is called. A Mosaic kernel cannot be partitioned automatically (the
+    TPU lowering refuses: "wrap the call in a shard_map"), and interpret
+    mode never shows it — the CPU mesh tests partition the interpreted
+    kernel like any XLA op. So over more than one device `auto` means
+    the XLA path `plain`, and asking for `kernel` by name is an error
+    here rather than deep inside the compile. Its two callers: the LoRA
+    epilogue over a training mesh (cli/common.build_mesh) and the serve
+    engine's one-shot prefill, whose heads are sharded over `tp`
+    (serve/engine.py). The kernels that DO run on a mesh are wrapped
+    where they are called and never come here: training attention over
+    the batch axes (ops/attention.attention), sharded_paged_attend,
+    ring attention's partials, the vocab-parallel CE."""
     if n_devices <= 1 or impl == plain:
         return impl
     if impl == kernel:

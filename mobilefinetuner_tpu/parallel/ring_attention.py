@@ -203,9 +203,9 @@ def ring_attention(q, k, v, mesh: Mesh, *,
     # (per-device scores stay blockwise in VMEM, O(Sq·D) HBM); the dense
     # body is the fallback oracle for tiny/odd shapes and non-causal use.
     from mobilefinetuner_tpu.ops.flash_attention import \
-        flash_partial_eligible
+        kernel_eligible
     Sq = S // mesh.shape[axis]
-    if is_causal and flash_partial_eligible(Sq, D):
+    if is_causal and kernel_eligible(Sq, D):
         fn = partial(_ring_shard_flash, axis=axis, n=mesh.shape[axis],
                      scale=float(scale), window=window)
     else:

@@ -147,6 +147,32 @@ def test_flash_backward_compiles(topo, as_tpu, shape, bwd_impl):
 AFMOE_FLASH = (1, 48, 8, 8192, 128)
 
 
+@pytest.mark.parametrize("data,fsdp", [(1, 4), (2, 2)])
+def test_flash_over_the_batch_axes_compiles_on_four_chips(topo, as_tpu,
+                                                          data, fsdp):
+    """The training mesh's attention (ops/attention.attention with the
+    mesh): the forward and the merged backward under shard_map over
+    ("data", "fsdp"), at the FSDP cell's shape — 32 sequences of gpt2-xl
+    over four described chips. Outside shard_map the TPU lowering refuses
+    the kernel in a partitioned program."""
+    from mobilefinetuner_tpu.ops.attention import attention
+    from mobilefinetuner_tpu.parallel.mesh import batch_sharding, make_mesh
+    mesh = make_mesh(data=data, fsdp=fsdp, devices=topo.devices)
+    x = jax.ShapeDtypeStruct((32, 25, 1024, 64), BF16,
+                             sharding=batch_sharding(mesh))
+    pad = jax.ShapeDtypeStruct((32, 1024), jnp.float32,
+                               sharding=batch_sharding(mesh))
+
+    def grads(mesh):
+        return jax.grad(lambda q, k, v, pad: attention(
+            q, k, v, impl="auto", mesh=mesh, padding_mask=pad).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))
+
+    assert_kernel(compile_for(grads(mesh), x, x, x, pad), at_least=2)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(grads(None)).lower(x, x, x, pad)
+
+
 @pytest.mark.parametrize("bwd_impl", ["merged", "split"])
 @pytest.mark.parametrize("window", [None, 4096])
 def test_flash_compiles_at_the_expert_cell_s_shapes(topo, as_tpu, window,
@@ -448,9 +474,10 @@ def test_no_program_converts_the_pool(topo, as_tpu, case, program):
 # --------------------------- whole programs (slow) --------------------------
 
 def gpt2_train_step(cfg: GPT2Config, B: int, S: int, remat: bool,
-                    lora_impl: str = "auto", out_shardings=None):
+                    lora_impl: str = "auto", out_shardings=None, mesh=None):
     """The GPT-2 LoRA optimizer step as cli/gpt2_lora_finetune builds it
-    (bf16, the smoke's four targets), and its operands as shapes."""
+    (bf16, the smoke's four targets; `mesh` as the CLI hands it to the
+    model), and its operands as shapes."""
     from mobilefinetuner_tpu.lora.lora import (LoRASpec, init_lora_gpt2,
                                                trainable_mask)
     from mobilefinetuner_tpu.models import gpt2
@@ -471,7 +498,7 @@ def gpt2_train_step(cfg: GPT2Config, B: int, S: int, remat: bool,
         logits = gpt2.forward(cfg, frozen, mb["input_ids"],
                               attention_mask=mb["attention_mask"],
                               lora=lora_t, compute_dtype=BF16, remat=remat,
-                              lora_impl=lora_impl)
+                              lora_impl=lora_impl, mesh=mesh)
         return lm_cross_entropy_sum(logits, mb["labels"])
 
     batch = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
@@ -500,10 +527,25 @@ def test_train_step_compiles_and_fits(topo, as_tpu, model, B, S, remat,
     assert_fits(compiled)
 
 
+def place(tree, shardings):
+    """Shapes of `tree` under a tree (or prefix) of shardings."""
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=s),
+        tree, shardings)
+
+
+def assert_fsdp_collectives(compiled) -> dict:
+    from mobilefinetuner_tpu.core.static_checks import hlo_collective_census
+    census = hlo_collective_census(compiled.as_text())
+    assert census["all-gather"] > 0, census       # weights gathered
+    assert census["all-reduce"] + census["reduce-scatter"] > 0, census
+    return census
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("B,S", [(64, 128), (8, 1024)])
-def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S):
-    """chip_smoke.py --chips 4's FSDP run, rehearsed before a four-chip
+@pytest.mark.parametrize("B,S,kernels", [(64, 128, False), (8, 1024, True)])
+def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S, kernels):
+    """chip_smoke.py --chips 4's FSDP runs, rehearsed before a four-chip
     call is paid for: the frozen base sharded by the CLI's own rule
     (parallel/mesh.params_shardings) over a (1, 4) mesh of the described
     devices, the batch over both axes, LoRA + Adam state replicated and
@@ -511,10 +553,11 @@ def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S):
 
     The TPU lowering refuses a Mosaic kernel in a GSPMD-partitioned
     step ("cannot be automatically partitioned"), which no interpret-
-    mode mesh test can see: with the one-chip `auto` choices this step
-    does not compile, with cli/common.build_mesh's (partitioned_impl)
-    it does."""
-    from mobilefinetuner_tpu.core.static_checks import hlo_collective_census
+    mode mesh test can see. Attention's `auto` compiles as it is: the
+    model hands the dispatcher the mesh and the flash kernels sit under
+    shard_map (in the executable from S = 1024, the XLA path at 128 by
+    the one-chip rule). The LoRA epilogue is not wrapped: `auto` there
+    compiles only after cli/common.build_mesh's partitioned_impl."""
     from mobilefinetuner_tpu.ops.pallas_util import partitioned_impl
     from mobilefinetuner_tpu.parallel.mesh import (batch_sharding,
                                                    make_mesh,
@@ -524,16 +567,11 @@ def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S):
     repl = replicated_sharding(mesh)
     all_repl = lambda tree: jax.tree.map(lambda _: repl, tree)
 
-    def lowered(attention_impl, lora_impl):
-        cfg = dataclasses.replace(GPT2Config.gpt2_small(),
-                                  attention_impl=attention_impl)
+    def lowered(lora_impl, mesh_arg):
         # (a sharding is a pytree prefix: one covers a whole subtree)
         step, (lora, params, opt, batch, _) = gpt2_train_step(
-            cfg, B, S, False, lora_impl=lora_impl,
-            out_shardings=(repl, repl, repl))
-        place = lambda tree, sh: jax.tree.map(
-            lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                              sharding=s), tree, sh)
+            GPT2Config.gpt2_small(), B, S, False, lora_impl=lora_impl,
+            out_shardings=(repl, repl, repl), mesh=mesh_arg)
         return params, step.lower(
             place(lora, all_repl(lora)),
             place(params, params_shardings(params, mesh)),
@@ -542,21 +580,73 @@ def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S):
                                       batch)),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
 
+    naive = partitioned_impl("lora_impl", "auto", "fused", "naive", 4)
     with pytest.raises(NotImplementedError, match="shard_map"):
-        lowered("auto", "auto")
-    params, low = lowered(
-        partitioned_impl("attention_impl", "auto", "flash", "xla", 4),
-        partitioned_impl("lora_impl", "auto", "fused", "naive", 4))
+        lowered("auto", mesh)          # the unwrapped LoRA epilogue
+    if kernels:
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            lowered(naive, None)       # flash with no mesh to wrap it over
+    params, low = lowered(naive, mesh)
     compiled = low.compile()
-    census = hlo_collective_census(compiled.as_text())
-    assert census["all-gather"] > 0, census       # weights gathered
-    assert census["all-reduce"] + census["reduce-scatter"] > 0, census
+    assert (tpu_custom_calls(compiled.as_text()) > 0) is kernels
+    assert_fsdp_collectives(compiled)
     # per device: a quarter of the base, not all of it
     one_chip = 4 * sum(int(np.prod(x.shape)) for x in
                        jax.tree.leaves(params))
     assert compiled.memory_analysis().argument_size_in_bytes \
         < 0.4 * one_chip
     assert_fits(compiled)
+
+
+@pytest.mark.slow
+def test_the_fsdp_cell_s_full_ft_step_compiles_and_fits(topo, as_tpu):
+    """`xl_fullft_fsdp4`'s step as cli/gpt2_full_finetune builds it:
+    gpt2-xl, every parameter trainable and FSDP-sharded over (1, 4) with
+    Adam's state on the params' shardings, global B 32 x S 1024, bf16,
+    remat, `auto` attention — the flash kernels under shard_map on 8
+    sequences a chip. Prints the compiled peak (PERF.md §4 quotes it)."""
+    from mobilefinetuner_tpu.models import gpt2
+    from mobilefinetuner_tpu.ops.loss import lm_cross_entropy_sum
+    from mobilefinetuner_tpu.parallel.mesh import (batch_sharding,
+                                                   make_mesh,
+                                                   params_shardings,
+                                                   replicated_sharding)
+    from mobilefinetuner_tpu.train.trainer import (TrainConfig,
+                                                   init_optimizer,
+                                                   make_train_step)
+    B, S = 32, 1024
+    cfg = dataclasses.replace(GPT2Config.gpt2_xl(), embd_pdrop=0.0,
+                              resid_pdrop=0.0, attn_pdrop=0.0)
+    mesh = make_mesh(data=1, fsdp=4, devices=topo.devices)
+    repl = replicated_sharding(mesh)
+    tc = TrainConfig(total_steps=1000, schedule="constant")
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    opt = jax.eval_shape(lambda p: init_optimizer(p, tc, None), params)
+
+    def loss_fn(params_t, _unused, mb):
+        logits = gpt2.forward(cfg, params_t, mb["input_ids"],
+                              attention_mask=mb["attention_mask"],
+                              compute_dtype=BF16, remat=True, mesh=mesh)
+        return lm_cross_entropy_sum(logits, mb["labels"])
+
+    psh, osh = params_shardings(params, mesh), params_shardings(opt, mesh)
+    batch = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "attention_mask": jax.ShapeDtypeStruct((B, S), jnp.float32),
+             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    step = make_train_step(loss_fn, tc, mask=None, donate=True,
+                           out_shardings=(psh, osh, repl))
+    compiled = step.lower(
+        place(params, psh), None, place(opt, osh),
+        place(batch, jax.tree.map(lambda _: batch_sharding(mesh), batch)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)).compile()
+    assert_kernel(compiled, at_least=3)   # forward, its remat, backward
+    census = assert_fsdp_collectives(compiled)
+    peak = compiled_peak_bytes(compiled)
+    print(f"\nxl_fullft_fsdp4 step, described v5e:2x2: compiled peak "
+          f"{peak / 1e9:.2f} GB a device = {peak / 16_909_336_064:.3f} of "
+          f"bytes_limit; collectives {census}")
+    assert peak < 0.85 * 16_909_336_064
 
 
 @pytest.mark.slow

@@ -194,11 +194,16 @@ def test_rehearsal_runs_every_one_chip_phase(tmp_path):
 def test_four_chip_option_runs_only_its_own_phases(tmp_path):
     p, lines = run_smoke(tmp_path, "--rehearse", "--chips", "4")
     assert_rehearsal(p, lines, [
-        "start", "device", "fixtures", "train_fsdp4", "train_fsdp1",
-        "fsdp4_vs_1", "tp_decode"])
+        "start", "device", "fixtures", "train_fsdp4_S32", "train_fsdp1_S32",
+        "fsdp4_vs_1_S32", "train_fsdp4_S64", "train_fsdp1_S64",
+        "fsdp4_vs_1_S64", "tp_decode"])
     assert lines[-1]["device"]["count"] == 4
-    fsdp = next(ln for ln in lines if ln.get("phase") == "fsdp4_vs_1")
-    assert fsdp["collectives_fsdp4"]["all-gather"] > 0
+    for S in (32, 64):
+        fsdp = next(ln for ln in lines
+                    if ln.get("phase") == f"fsdp4_vs_1_S{S}")
+        assert fsdp["collectives_fsdp4"]["all-gather"] > 0
+        assert set(fsdp["attention_impl_resolved"]) == \
+            set(fsdp["tpu_custom_calls"]) == {"fsdp4", "fsdp1"}
 
 
 @pytest.mark.slow

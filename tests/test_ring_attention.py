@@ -219,8 +219,8 @@ def test_flash_ring_forward_matches_oracle(case):
     kw = ({"sliding_window": case.pop("sliding_window")}
           if "sliding_window" in case else {})
     from mobilefinetuner_tpu.ops.flash_attention import \
-        flash_partial_eligible
-    assert flash_partial_eligible(case["S"] // n, case["D"])
+        kernel_eligible
+    assert kernel_eligible(case["S"] // n, case["D"])
     mesh = make_mesh(data=1, fsdp=n, devices=jax.devices()[:n])
     q, k, v = make_qkv(jax.random.PRNGKey(0), **case)
     ours = ring_attention(q, k, v, mesh, **kw)
@@ -306,14 +306,14 @@ def test_gemma_model_context_parallel_flash_body():
     from mobilefinetuner_tpu.core.config import Gemma3TextConfig
     from mobilefinetuner_tpu.models import gemma3
     from mobilefinetuner_tpu.ops.flash_attention import \
-        flash_partial_eligible
+        kernel_eligible
     mesh = make_mesh(data=1, fsdp=4, devices=jax.devices()[:4])
     cfg = Gemma3TextConfig(
         vocab_size=512, hidden_size=128, intermediate_size=256,
         num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=1,
         head_dim=64, max_position_embeddings=1024, sliding_window=96,
         query_pre_attn_scalar=64.0, sliding_window_pattern=3)
-    assert flash_partial_eligible(512 // 4, cfg.head_dim)
+    assert kernel_eligible(512 // 4, cfg.head_dim)
     params = gemma3.init_params(cfg, jax.random.PRNGKey(0))
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 512), 0, 512)
     mask = jnp.ones((2, 512))

@@ -31,10 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mobilefinetuner_tpu.cli.family import apply_adapter, load_family
 from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
 from mobilefinetuner_tpu.core.logging import JSONLWriter, get_logger
 from mobilefinetuner_tpu.eval import mmlu
+from mobilefinetuner_tpu.models.family import apply_adapter, load_family
 
 log = get_logger()
 
@@ -79,37 +79,32 @@ def build_parser() -> argparse.ArgumentParser:
 
 def setup_family(args):
     """(hidden_fn, head_key, compute_dtype, tok, letter_encode, max_len,
-    params, lora): family dispatch via cli/family.py. hidden_fn(params,
+    params, lora): family dispatch via models/family.py. hidden_fn(params,
     lora, ids) -> [1, S, E] final-norm hidden states; params[head_key] is
     the (tied) lm_head weight [V, E]; letter_encode is the BOS-free
-    encoder for the A-D letter-id lookup (None = use tok.encode as-is)."""
+    encoder for the A-D letter-id lookup."""
     compute_dtype = jnp.bfloat16 if args.dtype == "bfloat16" \
         else jnp.float32
     b = load_family(args.pretrained_dir, args.family)
     lora = apply_adapter(b, args.lora_path, args.lora_merge)
-    config, model = b.config, b.model
+    config, model = b.config, b.family.model
 
     def hidden_fn(params, lora, ids):
         return model.hidden_states(config, params, ids, lora=lora,
                                    compute_dtype=compute_dtype)
 
-    if b.family == "gemma":
-        # letter-id lookup must not see the auto-BOS (eval/mmlu.py)
-        tok = b.tok
-        letter_encode = lambda s: tok.encode(s, add_bos=False)
-        # prompts are bucketed; cap at 4096 (far above MMLU prompt sizes,
-        # far below the 32k max — a 32k zero-pad bucket would be waste)
-        max_len = min(b.max_len, 4096)
-    else:
-        letter_encode = None  # GPT-2 encode adds no sequence-start token
-        max_len = b.max_len
+    # letter-id lookup must not see an auto-BOS (eval/mmlu.py)
+    letter_encode = b.family.encode_plain(b.tok)
+    # prompts are bucketed; cap at 4096 (far above MMLU prompt sizes,
+    # far below Gemma's 32k max — a 32k zero-pad bucket would be waste)
+    max_len = min(b.max_len, 4096)
 
     # Commit weights to device once; numpy-backed jit args would be
     # re-transferred per item (see eval_ppl.py).
     params = jax.device_put(b.params)
     lora = jax.device_put(lora) if lora is not None else None
-    return (hidden_fn, b.head_key, compute_dtype, b.tok, letter_encode,
-            max_len, params, lora)
+    return (hidden_fn, b.family.head_key, compute_dtype, b.tok,
+            letter_encode, max_len, params, lora)
 
 
 def make_batched_logits_fn(hidden_fn, head_key, compute_dtype, params,

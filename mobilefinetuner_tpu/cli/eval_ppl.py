@@ -31,12 +31,13 @@ import time
 import jax
 import jax.numpy as jnp
 
-from mobilefinetuner_tpu.cli.family import apply_adapter, load_family
 from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
 from mobilefinetuner_tpu.core.logging import JSONLWriter, get_logger
 from mobilefinetuner_tpu.data.prefetch import Prefetcher
 from mobilefinetuner_tpu.data.wikitext2 import WT2Config, WikiText2Dataset
-from mobilefinetuner_tpu.ops.loss import (lm_cross_entropy_sum,
+from mobilefinetuner_tpu.models.family import apply_adapter, load_family
+from mobilefinetuner_tpu.ops.loss import (chunked_lm_cross_entropy_sum,
+                                          lm_cross_entropy_sum,
                                           perplexity_from_loss)
 
 log = get_logger()
@@ -89,41 +90,32 @@ def main(argv=None) -> int:
     enable_compile_cache()
     compute_dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     b = load_family(args.pretrained_dir, args.family)
-    family = b.family
+    fam = b.family
     lora = apply_adapter(b, args.lora_path, args.lora_merge)
     config, params, tok = b.config, b.params, b.tok
+    # the eval stream carries no sequence-start token; GPT-2's pad id is
+    # its eos id, which is also WikiText2Dataset's default
+    encode, eos_id, pad_id = fam.encode_plain(tok), tok.eos_id, tok.pad_id
 
-    if family == "gemma":
-        from mobilefinetuner_tpu.models import gemma3
-        from mobilefinetuner_tpu.ops.loss import chunked_lm_cross_entropy_sum
-        encode = lambda s: tok.encode(s, add_bos=False)
-        eos_id, pad_id = tok.eos_id, tok.pad_id
-
-        @jax.jit
-        def step(params, lora, batch):
-            hidden = gemma3.hidden_states(
-                config, params, batch["input_ids"],
-                attention_mask=batch["attention_mask"], lora=lora,
-                compute_dtype=compute_dtype)
-            # an lm_head adapter entry rides the chunked CE as
-            # lora_head (hidden_states only applies per-layer sites;
-            # dropping it here would score a different model than the
-            # one trained — DESIGN.md §17)
-            head_entry = (None if lora is None
-                          else lora["blocks"].get("lm_head"))
-            return chunked_lm_cross_entropy_sum(
-                hidden, params["embed"], batch["labels"],
-                num_chunks=args.loss_chunks, lora_head=head_entry)
-    else:
-        from mobilefinetuner_tpu.models import gpt2
-        encode, eos_id, pad_id = tok.encode, tok.eos_id, None
-
-        @jax.jit
-        def step(params, lora, batch):
-            logits = gpt2.forward(config, params, batch["input_ids"],
-                                  attention_mask=batch["attention_mask"],
-                                  lora=lora, compute_dtype=compute_dtype)
+    @jax.jit
+    def step(params, lora, batch):
+        kw = dict(attention_mask=batch["attention_mask"], lora=lora,
+                  compute_dtype=compute_dtype)
+        if fam.full_logits_eval:
+            logits = fam.model.forward(config, params, batch["input_ids"],
+                                       **kw)
             return lm_cross_entropy_sum(logits, batch["labels"])
+        hidden = fam.model.hidden_states(config, params,
+                                         batch["input_ids"], **kw)
+        # an lm_head adapter entry rides the chunked CE as lora_head
+        # (hidden_states only applies per-layer sites; dropping it here
+        # would score a different model than the one trained —
+        # DESIGN.md §17)
+        head_entry = (None if lora is None
+                      else lora["blocks"].get("lm_head"))
+        return chunked_lm_cross_entropy_sum(
+            hidden, params[fam.head_key], batch["labels"],
+            num_chunks=args.loss_chunks, lora_head=head_entry)
 
     max_pos = b.max_len
 
@@ -215,7 +207,7 @@ def main(argv=None) -> int:
     total, count = (float(total), int(count)) if n_done else (0.0, 0)
     mean = total / max(count, 1)
     ppl = perplexity_from_loss(mean)
-    record = {"type": "final", "family": family, "split": args.split,
+    record = {"type": "final", "family": fam.name, "split": args.split,
               "nll": mean, "ppl": ppl, "tokens": count,
               "seq_len": args.seq_len, "lora": bool(args.lora_path),
               "merged": args.lora_merge,

@@ -21,6 +21,7 @@ Gemma fields); --model forces it.
 from __future__ import annotations
 
 import argparse
+import functools
 import json as json_mod
 import sys
 import time
@@ -31,13 +32,11 @@ import numpy as np
 
 from mobilefinetuner_tpu.core.compile_cache import enable_compile_cache
 from mobilefinetuner_tpu.core.logging import get_logger
-from mobilefinetuner_tpu.models.generate import (SampleConfig, gemma3_generate,
-                                                 gpt2_generate, left_pad)
+from mobilefinetuner_tpu.models.family import apply_adapter, load_family
+from mobilefinetuner_tpu.models.generate import (GENERATE, SampleConfig,
+                                                 left_pad)
 
 log = get_logger()
-
-
-from mobilefinetuner_tpu.cli.family import apply_adapter, load_family
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -105,16 +104,13 @@ def main(argv=None) -> int:
     compute_dtype = jnp.bfloat16 if args.dtype == "bfloat16" \
         else jnp.float32
     b = load_family(args.pretrained_dir, args.model)
-    if args.prefill_chunk and b.family == "gpt2":
-        raise SystemExit("--prefill_chunk is Gemma-only (GPT-2's learned "
-                         "positions cap prompts at n_positions)")
-    if b.family == "gpt2":
-        gen = gpt2_generate
-    else:
-        import functools
-        gen = functools.partial(
-            gemma3_generate,
-            prefill_chunk=args.prefill_chunk or None)
+    gen = GENERATE[b.family.name]
+    if args.prefill_chunk:
+        if b.family.dims(b.config).cap is not None:
+            raise SystemExit(
+                "--prefill_chunk is Gemma-only (GPT-2's learned "
+                "positions cap prompts at n_positions)")
+        gen = functools.partial(gen, prefill_chunk=args.prefill_chunk)
     tok, encode = b.tok, b.tok.encode  # Gemma: add_bos default (HF parity)
     lora_paths = [p for p in args.lora_path.split(",") if p]
     if len(lora_paths) > 1:

@@ -105,7 +105,7 @@ def main(argv=None) -> int:
     log.info(f"jobs file: {len(jobs)} {family} job(s) "
              f"({', '.join(j.name for j in jobs)})")
 
-    from mobilefinetuner_tpu.cli.family import load_family
+    from mobilefinetuner_tpu.models.family import load_family
     bundle = load_family(args.pretrained_dir, family=family)
     config = bundle.config
     if args.seq_len > bundle.max_len:

@@ -53,6 +53,11 @@ PEFT_TARGET_MODULES = {
     "attn_qkv": "attn.c_attn", "attn_proj": "attn.c_proj",
     "mlp_fc_in": "mlp.c_fc", "mlp_fc_out": "mlp.c_proj",
 }
+# adapter_config.json per family: (fan_in_fan_out — GPT-2's Conv1D stores
+# [in, out] — and the target_modules naming; None = the target names are
+# already HF's module names)
+PEFT_CONFIG = {"gpt2": (True, PEFT_TARGET_MODULES), "gemma": (False, None),
+               "afmoe": (False, None)}
 
 
 # ----------------------------- native format --------------------------------
@@ -120,13 +125,9 @@ def export_peft(out_dir: str, lora_tree, spec: LoRASpec, family: str,
             tensors[mod + ".lora_B.weight"] = B[i].T.copy()
     save_safetensors(os.path.join(out_dir, "adapter_model.safetensors"),
                      tensors, metadata={"format": "pt"})
-    if family == "gpt2":
-        target_modules = sorted({PEFT_TARGET_MODULES[t]
-                                 for t in lora_tree["blocks"]})
-        fan_in_fan_out = True  # GPT-2 Conv1D
-    else:
-        target_modules = sorted(lora_tree["blocks"])
-        fan_in_fan_out = False
+    fan_in_fan_out, names = PEFT_CONFIG[family]
+    target_modules = sorted({names[t] if names else t
+                             for t in lora_tree["blocks"]})
     cfg = {
         "peft_type": "LORA", "task_type": "CAUSAL_LM",
         "base_model_name_or_path": base_model_name,

@@ -28,13 +28,15 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from mobilefinetuner_tpu.core.config import GPT2Config, Gemma3TextConfig
+from mobilefinetuner_tpu.lora.lora import GPT2_SPLIT_QKV_SLOTS
 from mobilefinetuner_tpu.models import gemma3, gpt2
+from mobilefinetuner_tpu.models.family import GEMMA, GPT2, Family
 from mobilefinetuner_tpu.models.lora_apply import maybe_lora
 from mobilefinetuner_tpu.ops.rope import apply_rope, rope_cos_sin
 
@@ -48,6 +50,30 @@ def _head_lora(logits, h, lora_b, impl):
     if lora_b is None or "lm_head" not in lora_b:
         return logits
     return maybe_lora(logits, h, lora_b["lm_head"], None, impl=impl)
+
+
+class AdapterSites:
+    """The routed adapters of one trace at the block's matmul sites:
+    `sites(y, x, name, i)` is the product y = x @ W plus adapter `name`
+    of layer i (y itself where the tree has no such entry), and
+    `name in sites` says whether it has one."""
+
+    def __init__(self, lora, impl: str):
+        self.blocks = None if lora is None else lora.get("blocks")
+        self.impl = impl
+
+    def __contains__(self, name) -> bool:
+        return self.blocks is not None and name in self.blocks
+
+    def __call__(self, y, x_in, name, i):
+        entry = None if self.blocks is None else self.blocks.get(name)
+        return maybe_lora(y, x_in, entry, i, impl=self.impl)
+
+
+def _cast_floats(tree, dtype):
+    return jax.tree.map(
+        lambda t: t.astype(dtype)
+        if jnp.issubdtype(t.dtype, jnp.floating) else t, tree)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,6 +179,22 @@ def _col_valid(attention_mask, P, T, t):
     return prompt & gen_ok
 
 
+def _decode_all(decode_step, logits0, kc, vc, rng, cfg: SampleConfig):
+    """Sample token 0 from the prefill's logits, then scan N-1 decode
+    steps: step t consumes token t and samples token t+1, so the final
+    token comes out of the carry — no trailing all-layers forward whose
+    sample would be discarded. Returns [B, N] ids."""
+    N = cfg.max_new_tokens
+    all_keys = jax.random.split(rng, N + 1)
+    tok0_raw = _sample(logits0.astype(jnp.float32), all_keys[N], cfg)
+    tok0, done0 = _advance(
+        tok0_raw, jnp.zeros((logits0.shape[0],), bool), cfg)
+    (tok_last, _, _, _), toks = jax.lax.scan(
+        decode_step, (tok0, done0, kc, vc),
+        (jnp.arange(N - 1, dtype=jnp.int32), all_keys[:N - 1]))
+    return jnp.concatenate([toks, tok_last[None]], axis=0).T
+
+
 # ----------------------------------------------------------- GPT-2 ----------
 
 def gpt2_generate(config: GPT2Config, params, input_ids, attention_mask,
@@ -202,11 +244,9 @@ def gpt2_generate(config: GPT2Config, params, input_ids, attention_mask,
     kc, vc = pad_kv(pk), pad_kv(pv)                  # [L, B, H, T, D]
 
     n_real = attention_mask.sum(-1).astype(jnp.int32)
-    wb = params["blocks"]
     eps = config.layer_norm_epsilon
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb = jax.tree.map(cast, wb)
+    wb = _cast_floats(params["blocks"], compute_dtype)
+    apply_lora = AdapterSites(lora, lora_impl)
 
     def decode_step(carry, step_rng_t):
         tok, done, kc, vc = carry
@@ -215,10 +255,6 @@ def gpt2_generate(config: GPT2Config, params, input_ids, attention_mask,
         x = params["wte"][tok].astype(compute_dtype) \
             + params["wpe"][pos].astype(compute_dtype)          # [B, E]
         valid = _col_valid(attention_mask, P, T, t)             # [B, T]
-
-        def apply_lora(y, x_in, name, i):
-            entry = None if lora_b is None else lora_b.get(name)
-            return maybe_lora(y, x_in, entry, i, impl=lora_impl)
 
         def layer(inner, inp):
             # The [L, B, H, T, D] caches ride the inner CARRY and are
@@ -232,16 +268,12 @@ def gpt2_generate(config: GPT2Config, params, input_ids, attention_mask,
             h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
             qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
             qkv = apply_lora(qkv, h, "attn_qkv", i)
-            if lora_b is not None:
-                # split-QKV adapters hit their column range of the fused
-                # c_attn output (models/gpt2.py _block, same site salts)
-                from mobilefinetuner_tpu.lora.lora import \
-                    GPT2_SPLIT_QKV_SLOTS
-                for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
-                    if name in lora_b:
-                        sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
-                        qkv = qkv.at[sl].set(
-                            apply_lora(qkv[sl], h, name, i))
+            # split-QKV adapters hit their column range of the fused
+            # c_attn output (models/gpt2.py _block, same site salts)
+            for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
+                if name in apply_lora:
+                    sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
+                    qkv = qkv.at[sl].set(apply_lora(qkv[sl], h, name, i))
             q, k, v = jnp.split(qkv, 3, axis=-1)
             hd = lambda z: z.reshape(B, H, D)
             q, k, v = hd(q), hd(k), hd(v)
@@ -288,25 +320,13 @@ def gpt2_generate(config: GPT2Config, params, input_ids, attention_mask,
         nxt, done = _advance(nxt_raw, done, cfg)
         return (nxt, done, kc, vc), tok
 
-    all_keys = jax.random.split(rng, N + 1)
-    tok0_raw = _sample(logits0.astype(jnp.float32), all_keys[N], cfg)
-    tok0, done0 = _advance(tok0_raw, jnp.zeros((B,), bool), cfg)
-    # N-1 decode steps: step t consumes token t and samples token t+1, so
-    # the final token comes out of the carry — no trailing all-layers
-    # forward whose sample would be discarded
-    steps = jnp.arange(N - 1, dtype=jnp.int32)
-    keys = all_keys[:N - 1]
-    (tok_last, _, _, _), toks = jax.lax.scan(
-        decode_step, (tok0, done0, kc, vc), (steps, keys))
-    toks = jnp.concatenate([toks, tok_last[None]], axis=0)
-    return toks.T                                              # [B, N]
+    return _decode_all(decode_step, logits0, kc, vc, rng, cfg)
 
 
 # ---------------------------------------------------------- Gemma-3 ---------
 
 def _gemma_chunked_prefill(c, params, wb, input_ids, attention_mask,
-                           lora_b, T, compute_dtype, W, apply_rope_fn,
-                           lora_impl: str = "auto"):
+                           apply_lora, T, compute_dtype, W, apply_rope_fn):
     """Windowed prefill for LONG prompts: process the prompt in W-token
     windows, each window's attention reading the K/V cache of everything
     before it plus itself — peak score memory is O(W·P) instead of the
@@ -342,10 +362,6 @@ def _gemma_chunked_prefill(c, params, wb, input_ids, attention_mask,
 
     kc = jnp.zeros((L, B, nkv, T, D), compute_dtype)
     vc = jnp.zeros((L, B, nkv, T, D), compute_dtype)
-
-    def apply_lora(y, x_in, name, i):
-        entry = None if lora_b is None else lora_b.get(name)
-        return maybe_lora(y, x_in, entry, i, impl=lora_impl)
 
     x_last = None
     for w0 in range(0, P, W):
@@ -460,14 +476,13 @@ def gemma3_generate(config: Gemma3TextConfig, params, input_ids,
             P += pad_n
     T = P + N
 
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    wb_pre = jax.tree.map(cast, params["blocks"])
+    wb_pre = _cast_floats(params["blocks"], compute_dtype)
+    apply_lora = AdapterSites(lora, lora_impl)
 
     if chunked:
         x_last, kc, vc = _gemma_chunked_prefill(
-            c, params, wb_pre, input_ids, attention_mask, lora_b, T,
-            compute_dtype, W, apply_rope, lora_impl=lora_impl)
+            c, params, wb_pre, input_ids, attention_mask, apply_lora, T,
+            compute_dtype, W, apply_rope)
         logits0 = x_last @ params["embed"].astype(compute_dtype).T
         logits0 = _head_lora(logits0, x_last, lora_b, lora_impl)
     else:
@@ -501,10 +516,6 @@ def gemma3_generate(config: Gemma3TextConfig, params, input_ids,
         # sliding-window validity uses POSITION ids (mask-derived), same
         # phases as the padded-batch training forward
         win_ok = (pos[:, None] - col_pos) < c.sliding_window    # [B, T]
-
-        def apply_lora(y, x_in, name, i):
-            entry = None if lora_b is None else lora_b.get(name)
-            return maybe_lora(y, x_in, entry, i, impl=lora_impl)
 
         def layer(inner, inp):
             # caches ride the inner CARRY (one [1,B,Hkv,1,D] DUS per
@@ -566,32 +577,29 @@ def gemma3_generate(config: Gemma3TextConfig, params, input_ids,
         nxt, done = _advance(nxt_raw, done, cfg)
         return (nxt, done, kc, vc), tok
 
-    all_keys = jax.random.split(rng, N + 1)
-    tok0_raw = _sample(logits0.astype(jnp.float32), all_keys[N], cfg)
-    tok0, done0 = _advance(tok0_raw, jnp.zeros((B,), bool), cfg)
-    # N-1 decode steps: step t consumes token t and samples token t+1, so
-    # the final token comes out of the carry — no trailing all-layers
-    # forward whose sample would be discarded
-    steps = jnp.arange(N - 1, dtype=jnp.int32)
-    keys = all_keys[:N - 1]
-    (tok_last, _, _, _), toks = jax.lax.scan(
-        decode_step, (tok0, done0, kc, vc), (steps, keys))
-    toks = jnp.concatenate([toks, tok_last[None]], axis=0)
-    return toks.T
+    return _decode_all(decode_step, logits0, kc, vc, rng, cfg)
+
+
+# the contiguous-cache program of each family, by Family.name
+GENERATE = {GPT2.name: gpt2_generate, GEMMA.name: gemma3_generate}
 
 
 # ------------------------------------------------- serving entry points -----
 #
 # The serving subsystem (serve/engine.py, DESIGN.md §16) decomposes the
-# one-shot generate() programs above into two trace-stable pieces it can
-# drive per request / per step:
+# one-shot generate() programs above into trace-stable pieces it can
+# drive per request / per step. Each exists ONCE, a skeleton over the
+# family record (models/family.py), which supplies the embedding, q/k/v,
+# the per-layer mask, the block's second half and the final norm:
 #
-#   *_prefill            one full-sequence forward for ONE admitted
+#   prefill              one full-sequence forward for ONE admitted
 #                        request (right-padded to the engine's static
 #                        prompt length), returning the next-token logits
 #                        at the last REAL position plus every layer's
 #                        K/V for the host to scatter into pool blocks;
-#   *_decode_step_paged  one token step for ALL slots against the shared
+#   prefill_chunk        one fixed-width slice of a long prompt against
+#                        the pages earlier slices already wrote;
+#   decode_step_paged    one token step for ALL slots against the shared
 #                        block pool (its layout is serve/paged_kv.py's
 #                        contract; these programs only go through that
 #                        module's writers and readers): write the fed
@@ -603,24 +611,37 @@ def gemma3_generate(config: Gemma3TextConfig, params, input_ids,
 #
 # Serve sequences start at position 0 with no padding inside (the engine
 # right-pads only the prompt TAIL), so validity is simply col <= pos —
-# none of the left-padded mask algebra above applies. The layer math is
-# kept line-for-line with decode_step; the buffer structure differs
-# (pool scatter/gather instead of contiguous DUS), and each copy is
-# pinned by the tests/test_serve.py paged-vs-contiguous greedy oracle.
+# none of the left-padded mask algebra above applies. The contiguous
+# generate() programs above do NOT share this layer math: they are the
+# oracle the paged programs are tested against (tests/test_serve.py).
 
 
-def gpt2_prefill(config: GPT2Config, params, input_ids, attention_mask,
-                 compute_dtype=jnp.float32, lora=None,
-                 lora_impl: str = "auto", shardings=None):
+def _cast_blocks(params, compute_dtype):
+    """(params as arrays, the block weights in the compute dtype)."""
+    params = jax.tree.map(jnp.asarray, params)
+    with jax.named_scope("decode_weight_cast"):
+        return params, _cast_floats(params["blocks"], compute_dtype)
+
+
+def _tied_head(fam: Family, params, x, sites: AdapterSites, compute_dtype):
+    """f32 logits of the (final-normed) rows x through the tied head."""
+    logits = x @ params[fam.head_key].astype(compute_dtype).T
+    logits = _head_lora(logits, x, sites.blocks, sites.impl)
+    return logits.astype(jnp.float32)
+
+
+def prefill(fam: Family, config, params, input_ids, attention_mask,
+            compute_dtype=jnp.float32, lora=None,
+            lora_impl: str = "auto", shardings=None):
     """Prefill for serving: [B, P] right-padded prompts -> (next-token
     logits [B, V] f32 at each row's last real position, (k, v) per-layer
-    caches [L, B, H, P, D]). shardings: a serve/sharding.ServeSharding
+    caches [L, B, KV, P, D]). shardings: a serve/sharding.ServeSharding
     under the (dp, tp) serve mesh — the prefill matmuls TP-partition by
     propagation from the column/row-sharded weight placement; the only
     explicit pin is the collected caches' KV-head axis, so the engine's
     prompt-page scatter receives pool-aligned K/V."""
     params = jax.tree.map(jnp.asarray, params)
-    x, (pk, pv) = gpt2.hidden_states(
+    x, (pk, pv) = fam.model.hidden_states(
         config, params, input_ids, attention_mask, lora=lora,
         compute_dtype=compute_dtype, collect_kv=True,
         lora_impl=lora_impl)
@@ -629,71 +650,13 @@ def gpt2_prefill(config: GPT2Config, params, input_ids, attention_mask,
         pv = shardings.prefill_cache(pv)
     n_real = attention_mask.sum(-1).astype(jnp.int32)
     last = x[jnp.arange(x.shape[0]), n_real - 1]          # [B, E]
-    logits = last @ params["wte"].astype(compute_dtype).T
-    lora_b = None if lora is None else lora.get("blocks")
-    logits = _head_lora(logits, last, lora_b, lora_impl)
-    return logits.astype(jnp.float32), (pk, pv)
+    return _tied_head(fam, params, last, AdapterSites(lora, lora_impl),
+                      compute_dtype), (pk, pv)
 
 
-def gemma3_prefill(config: Gemma3TextConfig, params, input_ids,
-                   attention_mask, compute_dtype=jnp.float32, lora=None,
-                   lora_impl: str = "auto", shardings=None):
-    """Gemma-3 serving prefill (see gpt2_prefill)."""
-    params = jax.tree.map(jnp.asarray, params)
-    x, (pk, pv) = gemma3.hidden_states(
-        config, params, input_ids, attention_mask, lora=lora,
-        compute_dtype=compute_dtype, collect_kv=True,
-        lora_impl=lora_impl)
-    if shardings is not None:
-        pk = shardings.prefill_cache(pk)
-        pv = shardings.prefill_cache(pv)
-    n_real = attention_mask.sum(-1).astype(jnp.int32)
-    last = x[jnp.arange(x.shape[0]), n_real - 1]
-    logits = last @ params["embed"].astype(compute_dtype).T
-    lora_b = None if lora is None else lora.get("blocks")
-    logits = _head_lora(logits, last, lora_b, lora_impl)
-    return logits.astype(jnp.float32), (pk, pv)
-
-
-def _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd):
-    """q, k, v [R, H, D] of the R rows `h` [R, E] (already layer-normed)
-    for the paged programs (decode step: R slots; prefill chunk: R
-    chunk rows), adapters applied."""
-    R, E = h.shape
-    qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
-    qkv = apply_lora(qkv, h, "attn_qkv", i)
-    if lora_b is not None:
-        from mobilefinetuner_tpu.lora.lora import GPT2_SPLIT_QKV_SLOTS
-        for name, slot in GPT2_SPLIT_QKV_SLOTS.items():
-            if name in lora_b:
-                sl = (Ellipsis, slice(slot * E, (slot + 1) * E))
-                qkv = qkv.at[sl].set(apply_lora(qkv[sl], h, name, i))
-    q, k, v = (z.reshape(R, H, E // H)
-               for z in jnp.split(qkv, 3, axis=-1))
-    if shd is not None:
-        q, k, v = shd.kv_rows(q), shd.kv_rows(k), shd.kv_rows(v)
-    return q, k, v
-
-
-def _gpt2_proj_mlp(x, ctx, bp, i, eps, apply_lora, shd):
-    """The rest of a GPT-2 block after attention, for the paged
-    programs: output projection, residual, MLP, residual."""
-    proj = ctx @ bp["attn"]["proj_w"] + bp["attn"]["proj_b"]
-    proj = apply_lora(proj, ctx, "attn_proj", i)
-    x = x + proj
-    h2 = gpt2.layer_norm(x, bp["ln_2"]["g"], bp["ln_2"]["b"], eps)
-    fc = h2 @ bp["mlp"]["fc_w"] + bp["mlp"]["fc_b"]
-    if shd is not None:
-        fc = shd.hidden(fc)
-    fc = gpt2.gelu_new(apply_lora(fc, h2, "mlp_fc_in", i))
-    out = fc @ bp["mlp"]["proj_w"] + bp["mlp"]["proj_b"]
-    return x + apply_lora(out, fc, "mlp_fc_out", i)
-
-
-def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
-                       start, n_tok, tbl, lora=None,
-                       compute_dtype=jnp.float32,
-                       lora_impl: str = "auto", shardings=None):
+def prefill_chunk(fam: Family, config, params, pool_k, pool_v, ids, start,
+                  n_tok, tbl, lora=None, compute_dtype=jnp.float32,
+                  lora_impl: str = "auto", shardings=None):
     """One fixed-width prefill CHUNK against the block pool (round 21):
     W prompt tokens starting at absolute position `start`, attending
     the pages earlier chunks (or the prefix cache) already wrote.
@@ -712,8 +675,9 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
     union of the already-written prefix (pool columns < start) and the
     chunk's own rows j <= w, so attention splits into a read-only page
     gather plus a dense within-chunk part under ONE joint softmax —
-    token-identical to one-shot prefill. The pools are NOT layer-scan
-    carries: the scan threads only the hidden state, stacks each
+    token-identical to one-shot prefill; a windowed layer's mask
+    (Family.layer_ok) applies to both parts. The pools are NOT layer-
+    scan carries: the scan threads only the hidden state, stacks each
     layer's chunk K/V as scan outputs, and a single post-scan scatter
     lands all L layers' rows at once. That keeps the chunk program's
     cost proportional to the chunk width, not the pool size (pool-
@@ -728,45 +692,40 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
     W = ids.shape[1]
     M = tbl.shape[1]
     L, bT = pool_k.shape[1:3]
-    E, H, D = config.n_embd, config.n_head, config.head_dim
-    eps = config.layer_norm_epsilon
-    params = jax.tree.map(jnp.asarray, params)
-    lora_b = None if lora is None else lora.get("blocks")
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    with jax.named_scope("decode_weight_cast"):
-        wb = jax.tree.map(cast, params["blocks"])
+    d = fam.dims(config)
+    KV, G = d.KV, d.nq // d.KV
+    params, wb = _cast_blocks(params, compute_dtype)
+    sites = AdapterSites(lora, lora_impl)
     shd = shardings
 
     rows = jnp.arange(W, dtype=jnp.int32)
     pos = start + rows                                        # [W]
     real = rows < n_tok
-    # padded rows clip their position lookup (their K/V goes to trash
-    # and their logits row is never read)
-    x = params["wte"][ids[0]].astype(compute_dtype) \
-        + params["wpe"][jnp.minimum(
-            pos, config.n_positions - 1)].astype(compute_dtype)
+    # padded rows clip a learned-position lookup (their K/V goes to
+    # trash and their logits row is never read)
+    x = fam.embed(config, params, ids[0],
+                  pos if d.cap is None else jnp.minimum(pos, d.cap - 1),
+                  compute_dtype)
     if shd is not None:
         x = shd.slots(x)
+    ctx = fam.pos_ctx(config, pos)
     cols = jnp.arange(M * bT, dtype=jnp.int32)
     # every chunk row shares the prefix span (pool columns < start);
     # columns >= start are this chunk's own rows, attended densely
-    pre_ok = cols < start                                     # [M*bT]
+    pre_ok = cols[None, :] < start                            # [1, M*bT]
     causal = rows[:, None] >= rows[None, :]                   # [W, W]
+    win_pre = win_in = None
+    if d.window is not None:
+        win_pre = (pos[:, None] - cols[None, :]) < d.window
+        win_in = (rows[:, None] - rows[None, :]) < d.window
     blk = jnp.where(real, tbl[0, pos // bT],
                     jnp.int32(TRASH_BLOCK))                   # [W]
     off = pos % bT
-    scale = D ** -0.5
-
-    def apply_lora(y, x_in, name, i):
-        entry = None if lora_b is None else lora_b.get(name)
-        return maybe_lora(y, x_in, entry, i, impl=lora_impl)
 
     def layer(x, inp):
-        bp, i = inp
+        bp, layer_x, i = inp
         with jax.named_scope("decode_proj_mlp"):
-            h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
-            q, k, v = _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd)
+            q, k, v = fam.qkv(config, x, bp, i, layer_x, ctx, sites, shd)
             # pool-dtype roundtrip: within-chunk attention must read the
             # same values the pages will hold, or chunked-vs-one-shot
             # token parity drifts at low pool precision
@@ -777,181 +736,51 @@ def gpt2_prefill_chunk(config: GPT2Config, params, pool_k, pool_v, ids,
             # are closed over READ-ONLY here (gather, never scatter), so
             # they are not scan carries — dtype discipline mirrors
             # ops.decode_attention.paged_attention
-            kc = gather_pages(pool_k, tbl[0], i, H)   # [M, bT, H, D]
-            vc = gather_pages(pool_v, tbl[0], i, H)
-            s1 = jnp.einsum("whd,mthd->whmt", q, kc,
-                            preferred_element_type=jnp.float32) * scale
-            s1 = jnp.where(pre_ok[None, None, :],
-                           s1.reshape(W, H, M * bT), NEG_INF)
-            s2 = jnp.einsum("whd,jhd->whj", q, kq,
-                            preferred_element_type=jnp.float32) * scale
-            s2 = jnp.where(causal[:, None, :], s2, NEG_INF)
-            p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-            ctx = jnp.einsum("whmt,mthd->whd",
-                             p[..., :M * bT].reshape(W, H, M, bT)
-                             .astype(vc.dtype), vc,
-                             preferred_element_type=jnp.float32) \
-                + jnp.einsum("whj,jhd->whd",
-                             p[..., M * bT:].astype(vq.dtype), vq,
-                             preferred_element_type=jnp.float32)
-        if shd is not None:
-            ctx = shd.heads4(ctx[:, :, None, :]).reshape(W, H, D)
-        with jax.named_scope("decode_proj_mlp"):
-            x = _gpt2_proj_mlp(x, ctx.reshape(W, E).astype(compute_dtype),
-                               bp, i, eps, apply_lora, shd)
-        return x, (kq, vq)
-
-    x, (ks, vs) = jax.lax.scan(
-        layer, x, (wb, jnp.arange(L, dtype=jnp.int32)))
-    # one write for all L layers' chunk rows (padded rows -> trash)
-    with jax.named_scope("decode_kv_write"):
-        pool_k = write_chunk_rows(pool_k, blk, off, ks)
-        pool_v = write_chunk_rows(pool_v, blk, off, vs)
-    with jax.named_scope("decode_head"):
-        x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
-                            params["ln_f"]["b"].astype(compute_dtype), eps)
-        last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
-                                            keepdims=True)    # [1, E]
-        logits = last @ params["wte"].astype(compute_dtype).T
-        logits = _head_lora(logits, last, lora_b, lora_impl)
-    return logits.astype(jnp.float32), pool_k, pool_v
-
-
-def gemma3_prefill_chunk(config: Gemma3TextConfig, params, pool_k,
-                         pool_v, ids, start, n_tok, tbl, lora=None,
-                         compute_dtype=jnp.float32,
-                         lora_impl: str = "auto", shardings=None):
-    """Gemma-3 prefill chunk (see gpt2_prefill_chunk): GQA pool, per-
-    layer global/local RoPE on the chunk's absolute positions, and the
-    sliding-window validity composed per layer — the same per-layer
-    `where(glob, causal, causal & window)` the paged decode step
-    applies, here split across the read-only prefix gather and the
-    dense within-chunk part of the joint softmax. As in the GPT-2
-    chunk, the pools ride closed-over (reads only) and one post-scan
-    scatter lands every layer's rows."""
-    from mobilefinetuner_tpu.ops.decode_attention import NEG_INF
-    from mobilefinetuner_tpu.serve.paged_kv import (TRASH_BLOCK,
-                                                    gather_pages,
-                                                    write_chunk_rows)
-    c = config
-    W = ids.shape[1]
-    M = tbl.shape[1]
-    L, bT = pool_k.shape[1:3]
-    KV, D = c.num_key_value_heads, c.head_dim
-    nq = c.num_attention_heads
-    G = nq // KV
-    eps = c.rms_norm_eps
-    scale = c.query_pre_attn_scalar ** -0.5
-    params = jax.tree.map(jnp.asarray, params)
-    lora_b = None if lora is None else lora.get("blocks")
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    with jax.named_scope("decode_weight_cast"):
-        wb = jax.tree.map(cast, params["blocks"])
-    is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
-    normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
-    shd = shardings
-
-    rows = jnp.arange(W, dtype=jnp.int32)
-    pos = start + rows                                        # [W]
-    real = rows < n_tok
-    x = params["embed"][ids[0]].astype(compute_dtype) * normalizer
-    if shd is not None:
-        x = shd.slots(x)
-    cos_g, sin_g = rope_cos_sin(pos[:, None], D, c.rope_theta)
-    cos_l, sin_l = rope_cos_sin(pos[:, None], D, c.rope_local_base_freq)
-    cols = jnp.arange(M * bT, dtype=jnp.int32)
-    pre_valid = jnp.broadcast_to(cols[None, :] < start,
-                                 (W, M * bT))                 # prefix
-    win_ok = (pos[:, None] - cols[None, :]) < c.sliding_window
-    causal = rows[:, None] >= rows[None, :]                   # [W, W]
-    win_in = (rows[:, None] - rows[None, :]) < c.sliding_window
-    blk = jnp.where(real, tbl[0, pos // bT],
-                    jnp.int32(TRASH_BLOCK))
-    off = pos % bT
-
-    def apply_lora(y, x_in, name, i):
-        entry = None if lora_b is None else lora_b.get(name)
-        return maybe_lora(y, x_in, entry, i, impl=lora_impl)
-
-    def layer(x, inp):
-        bp, glob, i = inp
-        with jax.named_scope("decode_proj_mlp"):
-            a = bp["attn"]
-            h = gemma3.rms_norm(x, bp["input_ln"], eps)
-            q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(W, nq, D)
-            k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(W, KV, D)
-            v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(W, KV, D)
-            q = gemma3.rms_norm(q, a["q_norm"], eps)
-            k = gemma3.rms_norm(k, a["k_norm"], eps)
-            cos = jnp.where(glob, cos_g, cos_l)
-            sin = jnp.where(glob, sin_g, sin_l)
-            q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
-            k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
-            if shd is not None:
-                k, v = shd.kv_rows(k), shd.kv_rows(v)
-            kq = k.astype(pool_k.dtype)               # pool-dtype roundtrip
-            vq = v.astype(pool_v.dtype)
-        with jax.named_scope("decode_paged_attn"):
-            ok1 = jnp.where(glob, pre_valid, pre_valid & win_ok)
-            ok2 = jnp.where(glob, causal, causal & win_in)        # [W, W]
-            q4 = q.reshape(W, KV, G, D)
-            if shd is not None:
-                q4 = shd.heads4(q4)
+            ok1 = fam.layer_ok(layer_x, pre_ok, win_pre)
+            ok2 = fam.layer_ok(layer_x, causal, win_in)       # [W, W]
             kc = gather_pages(pool_k, tbl[0], i, KV)  # [M, bT, KV, D]
             vc = gather_pages(pool_v, tbl[0], i, KV)
-            s1 = jnp.einsum("wkgd,mtkd->wkgmt", q4, kc,
-                            preferred_element_type=jnp.float32) * scale
+            s1 = jnp.einsum("wkgd,mtkd->wkgmt", q, kc,
+                            preferred_element_type=jnp.float32) * d.scale
             s1 = jnp.where(ok1[:, None, None, :],
                            s1.reshape(W, KV, G, M * bT), NEG_INF)
-            s2 = jnp.einsum("wkgd,jkd->wkgj", q4, kq,
-                            preferred_element_type=jnp.float32) * scale
+            s2 = jnp.einsum("wkgd,jkd->wkgj", q, kq,
+                            preferred_element_type=jnp.float32) * d.scale
             s2 = jnp.where(ok2[:, None, None, :], s2, NEG_INF)
             p = jax.nn.softmax(jnp.concatenate([s1, s2], -1), axis=-1)
-            ctx = jnp.einsum("wkgmt,mtkd->wkgd",
-                             p[..., :M * bT].reshape(W, KV, G, M, bT)
-                             .astype(vc.dtype), vc,
-                             preferred_element_type=jnp.float32) \
+            attn = jnp.einsum("wkgmt,mtkd->wkgd",
+                              p[..., :M * bT].reshape(W, KV, G, M, bT)
+                              .astype(vc.dtype), vc,
+                              preferred_element_type=jnp.float32) \
                 + jnp.einsum("wkgj,jkd->wkgd",
                              p[..., M * bT:].astype(vq.dtype), vq,
                              preferred_element_type=jnp.float32)
             if shd is not None:
-                ctx = shd.heads4(ctx)
+                attn = shd.heads4(attn)
         with jax.named_scope("decode_proj_mlp"):
-            ctx = ctx.reshape(W, nq * D).astype(compute_dtype)
-            attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
-            attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
-            x = x + attn_out
-            h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
-            act = gemma3.gelu_tanh(
-                apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
-                * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
-            if shd is not None:
-                act = shd.hidden(act)
-            down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
-            down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
-            x = x + down
+            x = fam.post(config, x,
+                         attn.reshape(W, d.nq * d.D).astype(compute_dtype),
+                         bp, i, sites, shd)
         return x, (kq, vq)
 
     x, (ks, vs) = jax.lax.scan(
-        layer, x, (wb, is_global, jnp.arange(L, dtype=jnp.int32)))
+        layer, x, (wb, fam.layer_xs(config),
+                   jnp.arange(L, dtype=jnp.int32)))
     # one write for all L layers' chunk rows (padded rows -> trash)
     with jax.named_scope("decode_kv_write"):
         pool_k = write_chunk_rows(pool_k, blk, off, ks)
         pool_v = write_chunk_rows(pool_v, blk, off, vs)
     with jax.named_scope("decode_head"):
-        x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
-                            eps)
+        x = fam.final_norm(config, params, x, compute_dtype)
         last = jax.lax.dynamic_index_in_dim(x, n_tok - 1, 0,
                                             keepdims=True)    # [1, E]
-        logits = last @ params["embed"].astype(compute_dtype).T
-        logits = _head_lora(logits, last, lora_b, lora_impl)
-    return logits.astype(jnp.float32), pool_k, pool_v
+        logits = _tied_head(fam, params, last, sites, compute_dtype)
+    return logits, pool_k, pool_v
 
 
 def _paged_attend(attn_impl: str, KV: int, G: int, D: int, pool_k,
                   shardings):
-    """The decode steps' attend(q, pool_k, pool_v, tbl, layer, ok, scale):
+    """The decode step's attend(q, pool_k, pool_v, tbl, layer, ok, scale):
     the Pallas paged kernel (under shard_map on a serve mesh) or the
     gather oracle, by ops/decode_attention.resolve_paged_impl."""
     from mobilefinetuner_tpu.ops.decode_attention import (
@@ -967,18 +796,18 @@ def _paged_attend(attn_impl: str, KV: int, G: int, D: int, pool_k,
     return paged_decode_attention
 
 
-def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
-                           tok, pos, tbl, lora=None,
-                           compute_dtype=jnp.float32,
-                           attn_impl: str = "auto",
-                           lora_impl: str = "auto", shardings=None):
+def decode_step_paged(fam: Family, config, params, pool_k, pool_v, tok,
+                      pos, tbl, lora=None, compute_dtype=jnp.float32,
+                      attn_impl: str = "auto", lora_impl: str = "auto",
+                      shardings=None):
     """One continuous-batching decode step over a block-paged KV pool.
 
-    pool_k/pool_v the block pools (serve/paged_kv.py's layout); tok [S]
-    the token each slot feeds; pos [S] its cache position (= tokens
-    already cached); tbl [S, M] per-slot block tables (idle slots ->
-    trash block 0). Returns (logits [S, V] f32, pool_k, pool_v) with
-    the fed tokens' K/V written in at (tbl[s, pos//bT], pos%bT).
+    pool_k/pool_v the block pools (serve/paged_kv.py's layout; KV heads
+    to a page row); tok [S] the token each slot feeds; pos [S] its cache
+    position (= tokens already cached); tbl [S, M] per-slot block tables
+    (idle slots -> trash block 0). Returns (logits [S, V] f32, pool_k,
+    pool_v) with the fed tokens' K/V written in at
+    (tbl[s, pos//bT], pos%bT).
 
     attn_impl: "xla" = gather-based paged_attention (every backend),
     "pallas" = the scalar-prefetch paged kernel, "auto" = pallas on TPU
@@ -989,166 +818,69 @@ def gpt2_decode_step_paged(config: GPT2Config, params, pool_k, pool_v,
     unchanged; the head/hidden axes get with_sharding_constraint pins
     (GSPMD inserts the collectives; check_compiled_contracts pins the
     census), the Pallas gate charges per-shard head counts, and the
-    kernel path routes through sharded_paged_attend's shard_map."""
+    kernel path routes through sharded_paged_attend's shard_map. The
+    GQA head placement follows shard_heads: the pool's KV axis shards
+    when KV % tp == 0, otherwise the query-group axis does (pools
+    replicated) — either way constraints pin the 4D [S, KV, G, D]
+    layout."""
     from mobilefinetuner_tpu.serve.paged_kv import write_token_rows
     S, M = tbl.shape
     L, bT = pool_k.shape[1:3]
-    E, H, D = config.n_embd, config.n_head, config.head_dim
-    eps = config.layer_norm_epsilon
-    params = jax.tree.map(jnp.asarray, params)
-    lora_b = None if lora is None else lora.get("blocks")
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    with jax.named_scope("decode_weight_cast"):
-        wb = jax.tree.map(cast, params["blocks"])
+    d = fam.dims(config)
+    params, wb = _cast_blocks(params, compute_dtype)
+    sites = AdapterSites(lora, lora_impl)
     shd = shardings
-    attend = _paged_attend(attn_impl, H, 1, D, pool_k, shd)
+    attend = _paged_attend(attn_impl, d.KV, d.nq // d.KV, d.D, pool_k, shd)
 
-    x = params["wte"][tok].astype(compute_dtype) \
-        + params["wpe"][pos].astype(compute_dtype)            # [S, E]
+    x = fam.embed(config, params, tok, pos, compute_dtype)    # [S, E]
     if shd is not None:
         x = shd.slots(x)
+    ctx = fam.pos_ctx(config, pos)
     cols = jnp.arange(M * bT, dtype=jnp.int32)
     ok = cols[None, :] <= pos[:, None]                        # [S, M*bT]
+    win_ok = None if d.window is None else \
+        (pos[:, None] - cols[None, :]) < d.window
     blk = tbl[jnp.arange(S), pos // bT]                       # [S]
     off = pos % bT
 
-    def apply_lora(y, x_in, name, i):
-        entry = None if lora_b is None else lora_b.get(name)
-        return maybe_lora(y, x_in, entry, i, impl=lora_impl)
-
     def layer(inner, inp):
         x, pk, pv = inner
-        bp, i = inp
+        bp, layer_x, i = inp
         with jax.named_scope("decode_proj_mlp"):
-            h = gpt2.layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
-            q, k, v = _gpt2_qkv(h, bp, i, H, lora_b, apply_lora, shd)
+            q, k, v = fam.qkv(config, x, bp, i, layer_x, ctx, sites, shd)
         # write the fed token's K/V row into its slot's current page;
         # idle slots land in the reserved trash block (never attended)
         with jax.named_scope("decode_kv_write"):
             pk = write_token_rows(pk, blk, i, off, k)
             pv = write_token_rows(pv, blk, i, off, v)
         with jax.named_scope("decode_paged_attn"):
-            ctx = attend(q[:, :, None, :], pk, pv, tbl, i, ok, D ** -0.5)
-        if shd is not None:
-            ctx = shd.heads4(ctx)
+            attn = attend(q, pk, pv, tbl, i,
+                          fam.layer_ok(layer_x, ok, win_ok), d.scale)
+            if shd is not None:
+                attn = shd.heads4(attn)
         with jax.named_scope("decode_proj_mlp"):
-            x = _gpt2_proj_mlp(x, ctx.reshape(S, E).astype(compute_dtype),
-                               bp, i, eps, apply_lora, shd)
-        return (x, pk, pv), None
-
-    (x, pool_k, pool_v), _ = jax.lax.scan(
-        layer, (x, pool_k, pool_v), (wb, jnp.arange(L, dtype=jnp.int32)))
-    with jax.named_scope("decode_head"):
-        x = gpt2.layer_norm(x, params["ln_f"]["g"].astype(compute_dtype),
-                            params["ln_f"]["b"].astype(compute_dtype), eps)
-        logits = x @ params["wte"].astype(compute_dtype).T
-        logits = _head_lora(logits, x, lora_b, lora_impl)
-    return logits.astype(jnp.float32), pool_k, pool_v
-
-
-def gemma3_decode_step_paged(config: Gemma3TextConfig, params, pool_k,
-                             pool_v, tok, pos, tbl, lora=None,
-                             compute_dtype=jnp.float32,
-                             attn_impl: str = "auto",
-                             lora_impl: str = "auto", shardings=None):
-    """Gemma-3 paged decode step (see gpt2_decode_step_paged): GQA pool
-    (Hkv heads to a page row), per-layer global/local RoPE, sliding-window
-    validity over absolute positions (serve sequences are unpadded, so
-    the column index IS the position).
-
-    Under `shardings` the GQA head placement follows shard_heads: the
-    pool's KV axis shards when Hkv % tp == 0, otherwise the query-group
-    axis does (pools replicated) — either way the gate charges per-shard
-    head counts and constraints pin the 4D [S, KV, G, D] layout."""
-    from mobilefinetuner_tpu.serve.paged_kv import write_token_rows
-    c = config
-    S, M = tbl.shape
-    L, bT = pool_k.shape[1:3]
-    KV, D = c.num_key_value_heads, c.head_dim
-    nq = c.num_attention_heads
-    G = nq // KV
-    eps = c.rms_norm_eps
-    scale = c.query_pre_attn_scalar ** -0.5
-    params = jax.tree.map(jnp.asarray, params)
-    lora_b = None if lora is None else lora.get("blocks")
-    cast = lambda t: (t.astype(compute_dtype)
-                      if jnp.issubdtype(t.dtype, jnp.floating) else t)
-    with jax.named_scope("decode_weight_cast"):
-        wb = jax.tree.map(cast, params["blocks"])
-    is_global = jnp.asarray([c.is_global_layer(i) for i in range(L)])
-    normalizer = jnp.asarray(c.hidden_size ** 0.5, compute_dtype)
-    shd = shardings
-    attend = _paged_attend(attn_impl, KV, G, D, pool_k, shd)
-
-    x = params["embed"][tok].astype(compute_dtype) * normalizer
-    if shd is not None:
-        x = shd.slots(x)
-    cos_g, sin_g = rope_cos_sin(pos[:, None], D, c.rope_theta)
-    cos_l, sin_l = rope_cos_sin(pos[:, None], D, c.rope_local_base_freq)
-    cols = jnp.arange(M * bT, dtype=jnp.int32)
-    valid = cols[None, :] <= pos[:, None]                     # [S, M*bT]
-    win_ok = (pos[:, None] - cols[None, :]) < c.sliding_window
-    blk = tbl[jnp.arange(S), pos // bT]
-    off = pos % bT
-
-    def apply_lora(y, x_in, name, i):
-        entry = None if lora_b is None else lora_b.get(name)
-        return maybe_lora(y, x_in, entry, i, impl=lora_impl)
-
-    def layer(inner, inp):
-        x, pk, pv = inner
-        bp, glob, i = inp
-        with jax.named_scope("decode_proj_mlp"):
-            a = bp["attn"]
-            h = gemma3.rms_norm(x, bp["input_ln"], eps)
-            q = apply_lora(h @ a["q_w"], h, "q_proj", i).reshape(S, nq, D)
-            k = apply_lora(h @ a["k_w"], h, "k_proj", i).reshape(S, KV, D)
-            v = apply_lora(h @ a["v_w"], h, "v_proj", i).reshape(S, KV, D)
-            q = gemma3.rms_norm(q, a["q_norm"], eps)
-            k = gemma3.rms_norm(k, a["k_norm"], eps)
-            cos = jnp.where(glob, cos_g, cos_l)
-            sin = jnp.where(glob, sin_g, sin_l)
-            q = apply_rope(q[:, :, None, :], cos, sin)[:, :, 0]
-            k = apply_rope(k[:, :, None, :], cos, sin)[:, :, 0]
-            if shd is not None:
-                k, v = shd.kv_rows(k), shd.kv_rows(v)
-        with jax.named_scope("decode_kv_write"):
-            pk = write_token_rows(pk, blk, i, off, k)
-            pv = write_token_rows(pv, blk, i, off, v)
-        with jax.named_scope("decode_paged_attn"):
-            ok = jnp.where(glob, valid, valid & win_ok)       # [S, M*bT]
-            q4 = q.reshape(S, KV, G, D)
-            if shd is not None:
-                q4 = shd.heads4(q4)
-            ctx = attend(q4, pk, pv, tbl, i, ok, scale)
-            if shd is not None:
-                ctx = shd.heads4(ctx)
-        with jax.named_scope("decode_proj_mlp"):
-            ctx = ctx.reshape(S, nq * D).astype(compute_dtype)
-            attn_out = apply_lora(ctx @ a["o_w"], ctx, "o_proj", i)
-            attn_out = gemma3.rms_norm(attn_out, bp["post_attn_ln"], eps)
-            x = x + attn_out
-            h2 = gemma3.rms_norm(x, bp["pre_ffn_ln"], eps)
-            act = gemma3.gelu_tanh(
-                apply_lora(h2 @ bp["mlp"]["gate_w"], h2, "gate_proj", i)) \
-                * apply_lora(h2 @ bp["mlp"]["up_w"], h2, "up_proj", i)
-            if shd is not None:
-                act = shd.hidden(act)
-            down = apply_lora(act @ bp["mlp"]["down_w"], act, "down_proj", i)
-            down = gemma3.rms_norm(down, bp["post_ffn_ln"], eps)
-            x = x + down
+            x = fam.post(config, x,
+                         attn.reshape(S, d.nq * d.D).astype(compute_dtype),
+                         bp, i, sites, shd)
         return (x, pk, pv), None
 
     (x, pool_k, pool_v), _ = jax.lax.scan(
         layer, (x, pool_k, pool_v),
-        (wb, is_global, jnp.arange(L, dtype=jnp.int32)))
+        (wb, fam.layer_xs(config), jnp.arange(L, dtype=jnp.int32)))
     with jax.named_scope("decode_head"):
-        x = gemma3.rms_norm(x, params["final_norm"].astype(compute_dtype),
-                            eps)
-        logits = x @ params["embed"].astype(compute_dtype).T
-        logits = _head_lora(logits, x, lora_b, lora_impl)
-    return logits.astype(jnp.float32), pool_k, pool_v
+        x = fam.final_norm(config, params, x, compute_dtype)
+        logits = _tied_head(fam, params, x, sites, compute_dtype)
+    return logits, pool_k, pool_v
+
+
+# the names callers outside this package import (benchmark/harness,
+# chip_smoke.py, tools/, tests/): the single programs, bound to a family
+gpt2_prefill = partial(prefill, GPT2)
+gpt2_prefill_chunk = partial(prefill_chunk, GPT2)
+gpt2_decode_step_paged = partial(decode_step_paged, GPT2)
+gemma3_prefill = partial(prefill, GEMMA)
+gemma3_prefill_chunk = partial(prefill_chunk, GEMMA)
+gemma3_decode_step_paged = partial(decode_step_paged, GEMMA)
 
 
 def left_pad(seqs, pad_id: int):

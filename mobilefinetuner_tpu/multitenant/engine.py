@@ -54,10 +54,9 @@ from mobilefinetuner_tpu.core.telemetry import Telemetry, run_manifest
 from mobilefinetuner_tpu.data.prefetch import Prefetcher
 from mobilefinetuner_tpu.io import async_ckpt
 from mobilefinetuner_tpu.lora import peft_io
-from mobilefinetuner_tpu.lora.lora import (assign_adapters,
-                                           init_lora_gemma3,
-                                           init_lora_gpt2, stack_adapters,
+from mobilefinetuner_tpu.lora.lora import (assign_adapters, stack_adapters,
                                            trainable_mask, unstack_adapter)
+from mobilefinetuner_tpu.models.family import get_family
 from mobilefinetuner_tpu.multitenant.jobspec import JobSpec, validate_jobs
 from mobilefinetuner_tpu.ops.loss import lm_cross_entropy_rows
 from mobilefinetuner_tpu.optim.adam import init_multi_state
@@ -226,23 +225,14 @@ class MultiTenantEngine:
                  telemetry: Optional[Telemetry] = None):
         cfg = cfg or EngineConfig()
         cfg.validate()
-        if family == "gpt2":
-            self._init_lora = init_lora_gpt2
-            self._forward = _gpt2_forward
-            default_init = "gpt2"
-        elif family == "gemma":
-            self._init_lora = init_lora_gemma3
-            self._forward = _gemma_forward
-            default_init = "peft"
-        else:
-            raise ValueError(f"unknown model family {family!r}")
+        fam = get_family(family)
         validate_jobs(jobs)
         self.family = family
         self.config = config
         self.cfg = cfg
         self.params = params
         self.k = cfg.slots
-        self._default_init = default_init
+        self._fam = fam
         self._dropout = jobs[0].dropout      # shared (validate_jobs)
         self._make_stream = make_stream
         self.tel = telemetry or Telemetry("", enabled=False)
@@ -250,8 +240,8 @@ class MultiTenantEngine:
 
         # the stacked bank: slot shapes come from the SHARED spec (rank/
         # targets validated equal); empty slots are all-zero (delta == 0)
-        template = self._init_lora(
-            config, jobs[0].lora_spec(default_init), jax.random.PRNGKey(0))
+        template = fam.init_lora(
+            config, jobs[0].lora_spec(fam.lora_init), jax.random.PRNGKey(0))
         zero = jax.tree.map(jnp.zeros_like, template)
         self.bank = stack_adapters([zero] * self.k)
         self.mask = trainable_mask(self.bank)
@@ -282,9 +272,11 @@ class MultiTenantEngine:
             self.trace_counts["train_step"] += 1
             routed = assign_adapters(tr, mb["adapter_ids"])
             rng = mb["dropout_rng"][0] if "dropout_rng" in mb else None
-            logits = self._forward(
-                config, frozen, mb, routed, compute_dtype,
-                self._dropout, rng, cfg.lora_impl)
+            logits = fam.model.forward(
+                config, frozen, mb["input_ids"],
+                attention_mask=mb["attention_mask"], lora=routed,
+                compute_dtype=compute_dtype, lora_dropout=self._dropout,
+                dropout_rng=rng, lora_impl=cfg.lora_impl)
             return lm_cross_entropy_rows(logits, mb["labels"])
 
         self._step_fn = make_multi_train_step(loss_rows, self._tc,
@@ -359,9 +351,9 @@ class MultiTenantEngine:
 
     def _admit(self, tenant: _Tenant, j: int) -> None:
         spec = tenant.spec
-        fresh = self._init_lora(self.config,
-                                spec.lora_spec(self._default_init),
-                                jax.random.PRNGKey(spec.seed))
+        fresh = self._fam.init_lora(
+            self.config, spec.lora_spec(self._fam.lora_init),
+            jax.random.PRNGKey(spec.seed))
         self.bank, self.opt = self._admit_jit(self.bank, self.opt, fresh,
                                               jnp.int32(j))
         self._lr[j] = spec.lr
@@ -526,7 +518,7 @@ class MultiTenantEngine:
         if os.path.dirname(path):
             os.makedirs(os.path.dirname(path), exist_ok=True)
         bank_h, snap_ms = async_ckpt.timed_snapshot(self.bank)
-        lspec = spec.lora_spec(self._default_init)
+        lspec = spec.lora_spec(self._fam.lora_init)
         name = spec.name
         final_path = tenant.save_path
 
@@ -655,24 +647,3 @@ class MultiTenantEngine:
         # contract): an exception's run_end names its type, and writer
         # errors must not mask it
         self.close("ok" if exc_type is None else exc_type.__name__)
-
-
-# --------------------------- family forwards --------------------------------
-
-def _gpt2_forward(config, frozen, mb, routed, compute_dtype, dropout,
-                  rng, lora_impl):
-    from mobilefinetuner_tpu.models import gpt2
-    return gpt2.forward(config, frozen, mb["input_ids"],
-                        attention_mask=mb["attention_mask"], lora=routed,
-                        compute_dtype=compute_dtype, lora_dropout=dropout,
-                        dropout_rng=rng, lora_impl=lora_impl)
-
-
-def _gemma_forward(config, frozen, mb, routed, compute_dtype, dropout,
-                   rng, lora_impl):
-    from mobilefinetuner_tpu.models import gemma3
-    return gemma3.forward(config, frozen, mb["input_ids"],
-                          attention_mask=mb["attention_mask"],
-                          lora=routed, compute_dtype=compute_dtype,
-                          lora_dropout=dropout, dropout_rng=rng,
-                          lora_impl=lora_impl)

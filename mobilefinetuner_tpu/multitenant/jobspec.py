@@ -39,6 +39,7 @@ import os
 from typing import Dict, List, Optional, Tuple
 
 from mobilefinetuner_tpu.lora.lora import LoRASpec
+from mobilefinetuner_tpu.models.family import FAMILIES
 
 
 @dataclasses.dataclass
@@ -133,8 +134,9 @@ def validate_jobs(jobs: List[JobSpec]) -> None:
 def parse_jobs(doc: dict) -> Tuple[str, List[JobSpec]]:
     """(family, validated jobs) from a parsed jobs document."""
     family = doc.get("family", "gpt2")
-    if family not in ("gpt2", "gemma"):
-        raise ValueError(f"family must be gpt2|gemma, got {family!r}")
+    if family not in FAMILIES:
+        raise ValueError(
+            f"family must be {'|'.join(FAMILIES)}, got {family!r}")
     raw_jobs = doc.get("jobs")
     if not isinstance(raw_jobs, list) or not raw_jobs:
         raise ValueError("jobs file needs a non-empty 'jobs' list")

@@ -107,12 +107,9 @@ from mobilefinetuner_tpu.core.preempt import PreemptionGuard
 from mobilefinetuner_tpu.core.telemetry import (HangWatchdog, Telemetry,
                                                 run_manifest)
 from mobilefinetuner_tpu.lora.lora import assign_adapters
-from mobilefinetuner_tpu.models.generate import (gemma3_decode_step_paged,
-                                                 gemma3_prefill,
-                                                 gemma3_prefill_chunk,
-                                                 gpt2_decode_step_paged,
-                                                 gpt2_prefill,
-                                                 gpt2_prefill_chunk,
+from mobilefinetuner_tpu.models.family import get_family
+from mobilefinetuner_tpu.models.generate import (decode_step_paged, prefill,
+                                                 prefill_chunk,
                                                  sample_per_row)
 from mobilefinetuner_tpu.serve.adapters import AdapterBank
 from mobilefinetuner_tpu.serve.paged_kv import (TRASH_BLOCK, BlockAllocator,
@@ -362,24 +359,15 @@ class ServeEngine:
                  watchdog: Optional[HangWatchdog] = None):
         cfg = cfg or ServeConfig()
         cfg.validate()
-        if family == "gpt2":
-            L, KV, D = config.n_layer, config.n_head, config.head_dim
-            if cfg.true_cap + cfg.max_new_tokens > config.n_positions:
-                raise ValueError(
-                    f"prompt cap + max_new_tokens = "
-                    f"{cfg.true_cap + cfg.max_new_tokens} exceeds "
-                    f"n_positions={config.n_positions}")
-            self._prefill_fn, self._step_fn = gpt2_prefill, \
-                gpt2_decode_step_paged
-            self._chunk_fn = gpt2_prefill_chunk
-        elif family == "gemma":
-            L = config.num_hidden_layers
-            KV, D = config.num_key_value_heads, config.head_dim
-            self._prefill_fn, self._step_fn = gemma3_prefill, \
-                gemma3_decode_step_paged
-            self._chunk_fn = gemma3_prefill_chunk
-        else:
-            raise ValueError(f"unknown family {family!r}")
+        fam = get_family(family)
+        shape = fam.dims(config)
+        L, KV, D = shape.L, shape.KV, shape.D
+        if shape.cap is not None \
+                and cfg.true_cap + cfg.max_new_tokens > shape.cap:
+            raise ValueError(
+                f"prompt cap + max_new_tokens = "
+                f"{cfg.true_cap + cfg.max_new_tokens} exceeds "
+                f"n_positions={shape.cap}")
         self.family, self.config, self.cfg = family, config, cfg
         self.bank = bank
         self.eos_id, self.pad_id = eos_id, pad_id
@@ -532,8 +520,6 @@ class ServeEngine:
         self.trace_counts: collections.Counter = collections.Counter()
         dt, impl = self.dtype, cfg.attn_impl
         l_impl = cfg.lora_impl
-        prefill_raw, step_raw = self._prefill_fn, self._step_fn
-        chunk_raw = self._chunk_fn
         conf = config
         sampling = cfg.sampling
 
@@ -550,10 +536,9 @@ class ServeEngine:
         def prefill_py(params, bank_tree, ids, mask, aid, *samp):
             self.trace_counts["prefill"] += 1
             lora = self._route(bank_tree, aid)
-            logits, (pk, pv) = prefill_raw(conf, params, ids, mask,
-                                           compute_dtype=dt, lora=lora,
-                                           lora_impl=l_impl,
-                                           shardings=shd)
+            logits, (pk, pv) = prefill(fam, conf, params, ids, mask,
+                                       compute_dtype=dt, lora=lora,
+                                       lora_impl=l_impl, shardings=shd)
             if sampling:
                 n_real = mask.sum(-1).astype(jnp.int32)       # [1]
                 tok0 = _select(logits, n_real, *samp)[0]
@@ -565,10 +550,10 @@ class ServeEngine:
                     aid, *samp):
             self.trace_counts["decode_step"] += 1
             lora = self._route(bank_tree, aid)
-            logits, pk, pv = step_raw(conf, params, pool_k, pool_v, tok,
-                                      pos, tbl, lora=lora,
-                                      compute_dtype=dt, attn_impl=impl,
-                                      lora_impl=l_impl, shardings=shd)
+            logits, pk, pv = decode_step_paged(
+                fam, conf, params, pool_k, pool_v, tok, pos, tbl,
+                lora=lora, compute_dtype=dt, attn_impl=impl,
+                lora_impl=l_impl, shardings=shd)
             if sampling:
                 nxt = _select(logits, pos + 1, *samp)
             else:
@@ -587,10 +572,10 @@ class ServeEngine:
             # the [1] aid to the row count so the per-row lora gather is
             # shape-identical to the decode step's
             lora = self._route(bank_tree, jnp.broadcast_to(aid, (W,)))
-            logits, pk, pv = chunk_raw(conf, params, pool_k, pool_v,
-                                       ids, start, n_tok, tbl, lora=lora,
-                                       compute_dtype=dt,
-                                       lora_impl=l_impl, shardings=shd)
+            logits, pk, pv = prefill_chunk(
+                fam, conf, params, pool_k, pool_v, ids, start, n_tok, tbl,
+                lora=lora, compute_dtype=dt, lora_impl=l_impl,
+                shardings=shd)
             if sampling:
                 tok = _select(logits, (start + n_tok)[None], *samp)[0]
             else:
@@ -651,10 +636,8 @@ class ServeEngine:
         # step's trace applies (ops/decode_attention.resolve_paged_impl)
         from mobilefinetuner_tpu.ops.decode_attention import \
             resolve_paged_impl
-        nq = (config.n_head if family == "gpt2"
-              else config.num_attention_heads)
         self.attn_impl_resolved = resolve_paged_impl(
-            cfg.attn_impl, KV, nq // KV, cfg.block_T, D,
+            cfg.attn_impl, KV, shape.nq // KV, cfg.block_T, D,
             self.dtype.itemsize, tp=cfg.mesh_tp)
         self.lora_impl_resolved = lora_impl_resolved
         # and the pools' resident layout (serve/paged_kv.py): computed

@@ -70,6 +70,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from mobilefinetuner_tpu.models.family import Family, get_family
 from mobilefinetuner_tpu.ops.decode_attention import shard_heads
 from mobilefinetuner_tpu.serve.paged_kv import pool_partition_spec
 
@@ -92,26 +93,14 @@ def make_serve_mesh(dp: int, tp: int, devices: Optional[Sequence] = None
     return Mesh(np.array(devices[:n]).reshape(dp, tp), ("dp", "tp"))
 
 
-# which LoRA targets are column- vs row-parallel (mirrors the param
-# tables below; lora.GPT2_TARGETS / GEMMA_TARGETS name the sites)
+# which LoRA targets are column- vs row-parallel (mirrors the families'
+# param leaf sets — models/family.py col_leaves / kv_col_leaves /
+# row_leaves; lora.GPT2_TARGETS / GEMMA_TARGETS name the sites)
 _COL_TARGETS = frozenset({"attn_qkv", "attn_q", "attn_k", "attn_v",
                           "mlp_fc_in", "q_proj", "gate_proj", "up_proj"})
 _KV_COL_TARGETS = frozenset({"k_proj", "v_proj"})   # only when pools shard
 _ROW_TARGETS = frozenset({"attn_proj", "mlp_fc_out", "o_proj",
                           "down_proj"})
-
-# param leaves sharded on the output-feature (last) axis / the
-# input-feature (second-to-last) axis, by family. Biases ride their
-# matmul's output axis. Everything unlisted (embeds, norms, row-parallel
-# biases) replicates. GPT-2's fused qkv_w [E, 3E] shards the packed 3E
-# axis: a tp boundary can cross the Q/K/V section edges — semantically
-# fine under GSPMD (the jnp.split resharding is part of the pinned
-# census), and head-aligned within each section because E % tp == 0.
-_COL_LEAVES = {"gpt2": frozenset({"qkv_w", "qkv_b", "fc_w", "fc_b"}),
-               "gemma": frozenset({"q_w", "gate_w", "up_w"})}
-_KV_COL_LEAVES = {"gpt2": frozenset(), "gemma": frozenset({"k_w", "v_w"})}
-_ROW_LEAVES = {"gpt2": frozenset({"proj_w"}),
-               "gemma": frozenset({"o_w", "down_w"})}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +113,7 @@ class ServeSharding:
     mesh: Mesh
     dp: int
     tp: int
-    family: str
+    family: Family
     nq: int           # query heads
     kv: int           # KV heads
     kv_shards: int    # tp when the pool's KV axis shards, else 1
@@ -133,13 +122,9 @@ class ServeSharding:
     @classmethod
     def build(cls, family: str, config, dp: int, tp: int,
               devices: Optional[Sequence] = None) -> "ServeSharding":
-        if family == "gpt2":
-            nq = kv = config.n_head
-        elif family == "gemma":
-            nq = config.num_attention_heads
-            kv = config.num_key_value_heads
-        else:
-            raise ValueError(f"unknown family {family!r}")
+        fam = get_family(family)
+        dims = fam.dims(config)
+        nq, kv = dims.nq, dims.KV
         if nq % tp:
             raise ValueError(
                 f"mesh_tp={tp} does not divide the {family} query-head "
@@ -147,7 +132,7 @@ class ServeSharding:
                 f"head-aligned weight shards")
         kv_local, g_local = shard_heads(kv, nq // kv, tp)
         return cls(mesh=make_serve_mesh(dp, tp, devices), dp=dp, tp=tp,
-                   family=family, nq=nq, kv=kv,
+                   family=fam, nq=nq, kv=kv,
                    kv_shards=kv // kv_local,
                    g_shards=(nq // kv) // g_local)
 
@@ -178,13 +163,14 @@ class ServeSharding:
         return self.named(P(None, kv, None, None))
 
     def param_shardings(self, params):
-        """NamedSharding tree for the frozen base params (tables above;
-        an axis shards only when tp divides it — indivisible leaves
-        replicate, same fallback idiom as parallel/mesh.fsdp_spec_for)."""
-        col = set(_COL_LEAVES[self.family])
+        """NamedSharding tree for the frozen base params (the family's
+        leaf sets; an axis shards only when tp divides it — indivisible
+        leaves replicate, same fallback idiom as
+        parallel/mesh.fsdp_spec_for)."""
+        col = set(self.family.col_leaves)
         if self.kv_shards > 1:
-            col |= _KV_COL_LEAVES[self.family]
-        row = _ROW_LEAVES[self.family]
+            col |= self.family.kv_col_leaves
+        row = self.family.row_leaves
 
         def rule(path, leaf):
             name = getattr(path[-1], "key", None) if path else None

@@ -575,6 +575,13 @@ def test_e2e_resume_from_corrupt_final_falls_back(gpt2_dir, wiki_dir,
     main(["--pretrained_dir", gpt2_dir, "--data_dir", wiki_dir,
           "--steps", "6", "--batch_size", "2", "--seq_len", "32",
           "--lora_out", out, "--save_every", "2", "--keep_ckpts", "3"])
+    # the newest entry below the final in the run's own lineage: step 4,
+    # or step 2 where the async writer's depth-1 queue coalesced the
+    # step-4 snapshot away before it was written (a fast host)
+    from mobilefinetuner_tpu.io.checkpoints import lineage_entries
+    final, prev = lineage_entries(out)[:2]
+    assert final["step"] == 6 and final["files"][0] == out
+    assert prev["step"] in (2, 4)
     _bitflip(out)  # corrupt the newest (final) checkpoint
     telem = str(tmp_path / "resume.jsonl")
     out2 = str(tmp_path / "b.safetensors")
@@ -588,7 +595,10 @@ def test_e2e_resume_from_corrupt_final_falls_back(gpt2_dir, wiki_dir,
     vfy = [e for e in evs if e["event"] == "ckpt_verify"]
     assert vfy[0]["ok"] is False and out in vfy[0]["path"]
     accepted = [e for e in vfy if e["ok"]]
-    assert accepted and accepted[0]["step"] == 4  # newest verified entry
-    # the resumed run continued from step 4 to 8
+    # the newest verified entry, and no older one
+    assert accepted and accepted[0]["step"] == prev["step"]
+    assert prev["files"][0] in accepted[0]["path"]
+    # the resumed run continued from that step to 8
     ends = [e for e in evs if e["event"] == "run_end"]
-    assert ends[0]["exit"] == "ok" and ends[0]["steps"] == 4
+    assert ends[0]["exit"] == "ok"
+    assert ends[0]["steps"] == 8 - prev["step"]

@@ -238,15 +238,25 @@ def test_gpt2_paged_serving_matches_contiguous_generate(gpt2_engine,
 
 
 def test_gpt2_eos_stops_request_early(gpt2_engine, gpt2_params):
-    """Declare a request's own second greedy token to be eos: the serve
-    loop must stop that request there (emitting the eos), freeing its
-    slot, while others run to their cap."""
+    """Declare one of a request's own later greedy tokens to be eos —
+    the first that differs from every token before it (index j >= 1),
+    so that it is the FIRST eos the request emits: the serve loop must
+    stop that request there (emitting the eos), freeing its slot, while
+    others run to their cap. The tiny fixture often repeats one token
+    (94, 94, ...), so probes are drawn until one has such a token."""
     eng = gpt2_engine
     rng = np.random.default_rng(3)
-    probe = list(rng.integers(1, 200, 6))
-    r0 = eng.submit(probe, max_new_tokens=6)
-    eng.drain()
-    eos = r0.tokens[1]
+    for _ in range(16):
+        probe = list(rng.integers(1, 200, 6))
+        r0 = eng.submit(probe, max_new_tokens=6)
+        eng.drain()
+        js = [j for j in range(1, 5) if r0.tokens[j] not in r0.tokens[:j]]
+        if js:
+            break
+    else:
+        pytest.fail("no probe whose greedy tokens change before the cap")
+    j = js[0]
+    eos = r0.tokens[j]
     eng.eos_id = eos
     try:
         reqs = [eng.submit(probe, max_new_tokens=6),
@@ -257,7 +267,7 @@ def test_gpt2_eos_stops_request_early(gpt2_engine, gpt2_params):
         want0 = oracle("gpt2", gpt2_params, reqs[0], eos_id=eos)
         assert by_id[reqs[0].id].tokens == want0
         assert by_id[reqs[0].id].tokens[-1] == eos
-        assert len(by_id[reqs[0].id].tokens) == 2      # stopped early
+        assert len(by_id[reqs[0].id].tokens) == j + 1  # stopped early
         assert len(by_id[reqs[1].id].tokens) <= 6
     finally:
         eng.eos_id = None

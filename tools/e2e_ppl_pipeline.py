@@ -213,16 +213,13 @@ def torch_eval_ppl(model_dir, data_root, seq_len, batch_size, max_batches,
     pytorch_alignment/gpt2_lora_finetune.py evaluation loop)."""
     import torch
     from transformers import AutoModelForCausalLM
-    from mobilefinetuner_tpu.cli.family import load_family
+    from mobilefinetuner_tpu.models.family import load_family
     from mobilefinetuner_tpu.data.wikitext2 import (WT2Config,
                                                     WikiText2Dataset)
 
     b = load_family(model_dir, family)
-    if family == "gemma":
-        encode = lambda s: b.tok.encode(s, add_bos=False)
-        eos_id, pad_id = b.tok.eos_id, b.tok.pad_id
-    else:
-        encode, eos_id, pad_id = b.tok.encode, b.tok.eos_id, None
+    encode = b.family.encode_plain(b.tok)
+    eos_id, pad_id = b.tok.eos_id, b.tok.pad_id
     seq_len = min(seq_len, b.max_len)
     cfg = WT2Config(seq_len=seq_len, batch_size=batch_size, stride=None,
                     shuffle=False, drop_last=False)

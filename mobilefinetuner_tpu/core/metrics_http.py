@@ -195,7 +195,10 @@ class MetricsRegistry:
                           # affinity scoring and the fleet report read
                           # them off /metrics, not the JSONL
                           "prefix_hit_rate", "cow_copies",
-                          "blocks_in_use"):
+                          "blocks_in_use",
+                          # PR 30: does the paged kernel's live range
+                          # engage (its chunk size: run_start below)
+                          "paged_live_chunk_share"):
                     self._gauge(f"mft_serve_{f}", g(f))
                 # page-pool occupancy: fraction of allocatable pages
                 # held by live requests (parked cache pages count free)
@@ -281,6 +284,14 @@ class MetricsRegistry:
                 self._gauge("mft_trend_z", g("z"), **labels)
                 if g("regressed"):
                     self._count("mft_trend_regressions", **labels)
+            elif ev == "run_start":
+                # PR 30: the serve engine's paged-kernel chunk (pages a
+                # grid step; absent from a trainer's manifest, null
+                # where the gather oracle serves)
+                conf = g("config") or {}
+                if "paged_pages_per_step" in conf:
+                    self._gauge("mft_serve_paged_pages_per_step",
+                                conf["paged_pages_per_step"])
             elif ev == "run_end":
                 self._count("mft_runs", exit=g("exit", "?"))
                 self._last_exit = g("exit")

@@ -286,6 +286,12 @@ EVENT_SCHEMA: Dict[str, Dict[str, tuple]] = {
         # gives the registry's mft_serve_pool_occupancy gauge (parked
         # cache pages count as free in both fields)
         "blocks_in_use": _OPT_NUM,
+        # PR 30 (optional on read): of the paged kernel's (slot, chunk)
+        # grid steps of a decode step, the share holding a live page,
+        # from the host's position table (null where the gather oracle
+        # serves); the chunk's size is run_start.config
+        # .paged_pages_per_step
+        "paged_live_chunk_share": _OPT_NUM,
     },
     # one memory-admission verdict (core/memory_guard.py, DESIGN.md
     # §21): immediately post-compile (phase=preflight), on a caught
@@ -517,7 +523,7 @@ OPTIONAL_FIELDS: Dict[str, frozenset] = {
                              "drained_ms", "drained_parts_ms"}),
     "serve_stats": frozenset({"hbm_mb", "pool_mb", "mesh",
                               "prefix_hit_rate", "cow_copies",
-                              "blocks_in_use"}),
+                              "blocks_in_use", "paged_live_chunk_share"}),
     "run_end": frozenset({"goodput", "reason"}),
     "checkpoint": frozenset({"snapshot_ms", "write_ms", "bytes", "mb_s",
                              "async"}),

@@ -52,6 +52,54 @@ def sized_copies(compiled, min_elems: int) -> list:
     return out
 
 
+# opcodes that move nothing on the device: a trace shows none of them
+_NO_DEVICE_OP = frozenset({"bitcast", "get-tuple-element", "parameter",
+                           "constant", "tuple"})
+
+
+def device_ops_from(compiled, path_part: str, in_loops: bool = False
+                    ) -> list:
+    """HLO lines of `compiled` that run as device ops of their own — a
+    fusion counts once, its body not; no bitcast, tuple, parameter or
+    constant — whose innermost source frame lies in a file whose path
+    holds `path_part`; with `in_loops`, only those in the body of a
+    while loop (the layer scan: XLA sinks a cheap producer of a loop
+    operand INTO the body, whatever its op_name says). What a device
+    trace attributes to that file: the "how many ops a layer does a
+    reader that matches this file average over" primitive
+    (tests/test_aot_tpu.py pins the paged kernel's file to ONE op a
+    layer: the custom call)."""
+    text = compiled.as_text()
+
+    def table(name):
+        if f"\n{name}\n" not in text:
+            return {}
+        rows = text.split(f"\n{name}\n", 1)[1].split("\n\n", 1)[0]
+        return {int(k): v for k, v in re.findall(r"^(\d+) (.*)$", rows,
+                                                  re.M)}
+
+    def ids(rows, key, keep):
+        return {k for k, v in rows.items()
+                if int(re.search(rf"{key}=(\d+)", v).group(1)) in keep}
+
+    files = {k for k, v in table("FileNames").items() if path_part in v}
+    frames = ids(table("StackFrames"), "file_location_id",
+                 ids(table("FileLocations"), "file_name_id", files))
+    bodies = set(re.findall(r" while\(.*body=(%[\w.\-]+)", text))
+    out, comp = [], ""
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace():      # a computation's header / "}"
+            comp = ln.split(" (")[0].replace("ENTRY ", "")
+        m = re.search(r" = (?:\(.*?\)|\S+) ([\w\-]+)\(.*stack_frame_id=(\d+)",
+                      ln)         # (a tuple-shaped result has spaces)
+        if m and m.group(1) not in _NO_DEVICE_OP \
+                and int(m.group(2)) in frames \
+                and not comp.startswith(("%fused_computation", "%region")) \
+                and (comp in bodies or not in_loops):
+            out.append(ln.strip())
+    return out
+
+
 def shaped_all_gathers(compiled, shape, dtypes=("f32", "bf16")) -> list:
     """HLO lines of `compiled` where an all-gather involves a tensor of
     exactly `shape` — the sharding-assertion primitive behind "the

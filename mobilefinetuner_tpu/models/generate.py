@@ -778,22 +778,46 @@ def prefill_chunk(fam: Family, config, params, pool_k, pool_v, ids, start,
     return logits, pool_k, pool_v
 
 
-def _paged_attend(attn_impl: str, KV: int, G: int, D: int, pool_k,
-                  shardings):
-    """The decode step's attend(q, pool_k, pool_v, tbl, layer, ok, scale):
-    the Pallas paged kernel (under shard_map on a serve mesh) or the
-    gather oracle, by ops/decode_attention.resolve_paged_impl."""
+def _paged_attend(fam: Family, d, attn_impl: str, pool_k, M: int, ok,
+                  win_ok, shardings):
+    """The decode step's attend(q, pool_k, pool_v, tbl, layer, layer_x)
+    -> ctx [S, KV, G, D] f32: the Pallas paged kernel (under shard_map
+    on a serve mesh) or the gather oracle, by
+    ops/decode_attention.resolve_paged_impl. Called ONCE a step, before
+    the layer scan: for the kernel it makes the step's plan here — the
+    mask cut into the kernel's chunks and each slot's live page range,
+    the same for every layer, or one of two where the family has
+    windowed layers (then the plan holds both and a layer picks, as it
+    picks its mask) — so the scan's body holds the kernel and nothing
+    that prepares its operands. The oracle takes the layer's boolean
+    mask and knows no range."""
     from mobilefinetuner_tpu.ops.decode_attention import (
-        paged_attention, paged_decode_attention, resolve_paged_impl,
-        sharded_paged_attend)
-    impl = resolve_paged_impl(attn_impl, KV, G, pool_k.shape[2], D,
-                              pool_k.dtype.itemsize,
-                              tp=1 if shardings is None else shardings.tp)
-    if impl != "pallas":
-        return paged_attention
-    if shardings is not None:
-        return sharded_paged_attend(shardings)
-    return paged_decode_attention
+        paged_attention, paged_decode_attention, paged_plan, pick_pages,
+        resolve_paged_impl, sharded_paged_attend)
+    KV, G, bT = d.KV, d.nq // d.KV, pool_k.shape[2]
+    itemsize = pool_k.dtype.itemsize
+    tp = 1 if shardings is None else shardings.tp
+    if resolve_paged_impl(attn_impl, KV, G, bT, d.D, itemsize,
+                          tp=tp) != "pallas":
+        return lambda q, pk, pv, tbl, i, layer_x: paged_attention(
+            q, pk, pv, tbl, i, fam.layer_ok(layer_x, ok, win_ok), d.scale)
+    kernel = paged_decode_attention if shardings is None \
+        else sharded_paged_attend(shardings)
+    with jax.named_scope("decode_paged_attn"):
+        # (the barrier: left alone, XLA sinks the cheap making of the
+        # plan's mask back into the scan's body, once a layer again)
+        plan = jax.lax.optimization_barrier(paged_plan(
+            jnp.stack([ok] if win_ok is None else [ok, ok & win_ok]),
+            bT, pick_pages(KV, G, bT, d.D, itemsize, M, tp)))
+
+    def attend(q, pk, pv, tbl, i, layer_x):
+        # which of the plan's masks layer_ok would hand this layer: the
+        # record's own answer, asked with one-element masks
+        kind = 0 if win_ok is None else jnp.where(
+            fam.layer_ok(layer_x, jnp.bool_(True), jnp.bool_(False)), 0, 1)
+        return kernel(q, pk, pv, tbl, i, plan.of_kind(kind), d.scale)
+
+    return attend
 
 
 def decode_step_paged(fam: Family, config, params, pool_k, pool_v, tok,
@@ -830,7 +854,6 @@ def decode_step_paged(fam: Family, config, params, pool_k, pool_v, tok,
     params, wb = _cast_blocks(params, compute_dtype)
     sites = AdapterSites(lora, lora_impl)
     shd = shardings
-    attend = _paged_attend(attn_impl, d.KV, d.nq // d.KV, d.D, pool_k, shd)
 
     x = fam.embed(config, params, tok, pos, compute_dtype)    # [S, E]
     if shd is not None:
@@ -842,6 +865,7 @@ def decode_step_paged(fam: Family, config, params, pool_k, pool_v, tok,
         (pos[:, None] - cols[None, :]) < d.window
     blk = tbl[jnp.arange(S), pos // bT]                       # [S]
     off = pos % bT
+    attend = _paged_attend(fam, d, attn_impl, pool_k, M, ok, win_ok, shd)
 
     def layer(inner, inp):
         x, pk, pv = inner
@@ -854,8 +878,7 @@ def decode_step_paged(fam: Family, config, params, pool_k, pool_v, tok,
             pk = write_token_rows(pk, blk, i, off, k)
             pv = write_token_rows(pv, blk, i, off, v)
         with jax.named_scope("decode_paged_attn"):
-            attn = attend(q, pk, pv, tbl, i,
-                          fam.layer_ok(layer_x, ok, win_ok), d.scale)
+            attn = attend(q, pk, pv, tbl, i, layer_x)
             if shd is not None:
                 attn = shd.heads4(attn)
         with jax.named_scope("decode_proj_mlp"):

@@ -634,11 +634,24 @@ class ServeEngine:
                 dims, S, rank, cfg.lora_impl, self.dtype.itemsize)
         # likewise the paged-attention path: the SAME rule the decode
         # step's trace applies (ops/decode_attention.resolve_paged_impl)
-        from mobilefinetuner_tpu.ops.decode_attention import \
-            resolve_paged_impl
+        from mobilefinetuner_tpu.ops.decode_attention import (
+            pick_pages, resolve_paged_impl)
         self.attn_impl_resolved = resolve_paged_impl(
             cfg.attn_impl, KV, shape.nq // KV, cfg.block_T, D,
             self.dtype.itemsize, tp=cfg.mesh_tp)
+        # and the pages the paged kernel folds a grid step (the rule the
+        # decode step's trace applies: pick_pages); None where the
+        # gather oracle serves, which walks no grid
+        self.paged_pages_per_step = pick_pages(
+            KV, shape.nq // KV, cfg.block_T, D, self.dtype.itemsize,
+            self.M, cfg.mesh_tp) \
+            if self.attn_impl_resolved == "pallas" else None
+        # the share of layers that attend under the family's window
+        # (Family.layer_ok, asked with one-element masks, as the decode
+        # step asks it)
+        self._window = shape.window
+        self._win_layers = 0.0 if shape.window is None else 1.0 - float(
+            np.mean(fam.layer_ok(fam.layer_xs(config), True, False)))
         self.lora_impl_resolved = lora_impl_resolved
         # and the pools' resident layout (serve/paged_kv.py): computed
         # from shapes alone, so a stream says which layout served
@@ -661,6 +674,7 @@ class ServeEngine:
             "max_new_tokens": cfg.max_new_tokens, "dtype": cfg.dtype,
             "attn_impl": cfg.attn_impl,
             "attn_impl_resolved": self.attn_impl_resolved,
+            "paged_pages_per_step": self.paged_pages_per_step,
             **self.pool_layout,
             "lora_impl": cfg.lora_impl,
             "lora_impl_resolved": lora_impl_resolved,
@@ -1517,9 +1531,29 @@ class ServeEngine:
                                 if self.prefix is not None else None),
             "cow_copies": self.cow_copies,
             "parked_blocks": self.alloc.parked_blocks,
+            "paged_live_chunk_share": self._live_chunk_share(),
             "counts": {s: int(self.counts.get(s, 0))
                        for s in Request.TERMINAL},
         }
+
+    def _live_chunk_share(self) -> Optional[float]:
+        """Of the paged kernel's (slot, chunk) grid steps of a decode
+        step at the slots' present positions, the share that hold a
+        live page (the rest skip their body and move nothing) — from
+        the host's own position table, no device read. None where the
+        gather oracle serves."""
+        P = self.paged_pages_per_step
+        if P is None:
+            return None
+        from mobilefinetuner_tpu.ops.decode_attention import \
+            live_chunk_share
+        w = self._win_layers
+        share = (1 - w) * live_chunk_share(self._pos, self.cfg.block_T,
+                                           self.M, P)
+        if w:
+            share += w * live_chunk_share(self._pos, self.cfg.block_T,
+                                          self.M, P, self._window)
+        return round(share, 4)
 
     def emit_stats(self) -> None:
         """One `serve_stats` snapshot into the stream (step() calls
@@ -1533,7 +1567,9 @@ class ServeEngine:
             p95_step_ms=h["p95_step_ms"], hbm_mb=h["hbm_mb"],
             pool_mb=h["pool_mb"], mesh=h["mesh"],
             prefix_hit_rate=h["prefix_hit_rate"],
-            cow_copies=h["cow_copies"], **h["counts"])
+            cow_copies=h["cow_copies"],
+            paged_live_chunk_share=h["paged_live_chunk_share"],
+            **h["counts"])
 
     # ------------------------------------------------------------ teardown --
     def close(self, exit: str = "ok", reason: Optional[str] = None) -> None:

@@ -32,9 +32,13 @@ copied both pools 52 times a token):
       (`write_chunk_rows`) and a prompt's whole pages
       (`write_prompt_blocks`) likewise;
   (c) the paged kernel (ops/decode_attention.paged_decode_attention)
-      DMAs the page [block_T, KV * D] as it lies and contracts it
-      against block-diagonal queries it lays out in VMEM: no per-head
-      slicing of a row, no relayout.
+      copies P pages a grid step inside the live range, each
+      [block_T, KV * D] as it lies, end to end into one VMEM chunk,
+      and contracts the chunk against block-diagonal queries it lays
+      out in VMEM: no per-head slicing of a row, no relayout. (A row
+      that fills whole lane tiles is what lets the kernel cut a page
+      out of the tiled HBM buffer itself; 25 heads of 64 do not, and
+      go a page a step through the pipeline: pick_pages.)
 
 Nothing here depends on the model's name or on a flag: KV and D come
 from the K/V rows and queries the callers hold, block_T and KV * D from

@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from mobilefinetuner_tpu.core.config import (  # noqa: E402
     Gemma3TextConfig, GPT2Config)
 from mobilefinetuner_tpu.core.xla_stats import (  # noqa: E402
-    compiled_peak_bytes, sized_copies, tpu_custom_calls)
+    compiled_peak_bytes, device_ops_from, sized_copies, tpu_custom_calls)
 from mobilefinetuner_tpu.ops import decode_attention as da  # noqa: E402
 from mobilefinetuner_tpu.serve import paged_kv  # noqa: E402
 
@@ -237,14 +237,24 @@ def test_the_expert_cell_s_train_step_compiles_and_fits(topo, as_tpu):
 
 # --------------------------- paged decode attention -------------------------
 
-# (S, KV, G, D, bT, M, NB, L): the smoke's engine (build_engine("gpt2s"),
-# 8 slots, block_T 16, 256 blocks, prompt 64 + 32 new -> 6 pages a slot),
-# a 128-token page, and gpt2-xl's 25 KV heads
+# (S, KV, G, D, bT, M, NB, L) and the pages a grid step folds there
+# (pick_pages): md_batchgen_long's engine (gpt2-medium, 16 slots, tables
+# of 64 pages of 16 tokens: 16 pages = 256 columns a step); the smoke's
+# engine (build_engine("gpt2s"), 8 slots, block_T 16, 256 blocks, prompt
+# 64 + 32 new -> 6 pages a slot); a 128-token page; gpt2-xl's 25 KV
+# heads, whose row of 1600 lanes fills no whole number of lane tiles,
+# so the pipeline brings it a page a step; Gemma's GQA heads; and the
+# two sides of the chunk's VMEM term — 36 and 40 heads of 128 at a
+# 128-token page, where two pages a step need 11.4 and 12.9 MB of the
+# 12 MB budget
 PAGED_SHAPES = {
-    "smoke_engine": (8, 12, 1, 64, 16, 6, 256, 12),
-    "bT128": (8, 12, 1, 64, 128, 2, 64, 12),
-    "kv25": (8, 25, 1, 64, 16, 6, 128, 4),
-    "gqa_d256": (4, 1, 4, 256, 16, 6, 64, 2),
+    "cell_md": ((16, 16, 1, 64, 16, 64, 1089, 24), 16),
+    "smoke_engine": ((8, 12, 1, 64, 16, 6, 256, 12), 4),
+    "bT128": ((8, 12, 1, 64, 128, 2, 64, 12), 2),
+    "kv25": ((8, 25, 1, 64, 16, 6, 128, 4), 1),
+    "gqa_d256": ((4, 1, 4, 256, 16, 6, 64, 2), 4),
+    "vmem_under": ((4, 36, 1, 128, 128, 4, 16, 2), 2),
+    "vmem_over": ((4, 40, 1, 128, 128, 4, 16, 2), 1),
 }
 # over the VMEM gate: the rule says no AND the compiler says no (a page
 # is [bT, KV*D] with no lane padding, so it takes twice the tokens the
@@ -274,9 +284,10 @@ def paged_operands(topo, shape, shard=lambda name: None):
 
 @pytest.mark.parametrize("name", sorted(PAGED_SHAPES))
 def test_paged_decode_compiles_where_eligible(topo, as_tpu, name):
-    S, KV, G, D, bT, M, NB, L = shape = PAGED_SHAPES[name]
+    (S, KV, G, D, bT, M, NB, L), pages = shape, _ = PAGED_SHAPES[name]
     assert da.paged_eligible(KV, G, bT, D, 2)
     assert da.resolve_paged_impl("auto", KV, G, bT, D, 2) == "pallas"
+    assert da.pick_pages(KV, G, bT, D, 2, M) == pages
     assert_kernel(compile_for(
         lambda q, pk, pv, tbl, ok: da.paged_decode_attention(
             q, pk, pv, tbl, L - 1, ok, D ** -0.5),
@@ -305,7 +316,9 @@ def test_paged_rule_and_compiler_refuse_together(topo, as_tpu, name,
 def test_sharded_paged_attend_compiles_on_four_chips(topo, as_tpu, dp, tp):
     """The shard_map wrapper on a mesh built from the described devices:
     each shard's kernel gets KV/tp heads of its own pool slice, and the
-    attention itself needs no collective."""
+    attention itself needs no collective. P follows the LOCAL row: 3
+    heads of 64 (tp 4) fill no lane tile and go a page a step, 6 heads
+    (tp 2) go 4 pages a step by the kernel's own copies."""
     from mobilefinetuner_tpu.serve.sharding import ServeSharding
     sh = ServeSharding.build("gpt2", GPT2Config.gpt2_small(), dp, tp,
                              devices=topo.devices)
@@ -314,8 +327,9 @@ def test_sharded_paged_attend_compiles_on_four_chips(topo, as_tpu, dp, tp):
              "pool": paged_kv.pool_partition_spec(True),
              "tbl": P(dpn, None), "ok": P(dpn, None)}
     ops = paged_operands(
-        topo, PAGED_SHAPES["smoke_engine"],
+        topo, PAGED_SHAPES["smoke_engine"][0],
         shard=lambda name: NamedSharding(sh.mesh, specs[name]))
+    assert da.pick_pages(12, 1, 16, 64, 2, 6, tp) == {4: 1, 2: 4}[tp]
     attend = da.sharded_paged_attend(sh)
     compiled = compile_for(
         lambda q, pk, pv, tbl, ok: attend(q, pk, pv, tbl, 3, ok, 0.125),
@@ -469,6 +483,74 @@ def test_no_program_converts_the_pool(topo, as_tpu, case, program):
         compiled_peak_bytes(compiled) / 1e9, held / 1e9)
     if program == "decode_step":
         assert_kernel(compiled)
+
+
+def test_device_ops_from_reads_files_loops_and_fusions():
+    """The witness below must not go blind either: an instruction counts
+    by its innermost frame's FILE, a fusion once and its body not, a
+    bitcast never — and `in_loops` goes by the computation a while
+    names as its body, not by the op_name (a producer XLA sank into the
+    loop keeps the op_name it had outside)."""
+    class Text:
+        def __init__(self, *lines):
+            self.as_text = lambda: "\n".join(lines)
+    text = Text(
+        "HloModule m", "", "FileNames", '1 "/r/models/generate.py"',
+        '2 "/r/ops/decode_attention.py"', "", "FunctionNames", '1 "f"', "",
+        "FileLocations", "1 {file_name_id=1 function_name_id=1 line=3}",
+        "2 {file_name_id=2 function_name_id=1 line=9}", "",
+        "StackFrames", "1 {file_location_id=1 parent_frame_id=1}",
+        "2 {file_location_id=2 parent_frame_id=1}", "",
+        "%fused_computation.1 (p: s32[4]) -> s32[4] {",
+        '  ROOT %c = s32[4] convert(%p), metadata={op_name="a/convert"'
+        " stack_frame_id=2}", "}", "",
+        "%body.sunk (t: (s32[], s32[4])) -> (s32[], s32[4]) {",
+        '  %k = f32[4] custom-call(%x), custom_call_target="tpu_custom_call"'
+        ', metadata={op_name="jit(f)/while/body/pallas_call"'
+        " stack_frame_id=2}",
+        "  %sunk = s32[4] fusion(%g), kind=kLoop, calls=%fused_computation.1,"
+        ' metadata={op_name="jit(f)/attn/convert" stack_frame_id=2}',
+        '  %b = s32[4] bitcast(%g), metadata={op_name="jit(f)/while/body/r"'
+        " stack_frame_id=2}",
+        '  %m = (f32[4]{0}, f32[4]{0}) fusion(%g), kind=kLoop,'
+        ' calls=%fused_computation.2,'
+        ' metadata={op_name="jit(f)/while/body/mlp" stack_frame_id=1}',
+        "}", "", "ENTRY %main (p: s32[4]) -> s32[4] {",
+        '  %plan = s32[4] fusion(%p), kind=kLoop, calls=%fused_computation.1,'
+        ' metadata={op_name="jit(f)/attn/convert" stack_frame_id=2}',
+        "  %w = (s32[], s32[4]) while(%t), condition=%cond, body=%body.sunk",
+        "}")
+    here = "ops/decode_attention.py"
+    names = lambda lines: [ln.split(" = ")[0] for ln in lines]
+    assert names(device_ops_from(text, here, in_loops=True)) \
+        == ["%k", "%sunk"]
+    assert names(device_ops_from(text, here)) == ["%k", "%sunk", "%plan"]
+    assert names(device_ops_from(text, "models/generate.py")) == ["%m"]
+    assert device_ops_from(text, "ops/flash_attention.py") == []
+
+
+@pytest.mark.parametrize("case", sorted(POOL_CASES))
+def test_decode_scan_holds_one_attention_op(topo, as_tpu, case):
+    """Inside the layer scan the paged kernel's file owns ONE device op,
+    the custom call: the mask's cut into chunks and the live ranges are
+    made once a step, before the scan (generate._paged_attend), and XLA
+    does not sink them back (left alone it does: the making is cheap and
+    the loop's operand). The benchmark's paged_attn_roofline averages
+    over every device op of that file, so a second small op a layer
+    would double what it prints."""
+    c = POOL_CASES[case]()
+    L, KV, D, S, NB, P = (c[k] for k in ("L", "KV", "D", "S", "NB", "P"))
+    M = paged_kv.blocks_for(2 * P - 1, 16)
+    pool = pool_sds(NB, L, KV, 16, D, SingleDeviceSharding(topo.devices[0]))
+    i32 = lambda *shape: sds(topo, shape, jnp.int32)
+    compiled = compile_for(
+        c["step"], on_chip0(topo, c["params"]), pool, pool, i32(S), i32(S),
+        i32(S, M), donate_argnums=(1, 2))
+    here = "ops/decode_attention.py"
+    in_scan = device_ops_from(compiled, here, in_loops=True)
+    assert len(in_scan) == 1 and "custom-call(" in in_scan[0], in_scan
+    # and the once-a-step making of the plan is there, outside it
+    assert len(device_ops_from(compiled, here)) > 1
 
 
 # --------------------------- whole programs (slow) --------------------------

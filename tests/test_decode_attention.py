@@ -15,10 +15,12 @@ import pytest
 
 from mobilefinetuner_tpu.ops.decode_attention import (decode_attention,
                                                       decode_eligible,
+                                                      live_chunk_share,
                                                       paged_attention,
                                                       paged_decode_attention,
                                                       paged_eligible,
-                                                      pick_kvb,
+                                                      paged_plan,
+                                                      pick_kvb, pick_pages,
                                                       xla_reference)
 from mobilefinetuner_tpu.serve.paged_kv import gather_pages, pool_shape
 
@@ -144,6 +146,95 @@ def test_paged_matches_gathered_contiguous(shape, dtype):
                                    atol=tol, rtol=tol)
 
 
+def serve_masks(pos, M, bT, window=None):
+    """The decode step's masks for slots at `pos`: columns 0..pos, and
+    under a window the last `window` of them."""
+    cols = np.arange(M * bT)
+    pos = np.asarray(pos)[:, None]
+    ok = cols[None, :] <= pos
+    if window is not None:
+        ok &= (pos - cols[None, :]) < window
+    return jnp.asarray(ok)
+
+
+# (S, KV, G, D, bT, M, NB, L), P, each slot's position, window
+CHUNK_CASES = {
+    "ends_mid_page": ((3, 2, 1, 64, 8, 8, 40, 2), 4, [3, 20, 45], None),
+    "ends_at_a_chunk_edge": ((3, 2, 1, 64, 8, 8, 40, 2), 4,
+                             [31, 32, 63], None),
+    "one_token": ((2, 2, 1, 64, 8, 8, 40, 1), 4, [0, 0], None),
+    "full_table": ((2, 2, 1, 64, 8, 8, 40, 1), 4, [63, 63], None),
+    "M_not_a_multiple_of_P": ((3, 2, 1, 64, 8, 7, 40, 2), 4,
+                              [55, 30, 33], None),
+    # (the second: a row of 64 lanes, no whole lane tile, which only the
+    # pipeline's block operands can bring, a page a step)
+    "P_1": ((3, 2, 1, 64, 8, 6, 40, 1), 1, [47, 9, 17], None),
+    "P_1_blocks": ((3, 2, 1, 32, 8, 6, 40, 1), 1, [47, 9, 17], None),
+    "P_M": ((3, 2, 1, 64, 8, 8, 40, 1), 8, [60, 9, 17], None),
+    # Gemma's heads, and a window whose first live page (page 10 of slot
+    # 0, page 4 of slot 1) lies past chunk 0; slot 2 is inside its window
+    "gqa_window_past_chunk_0": ((3, 1, 4, 128, 8, 16, 60, 2), 4,
+                                [100, 50, 9], 20),
+    # slot 1 is idle as the engine leaves it: table all trash, position 0
+    "idle_slot": ((3, 2, 1, 64, 8, 8, 40, 1), 4, [40, 0, 13], None),
+}
+
+
+def chunk_case(name, dtype=jnp.float32):
+    """(q, pool_k, pool_v, tbl, ok, P, live) of a case: tables as the
+    engine keeps them — a slot's pages up to its position, trash past
+    them — with `live` the pages inside each slot's range [lo, hi)."""
+    (S, KV, G, D, bT, M, NB, L), P, pos, window = CHUNK_CASES[name]
+    q, pk, pv, tbl, _ = make_paged(S, KV, G, D, bT, M, NB, L, dtype)
+    ok = serve_masks(pos, M, bT, window)
+    idx = np.arange(M)[None, :]
+    hi = np.asarray(pos)[:, None] // bT + 1
+    lo = 0 if window is None else \
+        np.maximum(np.asarray(pos)[:, None] - window + 1, 0) // bT
+    if name == "idle_slot":
+        hi[1] = 0
+    # pages before a window stay in the table (the engine frees none)
+    tbl = jnp.where(jnp.asarray(idx < hi), tbl, 0)
+    return q, pk, pv, tbl, ok, P, (idx >= lo) & (idx < hi), bT
+
+
+@pytest.mark.parametrize("name", sorted(CHUNK_CASES))
+def test_paged_chunks_match_the_oracle(name):
+    """The kernel at P pages a grid step against paged_attention, at
+    the edges of the live range: every layer, f32 and bf16."""
+    for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)):
+        q, pk, pv, tbl, ok, P, _, bT = chunk_case(name, dtype)
+        plan = paged_plan(ok[None], bT, P)
+        assert plan.ok.shape[2:] == (-(-tbl.shape[1] // P), 1, P * bT)
+        for layer in range(pk.shape[1]):
+            want = paged_attention(q, pk, pv, tbl, layer, ok, q.shape[-1]
+                                   ** -0.5)
+            got = paged_decode_attention(q, pk, pv, tbl, layer, plan,
+                                         q.shape[-1] ** -0.5)
+            assert np.isfinite(np.asarray(got)).all()
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("name", sorted(CHUNK_CASES))
+def test_paged_plan_ranges(name):
+    """The plan's [lo, hi) is the span of pages with an attendable
+    column (an idle slot's is its trash page, [0, 1)), two masks make
+    two ranges, and the host's share counts the same live chunks."""
+    q, pk, pv, tbl, ok, P, live, bT = chunk_case(name)
+    (_, _, _, _, _, M, _, _), _, pos, window = CHUNK_CASES[name]
+    full = serve_masks(pos, M, bT)
+    plan = paged_plan(jnp.stack([full, ok]), bT, P)
+    rng = np.asarray(plan.rng)
+    assert rng.shape == (4, len(pos))
+    for s, p in enumerate(pos):
+        want_lo = 0 if window is None else max(p - window + 1, 0) // bT
+        assert tuple(rng[:, s]) == (0, p // bT + 1, want_lo, p // bT + 1)
+    chunks = rng[3] - 1 - (rng[3] - 1) % P - (rng[2] - rng[2] % P)
+    assert live_chunk_share(np.asarray(pos), bT, M, P, window) == pytest.approx(
+        (chunks // P + 1).sum() / (len(pos) * -(-M // P)))
+
+
 def test_paged_trash_pages_never_leak():
     """Columns routed to the trash page (idle padding in a block table)
     must contribute nothing even when the trash page holds garbage."""
@@ -163,6 +254,31 @@ def test_paged_trash_pages_never_leak():
                                   D ** -0.5)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(base),
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("name", sorted(set(CHUNK_CASES) - {"idle_slot"}))
+def test_paged_dead_pages_never_leak(name):
+    """NaN in EVERY page outside a slot's live range — the trash page,
+    the pages before a window, and stale pages the table still names
+    inside a live chunk alike — leaves the kernel's output bit-equal:
+    the range says where work cannot be, and no grid step fetches a
+    page from there. (The gather oracle reads them all: 0 * NaN.)"""
+    q, pk, pv, tbl, ok, P, live, bT = chunk_case(name)
+    # stale pages: the table's dead entries name real blocks again
+    rng = np.random.default_rng(1)
+    stale = jnp.asarray(rng.integers(1, pk.shape[0], tbl.shape), jnp.int32)
+    tbl = jnp.where(jnp.asarray(live) | (jnp.arange(tbl.shape[1]) % 2 == 0),
+                    tbl, stale)
+    dead = np.ones(pk.shape[0], bool)
+    dead[np.asarray(tbl)[live]] = False
+    assert dead[0] and dead.sum() > 1
+    nan = lambda pool: pool.at[jnp.asarray(np.flatnonzero(dead))].set(jnp.nan)
+    plan = paged_plan(ok[None], bT, P)
+    scale = q.shape[-1] ** -0.5
+    want = paged_decode_attention(q, pk, pv, tbl, 0, plan, scale)
+    got = paged_decode_attention(q, nan(pk), nan(pv), tbl, 0, plan, scale)
+    assert np.isfinite(np.asarray(want)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_paged_validation():
@@ -196,3 +312,24 @@ def test_vmem_gate_charges_gqa_terms():
     big = pick_kvb(16, 2048, 256, 2)
     small = pick_kvb(16, 2048, 256, 2, G=64)
     assert big is not None and small is not None and small <= big
+    # the paged kernel's gate charges the P pages a grid step folds
+    # (pick_pages): 256 score columns a step where VMEM and the table
+    # allow — md_batchgen_long's 16 pages of 16 tokens — fewer where
+    # the table is short or a page long, ...
+    assert pick_pages(16, 1, 16, 64, 2, M=64) == 16
+    assert pick_pages(16, 1, 16, 64, 2, M=6) == 4
+    assert [pick_pages(16, 1, bT, 64, 2, M=64) for bT in (128, 256)] \
+        == [2, 1]
+    # ... one where a page row fills no whole lane tile (25 heads of
+    # 64; 3 of 12 on each of four shards, but 6 on each of two), ...
+    assert pick_pages(25, 1, 16, 64, 2, M=64) == 1
+    assert [pick_pages(12, 1, 16, 64, 2, M=64, tp=tp) for tp in (4, 2)] \
+        == [1, 16]
+    # ... and as many as the two K+V chunk buffers leave room for
+    # beside the G-dependent accumulators: 36 heads of 128 at a
+    # 128-token page hold two pages a step (11.4 MB), 40 hold one
+    # (12.9), and a larger G takes pages away before it takes the kernel
+    assert [pick_pages(KV, 1, 128, 128, 2, M=4) for KV in (36, 40)] == [2, 1]
+    assert paged_eligible(40, 1, 128, 128, 2)
+    assert [pick_pages(32, G, 64, 128, 2, M=64) for G in (2, 4)] == [4, 2]
+    assert not paged_eligible(32, 8, 64, 128, 2)

@@ -185,6 +185,75 @@ def test_run_start_names_the_pool_layout(gpt2_params, tmp_path):
     assert conf["attn_impl_resolved"] == eng.attn_impl_resolved
 
 
+# rows of 128 lanes (2 heads of 64), so the paged kernel brings pages by
+# its own copies, several a grid step; Gemma's window of 6 keeps the
+# local layers' live range in the table's second chunk once a context
+# passes 69 tokens
+WIDE = {
+    "gpt2": (dataclasses.replace(GPT2_CFG, n_embd=128, n_head=2,
+                                 n_positions=128), gpt2),
+    "gemma": (dataclasses.replace(GEMMA_CFG, head_dim=64), gemma3),
+}
+
+
+@pytest.mark.parametrize("family", sorted(WIDE))
+def test_paged_kernel_serves_and_counts(family, tmp_path):
+    """attn_impl=pallas end to end (interpret mode here): the decode
+    step makes the kernel's plan once a step and every layer picks its
+    mask's part of it — token for token what the gather oracle serves,
+    Gemma's windowed layers included — and the stream and /metrics say
+    how many pages a grid step folds and what share of the grid's
+    steps hold a live page."""
+    from mobilefinetuner_tpu.core.metrics_http import MetricsRegistry
+    from mobilefinetuner_tpu.ops.decode_attention import pick_pages
+    config, model = WIDE[family]
+    params = model.init_params(config, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 190, n).tolist() for n in (60, 9, 33)]
+
+    def serve(attn_impl, path):
+        tel = Telemetry(path)
+        reg = MetricsRegistry()
+        tel.add_observer(reg.observe)
+        eng = ServeEngine(family, config, params, ServeConfig(
+            num_slots=2, block_T=8, num_blocks=40, max_prompt=64,
+            max_new_tokens=32, attn_impl=attn_impl, stats_every=1),
+            telemetry=tel)
+        try:
+            reqs = [eng.submit(p, max_new_tokens=12) for p in prompts]
+            eng.drain()
+        finally:
+            eng.close()
+        return eng, [r.tokens for r in reqs], reg.render(), \
+            [json.loads(l) for l in open(path)]
+
+    eng, want, text, evs = serve("xla", str(tmp_path / "xla.jsonl"))
+    assert eng.paged_pages_per_step is None
+    assert all(e["paged_live_chunk_share"] is None
+               for e in evs if e["event"] == "serve_stats")
+    eng, got, text, evs = serve("pallas", str(tmp_path / "pallas.jsonl"))
+    assert got == want
+    d = eng._pool_dims
+    P = pick_pages(d[1], config.num_attention_heads // d[1]
+                   if family == "gemma" else 1, 8, d[2], 4, eng.M)
+    assert P == 8 and eng.M == 12          # two chunks a slot
+    start = next(e for e in evs if e["event"] == "run_start")
+    assert start["config"]["paged_pages_per_step"] == P
+    stats = [e for e in evs if e["event"] == "serve_stats"]
+    assert stats and all(validate_event(e) is None for e in stats)
+    shares = [e["paged_live_chunk_share"] for e in stats]
+    # 2 slots x 2 chunks: a slot holds one live chunk or two
+    assert all(0.5 <= x <= 1.0 for x in shares) and max(shares) > 0.5
+    if family == "gpt2":
+        assert set(shares) <= {0.5, 0.75, 1.0}
+    else:
+        # a windowed layer's range is one chunk where a global layer's
+        # is two: the share is the layers' mean, no multiple of 1/4
+        assert any(x % 0.25 for x in shares)
+    assert f"mft_serve_paged_pages_per_step {P}\n" in text
+    assert "mft_serve_paged_live_chunk_share " in text
+
+
 def test_serve_config_validation(gpt2_params):
     with pytest.raises(ValueError, match="multiple"):
         ServeConfig(max_prompt=12, block_T=8).validate()

@@ -329,6 +329,9 @@ def replica_main(args) -> int:
         telemetry_out=shard_path(base, k), host=k, **spec)
     registry = MetricsRegistry()
     eng.telemetry.add_observer(registry.observe)
+    # run_start went out while the engine was built, before the observer
+    registry.set_gauge("mft_serve_paged_pages_per_step",
+                       eng.paged_pages_per_step)
     gw = ReplicaGateway()
 
     def health():

@@ -2,21 +2,11 @@
 
 The third family on the shared training loop (cli/common.run_training):
 same flags, flush, telemetry, memory admission, prefetch, resume and
-`--lora_out` as `gpt2_lora_finetune`. What the family adds:
-
-  - `--expert_parallel N --expert_rank r` describe the deployment: this
-    process is rank r of N that share each expert layer and holds experts
-    [r·E/N, (r+1)·E/N). The loader reads only those, the expert layer
-    routes over all E and computes its own part (ops/moe.py). They select
-    no code path: 1/0 holds every expert.
-  - a checkpoint published in bf16 stays bf16 (io/checkpoints.load_afmoe):
-    with `--dtype bfloat16` the frozen base is resident at 2 bytes a
-    parameter and no cast runs in the step.
-  - `step_stats` carries `moe_assign_here`, `moe_load_max_over_mean` and
-    `moe_dropped`, counted on the device inside the step.
-
-The tokenizer is read from `vocab.json` / `merges.txt` (byte-level BPE);
-the published family's own tokenizer file format is not implemented.
+`--lora_out` as `gpt2_lora_finetune`. The entry point itself —
+`--expert_parallel N --expert_rank r`, the bf16-resident frozen base,
+the chunked loss, the `moe_*` counters in `step_stats` — is
+cli/expert_lora.py's, shared with cli/train_lora_ling.py; this file says
+what the family is.
 
 Usage (tiny smoke):
   python -m mobilefinetuner_tpu.cli.train_lora_afmoe \
@@ -26,198 +16,34 @@ Usage (tiny smoke):
 
 from __future__ import annotations
 
-import argparse
-import os
 import sys
 
-import jax
-
-from mobilefinetuner_tpu.cli import common
+from mobilefinetuner_tpu.cli import expert_lora
+from mobilefinetuner_tpu.cli.expert_lora import LOSS_CHUNKS  # noqa: F401
 from mobilefinetuner_tpu.core.config import AfmoeConfig
-from mobilefinetuner_tpu.core.logging import get_logger
-from mobilefinetuner_tpu.data.tokenizer_bpe import GPT2BPETokenizer
-from mobilefinetuner_tpu.data.wikitext2 import WT2Config, WikiText2Dataset
-from mobilefinetuner_tpu.io import async_ckpt
 from mobilefinetuner_tpu.io.checkpoints import load_afmoe
-from mobilefinetuner_tpu.lora import peft_io
-from mobilefinetuner_tpu.lora.lora import (AFMOE_TARGETS, LoRASpec,
-                                           init_lora_afmoe, num_trainable,
-                                           trainable_mask)
+from mobilefinetuner_tpu.lora.lora import AFMOE_TARGETS, init_lora_afmoe
 from mobilefinetuner_tpu.models import afmoe
-from mobilefinetuner_tpu.ops.loss import chunked_lm_cross_entropy_sum
-from mobilefinetuner_tpu.optim import adam as adam_mod
 
-log = get_logger()
-# sequence chunks of the cross-entropy: [B, S/8, V] logits at a time
-LOSS_CHUNKS = 8
+FAMILY = expert_lora.ExpertFamily(
+    name="afmoe", prog="train_lora_afmoe", title="AFMoE (Arcee Trinity)",
+    config_cls=AfmoeConfig, load=load_afmoe, model=afmoe,
+    targets=AFMOE_TARGETS, init_lora=init_lora_afmoe,
+    default_out="afmoe_lora.safetensors",
+    describe=lambda c: (
+        f"AFMoE: layers={c.num_hidden_layers} "
+        f"(dense {c.num_dense_layers}) hidden={c.hidden_size} "
+        f"q/kv heads={c.num_attention_heads}/{c.num_key_value_heads} "
+        f"experts held {c.experts_held} of {c.num_experts} "
+        f"top-{c.num_experts_per_tok} vocab={c.vocab_size}"))
 
 
-def build_parser() -> argparse.ArgumentParser:
-    p = argparse.ArgumentParser(
-        prog="train_lora_afmoe",
-        description="AFMoE (Arcee Trinity) LoRA fine-tuning (TPU)")
-    p.add_argument("--data_dir", required=True,
-                   help="WikiText-2 directory (wiki.{train,valid}.tokens)")
-    p.add_argument("--pretrained_dir", required=True,
-                   help="HF afmoe checkpoint dir (config.json, "
-                        "safetensors, vocab.json, merges.txt)")
-    p.add_argument("--lora_out", default="afmoe_lora.safetensors")
-    p.add_argument("--resume_from", default="",
-                   help="adapter safetensors to resume from")
-    p.add_argument("--eval_out", default="", help="eval JSONL output path")
-    p.add_argument("--rank", type=int, default=8)
-    p.add_argument("--alpha", type=float, default=16.0)
-    p.add_argument("--lora_dropout", type=float, default=0.0)
-    p.add_argument("--lora_targets", default="q_proj,k_proj,v_proj,o_proj",
-                   help="comma list of " + ",".join(AFMOE_TARGETS))
-    p.add_argument("--peft_export_dir", default="",
-                   help="also export an HF-PEFT adapter directory")
-    p.add_argument("--expert_parallel", type=int, default=1,
-                   help="ranks that share each expert layer (the "
-                        "deployment this process is one rank of)")
-    p.add_argument("--expert_rank", type=int, default=0,
-                   help="this process's rank among them: it holds "
-                        "experts [r*E/N, (r+1)*E/N)")
-    common.add_train_flags(p, lr=1e-4, seq_len=256, batch_size=1)
-    common.add_pm_flags(p)
-    common.add_shard_flags(p)
-    common.add_mesh_flags(p)
-    return p
+def build_parser():
+    return expert_lora.build_parser(FAMILY)
 
 
 def main(argv=None) -> int:
-    args = build_parser().parse_args(argv)
-    common.enable_compile_cache()
-
-    config = AfmoeConfig.from_pretrained(args.pretrained_dir) \
-        .with_expert_parallel(args.expert_parallel, args.expert_rank)
-    config, params = load_afmoe(args.pretrained_dir, config=config)
-    log.info(f"AFMoE: layers={config.num_hidden_layers} "
-             f"(dense {config.num_dense_layers}) hidden={config.hidden_size} "
-             f"q/kv heads={config.num_attention_heads}/"
-             f"{config.num_key_value_heads} experts held "
-             f"{config.experts_held} of {config.num_experts} "
-             f"top-{config.num_experts_per_tok} vocab={config.vocab_size}")
-
-    if args.resume_from:
-        common.resolve_resume_from(args)
-        lora, spec = peft_io.load_adapter(args.resume_from)
-        log.info(f"resumed adapter: r={spec.rank} alpha={spec.alpha} "
-                 f"targets={spec.targets}")
-    else:
-        spec = LoRASpec(rank=args.rank, alpha=args.alpha,
-                        dropout=args.lora_dropout,
-                        targets=[t for t in args.lora_targets.split(",")
-                                 if t], init="peft")
-        lora = init_lora_afmoe(config, spec, jax.random.PRNGKey(args.seed))
-    mask = trainable_mask(lora)
-    log.info(f"trainable params: {num_trainable(lora):,}")
-
-    tok = GPT2BPETokenizer.from_pretrained(args.pretrained_dir)
-    wt2 = WT2Config(seq_len=args.seq_len, batch_size=args.batch_size,
-                    data_fraction=args.data_fraction, seed=args.seed,
-                    **common.data_retry_kwargs(args))
-    train_ds = WikiText2Dataset(args.data_dir, "train", wt2, tok.encode,
-                                tok.eos_id)
-    valid_ds = None
-    if args.eval_interval:
-        wt2_eval = WT2Config(seq_len=args.seq_len,
-                             batch_size=args.eval_batch_size, shuffle=False,
-                             **common.data_retry_kwargs(args))
-        valid_ds = WikiText2Dataset(args.data_dir, "valid", wt2_eval,
-                                    tok.encode, tok.eos_id)
-
-    steps_per_epoch = max(train_ds.num_batches() // args.grad_accum_steps, 1)
-    total_steps = common.resolve_total_steps(args, steps_per_epoch)
-    tc = common.train_config_from_args(args, total_steps)
-    log.info(f"{train_ds.num_chunks} chunks, {steps_per_epoch} steps/epoch, "
-             f"{total_steps} total steps")
-
-    opt_state, start_step = common.maybe_resume_opt_state(
-        args, lora, tc, mask)
-
-    mesh, _ = common.build_mesh(args)
-    if mesh.size > 1:
-        raise SystemExit(
-            "train_lora_afmoe runs one expert-parallel rank on one device: "
-            "the exchange over an expert axis is not implemented "
-            "(ROADMAP R1); describe the deployment with --expert_parallel")
-    config = common.with_attention_impl(args, config)
-    params, fetch_fn, _ = common.setup_frozen_params(args, params, mesh)
-    compute_dtype = common.compute_dtype_from_args(args)
-    base_rng = (jax.random.PRNGKey(args.seed + 1)
-                if args.lora_dropout > 0 else None)
-    common.log_lora_impl_resolution(
-        args, {t: AFMOE_TARGETS[t](config) for t in spec.targets or []},
-        spec.rank, compute_dtype)
-
-    def nll(lora_t, frozen, mb, train: bool):
-        rng = mb["dropout_rng"][0] if train and "dropout_rng" in mb \
-            else None
-        p = fetch_fn(frozen)
-        hidden, aux = afmoe.hidden_states(
-            config, p, mb["input_ids"],
-            attention_mask=mb["attention_mask"], lora=lora_t,
-            compute_dtype=compute_dtype, remat=train and args.remat,
-            lora_dropout=args.lora_dropout if train else 0.0,
-            dropout_rng=rng, lora_impl=args.lora_impl, with_aux=True)
-        s, n = chunked_lm_cross_entropy_sum(
-            hidden, p["lm_head"], mb["labels"], num_chunks=LOSS_CHUNKS)
-        return s, n, aux["counters"]
-
-    def loss_fn(lora_t, frozen, mb):
-        return nll(lora_t, frozen, mb, True)
-
-    def nll_fn(lora_t, frozen, mb):
-        return nll(lora_t, frozen, mb, False)[:2]
-
-    def save_hook(step, lora_t, opt_st, final, ckpt=None):
-        path = args.lora_out
-        if not final:
-            root, ext = os.path.splitext(path)
-            path = f"{root}_step{step}{ext}"
-        if os.path.dirname(path):
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-        (lora_h, opt_h), snap_ms = async_ckpt.timed_snapshot(
-            (lora_t, opt_st))
-
-        def write():
-            peft_io.save_adapter(path, lora_h, spec)
-            adam_mod.save_state(path + ".opt", opt_h, tc.adam(),
-                                extra_metadata={"loop_step": str(step)})
-            common.record_ckpt_files(args, args.lora_out, step,
-                                     [path, path + ".opt"])
-            log.info(f"saved adapter -> {path}")
-            if final and args.peft_export_dir:
-                peft_io.export_peft(args.peft_export_dir, lora_h, spec,
-                                    "afmoe",
-                                    base_model_name=args.pretrained_dir)
-                log.info(f"PEFT export -> {args.peft_export_dir}")
-            return [path, path + ".opt"]
-
-        async_ckpt.submit(ckpt, step, write, final=final,
-                          snapshot_ms=snap_ms)
-
-    # in-loop MFU: the shared estimator over the matmul parameters a token
-    # is EXPECTED to meet here (uniform routing over the held experts)
-    from mobilefinetuner_tpu.core.telemetry import transformer_flops
-    flops = transformer_flops(
-        sum(int(x.size) for x in jax.tree.leaves(lora)),
-        afmoe.matmul_params_per_token(config),
-        args.batch_size * tc.grad_accum_steps, args.seq_len,
-        config.num_hidden_layers, config.num_attention_heads,
-        config.head_dim, full_ft=False)
-
-    common.run_training(
-        args, trainable=lora, frozen=params, loss_fn=loss_fn, nll_fn=nll_fn,
-        train_ds=train_ds, valid_ds=valid_ds, total_steps=total_steps,
-        tc=tc, mask=mask, start_step=start_step, opt_state=opt_state,
-        save_hook=save_hook, mesh=mesh, dropout_rng=base_rng,
-        flops_per_step=flops,
-        load_hook=common.make_rollback_loader(
-            tc, mask, lambda p: peft_io.load_adapter(p)[0]),
-        ckpt_path=args.lora_out)
-    return 0
+    return expert_lora.main(FAMILY, argv)
 
 
 if __name__ == "__main__":

@@ -265,3 +265,148 @@ class AfmoeConfig:
                    num_attention_heads=4, num_key_value_heads=2,
                    head_dim=16, num_experts=8, num_experts_per_tok=2,
                    sliding_window=8, max_position_embeddings=128)
+
+
+@dataclasses.dataclass
+class LingHybridConfig:
+    """The language model of inclusionAI's Ling-3.0-flash family (the text
+    sibling's `model_type` is `bailing_hybrid`): delta-rule linear
+    attention (KDA) in `layer_group_size - 1` layers of every
+    `layer_group_size`, latent attention (MLA) in the last of each group,
+    a SwiGLU MLP below `first_k_dense_replace` and sparse experts routed
+    by groups above — the published `config.json` keys (DESIGN.md §32
+    says which equation reads which), plus what this chip holds of each
+    expert layer, as AfmoeConfig does.
+
+    `num_experts` is the router's width; `experts_held = (first, count)`
+    the experts that live here; `vocab_size` the rows held. Defaults are
+    Ling-3.0-flash-VL's widths at one dense layer plus one period."""
+
+    vocab_size: int = 157184
+    hidden_size: int = 2560
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 768
+    moe_shared_expert_intermediate_size: int = 768
+    num_hidden_layers: int = 7
+    first_k_dense_replace: int = 1
+    layer_group_size: int = 6
+    num_attention_heads: int = 32
+    head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    kda_safe_gate: bool = True
+    no_kda_lora: bool = True
+    gated_attention_proj_granularity_type: str = "head_wise"
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rotary_dim: int = 64
+    rope_theta: float = 6000000.0
+    use_qk_norm: bool = True
+    num_experts: int = 512
+    num_experts_per_tok: int = 8
+    num_shared_experts: int = 1
+    n_group: int = 8
+    topk_group: int = 4
+    score_function: str = "sigmoid"
+    moe_router_enable_expert_bias: bool = True
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    expert_swiglu_limit_list: Optional[List[float]] = None
+    share_expert_swiglu_limit_list: Optional[List[float]] = None
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    max_position_embeddings: int = 131072
+    attention_impl: str = "auto"
+    kda_chunk: int = 64
+    experts_held: Optional[tuple] = None
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            self.experts_held = (0, self.num_experts)
+        self.experts_held = tuple(int(x) for x in self.experts_held)
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(f"experts_held={self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+        limits = [x for name in ("expert_swiglu_limit_list",
+                                 "share_expert_swiglu_limit_list")
+                  for x in (getattr(self, name) or [])
+                  [:self.num_hidden_layers]]
+        if any(limits):
+            raise ValueError(
+                "LingHybridConfig: a non-zero SwiGLU limit among the kept "
+                f"layers ({limits}): config.json does not give the "
+                "clamp's formula, and guessing one under the model's name "
+                "is worse than refusing (the published limits are 0 for "
+                "layers 0-33)")
+        if (self.score_function != "sigmoid" or self.tie_word_embeddings
+                or self.num_shared_experts != 1
+                or self.q_lora_rank is not None
+                or not self.kda_safe_gate or not self.no_kda_lora
+                or not self.use_qk_norm
+                or not self.moe_router_enable_expert_bias
+                or self.gated_attention_proj_granularity_type
+                != "head_wise"
+                or self.rotary_dim != self.qk_rope_head_dim
+                or self.num_experts % self.n_group):
+            raise ValueError(
+                "LingHybridConfig: only the published Ling-3.0-flash form "
+                "is implemented (sigmoid scores with a selection bias, an "
+                "untied head, one shared expert, no query latent, the "
+                "safe gate at full rank, head-wise output gates, q/k "
+                "norm, rotary over the rope dims, groups that divide the "
+                "experts)")
+
+    def layer_kind(self, i: int) -> str:
+        return "mla" if (i + 1) % self.layer_group_size == 0 else "kda"
+
+    @property
+    def layer_kinds(self) -> List[str]:
+        return [self.layer_kind(i) for i in range(self.num_hidden_layers)]
+
+    def is_dense_layer(self, i: int) -> bool:
+        return i < self.first_k_dense_replace
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def with_expert_parallel(self, n: int, rank: int) -> "LingHybridConfig":
+        """This chip as rank `rank` of `n` that share each expert layer:
+        experts [rank·E/n, (rank+1)·E/n) — with n = n_group a rank's
+        experts are one routing group."""
+        if n < 1 or self.num_experts % n or not 0 <= rank < n:
+            raise ValueError(f"expert_parallel={n} rank={rank} does not "
+                             f"divide {self.num_experts} experts")
+        per = self.num_experts // n
+        return dataclasses.replace(self, experts_held=(rank * per, per))
+
+    @classmethod
+    def from_pretrained(cls, model_dir: str) -> "LingHybridConfig":
+        raw = _load_config_json(model_dir)
+        return cls(**{f.name: raw[f.name] for f in dataclasses.fields(cls)
+                      if f.name in raw})
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 300) -> "LingHybridConfig":
+        """Tiny config for tests: one dense layer, then one whole period
+        (k k k k m k on the cut's own indices), 16 experts in 4 groups of
+        which 2 are kept, top-2; MLA at 24 + 8 rotary / v 16, so q.k and
+        v differ in width as at the published sizes."""
+        return cls(vocab_size=vocab_size, hidden_size=64,
+                   intermediate_size=128, moe_intermediate_size=32,
+                   moe_shared_expert_intermediate_size=32,
+                   num_hidden_layers=7, first_k_dense_replace=1,
+                   num_attention_heads=4, head_dim=16, kv_lora_rank=32,
+                   qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=16,
+                   rotary_dim=8, num_experts=16, num_experts_per_tok=2,
+                   n_group=4, topk_group=2, kda_chunk=16,
+                   max_position_embeddings=256)

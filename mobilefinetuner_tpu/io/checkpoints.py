@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
+                                             LingHybridConfig,
                                               Gemma3TextConfig)
 from mobilefinetuner_tpu.io.safetensors_io import (CheckpointIntegrityError,
                                                    SafeTensorsReader,
@@ -399,6 +400,131 @@ def afmoe_params_to_hf(params, first_expert: int = 0) -> Dict[str, np.ndarray]:
 
     put_layers(params["dense"], 0)
     put_layers(params["moe"], nd)
+    return p
+
+
+# ----------------------------- Ling hybrid ----------------------------------
+#
+# Key scheme of models/ling_hybrid.py's checkpoints — ASSUMED (the
+# family's modelling code is not in this installation; the names follow
+# its Ling-2.0 predecessor where a weight has one there, FLA's KDA layer
+# and DeepSeek-V2's MLA otherwise; DESIGN.md §32):
+#   model.word_embeddings.weight, lm_head.weight, model.norm.weight,
+#   model.layers.{i}.input_layernorm / post_attention_layernorm .weight,
+#   kda layers  .attention.{q,k,v,o,f,b,g}_proj.weight,
+#               .attention.{q,k,v}_conv1d.weight [C, K], .attention.A_log,
+#               .attention.dt_bias, .attention.o_norm.weight
+#   mla layers  .attention.{q_proj, kv_a_proj_with_mqa, kv_b_proj, g_proj,
+#               o_proj}.weight, .attention.kv_a_layernorm.weight,
+#               .attention.{q,k}_norm.weight
+#   dense MLP   .mlp.{gate,up,down}_proj.weight
+#   expert MLP  .mlp.gate.weight [E, H], .mlp.gate.expert_bias [E],
+#               .mlp.shared_experts.{gate,up,down}_proj.weight,
+#               .mlp.experts.{e}.{gate,up,down}_proj.weight
+# Linear weights are [out, in].
+
+_LING_LAYER = "model.layers.{}."
+_LING_KDA_LINEAR = tuple((f"{t}_w", f"{t}_proj") for t in "qkvofbg")
+_LING_KDA_PLAIN = (("A_log", "A_log"), ("dt_bias", "dt_bias"),
+                   ("o_norm", "o_norm.weight"))
+_LING_MLA_LINEAR = (("q_w", "q_proj"), ("kv_a_w", "kv_a_proj_with_mqa"),
+                    ("kv_b_w", "kv_b_proj"), ("g_w", "g_proj"),
+                    ("o_w", "o_proj"))
+_LING_MLA_PLAIN = (("kv_a_norm", "kv_a_layernorm.weight"),
+                   ("q_norm", "q_norm.weight"), ("k_norm", "k_norm.weight"))
+
+
+def load_ling_hybrid(model_dir: str,
+                     config: Optional[LingHybridConfig] = None):
+    """(config, params in models/ling_hybrid.py's layout) from a
+    directory in the key scheme above. Of each expert layer only the
+    `config.experts_held` experts are read; a BF16 tensor stays bf16, so
+    a frozen base published in bf16 is resident at 2 bytes a parameter."""
+    if config is None:
+        config = LingHybridConfig.from_pretrained(model_dir)
+    c = config
+    readers = _tensor_readers(model_dir)
+
+    def get(key, linear=False):
+        a = readers[key].load(key, keep_bf16=True)
+        return a.T if linear else a      # [out, in] -> [in, out]
+
+    def swiglu(pre):
+        return {name: get(f"{pre}{hf}.weight", True) for name, hf in _SWIGLU}
+
+    first, n = c.experts_held
+    layers = []
+    for i in range(c.num_hidden_layers):
+        pre = _LING_LAYER.format(i)
+        at = pre + "attention."
+        lp = {"input_ln": get(pre + "input_layernorm.weight"),
+              "post_attn_ln": get(pre + "post_attention_layernorm.weight")}
+        if c.layer_kind(i) == "kda":
+            mix = {name: get(f"{at}{hf}.weight", True)
+                   for name, hf in _LING_KDA_LINEAR}
+            mix.update({name: get(at + hf) for name, hf in _LING_KDA_PLAIN})
+            mix.update({f"{t}_conv": get(f"{at}{t}_conv1d.weight").T
+                        for t in "qkv"})
+            lp["kda"] = mix
+        else:
+            mix = {name: get(f"{at}{hf}.weight", True)
+                   for name, hf in _LING_MLA_LINEAR}
+            mix.update({name: get(at + hf) for name, hf in _LING_MLA_PLAIN})
+            lp["mla"] = mix
+        mlp = pre + "mlp."
+        if c.is_dense_layer(i):
+            lp["mlp"] = swiglu(mlp)
+        else:
+            lp["router_w"] = get(mlp + "gate.weight", True)
+            lp["expert_bias"] = get(mlp + "gate.expert_bias")
+            lp["shared"] = swiglu(mlp + "shared_experts.")
+            lp["experts"] = {
+                name: np.stack([get(f"{mlp}experts.{e}.{hf}.weight", True)
+                                for e in range(first, first + n)])
+                for name, hf in _SWIGLU}
+        layers.append(lp)
+    return config, {"embed": get("model.word_embeddings.weight"),
+                    "layers": layers,
+                    "final_norm": get("model.norm.weight"),
+                    "lm_head": get("lm_head.weight")}
+
+
+def ling_hybrid_params_to_hf(params,
+                             first_expert: int = 0) -> Dict[str, np.ndarray]:
+    """models/ling_hybrid.py's tree -> the key scheme above (the inverse
+    of load_ling_hybrid); the held experts under their own numbers."""
+    p = {"model.word_embeddings.weight": np.asarray(params["embed"]),
+         "lm_head.weight": np.asarray(params["lm_head"]),
+         "model.norm.weight": np.asarray(params["final_norm"])}
+    for i, lp in enumerate(params["layers"]):
+        pre = _LING_LAYER.format(i)
+        at = pre + "attention."
+        p[pre + "input_layernorm.weight"] = np.asarray(lp["input_ln"])
+        p[pre + "post_attention_layernorm.weight"] = \
+            np.asarray(lp["post_attn_ln"])
+        if "kda" in lp:
+            mix, linear, plain = lp["kda"], _LING_KDA_LINEAR, _LING_KDA_PLAIN
+            for t in "qkv":
+                p[f"{at}{t}_conv1d.weight"] = np.asarray(mix[f"{t}_conv"]).T
+        else:
+            mix, linear, plain = lp["mla"], _LING_MLA_LINEAR, _LING_MLA_PLAIN
+        for name, hf in linear:
+            p[f"{at}{hf}.weight"] = np.asarray(mix[name]).T
+        for name, hf in plain:
+            p[at + hf] = np.asarray(mix[name])
+        mlp = pre + "mlp."
+        for name, hf in _SWIGLU:
+            if "mlp" in lp:
+                p[f"{mlp}{hf}.weight"] = np.asarray(lp["mlp"][name]).T
+                continue
+            p[f"{mlp}shared_experts.{hf}.weight"] = \
+                np.asarray(lp["shared"][name]).T
+            ex = np.asarray(lp["experts"][name])
+            for e in range(ex.shape[0]):
+                p[f"{mlp}experts.{first_expert + e}.{hf}.weight"] = ex[e].T
+        if "mlp" not in lp:
+            p[mlp + "gate.weight"] = np.asarray(lp["router_w"]).T
+            p[mlp + "gate.expert_bias"] = np.asarray(lp["expert_bias"])
     return p
 
 

@@ -77,6 +77,31 @@ GEMMA_TARGETS = {
 # the router, the shared and the routed experts stay frozen.
 AFMOE_TARGETS = {t: GEMMA_TARGETS[t]
                  for t in ("q_proj", "k_proj", "v_proj", "o_proj")}
+# The Ling-3.0-flash hybrid (models/ling_hybrid.py): two kinds of mixer
+# with different weights, so a target names its kind and its stack runs
+# over the layers OF THAT KIND only (`kda_*` [number of kda layers, ...],
+# `mla_*` [number of mla layers, ...]). The projections that carry
+# attention's content; gates, conv taps, norms, router and experts stay
+# frozen.
+LING_TARGETS = {
+    "kda_q_proj": lambda c: (c.hidden_size,
+                             c.num_attention_heads * c.head_dim),
+    "kda_k_proj": lambda c: (c.hidden_size,
+                             c.num_attention_heads * c.head_dim),
+    "kda_v_proj": lambda c: (c.hidden_size,
+                             c.num_attention_heads * c.head_dim),
+    "kda_o_proj": lambda c: (c.num_attention_heads * c.head_dim,
+                             c.hidden_size),
+    "mla_q_proj": lambda c: (c.hidden_size,
+                             c.num_attention_heads * c.qk_head_dim),
+    "mla_kv_a_proj": lambda c: (c.hidden_size,
+                                c.kv_lora_rank + c.qk_rope_head_dim),
+    "mla_kv_b_proj": lambda c: (
+        c.kv_lora_rank,
+        c.num_attention_heads * (c.qk_nope_head_dim + c.v_head_dim)),
+    "mla_o_proj": lambda c: (c.num_attention_heads * c.v_head_dim,
+                             c.hidden_size),
+}
 # targets with ONE site instead of a per-layer stack: A [in, r],
 # B [r, out] (no leading L axis; maybe_lora's ndim checks skip the
 # layer_idx slice for them)
@@ -177,6 +202,22 @@ def init_lora_afmoe(config, spec: LoRASpec, key: jax.Array,
     dims = {t: AFMOE_TARGETS[t](config)
             for t in spec.targets or list(AFMOE_TARGETS)}
     return init_lora(dims, config.num_hidden_layers, spec, key, dtype)
+
+
+def init_lora_ling(config, spec: LoRASpec, key: jax.Array,
+                   dtype=jnp.float32) -> dict:
+    """One stack a target, over the layers of the target's kind (the
+    prefix of its name) in layer order."""
+    kinds = config.layer_kinds
+    tree = {}
+    for name in sorted(spec.targets or list(LING_TARGETS)):
+        n = kinds.count(name.split("_")[0])
+        if n:
+            tree.update(init_lora(
+                {name: LING_TARGETS[name](config)}, n, spec,
+                jax.random.fold_in(key, sorted(LING_TARGETS).index(name)),
+                dtype)["blocks"])
+    return {"blocks": tree}
 
 
 def stack_adapters(loras) -> dict:

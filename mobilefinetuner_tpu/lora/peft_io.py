@@ -43,8 +43,20 @@ GEMMA_PEFT_MODULES.update({
 # AFMoE (HF `afmoe`): the same attention module names as Gemma's
 AFMOE_PEFT_MODULES = {t: GEMMA_PEFT_MODULES[t] for t in
                       ("q_proj", "k_proj", "v_proj", "o_proj")}
+# The Ling hybrid (io/checkpoints.py's key scheme): a target's stack runs
+# over the layers of its kind, so export/import take `layer_ids`
+# {target: [layer index of each stack position]}
+LING_PEFT_MODULES = {
+    **{f"kda_{t}": "model.layers.{}.attention." + t
+       for t in ("q_proj", "k_proj", "v_proj", "o_proj")},
+    "mla_q_proj": "model.layers.{}.attention.q_proj",
+    "mla_kv_a_proj": "model.layers.{}.attention.kv_a_proj_with_mqa",
+    "mla_kv_b_proj": "model.layers.{}.attention.kv_b_proj",
+    "mla_o_proj": "model.layers.{}.attention.o_proj",
+}
 PEFT_MODULES = {"gpt2": GPT2_PEFT_MODULES, "gemma": GEMMA_PEFT_MODULES,
-                "afmoe": AFMOE_PEFT_MODULES}
+                "afmoe": AFMOE_PEFT_MODULES,
+                "ling_hybrid": LING_PEFT_MODULES}
 # For adapter_config.json target_modules. PEFT suffix-matches these against
 # full module paths, so they must be path-qualified: a bare "c_proj" would
 # match BOTH attn.c_proj and mlp.c_proj and make PEFT instantiate phantom
@@ -56,8 +68,11 @@ PEFT_TARGET_MODULES = {
 # adapter_config.json per family: (fan_in_fan_out — GPT-2's Conv1D stores
 # [in, out] — and the target_modules naming; None = the target names are
 # already HF's module names)
+LING_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
+                       for t, m in LING_PEFT_MODULES.items()}
 PEFT_CONFIG = {"gpt2": (True, PEFT_TARGET_MODULES), "gemma": (False, None),
-               "afmoe": (False, None)}
+               "afmoe": (False, None),
+               "ling_hybrid": (False, LING_TARGET_MODULES)}
 
 
 # ----------------------------- native format --------------------------------
@@ -99,11 +114,13 @@ def load_adapter(path: str) -> Tuple[dict, LoRASpec]:
 # ----------------------------- PEFT export ----------------------------------
 
 def export_peft(out_dir: str, lora_tree, spec: LoRASpec, family: str,
-                base_model_name: str = ""):
+                base_model_name: str = "", layer_ids=None):
     """Write adapter_model.safetensors + adapter_config.json loadable by HF
     PEFT. A/B are stored in torch nn.Linear layout: lora_A.weight [r, in],
     lora_B.weight [out, r] (our stacked layout is A [L, in, r], B [L, r, out]
-    → transpose per layer)."""
+    → transpose per layer). layer_ids: {target: the model's layer index
+    of each stack position} where a stack does not run over all layers
+    (default: position i is layer i)."""
     modules = PEFT_MODULES[family]
     unsupported = sorted(set(lora_tree["blocks"]) - set(modules))
     if unsupported:
@@ -119,8 +136,9 @@ def export_peft(out_dir: str, lora_tree, spec: LoRASpec, family: str,
         A = np.asarray(entry["A"], dtype=np.float32)
         B = np.asarray(entry["B"], dtype=np.float32)
         L = A.shape[0]
+        ids = (layer_ids or {}).get(name, range(L))
         for i in range(L):
-            mod = "base_model.model." + modules[name].format(i)
+            mod = "base_model.model." + modules[name].format(ids[i])
             tensors[mod + ".lora_A.weight"] = A[i].T.copy()
             tensors[mod + ".lora_B.weight"] = B[i].T.copy()
     save_safetensors(os.path.join(out_dir, "adapter_model.safetensors"),
@@ -144,8 +162,10 @@ def export_peft(out_dir: str, lora_tree, spec: LoRASpec, family: str,
             json.dump(cfg, f, indent=2)
 
 
-def import_peft(adapter_dir: str, family: str) -> Tuple[dict, LoRASpec]:
-    """Load an HF-PEFT adapter dir into our stacked lora_tree."""
+def import_peft(adapter_dir: str, family: str,
+                layer_ids=None) -> Tuple[dict, LoRASpec]:
+    """Load an HF-PEFT adapter dir into our stacked lora_tree (layer_ids
+    as in export_peft: which layers a target's stack holds)."""
     with open(os.path.join(adapter_dir, "adapter_config.json")) as f:
         cfg = json.load(f)
     spec = LoRASpec(rank=cfg["r"], alpha=cfg["lora_alpha"],
@@ -153,12 +173,19 @@ def import_peft(adapter_dir: str, family: str) -> Tuple[dict, LoRASpec]:
     path = os.path.join(adapter_dir, "adapter_model.safetensors")
     raw = SafeTensorsReader(path).load_all(promote_to_f32=True)
     modules = PEFT_MODULES[family]
+    if layer_ids is None and len(set(modules.values())) < len(modules):
+        raise ValueError(
+            f"family {family!r} names two targets by one module path (its "
+            f"layers are of several kinds): pass layer_ids, which layers "
+            f"each target's stack holds")
     blocks: dict = {}
     for name, fmt in modules.items():
         per_layer_A, per_layer_B = [], []
+        ids = (layer_ids or {}).get(name)
         i = 0
-        while True:
-            mod = "base_model.model." + fmt.format(i)
+        while ids is None or i < len(ids):
+            mod = "base_model.model." + fmt.format(i if ids is None
+                                                   else ids[i])
             ka, kb = mod + ".lora_A.weight", mod + ".lora_B.weight"
             if ka not in raw:
                 break

@@ -193,9 +193,9 @@ def _block(c: AfmoeConfig, bp, x, is_full, layer, padding_mask, rope,
         # and residual with the combine
         with jax.named_scope("moe_router"):
             mt = rms_norm(x, bp["pre_mlp_ln"], eps).reshape(B * S, H)
-            idx, w = moe.route(mt, bp["router_w"], bp["expert_bias"],
-                               c.num_experts_per_tok, c.route_norm,
-                               c.route_scale)
+            idx, w, _ = moe.route(mt, bp["router_w"], bp["expert_bias"],
+                                  c.num_experts_per_tok, c.route_norm,
+                                  c.route_scale)
         with jax.named_scope("moe_experts"):
             experts = jax.tree.map(lambda t: t.astype(dt), bp["experts"])
         f, counters = moe.expert_layer(mt, idx, w, experts, c.experts_held,

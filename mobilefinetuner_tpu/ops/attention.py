@@ -55,7 +55,8 @@ def dot_product_attention(
         attn_dropout_rng: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Scaled dot-product attention with GQA.
 
-    q: [B, Hq, S, D]; k, v: [B, Hkv, S, D] with Hq % Hkv == 0 — GQA is
+    q: [B, Hq, S, D]; k: [B, Hkv, S, D], v: [B, Hkv, S, Dv] (Dv = D but
+    for latent attention) with Hq % Hkv == 0 — GQA is
     expressed by reshaping q into [B, Hkv, G, S, D] groups rather than
     materializing repeated K/V heads (the reference materializes via
     `repeat_kv_heads`, core/ops.cpp:2072; on TPU the einsum broadcast keeps
@@ -96,7 +97,7 @@ def dot_product_attention(
     probs = inverted_dropout(probs, attn_dropout, attn_dropout_rng)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32).astype(v.dtype)
-    return out.reshape(B, Hq, S, D)
+    return out.reshape(B, Hq, S, v.shape[-1])
 
 
 def resolve_impl(S: int, D: int) -> str:
@@ -156,6 +157,24 @@ def _flash_over_batch(mesh, axes, q, k, v, *, padding_mask=None,
                          out_specs=heads, check_vma=False)(*operands)
 
 
+def _pad_qk(q, k, kwargs, head_sizes):
+    """q and k at a width between the kernels' head sizes (latent
+    attention's 128 + 64 rotary = 192) are zero-padded to the next one:
+    exact — a zero column adds nothing to q·k — with the scale fixed at
+    the TRUE width first. v keeps its own width (the kernels take two).
+    What it costs: the extra columns' HBM bytes on q, k, dq, dk; no MXU
+    time where the true width already fills the same 128-lane tiles
+    (192 -> 256)."""
+    D = q.shape[3]
+    wider = [d for d in head_sizes if d > D]
+    if D in head_sizes or not wider:
+        return q, k, kwargs
+    if kwargs.get("scale") is None:
+        kwargs = dict(kwargs, scale=1.0 / (D ** 0.5))
+    pad = [(0, 0)] * 3 + [(0, min(wider) - D)]
+    return jnp.pad(q, pad), jnp.pad(k, pad), kwargs
+
+
 def attention(q, k, v, *, impl: str = "auto", mesh=None, **kwargs):
     """Dispatch between the XLA reference and the Pallas flash kernel.
 
@@ -188,6 +207,7 @@ def attention(q, k, v, *, impl: str = "auto", mesh=None, **kwargs):
             raise NotImplementedError(
                 "attention_impl='flash' requires the Pallas kernel "
                 "(ops/flash_attention.py); use attention_impl='xla'") from e
+        q, k, kwargs = _pad_qk(q, k, kwargs, flash_attention.HEAD_SIZES)
         if mesh is None or mesh.size == 1:
             return flash_attention.flash_attention(q, k, v, **kwargs)
         # the batch axes as parallel/mesh.batch_sharding states them
@@ -195,7 +215,8 @@ def attention(q, k, v, *, impl: str = "auto", mesh=None, **kwargs):
         axes = tuple(batch_sharding(mesh).spec[0])
         n = math.prod(mesh.shape[a] for a in axes)
         if (q.shape[0] % n == 0 and kwargs.get("attn_mask") is None
-                and flash_attention.kernel_eligible(*q.shape[2:])):
+                and flash_attention.kernel_eligible(*q.shape[2:],
+                                                    v.shape[3])):
             return _flash_over_batch(mesh, axes, q, k, v, **kwargs)
     # backward-impl selection is a flash-kernel knob; the XLA path has
     # one backward (jax autodiff)

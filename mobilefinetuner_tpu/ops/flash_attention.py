@@ -165,7 +165,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, pad_ref, seed_ref, o_ref, lse_ref, *,
     qi = pl.program_id(2)
     row0 = qi * block_q
     q = q_ref[0, 0].astype(jnp.float32)           # [BQ, D]
-    D = q.shape[-1]
+    D = v_ref.shape[-1]                           # the output's width
 
     def step(col0, k, v, pad, carry):
         m, l, acc = carry
@@ -221,7 +221,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, pad_ref, seed_ref, o_ref, lse_ref, *,
 def _fwd(q, k, v, padding_mask, seed, *, scale, causal, window, block_q,
          block_k, p_drop=0.0):
     B, Hq, S, D = q.shape
-    Hkv = k.shape[1]
+    Hkv, Dv = k.shape[1], v.shape[3]
     G = Hq // Hkv
     grid = (B, Hq, S // block_q)
     pad3 = padding_mask.reshape(B, 1, S)
@@ -237,24 +237,26 @@ def _fwd(q, k, v, padding_mask, seed, *, scale, causal, window, block_q,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // G, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // G, 0, 0),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h // G, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
+            pl.BlockSpec((1, 1, block_q, Dv),
                          lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, S, 1), jnp.float32),
         ],
-        **tpu_call_params("parallel", "parallel", "parallel"),
+        **tpu_call_params("parallel", "parallel", "parallel",
+                          vmem_limit_bytes=_kv_vmem_limit(
+                              S, D, Dv, q.dtype.itemsize)),
     )
     # semantic trace annotation: the kernel shows up as attention/flash_fwd
     # in profiler traces and HLO metadata (DESIGN.md §13)
@@ -391,8 +393,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, pad_ref, seed_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    z = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qlo, qhi, body, (z, z))
+    dk, dv = jax.lax.fori_loop(
+        qlo, qhi, body, (jnp.zeros((block_k, D), jnp.float32),
+                         jnp.zeros(v.shape, jnp.float32)))
 
     if G == 1:
         dk_ref[0, 0] = dk
@@ -466,8 +469,9 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, pad_ref, seed_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         return dk, dv
 
-    z = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(qlo, qhi, body, (z, z))
+    dk, dv = jax.lax.fori_loop(
+        qlo, qhi, body, (jnp.zeros((block_k, D), jnp.float32),
+                         jnp.zeros(v.shape, jnp.float32)))
     dk_ref[0, 0] = dk
     dv_ref[0, 0] = dv
 
@@ -483,21 +487,25 @@ _LONG_S = 4096
 _BWD_VMEM_LONG = 48 * 2 ** 20
 
 
-def _bwd_laid_out(S: int, D: int, block_k: int, itemsize: int) -> int:
+def _bwd_laid_out(S: int, D: int, block_k: int, itemsize: int,
+                  Dv: Optional[int] = None) -> int:
     """Bytes the merged backward's operands take in VMEM as the compiler
     lays them out: widths padded to 128 lanes, an [S, 1] float32 column
     (lse, delta) one 128-lane row per query, and every pipelined operand
     twice for its two buffers. The split pair's dK/dV kernel holds the
-    same whole-S q, dO, lse and delta, less the dQ slabs."""
+    same whole-S q, dO, lse and delta, less the dQ slabs. D is q's and
+    k's width, Dv v's and dO's (default: D)."""
     W = -(-D // 128) * 128
-    return (2 * 2 * S * W * itemsize      # q + dO slabs
+    Wv = W if Dv is None else -(-Dv // 128) * 128
+    return (2 * S * (W + Wv) * itemsize   # q + dO slabs
             + S * W * 4                   # dq f32 scratch accumulator
             + 2 * S * W * itemsize        # dq output block
             + 2 * 2 * S * 128 * 4         # lse + delta columns
-            + 2 * 2 * block_k * W * (itemsize + 4))   # K/V in, dK/dV out
+            + 2 * block_k * (W + Wv) * (itemsize + 4))  # K/V in, dK/dV out
 
 
-def _bwd_vmem_limit(S: int, D: int, block_k: int, itemsize: int):
+def _bwd_vmem_limit(S: int, D: int, block_k: int, itemsize: int,
+                    Dv: Optional[int] = None):
     """vmem_limit_bytes of a backward kernel that holds whole-S operands:
     None below LONG_S (the default every shape there has compiled and run
     with), else the operands as laid out plus 12 MiB for the [BQ, BK]
@@ -505,11 +513,24 @@ def _bwd_vmem_limit(S: int, D: int, block_k: int, itemsize: int):
     at 96 of the chip's 128 MiB."""
     if S < _LONG_S:
         return None
-    return min(_bwd_laid_out(S, D, block_k, itemsize) + 12 * 2 ** 20,
+    return min(_bwd_laid_out(S, D, block_k, itemsize, Dv) + 12 * 2 ** 20,
                96 * 2 ** 20)
 
 
-def merged_bwd_fits(S: int, D: int, block_k: int, itemsize: int) -> bool:
+def _kv_vmem_limit(S: int, D: int, Dv: int, itemsize: int):
+    """vmem_limit_bytes of a kernel that holds a (batch, kv-head)'s
+    WHOLE-S K and V (the forward and the split pair's dQ kernel): None
+    while their two buffers each stay under 12 MiB as laid out — every
+    shape up to S = 8192 at D = 128 in bf16, which compiled and ran with
+    the default — else that much plus 12 MiB for the tile temporaries
+    (S = 16,384 at 256/128 lanes: 24 MiB of K and V)."""
+    lanes = (-(-D // 128) + -(-Dv // 128)) * 128
+    kv = 2 * S * lanes * itemsize
+    return None if kv <= 12 * 2 ** 20 else kv + 12 * 2 ** 20
+
+
+def merged_bwd_fits(S: int, D: int, block_k: int, itemsize: int,
+                    Dv: Optional[int] = None) -> bool:
     """VMEM accounting for one merged-backward program: whole-S q/dO
     slabs + the f32 dQ accumulator + the dQ output block + lse/Δ rows
     resident for the whole (b, h) sweep, plus double-buffered K/V input
@@ -526,19 +547,22 @@ def merged_bwd_fits(S: int, D: int, block_k: int, itemsize: int) -> bool:
     (`_bwd_laid_out`), the backward kernels ask for that much VMEM plus
     their tile temporaries (`_bwd_vmem_limit`), and the merged kernel is
     taken while the count stays under 48 MiB — 33.5 MiB at S = 8192,
-    D = 128 in bf16."""
+    D = 128 in bf16; 88 MiB at S = 16,384 with q/k at 256 lanes and v
+    at 128 (latent attention, PR 31), which takes the split pair."""
+    Dv = D if Dv is None else Dv
     if S >= _LONG_S:
-        return _bwd_laid_out(S, D, block_k, itemsize) <= _BWD_VMEM_LONG
-    need = (2 * S * D * itemsize          # q + dO slabs
+        return _bwd_laid_out(S, D, block_k, itemsize, Dv) <= _BWD_VMEM_LONG
+    need = (S * (D + Dv) * itemsize       # q + dO slabs
             + S * D * 4                   # dq f32 scratch accumulator
             + S * D * itemsize            # dq output block
             + 2 * S * 4                   # lse + delta rows
-            + 2 * 2 * block_k * D * itemsize   # K/V blocks, double-buffered
-            + 2 * 2 * block_k * D * 4)    # dk/dv out blocks, double-buffered
+            + 2 * block_k * (D + Dv) * itemsize   # K/V blocks, 2 buffers
+            + 2 * block_k * (D + Dv) * 4)  # dk/dv out blocks, 2 buffers
     return need <= _BWD_VMEM_BUDGET
 
 
-def resolve_bwd_impl(S: int, D: int, block_k: int, itemsize: int) -> str:
+def resolve_bwd_impl(S: int, D: int, block_k: int, itemsize: int,
+                     Dv: Optional[int] = None) -> str:
     """The backward 'auto' rule, mirroring ops/attention.resolve_impl:
     the merged one-pass kernel whenever its VMEM accounting fits — it
     does for every bf16 training shape the forward dispatches today
@@ -546,13 +570,14 @@ def resolve_bwd_impl(S: int, D: int, block_k: int, itemsize: int) -> str:
     Gemma-shaped S=2048 D=256 slabs exceed the budget and take the split
     FlashAttention-2 pair. Kept as ONE function so the vjp and the tests
     force paths through the same gate."""
-    return "merged" if merged_bwd_fits(S, D, block_k, itemsize) else "split"
+    return "merged" if merged_bwd_fits(S, D, block_k, itemsize, Dv) \
+        else "split"
 
 
 def _bwd_merged(scale, causal, window, block_q, block_k, p_drop, q, k, v,
                 pad3, seed, lse, delta, do):
     B, Hq, S, D = q.shape
-    Hkv = k.shape[1]
+    Hkv, Dv = k.shape[1], v.shape[3]
     G = Hq // Hkv
     kernel = functools.partial(
         _dkvq_kernel, scale=scale, block_q=block_q, block_k=block_k,
@@ -566,7 +591,7 @@ def _bwd_merged(scale, causal, window, block_q, block_k, p_drop, q, k, v,
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, i: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, h, i: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_k), lambda b, h, i: (b, 0, i),
@@ -576,7 +601,7 @@ def _bwd_merged(scale, causal, window, block_q, block_k, p_drop, q, k, v,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S, 1), lambda b, h, i: (b, h, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -584,24 +609,24 @@ def _bwd_merged(scale, causal, window, block_q, block_k, p_drop, q, k, v,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i: (b, h, i, 0),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, S, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, S, Dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
         **tpu_call_params("parallel", "parallel", "arbitrary",
                           vmem_limit_bytes=_bwd_vmem_limit(
-                              S, D, block_k, q.dtype.itemsize)),
+                              S, D, block_k, q.dtype.itemsize, Dv)),
     )
     with jax.named_scope("attention"), jax.named_scope("flash_bwd_merged"):
         dq, dk_p, dv_p = call(q, k, v, pad3, seed, lse, delta, do)
     if G > 1:
         dk = dk_p.reshape(B, Hkv, G, S, D).sum(axis=2)
-        dv = dv_p.reshape(B, Hkv, G, S, D).sum(axis=2)
+        dv = dv_p.reshape(B, Hkv, G, S, Dv).sum(axis=2)
     else:
         dk, dv = dk_p, dv_p
     return dq, dk.astype(k.dtype), dv.astype(v.dtype), None, None
@@ -612,7 +637,7 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
     q, k, v, padding_mask, seed, out, lse = res
     do = g
     B, Hq, S, D = q.shape
-    Hkv = k.shape[1]
+    Hkv, Dv = k.shape[1], v.shape[3]
     G = Hq // Hkv
     pad3 = padding_mask.reshape(B, 1, S)
     # Δ = rowsum(dO ∘ O): one fused XLA pass, shared by every kernel.
@@ -627,7 +652,7 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
         delta = delta - dlse.astype(jnp.float32)
 
     if bwd_impl == "auto":
-        bwd_impl = resolve_bwd_impl(S, D, block_k, q.dtype.itemsize)
+        bwd_impl = resolve_bwd_impl(S, D, block_k, q.dtype.itemsize, Dv)
     if bwd_impl == "merged":
         return _bwd_merged(scale, causal, window, block_q, block_k,
                            p_drop, q, k, v, pad3, seed, lse, delta, do)
@@ -645,7 +670,7 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // G, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h // G, 0, 0),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, h, i: (b, h // G, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S), lambda b, h, i: (b, 0, 0),
                          memory_space=pltpu.VMEM),
@@ -654,7 +679,7 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_q, D),
+            pl.BlockSpec((1, 1, block_q, Dv),
                          lambda b, h, i: (b, h, i, 0),
                          memory_space=pltpu.VMEM),
         ],
@@ -662,7 +687,9 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
                                lambda b, h, i: (b, h, i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((B, Hq, S, D), q.dtype),
-        **tpu_call_params("parallel", "parallel", "parallel"),
+        **tpu_call_params("parallel", "parallel", "parallel",
+                          vmem_limit_bytes=_kv_vmem_limit(
+                              S, D, Dv, q.dtype.itemsize)),
     )
     with jax.named_scope("attention"), jax.named_scope("flash_bwd_dq"):
         dq = dq_call(q, k, v, pad3, seed, lse, delta, do)
@@ -681,7 +708,7 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, i, h: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, i, h: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_k), lambda b, i, h: (b, 0, i),
@@ -691,25 +718,25 @@ def _bwd(scale, causal, window, block_q, block_k, res, g, dlse=None,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, S, 1), lambda b, i, h: (b, h, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, S, D), lambda b, i, h: (b, h, 0, 0),
+            pl.BlockSpec((1, 1, S, Dv), lambda b, i, h: (b, h, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, i, h: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D),
+            pl.BlockSpec((1, 1, block_k, Dv),
                          lambda b, i, h: (b, h // G, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, S, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, S, Dv), jnp.float32),
         ],
         **tpu_call_params("parallel", "parallel",
                           "parallel" if G == 1 else "arbitrary",
                           vmem_limit_bytes=_bwd_vmem_limit(
-                              S, D, block_k, q.dtype.itemsize)),
+                              S, D, block_k, q.dtype.itemsize, Dv)),
     )
     with jax.named_scope("attention"), jax.named_scope("flash_bwd_dkv"):
         dk, dv = dkv_call(q, k, v, pad3, seed, lse, delta, do)
@@ -773,13 +800,18 @@ def _flash_lse_bwd(scale, causal, window, block_q, block_k, bwd_impl, res,
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def kernel_eligible(S: int, D: int) -> bool:
+HEAD_SIZES = (64, 128, 256)
+
+
+def kernel_eligible(S: int, D: int, Dv: Optional[int] = None) -> bool:
     """Do the kernels (at their default blocks) serve a [.., S, D]
-    operand, or would flash_attention / flash_attention_partial fall
-    back? The dispatchers that wrap them in shard_map ask this OUTSIDE
-    it, where the decision must be static: the ring on its shard's S,
-    ops/attention.attention on the whole S (it shards the batch only)."""
-    return D in (64, 128, 256) and _valid_blocks(S, 512, 512) is not None
+    operand (v at [.., S, Dv], default D), or would flash_attention /
+    flash_attention_partial fall back? The dispatchers that wrap them in
+    shard_map ask this OUTSIDE it, where the decision must be static:
+    the ring on its shard's S, ops/attention.attention on the whole S
+    (it shards the batch only)."""
+    return D in HEAD_SIZES and (Dv is None or Dv in HEAD_SIZES) \
+        and _valid_blocks(S, 512, 512) is not None
 
 
 def flash_attention_partial(q, k, v, padding_mask=None, *,
@@ -803,7 +835,8 @@ def flash_attention_partial(q, k, v, padding_mask=None, *,
         raise ValueError(f"bwd_impl must be 'auto', 'merged' or 'split', "
                          f"got {bwd_impl!r}")
     B, Hq, S, D = q.shape
-    if D not in (64, 128, 256) or k.shape[2] != S:
+    if D not in HEAD_SIZES or v.shape[3] not in HEAD_SIZES \
+            or k.shape[2] != S:
         return None
     picked = _valid_blocks(S, block_q, block_k)
     if (pallas_util.interpret_mode() and S % block_q == 0
@@ -885,7 +918,7 @@ def flash_attention(q, k, v, *,
     if picked is not None:
         block_q, block_k = picked
     if (attn_mask is not None or picked is None
-            or D not in (64, 128, 256)):
+            or D not in HEAD_SIZES or v.shape[3] not in HEAD_SIZES):
         return dot_product_attention(
             q, k, v, scale=scale, is_causal=is_causal,
             sliding_window=sliding_window, padding_mask=padding_mask,

@@ -42,33 +42,73 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm as _megablox_gmm
 
 from mobilefinetuner_tpu.ops import pallas_util
 
-# (tm, tk, tn) of the grouped product, measured on a v5e at the published
-# widths (K = N = 3072, ~256 rows an expert): 3.04 ms for (128, 3072,
-# 1024) against 3.40 for (256, 1024, 1024) and 3.82 for (512, 1024,
-# 1024) — small row tiles waste fewer rows on a group's ragged ends.
-# Clamped to the problem where it is smaller (the CPU tests' sizes).
-GMM_TILING = (128, 3072, 1024)
+# Rows a tile of the grouped product: small row tiles waste fewer rows on
+# a group's ragged ends (measured below); also what the sorted buffer is
+# padded to.
+GMM_TM = 128
 
 
-def route(m, w_r, bias, k: int, norm: bool, scale: float):
-    """m [T, H] -> (idx [T, k] int32, w [T, k] float32).
+def gmm_tiling(K: int, N: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of the grouped product rows x [K, N], from the
+    product's shape: the whole contraction in one tile while K <= 3072
+    (no accumulation over K steps, a [128, K] row tile is at most 768 KB
+    in bf16), else its largest 128-multiple divisor under that; the
+    widest column tile of 1024, 768, 512, 384, 256, 128 that divides N
+    (N itself where none does: the CPU tests' sizes). Measured on a v5e:
+    K = N = 3072, ~256 rows an expert over 32 (PR 27): 3.04 ms for
+    (128, 3072, 1024) against 3.40 for (256, 1024, 1024) and 3.82 for
+    (512, 1024, 1024); K 2560 / N 768, 256 rows an expert over 64
+    (PR 31): see PERF.md section 6 for the readings of (128, 2560, 768)
+    against (128, 2560, 384), (256, 2560, 768) and (128, 1280, 768)."""
+    tk = K if K <= 3072 else max(d for d in range(128, 3073, 128)
+                                 if K % d == 0)
+    tn = next((t for t in (1024, 768, 512, 384, 256, 128) if N % t == 0), N)
+    return GMM_TM, tk, tn
+
+
+def kept_groups(biased, n_group: int, topk_group: int):
+    """[T, E] biased scores -> [T, n_group] bool: the `topk_group` groups
+    of E / n_group consecutive experts whose two largest biased scores
+    sum highest (DeepSeek-V3's group-limited selection)."""
+    T, E = biased.shape
+    top2, _ = jax.lax.top_k(biased.reshape(T, n_group, E // n_group), 2)
+    _, gi = jax.lax.top_k(top2.sum(-1), topk_group)
+    return jnp.zeros((T, n_group), bool).at[
+        jnp.arange(T)[:, None], gi].set(True)
+
+
+def route(m, w_r, bias, k: int, norm: bool, scale: float,
+          n_group: int = 1, topk_group: int = 1):
+    """m [T, H] -> (idx [T, k] int32, w [T, k] float32, kept).
 
     Sigmoid scores over all E experts in float32 whatever m's dtype is
     (full-precision products: a bf16 pass over the 3072-term sum would
     flip near-ties that the activations' own rounding does not); `bias`
     (the frozen load-balancing buffer) enters the SELECTION only; the
     weights are the selected scores, normalised to sum 1 (`norm`) and
-    multiplied by `scale`. Differentiable to m through the weights."""
+    multiplied by `scale`. Differentiable to m through the weights.
+
+    n_group > 1: the top-k is taken among the experts of the
+    `topk_group` kept groups only (`kept_groups`), and `kept` is their
+    [T, n_group] mask (a counter's input); with one group idx and w are
+    what they were and `kept` is None."""
     with jax.named_scope("moe_router"):
         logits = jnp.dot(m.astype(jnp.float32), w_r.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
         s = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        biased = s + bias.astype(jnp.float32)
+        kept = None
+        if n_group > 1:
+            kept = kept_groups(biased, n_group, topk_group)
+            biased = jnp.where(
+                jnp.repeat(kept, s.shape[1] // n_group, axis=1), biased,
+                -jnp.inf)
+        _, idx = jax.lax.top_k(biased, k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if norm:
             w = w / (w.sum(-1, keepdims=True) + 1e-20)
-        return idx.astype(jnp.int32), w * scale
+        return idx.astype(jnp.int32), w * scale, kept
 
 
 # The sorted buffer and the tokens: two gathers that are each other's
@@ -141,10 +181,9 @@ def _gmm(x, w, sizes, layer=None):
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((L * n + 1,), sizes.dtype).at[-1].set(sizes[-1]),
             sizes[:-1], (layer * n,))
-    tm, tk, tn = GMM_TILING
-    tiling = (tm, min(tk, x.shape[1]), min(tn, w.shape[2]))
-    return _megablox_gmm(x, w, sizes, x.dtype, tiling, None, None, False,
-                         pallas_util.interpret_mode())
+    return _megablox_gmm(x, w, sizes, x.dtype,
+                         gmm_tiling(x.shape[1], w.shape[2]), None, None,
+                         False, pallas_util.interpret_mode())
 
 
 def expert_layer(m, idx, w, experts, held: Tuple[int, int], layer=None):
@@ -162,7 +201,7 @@ def expert_layer(m, idx, w, experts, held: Tuple[int, int], layer=None):
     T, H = m.shape
     k = idx.shape[1]
     first, n = held
-    tm = GMM_TILING[0]
+    tm = GMM_TM
     rows = T * k
     padded = -(-rows // tm) * tm
     with jax.named_scope("moe_dispatch"):

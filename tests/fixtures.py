@@ -14,11 +14,13 @@ import jax
 import numpy as np
 
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
-                                              Gemma3TextConfig)
+                                              Gemma3TextConfig,
+                                              LingHybridConfig)
 from mobilefinetuner_tpu.io.checkpoints import (afmoe_params_to_hf,
-                                                gpt2_params_to_hf)
+                                                gpt2_params_to_hf,
+                                                ling_hybrid_params_to_hf)
 from mobilefinetuner_tpu.io.safetensors_io import save_safetensors
-from mobilefinetuner_tpu.models import afmoe, gemma3, gpt2
+from mobilefinetuner_tpu.models import afmoe, gemma3, gpt2, ling_hybrid
 
 WIKI_LINES = [
     " = Heading = ",
@@ -157,6 +159,26 @@ def write_tiny_afmoe_dir(d: str, seed: int = 0, dtype=None):
                                dtype or jnp.float32)
     save_safetensors(os.path.join(d, "model.safetensors"),
                      afmoe_params_to_hf(jax.tree.map(np.asarray, params)),
+                     metadata={"format": "pt"})
+    return config, params
+
+
+def write_tiny_ling_dir(d: str, seed: int = 0, dtype=None):
+    """As write_tiny_afmoe_dir, for the Ling hybrid family
+    (io/checkpoints.load_ling_hybrid's key scheme)."""
+    import dataclasses
+    import jax.numpy as jnp
+    os.makedirs(d, exist_ok=True)
+    config = LingHybridConfig.tiny(vocab_size=train_tiny_gpt2_tokenizer(d))
+    with open(os.path.join(d, "config.json"), "w") as f:
+        raw = dataclasses.asdict(config)
+        del raw["experts_held"], raw["attention_impl"]
+        json.dump({"model_type": "ling_hybrid", **raw}, f)
+    params = ling_hybrid.init_params(config, jax.random.PRNGKey(seed),
+                                     dtype or jnp.float32)
+    save_safetensors(os.path.join(d, "model.safetensors"),
+                     ling_hybrid_params_to_hf(jax.tree.map(np.asarray,
+                                                           params)),
                      metadata={"format": "pt"})
     return config, params
 

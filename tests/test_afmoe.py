@@ -158,8 +158,8 @@ def test_the_ranks_parts_and_one_shared_expert_add_up_to_the_uncut_layer():
     c, params, _, _ = make(seed=5)
     m = jax.random.normal(jax.random.PRNGKey(9), (B * S, c.hidden_size))
     lp = jax.tree.map(lambda t: t[1], params["moe"])
-    idx, w = moe.route(m, lp["router_w"], lp["expert_bias"],
-                       c.num_experts_per_tok, c.route_norm, c.route_scale)
+    idx, w, _ = moe.route(m, lp["router_w"], lp["expert_bias"],
+                          c.num_experts_per_tok, c.route_norm, c.route_scale)
     total = afmoe._swiglu(m, lp["shared"], jnp.float32)
     here = 0.0
     for rank in range(4):
@@ -203,23 +203,23 @@ def router_inputs(seed=0, T=64, H=64, E=8):
 
 def test_the_bias_moves_the_selection_and_not_the_weights():
     m, w_r, bias = router_inputs()
-    i0, w0 = moe.route(m, w_r, jnp.zeros_like(bias), 2, False, 1.0)
-    i1, w1 = moe.route(m, w_r, bias, 2, False, 1.0)
+    i0, w0, _ = moe.route(m, w_r, jnp.zeros_like(bias), 2, False, 1.0)
+    i1, w1, _ = moe.route(m, w_r, bias, 2, False, 1.0)
     assert (np.sort(i0, -1) != np.sort(i1, -1)).any()
     s = jax.nn.sigmoid(m @ w_r)
     np.testing.assert_allclose(w1, jnp.take_along_axis(s, i1, -1),
                                atol=1e-6)
     # a bias on one expert so large that every token selects it
     big = jnp.zeros_like(bias).at[5].set(10.0)
-    i2, w2 = moe.route(m, w_r, big, 2, False, 1.0)
+    i2, w2, _ = moe.route(m, w_r, big, 2, False, 1.0)
     assert (i2 == 5).any(-1).all() and float(w2.max()) <= 1.0
 
 
 def test_the_weights_sum_to_route_scale():
     m, w_r, bias = router_inputs(1)
-    _, w = moe.route(m, w_r, bias, 2, True, 2.448)
+    _, w, _ = moe.route(m, w_r, bias, 2, True, 2.448)
     np.testing.assert_allclose(w.sum(-1), 2.448, rtol=1e-5)
-    _, raw = moe.route(m, w_r, bias, 2, False, 1.0)
+    _, raw, _ = moe.route(m, w_r, bias, 2, False, 1.0)
     assert float(jnp.abs(raw.sum(-1) - 1.0).max()) > 1e-2
 
 
@@ -297,7 +297,7 @@ def test_a_wrong_router_fails_the_comparison(monkeypatch, fault):
             sel if fault == "bias_in_the_weights" else s, idx, -1)
         if norm and fault != "no_normalisation":
             w = w / (w.sum(-1, keepdims=True) + 1e-20)
-        return idx.astype(jnp.int32), w * scale
+        return idx.astype(jnp.int32), w * scale, None
 
     monkeypatch.setattr(moe, "route", wrong)
     got = afmoe.forward(c, params, ids, lora=lora)

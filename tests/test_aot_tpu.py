@@ -235,6 +235,90 @@ def test_the_expert_cell_s_train_step_compiles_and_fits(topo, as_tpu):
     assert compiled_peak_bytes(c) <= 0.85 * size_afmoe.BYTES_LIMIT
 
 
+# the Ling hybrid's cell (ling_lora_long): latent attention at S = 16,384,
+# q and k zero-padded 192 -> 256, v at 128: whole-S K and V are 24 MiB
+# of VMEM (the forward and dQ kernels ask for it), the merged backward's
+# operands 88 MiB as laid out, so `auto` takes the split pair
+LING_FLASH = (1, 32, 16384, 192, 128)
+
+
+def test_flash_compiles_at_two_head_sizes_at_the_latent_cell_s_length(
+        topo, as_tpu):
+    from mobilefinetuner_tpu.ops.attention import attention, resolve_impl
+    from mobilefinetuner_tpu.ops.flash_attention import resolve_bwd_impl
+    B, n, S, dq, dv = LING_FLASH
+    qk, v = sds(topo, (B, n, S, dq), BF16), sds(topo, (B, n, S, dv), BF16)
+    assert resolve_impl(S, dq) == "flash"
+    assert resolve_bwd_impl(S, 256, 512, 2, dv) == "split"
+
+    def loss(q, k, v):
+        return attention(q, k, v, impl="auto", is_causal=True,
+                         scale=dq ** -0.5).astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert assert_kernel(c, 3) == 3         # forward, dQ, dK/dV
+
+
+def test_the_chunked_delta_rule_compiles_at_the_cell_s_shapes(topo,
+                                                              as_tpu):
+    """ops/kda.py forward and its hand-written backward at 32 heads of
+    128 over 16,384 tokens: XLA ops only (no Mosaic kernel), and the
+    blocks' intermediates do not outlive their block — the whole program
+    stays under 3 GiB around 0.9 GiB of operands."""
+    from mobilefinetuner_tpu.ops import kda
+    B, n, S, d = 1, 32, 16384, 128
+    x = sds(topo, (B, n, S, d), BF16)
+    g = sds(topo, (B, n, S, d), jnp.float32)
+    beta = sds(topo, (B, n, S), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return kda.kda_chunked(q, k, v, g, beta).astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x, g,
+                    beta)
+    assert tpu_custom_calls(c.as_text()) == 0
+    assert compiled_peak_bytes(c) < 3 * 2 ** 30
+
+
+def test_grouped_product_compiles_at_the_ling_cell_s_widths(topo, as_tpu):
+    """K 2560 / N 768 and back over 64 held experts, 16,384 tokens top-8:
+    the tiles ops/moe.gmm_tiling derives from the shape."""
+    from mobilefinetuner_tpu.ops import moe
+    T, k, H, I, n = 16384, 8, 2560, 768, 64
+    ex = {"gate_w": sds(topo, (n, H, I), BF16),
+          "up_w": sds(topo, (n, H, I), BF16),
+          "down_w": sds(topo, (n, I, H), BF16)}
+
+    def f(m, idx, w, ex):
+        return moe.expert_layer(m, idx, w, ex, (0, n))[0] \
+            .astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(f, argnums=(0, 2)), sds(topo, (T, H), BF16),
+                    sds(topo, (T, k), jnp.int32),
+                    sds(topo, (T, k), jnp.float32), ex)
+    assert assert_kernel(c, 6) == 6
+
+
+@pytest.mark.slow
+def test_the_ling_cell_s_train_step_compiles_and_fits(topo, as_tpu):
+    """The LoRA step of ling_lora_long as benchmark/tools/size_ling.py
+    builds it, at the cell's length and batch size: it compiles for a
+    v5e, holds the flash (forward twice under remat, the split pair) and
+    grouped-product kernels, and stays within 0.85 of bytes_limit."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench, os.path.join(bench, "tools")]
+    import size_ling
+    from harness import common
+    from harness.traffic import argv_value
+    B = int(argv_value(common.load_cell("ling_lora_long")
+                       ["traffic_file"]["argv"], "--batch_size"))
+    c = size_ling.lower_step(B, topo.devices[0]).compile()
+    assert_kernel(c, 4 + 6 * 9)
+    assert compiled_peak_bytes(c) <= 0.85 * size_ling.BYTES_LIMIT
+
+
 # --------------------------- paged decode attention -------------------------
 
 # (S, KV, G, D, bT, M, NB, L) and the pages a grid step folds there

@@ -55,9 +55,16 @@ DECODE_SCOPES = ("decode_kv_write", "decode_paged_attn",
 # an AFMoE step's scopes (models/afmoe.py, ops/moe.py): the families'
 # outer ones, and inside them what tells the router from the sort from
 # the grouped product, and windowed attention from full
-AFMOE_SCOPES = TRAIN_SCOPES + (
-    "moe_router", "moe_dispatch", "moe_experts", "moe_shared",
-    "moe_combine", "attn_window", "attn_full")
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_shared",
+              "moe_combine")
+AFMOE_SCOPES = TRAIN_SCOPES + MOE_SCOPES + ("attn_window", "attn_full")
+# the Ling hybrid's (models/ling_hybrid.py): inside `attention` what
+# tells the delta rule's projections and conv from its gate, its chunked
+# scan (forward and backward) and its output, and latent attention's
+# projections from the attention call
+LING_SCOPES = TRAIN_SCOPES + MOE_SCOPES + (
+    "kda_proj_conv", "kda_gate", "kda_scan", "kda_out", "mla_proj",
+    "attn_latent", "mla_out")
 
 
 def _ensure_cpu_devices() -> None:
@@ -123,23 +130,20 @@ def prog_train_gpt2_lora():
     return text, retraces, TRAIN_SCOPES
 
 
-def prog_train_afmoe_lora():
-    """Single-device AFMoE LoRA optimizer step (one expert-parallel rank
-    of two), donate=True, the model's counters riding the metrics."""
+def _prog_expert_lora(model, cfg, init_lora, scopes):
+    """Single-device LoRA optimizer step of a sparse-expert family (one
+    expert-parallel rank), donate=True, the model's counters riding the
+    metrics."""
     import jax
     import jax.numpy as jnp
-    from mobilefinetuner_tpu.core.config import AfmoeConfig
-    from mobilefinetuner_tpu.lora.lora import (LoRASpec, init_lora_afmoe,
-                                               trainable_mask)
-    from mobilefinetuner_tpu.models import afmoe
+    from mobilefinetuner_tpu.lora.lora import LoRASpec, trainable_mask
     from mobilefinetuner_tpu.ops.loss import chunked_lm_cross_entropy_sum
     from mobilefinetuner_tpu.train.trainer import (TrainConfig,
                                                    init_optimizer,
                                                    make_train_step)
-    cfg = AfmoeConfig.tiny().with_expert_parallel(2, 1)
-    params = afmoe.init_params(cfg, jax.random.PRNGKey(0))
-    lora = init_lora_afmoe(cfg, LoRASpec(rank=2, alpha=4.0, init="peft"),
-                           jax.random.PRNGKey(1))
+    params = model.init_params(cfg, jax.random.PRNGKey(0))
+    lora = init_lora(cfg, LoRASpec(rank=2, alpha=4.0, init="peft"),
+                     jax.random.PRNGKey(1))
     mask = trainable_mask(lora)
     tc = TrainConfig(total_steps=8, lr=1e-3, warmup_ratio=0.0,
                      schedule="constant")
@@ -147,7 +151,7 @@ def prog_train_afmoe_lora():
 
     def loss_fn(lo, p, mb):
         traces["n"] += 1  # runs exactly when jax (re)traces
-        hidden, aux = afmoe.hidden_states(
+        hidden, aux = model.hidden_states(
             cfg, p, mb["input_ids"], attention_mask=mb["attention_mask"],
             lora=lo, remat=True, with_aux=True)
         s, n = chunked_lm_cross_entropy_sum(hidden, p["lm_head"],
@@ -163,7 +167,27 @@ def prog_train_afmoe_lora():
     retraces = traces["n"]
     text = step.lower(tr, params, opt, batch,
                       jnp.int32(3)).compile().as_text()
-    return text, retraces, AFMOE_SCOPES
+    return text, retraces, scopes
+
+
+def prog_train_afmoe_lora():
+    """AFMoE, rank 1 of two."""
+    from mobilefinetuner_tpu.core.config import AfmoeConfig
+    from mobilefinetuner_tpu.lora.lora import init_lora_afmoe
+    from mobilefinetuner_tpu.models import afmoe
+    return _prog_expert_lora(
+        afmoe, AfmoeConfig.tiny().with_expert_parallel(2, 1),
+        init_lora_afmoe, AFMOE_SCOPES)
+
+
+def prog_train_ling_lora():
+    """The Ling hybrid, rank 1 of four (one routing group)."""
+    from mobilefinetuner_tpu.core.config import LingHybridConfig
+    from mobilefinetuner_tpu.lora.lora import init_lora_ling
+    from mobilefinetuner_tpu.models import ling_hybrid
+    return _prog_expert_lora(
+        ling_hybrid, LingHybridConfig.tiny().with_expert_parallel(4, 1),
+        init_lora_ling, LING_SCOPES)
 
 
 def prog_train_gpt2_fsdp():
@@ -431,6 +455,7 @@ def prog_multitenant_gpt2():
 PROGRAMS = {
     "train_gpt2_lora": prog_train_gpt2_lora,
     "train_afmoe_lora": prog_train_afmoe_lora,
+    "train_ling_lora": prog_train_ling_lora,
     "train_gpt2_fsdp": prog_train_gpt2_fsdp,
     "decode_gpt2_paged": prog_decode_gpt2_paged,
     "decode_gpt2_paged_tp": prog_decode_gpt2_paged_tp,

@@ -139,6 +139,36 @@ GRAFT_SHARED_STATE = {
 }
 
 
+def _leaf_dtype(x) -> np.dtype:
+    return np.dtype(getattr(x, "dtype", np.float32))
+
+
+def _is_float(x) -> bool:
+    return jnp.issubdtype(_leaf_dtype(x), jnp.floating)
+
+
+def _resident_params(params, dtype, shardings=None):
+    """The base tree as the engine holds it: every floating leaf in
+    `dtype`, on `shardings`' placement (a matching tree; None = the
+    default device). Leaf by leaf, so beside the cast tree the device
+    never holds more than ONE leaf's other-dtype form on the engine's
+    account; a host (numpy) leaf is cast on the host and only its cast
+    form is transferred. A leaf that already is a device array of
+    `dtype` is kept as it is — at float32 an f32 tree costs no copy
+    and no op."""
+    def place(x, s=None):
+        if _is_float(x) and _leaf_dtype(x) != dtype:
+            if not isinstance(x, jax.Array):
+                # graftlint: disable=sync-hazard(build-time, host leaves only: no device buffer is read)
+                x = np.asarray(x)
+            x = x.astype(dtype)
+        return jnp.asarray(x) if s is None else jax.device_put(x, s)
+
+    if shardings is None:
+        return jax.tree.map(place, params)
+    return jax.tree.map(place, params, shardings)
+
+
 @dataclasses.dataclass
 class ServeConfig:
     """Engine shape knobs — all STATIC: together they fix the compiled
@@ -148,7 +178,9 @@ class ServeConfig:
     num_blocks: int = 512     # pool pages incl. the reserved trash page
     max_prompt: int = 64      # prompts right-padded to this (block_T mult)
     max_new_tokens: int = 64  # per-request generation cap
-    dtype: str = "float32"    # compute + cache dtype
+    dtype: str = "float32"    # compute + cache dtype, and the dtype
+                              # the base weights are HELD in (cast
+                              # once at build)
     attn_impl: str = "auto"   # auto | xla | pallas (paged attention path)
     lora_impl: str = "auto"   # auto | naive | fused (models/lora_apply)
     # --- robustness knobs (round 14, DESIGN.md §19) — host-side policy
@@ -345,7 +377,9 @@ class ServeEngine:
     """The serving loop. Drive it with submit() + step() (or drain());
     close() terminates the telemetry stream.
 
-    family: "gpt2" | "gemma"; params: the frozen base tree;
+    family: "gpt2" | "gemma"; params: the frozen base tree — any
+    float dtype, host or device leaves; the engine holds its own copy
+    in cfg.dtype (`_resident_params`), never the tree it was given;
     bank: optional AdapterBank for multi-tenant routing;
     telemetry: optional core.telemetry.Telemetry (emits run_start /
     per-request `request` events / run_end).
@@ -402,22 +436,27 @@ class ServeEngine:
         # ---- memory admission at BUILD (round 16, DESIGN.md §21):
         # params + adapter bank + both KV pools are the engine's static
         # HBM footprint — refuse an infeasible num_blocks/num_slots
-        # BEFORE anything lands on device (the sizes come from the RAW
-        # input trees: a params-dominated over-capacity config must be
-        # refused by name, not crash in the placement below), naming
-        # the max feasible values so the retry is a calculation.
+        # BEFORE anything lands on device (the sizes come from the
+        # input trees' SHAPES: a params-dominated over-capacity config
+        # must be refused by name, not crash in the placement below),
+        # naming the max feasible values so the retry is a calculation.
+        # The base weights count at the dtype the engine will HOLD them
+        # in (_resident_params below), not the one they arrive in.
         from mobilefinetuner_tpu.core import memory_guard as mg
         per_block_mb = (2 * L * KV * cfg.block_T * D
                         * self.dtype.itemsize) / 2 ** 20
         self.pool_mb = per_block_mb * cfg.num_blocks
 
-        def tree_mb(t):
+        def tree_mb(t, held=None):
+            """MB of a tree's leaves as they are, or its floating leaves
+            at the width of `held` (the dtype they will be held in)."""
             return sum(
                 int(np.prod(np.shape(x)))
-                * np.dtype(getattr(x, "dtype", np.float32)).itemsize
+                * (held if held is not None and _is_float(x)
+                   else _leaf_dtype(x)).itemsize
                 for x in jax.tree.leaves(t)) / 2 ** 20
 
-        params_mb = tree_mb(params)
+        params_mb = tree_mb(params, held=self.dtype)
         bank_mb = tree_mb(bank.tree) if bank is not None else 0.0
         self.mem_check = mg.analytic_check(
             params_mb + bank_mb + self.pool_mb, cap_mb=cfg.hbm_cap_mb,
@@ -436,10 +475,23 @@ class ServeEngine:
                 f"({per_block_mb:.2f} MB/page), which serves at most "
                 f"num_slots={max_slots} worst-case requests of "
                 f"{self.M} pages each", check=self.mem_check)
+        # ---- the base weights, RESIDENT in the engine's dtype: cast
+        # once here, so no compiled program re-casts the tree per call
+        # (their own casts to compute_dtype are then no operation). The
+        # span is in a profiler trace of the build; its stream record
+        # follows run_start, which stays the stream's first event.
+        from mobilefinetuner_tpu.core.trace import NULL_TRACER
         sh = self.sharding
+        cast_t0 = time.perf_counter()
+        with NULL_TRACER.span("serve.weight_cast"):
+            self.params = _resident_params(
+                params, self.dtype,
+                None if sh is None else sh.param_shardings(params))
+            del params          # the engine keeps its own tree only
+            # graftlint: disable=sync-hazard(build-time, once: weight_cast_ms must hold the cast, not its dispatch)
+            jax.block_until_ready(self.params)
+        cast_ms = (time.perf_counter() - cast_t0) * 1000.0
         if sh is not None:
-            self.params = jax.device_put(params,
-                                         sh.param_shardings(params))
             # every host-born array a compiled program sees must be
             # COMMITTED to the mesh, or jit refuses to mix placements
             # graftlint: disable=sync-hazard(host-born numpy coerced on its way INTO device_put; no device buffer is read)
@@ -447,7 +499,6 @@ class ServeEngine:
             if bank is not None:
                 bank.place(sh.bank_shardings(bank.tree), sh.put_repl)
         else:
-            self.params = jax.tree.map(jnp.asarray, params)
             self._dev = jnp.asarray
         self.alloc = BlockAllocator(cfg.num_blocks)
         # shared-prefix reuse (round 21): the cache owns the key<->page
@@ -672,6 +723,11 @@ class ServeEngine:
             "block_T": cfg.block_T, "num_blocks": cfg.num_blocks,
             "max_prompt": cfg.max_prompt,
             "max_new_tokens": cfg.max_new_tokens, "dtype": cfg.dtype,
+            # what the base weights are held in, their resident size
+            # and what the build's one cast + placement took
+            "weights_dtype": str(self.dtype),
+            "weights_mb": round(tree_mb(self.params), 3),
+            "weight_cast_ms": round(cast_ms, 3),
             "attn_impl": cfg.attn_impl,
             "attn_impl_resolved": self.attn_impl_resolved,
             "paged_pages_per_step": self.paged_pages_per_step,
@@ -692,6 +748,7 @@ class ServeEngine:
         # "how many more blocks/slots could this chip hold" number the
         # ROADMAP's adapter-packing and KV-sizing questions start from
         self.telemetry.emit("mem_check", **self.mem_check.event())
+        self.tracer.emit_span("serve.weight_cast", "main", cast_t0, cast_ms)
 
     # ------------------------------------------------------------ helpers ---
     def _init_pools(self):

@@ -893,10 +893,10 @@ def test_gpt2_xl_serve_programs_compile_and_fit(topo, as_tpu, program):
     """The next PR's anchor model (gpt2-xl: H 1600, 25 heads, 48 layers)
     through the model functions the engine jits, from shapes alone —
     known to compile and to fit one 16 GiB chip before chip time is
-    spent on it. The weights are handed over in bf16: the engine keeps
-    params in the dtype it is given and casts inside the step, and with
-    the float32 tree build_engine makes today this decode step needs
-    17.3 GiB (6.2 f32 + the 3.1 bf16 copy + pools) — over one chip."""
+    spent on it. The weights are handed over in bf16, as the engine
+    hands them since PR 32 (it casts its tree once at build): a float32
+    tree with the in-step cast needed 17.3 GiB here (6.2 f32 + the 3.1
+    bf16 copy + pools) — over one chip."""
     from mobilefinetuner_tpu.models import generate as g
     from mobilefinetuner_tpu.models import gpt2
     cfg = GPT2Config.gpt2_xl()

@@ -46,8 +46,10 @@ DEFAULT_CONTRACTS = os.path.join(REPO, "tools", "compiled_contracts.json")
 TRAIN_SCOPES = ("embed", "attention", "mlp", "loss", "optimizer")
 # the paged decode step's and prefill chunk's scopes (models/generate.py):
 # how a device trace tells the KV scatter from the paged read from the
-# block's matmuls. `decode_weight_cast` is not pinned here: these
-# programs compute in float32, where the cast is no operation at all
+# block's matmuls. `decode_weight_cast` is not pinned here: it holds an
+# op only where a program is handed a tree of another dtype than it
+# computes in; these programs take float32 and compute in float32, and
+# the engine's own steps take the tree it cast at build (DESIGN.md §16)
 DECODE_SCOPES = ("decode_kv_write", "decode_paged_attn",
                  "decode_proj_mlp", "decode_head")
 

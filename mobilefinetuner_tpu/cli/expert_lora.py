@@ -1,7 +1,8 @@
 """What the LoRA CLIs of the sparse-expert families share: one trainer
 entry point over `cli/common.run_training`, parametrised by a record
-that says what a family is (cli/train_lora_afmoe.py and
-cli/train_lora_ling.py each hold one and nothing else).
+that says what a family is (cli/train_lora_afmoe.py,
+cli/train_lora_ling.py and cli/train_lora_xing4.py each hold one and
+nothing else).
 
 Common to them: the flags; `--expert_parallel N --expert_rank r`
 describing the deployment this process is one rank of (it holds experts
@@ -12,6 +13,19 @@ carrying the model's own device counters (`moe_assign_here`,
 `moe_load_max_over_mean`, `moe_dropped`, and whatever else the family's
 `hidden_states(..., with_aux=True)` counts); adapters saved in the
 native format and, on request, as an HF-PEFT directory.
+
+What a family's `hidden_states(..., with_aux=True)` hands back: the
+final-norm hidden states [B, S, H] that the shared head scores against
+the labels, and `aux` with "counters" ({"sum": {...}, "max": {...}},
+device scalars). Beyond that ONE hidden state a family may hand back a
+second, `aux["second"] = {"hidden": [B, S, H], "shift": k, "weight": w,
+"name": str}` (a multi-token-prediction module's): the same head and
+chunked cross-entropy score it against the labels moved k further
+(position i then predicts label i + 1 + k), under the device scope
+`name`, and the step's loss is L_main + w · L_second, each a mean over
+its own valid positions; `step_stats` then carries `loss_main` and
+`loss_<name>` beside `loss`. Evaluation (`nll_fn`) reports the main
+loss only.
 
 The tokenizer is read from `vocab.json` / `merges.txt` (byte-level BPE);
 the published families' own tokenizer file formats are not implemented.
@@ -25,6 +39,7 @@ import os
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 
 from mobilefinetuner_tpu.cli import common
 from mobilefinetuner_tpu.core.logging import get_logger
@@ -34,7 +49,8 @@ from mobilefinetuner_tpu.io import async_ckpt
 from mobilefinetuner_tpu.lora import peft_io
 from mobilefinetuner_tpu.lora.lora import (LoRASpec, num_trainable,
                                            trainable_mask)
-from mobilefinetuner_tpu.ops.loss import chunked_lm_cross_entropy_sum
+from mobilefinetuner_tpu.ops.loss import (IGNORE_INDEX,
+                                          chunked_lm_cross_entropy_sum)
 from mobilefinetuner_tpu.optim import adam as adam_mod
 
 log = get_logger()
@@ -50,7 +66,9 @@ class ExpertFamily:
     title: str
     config_cls: Any                 # .from_pretrained, .with_expert_parallel
     load: Callable                  # (dir, config=) -> (config, params)
-    model: Any                      # .hidden_states, .matmul_params_per_token
+    # .hidden_states (one hidden state, or a second in aux["second"]:
+    # the module docstring), .matmul_params_per_token
+    model: Any
     targets: Dict[str, Callable]    # LoRA target -> dims(config)
     init_lora: Callable             # (config, spec, key) -> tree
     default_out: str
@@ -61,6 +79,8 @@ class ExpertFamily:
     # config -> {target: layer index of each stack position}, where a
     # target's stack does not run over all layers
     peft_layer_ids: Optional[Callable] = None
+    # parser -> None: the family's own flags (`resolve` reads them)
+    add_flags: Optional[Callable] = None
 
 
 def build_parser(fam: ExpertFamily) -> argparse.ArgumentParser:
@@ -88,11 +108,33 @@ def build_parser(fam: ExpertFamily) -> argparse.ArgumentParser:
     p.add_argument("--expert_rank", type=int, default=0,
                    help="this process's rank among them: it holds "
                         "experts [r*E/N, (r+1)*E/N)")
+    if fam.add_flags:
+        fam.add_flags(p)
     common.add_train_flags(p, lr=1e-4, seq_len=256, batch_size=1)
     common.add_pm_flags(p)
     common.add_shard_flags(p)
     common.add_mesh_flags(p)
     return p
+
+
+def add_second_loss(s, n, counters, second, head, labels, accum: int = 1):
+    """The step's loss sum and counters with a family's second hidden
+    state scored (module docstring): returns (s', counters') with
+    s' / n = L_main + weight · L_second, each loss a mean over its own
+    valid positions, and `loss_main` / `loss_<name>` among the summed
+    counters (÷ accum: the micro-batches' values add up to their mean)."""
+    k = second["shift"]
+    with jax.named_scope(second["name"]):
+        moved = jnp.concatenate(
+            [labels[:, k:], jnp.full_like(labels[:, :k], IGNORE_INDEX)], 1)
+        s2, n2 = chunked_lm_cross_entropy_sum(
+            second["hidden"], head, moved, num_chunks=LOSS_CHUNKS)
+    nf = jnp.maximum(n.astype(jnp.float32), 1.0)
+    n2f = jnp.maximum(n2.astype(jnp.float32), 1.0)
+    counters = {**counters, "sum": {
+        **counters.get("sum", {}), "loss_main": s / nf / accum,
+        f"loss_{second['name']}": s2 / n2f / accum}}
+    return s + second["weight"] * s2 * (nf / n2f), counters
 
 
 def main(fam: ExpertFamily, argv=None) -> int:
@@ -168,12 +210,20 @@ def main(fam: ExpertFamily, argv=None) -> int:
             dropout_rng=rng, lora_impl=args.lora_impl, with_aux=True)
         s, n = chunked_lm_cross_entropy_sum(
             hidden, p["lm_head"], mb["labels"], num_chunks=LOSS_CHUNKS)
-        return s, n, aux["counters"]
+        counters = aux["counters"]
+        second = aux.get("second") if train else None
+        if second is not None:
+            s, counters = add_second_loss(
+                s, n, counters, second, p["lm_head"], mb["labels"],
+                tc.grad_accum_steps)
+        return s, n, counters
 
     def loss_fn(lora_t, frozen, mb):
         return nll(lora_t, frozen, mb, True)
 
     def nll_fn(lora_t, frozen, mb):
+        """Evaluation: the MAIN loss only (a second hidden state is a
+        training objective, not what perplexity is reported on)."""
         return nll(lora_t, frozen, mb, False)[:2]
 
     def save_hook(step, lora_t, opt_st, final, ckpt=None):

@@ -410,3 +410,142 @@ class LingHybridConfig:
                    rotary_dim=8, num_experts=16, num_experts_per_tok=2,
                    n_group=4, topk_group=2, kda_chunk=16,
                    max_position_embeddings=256)
+
+
+@dataclasses.dataclass
+class Xing4Config:
+    """XingChen-AGI's Xing4.0 family (`model_type: xing4_0`): DeepSeek-V3's
+    latent attention with a query latent in every layer, a SwiGLU MLP
+    below `first_k_dense_replace` and sigmoid-routed experts above, an
+    `hc_mult`-row residual stream mixed by manifold-constrained
+    hyper-connections around every sublayer, and
+    `num_nextn_predict_layers` multi-token-prediction modules — the
+    published `config.json` keys (DESIGN.md §33 says which equation reads
+    which), plus what this chip holds of each expert layer, as
+    AfmoeConfig does.
+
+    `n_routed_experts` is the router's width; `experts_held = (first,
+    count)` the experts that live here; `vocab_size` the rows held;
+    `mtp_loss_weight` the second loss's weight (the trainer's flag: 0
+    drops the module from the step). Defaults are Xing4.0-29B-A4B's
+    widths at one dense layer plus four sparse ones."""
+
+    vocab_size: int = 131072
+    hidden_size: int = 3584
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 5
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 32
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_theta: float = 10000.0
+    rope_scaling: Optional[dict] = None
+    attention_bias: bool = False
+    n_routed_experts: int = 64
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.0
+    moe_layer_freq: int = 1
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    num_nextn_predict_layers: int = 1
+    rms_norm_eps: float = 1e-6
+    tie_word_embeddings: bool = False
+    max_position_embeddings: int = 262144
+    attention_impl: str = "auto"
+    mtp_loss_weight: float = 0.3
+    experts_held: Optional[tuple] = None
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            self.experts_held = (0, self.n_routed_experts)
+        self.experts_held = tuple(int(x) for x in self.experts_held)
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1
+                and first + count <= self.n_routed_experts):
+            raise ValueError(f"experts_held={self.experts_held} is not a "
+                             f"range of the {self.n_routed_experts} experts")
+        rs = self.rope_scaling
+        if (self.scoring_func != "sigmoid" or self.topk_method != "noaux_tc"
+                or self.tie_word_embeddings or self.n_shared_experts != 1
+                or self.attention_bias or self.moe_layer_freq != 1
+                or self.num_key_value_heads != self.num_attention_heads
+                or self.num_nextn_predict_layers not in (0, 1)
+                or self.n_routed_experts % self.n_group
+                or (rs is not None and rs.get("type") != "yarn")):
+            raise ValueError(
+                "Xing4Config: only the published Xing4.0 form is "
+                "implemented (sigmoid scores with a selection bias, an "
+                "untied head, one shared expert, experts in every layer "
+                "above the dense ones, as many key as query heads, no "
+                "attention bias, at most one multi-token-prediction "
+                "module, yarn rotary or none, groups that divide the "
+                "experts)")
+
+    def is_dense_layer(self, i: int) -> bool:
+        return i < self.first_k_dense_replace
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def head_dim(self) -> int:
+        """The width the in-loop FLOP estimate counts attention at."""
+        return self.qk_head_dim
+
+    @property
+    def has_mtp(self) -> bool:
+        """Whether the step runs the multi-token-prediction module."""
+        return self.num_nextn_predict_layers > 0 and self.mtp_loss_weight > 0
+
+    def with_expert_parallel(self, n: int, rank: int) -> "Xing4Config":
+        """This chip as rank `rank` of `n` that share each expert layer:
+        experts [rank·E/n, (rank+1)·E/n)."""
+        if n < 1 or self.n_routed_experts % n or not 0 <= rank < n:
+            raise ValueError(f"expert_parallel={n} rank={rank} does not "
+                             f"divide {self.n_routed_experts} experts")
+        per = self.n_routed_experts // n
+        return dataclasses.replace(self, experts_held=(rank * per, per))
+
+    @classmethod
+    def from_pretrained(cls, model_dir: str) -> "Xing4Config":
+        raw = _load_config_json(model_dir)
+        return cls(**{f.name: raw[f.name] for f in dataclasses.fields(cls)
+                      if f.name in raw})
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 300) -> "Xing4Config":
+        """Tiny config for tests: one dense layer and two sparse ones, 8
+        experts top-2, MLA at 24 + 8 rotary / v 16 with both latents, a
+        4-row stream, yarn over an original length of 16 (so a test of
+        32 tokens runs past it), one multi-token-prediction module."""
+        return cls(vocab_size=vocab_size, hidden_size=64,
+                   intermediate_size=128, moe_intermediate_size=32,
+                   num_hidden_layers=3, first_k_dense_replace=1,
+                   num_attention_heads=4, num_key_value_heads=4,
+                   q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=24,
+                   qk_rope_head_dim=8, v_head_dim=16,
+                   rope_scaling={"type": "yarn", "factor": 8,
+                                 "original_max_position_embeddings": 16,
+                                 "beta_fast": 4, "beta_slow": 1,
+                                 "mscale": 1, "mscale_all_dim": 1},
+                   n_routed_experts=8, num_experts_per_tok=2,
+                   max_position_embeddings=128)

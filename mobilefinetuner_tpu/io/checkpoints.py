@@ -26,7 +26,7 @@ import numpy as np
 
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
                                              LingHybridConfig,
-                                              Gemma3TextConfig)
+                                             Gemma3TextConfig, Xing4Config)
 from mobilefinetuner_tpu.io.safetensors_io import (CheckpointIntegrityError,
                                                    SafeTensorsReader,
                                                    atomic_publish,
@@ -525,6 +525,145 @@ def ling_hybrid_params_to_hf(params,
         if "mlp" not in lp:
             p[mlp + "gate.weight"] = np.asarray(lp["router_w"]).T
             p[mlp + "gate.expert_bias"] = np.asarray(lp["expert_bias"])
+    return p
+
+
+# ------------------------------- Xing4.0 ------------------------------------
+#
+# Key scheme of models/xing4.py's checkpoints — ASSUMED (the family's
+# modelling code is not in this installation; DeepSeek-V3's names where a
+# weight has one there, DESIGN.md §33):
+#   model.embed_tokens.weight, lm_head.weight, model.norm.weight,
+#   model.layers.{i}.input_layernorm / post_attention_layernorm .weight,
+#   .self_attn.{q_a_proj, q_b_proj, kv_a_proj_with_mqa, kv_b_proj,
+#               o_proj}.weight, .self_attn.{q_a,kv_a}_layernorm.weight,
+#   .attn_hc / .mlp_hc .{phi.weight [2n + n², n·C], base [2n + n²],
+#               scale [3]}  (the two sublayers' hyper-connections)
+#   dense MLP   .mlp.{gate,up,down}_proj.weight
+#   expert MLP  .mlp.gate.weight [E, H],
+#               .mlp.gate.e_score_correction_bias [E],
+#               .mlp.shared_experts.{gate,up,down}_proj.weight,
+#               .mlp.experts.{e}.{gate,up,down}_proj.weight
+#   the multi-token-prediction module as layer L = num_hidden_layers:
+#   a sparse block's keys and .enorm.weight, .hnorm.weight,
+#   .eh_proj.weight [C, 2C], .shared_head.norm.weight (the head itself is
+#   lm_head, shared).
+# Linear weights are [out, in].
+
+_XING4_MLA_LINEAR = (("q_a_w", "q_a_proj"), ("q_b_w", "q_b_proj"),
+                     ("kv_a_w", "kv_a_proj_with_mqa"),
+                     ("kv_b_w", "kv_b_proj"), ("o_w", "o_proj"))
+_XING4_MLA_PLAIN = (("q_a_norm", "q_a_layernorm.weight"),
+                    ("kv_a_norm", "kv_a_layernorm.weight"))
+_XING4_HC = (("hc_attn", "attn_hc."), ("hc_mlp", "mlp_hc."))
+_XING4_MTP = (("enorm", "enorm.weight"), ("hnorm", "hnorm.weight"),
+              ("norm", "shared_head.norm.weight"))
+
+
+def load_xing4(model_dir: str, config: Optional[Xing4Config] = None):
+    """(config, params in models/xing4.py's layout) from a directory in
+    the key scheme above. Of each expert layer only the
+    `config.experts_held` experts are read; a BF16 tensor stays bf16, so
+    a frozen base published in bf16 is resident at 2 bytes a parameter."""
+    if config is None:
+        config = Xing4Config.from_pretrained(model_dir)
+    c = config
+    readers = _tensor_readers(model_dir)
+
+    def get(key, linear=False):
+        a = readers[key].load(key, keep_bf16=True)
+        return a.T if linear else a      # [out, in] -> [in, out]
+
+    def swiglu(pre):
+        return {name: get(f"{pre}{hf}.weight", True) for name, hf in _SWIGLU}
+
+    first, n = c.experts_held
+
+    def block(i: int, dense: bool):
+        pre = _LING_LAYER.format(i)
+        at = pre + "self_attn."
+        bp = {"input_ln": get(pre + "input_layernorm.weight"),
+              "post_attn_ln": get(pre + "post_attention_layernorm.weight"),
+              "mla": {**{name: get(f"{at}{hf}.weight", True)
+                         for name, hf in _XING4_MLA_LINEAR},
+                      **{name: get(at + hf)
+                         for name, hf in _XING4_MLA_PLAIN}}}
+        for name, hf in _XING4_HC:
+            bp[name] = {"phi": get(pre + hf + "phi.weight", True),
+                        "b": get(pre + hf + "base"),
+                        "alpha": get(pre + hf + "scale")}
+        mlp = pre + "mlp."
+        if dense:
+            bp["mlp"] = swiglu(mlp)
+        else:
+            bp["router_w"] = get(mlp + "gate.weight", True)
+            bp["expert_bias"] = get(mlp + "gate.e_score_correction_bias")
+            bp["shared"] = swiglu(mlp + "shared_experts.")
+            bp["experts"] = {
+                name: np.stack([get(f"{mlp}experts.{e}.{hf}.weight", True)
+                                for e in range(first, first + n)])
+                for name, hf in _SWIGLU}
+        return bp
+
+    L = c.num_hidden_layers
+    params = {"embed": get("model.embed_tokens.weight"),
+              "layers": [block(i, c.is_dense_layer(i)) for i in range(L)],
+              "final_norm": get("model.norm.weight"),
+              "lm_head": get("lm_head.weight")}
+    if c.num_nextn_predict_layers:
+        pre = _LING_LAYER.format(L)
+        params["mtp"] = {
+            **{name: get(pre + hf) for name, hf in _XING4_MTP},
+            "eh_proj": get(pre + "eh_proj.weight", True),
+            "block": block(L, False)}
+    return config, params
+
+
+def xing4_params_to_hf(params, first_expert: int = 0) -> Dict[str, np.ndarray]:
+    """models/xing4.py's tree -> the key scheme above (the inverse of
+    load_xing4); the held experts under their own numbers."""
+    p = {"model.embed_tokens.weight": np.asarray(params["embed"]),
+         "lm_head.weight": np.asarray(params["lm_head"]),
+         "model.norm.weight": np.asarray(params["final_norm"])}
+
+    def block(i: int, bp):
+        pre = _LING_LAYER.format(i)
+        at = pre + "self_attn."
+        p[pre + "input_layernorm.weight"] = np.asarray(bp["input_ln"])
+        p[pre + "post_attention_layernorm.weight"] = \
+            np.asarray(bp["post_attn_ln"])
+        for name, hf in _XING4_MLA_LINEAR:
+            p[f"{at}{hf}.weight"] = np.asarray(bp["mla"][name]).T
+        for name, hf in _XING4_MLA_PLAIN:
+            p[at + hf] = np.asarray(bp["mla"][name])
+        for name, hf in _XING4_HC:
+            p[pre + hf + "phi.weight"] = np.asarray(bp[name]["phi"]).T
+            p[pre + hf + "base"] = np.asarray(bp[name]["b"])
+            p[pre + hf + "scale"] = np.asarray(bp[name]["alpha"])
+        mlp = pre + "mlp."
+        for name, hf in _SWIGLU:
+            if "mlp" in bp:
+                p[f"{mlp}{hf}.weight"] = np.asarray(bp["mlp"][name]).T
+                continue
+            p[f"{mlp}shared_experts.{hf}.weight"] = \
+                np.asarray(bp["shared"][name]).T
+            ex = np.asarray(bp["experts"][name])
+            for e in range(ex.shape[0]):
+                p[f"{mlp}experts.{first_expert + e}.{hf}.weight"] = ex[e].T
+        if "mlp" not in bp:
+            p[mlp + "gate.weight"] = np.asarray(bp["router_w"]).T
+            p[mlp + "gate.e_score_correction_bias"] = \
+                np.asarray(bp["expert_bias"])
+
+    for i, bp in enumerate(params["layers"]):
+        block(i, bp)
+    if "mtp" in params:
+        L = len(params["layers"])
+        pre = _LING_LAYER.format(L)
+        for name, hf in _XING4_MTP:
+            p[pre + hf] = np.asarray(params["mtp"][name])
+        p[pre + "eh_proj.weight"] = np.asarray(params["mtp"]["eh_proj"]).T
+        block(L, params["mtp"]["block"])
     return p
 
 

@@ -102,6 +102,24 @@ LING_TARGETS = {
     "mla_o_proj": lambda c: (c.num_attention_heads * c.v_head_dim,
                              c.hidden_size),
 }
+# Xing4.0 (models/xing4.py): the five projections of latent attention,
+# both latents' down- and up-projections and the output, in every block
+# — stacks over the layers and then the multi-token-prediction module's
+# block (position num_hidden_layers). Hyper-connection coefficients,
+# norms, router, experts, the module's merge, embedding and head stay
+# frozen.
+XING4_TARGETS = {
+    "q_a_proj": lambda c: (c.hidden_size, c.q_lora_rank),
+    "q_b_proj": lambda c: (c.q_lora_rank,
+                           c.num_attention_heads * c.qk_head_dim),
+    "kv_a_proj": lambda c: (c.hidden_size,
+                            c.kv_lora_rank + c.qk_rope_head_dim),
+    "kv_b_proj": lambda c: (
+        c.kv_lora_rank,
+        c.num_attention_heads * (c.qk_nope_head_dim + c.v_head_dim)),
+    "o_proj": lambda c: (c.num_attention_heads * c.v_head_dim,
+                         c.hidden_size),
+}
 # targets with ONE site instead of a per-layer stack: A [in, r],
 # B [r, out] (no leading L axis; maybe_lora's ndim checks skip the
 # layer_idx slice for them)
@@ -218,6 +236,17 @@ def init_lora_ling(config, spec: LoRASpec, key: jax.Array,
                 jax.random.fold_in(key, sorted(LING_TARGETS).index(name)),
                 dtype)["blocks"])
     return {"blocks": tree}
+
+
+def init_lora_xing4(config, spec: LoRASpec, key: jax.Array,
+                    dtype=jnp.float32) -> dict:
+    """Stacks over the layers, then the multi-token-prediction module's
+    block where the model has one (models/xing4.py)."""
+    dims = {t: XING4_TARGETS[t](config)
+            for t in spec.targets or list(XING4_TARGETS)}
+    return init_lora(
+        dims, config.num_hidden_layers + config.num_nextn_predict_layers,
+        spec, key, dtype)
 
 
 def stack_adapters(loras) -> dict:

@@ -54,9 +54,20 @@ LING_PEFT_MODULES = {
     "mla_kv_b_proj": "model.layers.{}.attention.kv_b_proj",
     "mla_o_proj": "model.layers.{}.attention.o_proj",
 }
+# Xing4.0 (io/checkpoints.py's key scheme, DeepSeek-V3's module names):
+# the stack's last position is the multi-token-prediction module's
+# block, which the checkpoint numbers as layer num_hidden_layers
+XING4_PEFT_MODULES = {
+    "q_a_proj": "model.layers.{}.self_attn.q_a_proj",
+    "q_b_proj": "model.layers.{}.self_attn.q_b_proj",
+    "kv_a_proj": "model.layers.{}.self_attn.kv_a_proj_with_mqa",
+    "kv_b_proj": "model.layers.{}.self_attn.kv_b_proj",
+    "o_proj": "model.layers.{}.self_attn.o_proj",
+}
 PEFT_MODULES = {"gpt2": GPT2_PEFT_MODULES, "gemma": GEMMA_PEFT_MODULES,
                 "afmoe": AFMOE_PEFT_MODULES,
-                "ling_hybrid": LING_PEFT_MODULES}
+                "ling_hybrid": LING_PEFT_MODULES,
+                "xing4_0": XING4_PEFT_MODULES}
 # For adapter_config.json target_modules. PEFT suffix-matches these against
 # full module paths, so they must be path-qualified: a bare "c_proj" would
 # match BOTH attn.c_proj and mlp.c_proj and make PEFT instantiate phantom
@@ -70,9 +81,12 @@ PEFT_TARGET_MODULES = {
 # already HF's module names)
 LING_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
                        for t, m in LING_PEFT_MODULES.items()}
+XING4_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
+                        for t, m in XING4_PEFT_MODULES.items()}
 PEFT_CONFIG = {"gpt2": (True, PEFT_TARGET_MODULES), "gemma": (False, None),
                "afmoe": (False, None),
-               "ling_hybrid": (False, LING_TARGET_MODULES)}
+               "ling_hybrid": (False, LING_TARGET_MODULES),
+               "xing4_0": (False, XING4_TARGET_MODULES)}
 
 
 # ----------------------------- native format --------------------------------

@@ -523,10 +523,12 @@ def _kv_vmem_limit(S: int, D: int, Dv: int, itemsize: int):
     while their two buffers each stay under 12 MiB as laid out — every
     shape up to S = 8192 at D = 128 in bf16, which compiled and ran with
     the default — else that much plus 12 MiB for the tile temporaries
-    (S = 16,384 at 256/128 lanes: 24 MiB of K and V)."""
+    (S = 16,384 at 256/128 lanes: 24 MiB of K and V; S = 8192 at
+    256/128 lanes is 12 MiB to the byte and the default refuses it,
+    compiled for a described v5e inside the Xing4.0 train step, PR 33)."""
     lanes = (-(-D // 128) + -(-Dv // 128)) * 128
     kv = 2 * S * lanes * itemsize
-    return None if kv <= 12 * 2 ** 20 else kv + 12 * 2 ** 20
+    return None if kv < 12 * 2 ** 20 else kv + 12 * 2 ** 20
 
 
 def merged_bwd_fits(S: int, D: int, block_k: int, itemsize: int,

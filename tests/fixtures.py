@@ -15,12 +15,15 @@ import numpy as np
 
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
                                               Gemma3TextConfig,
-                                              LingHybridConfig)
+                                              LingHybridConfig,
+                                              Xing4Config)
 from mobilefinetuner_tpu.io.checkpoints import (afmoe_params_to_hf,
                                                 gpt2_params_to_hf,
-                                                ling_hybrid_params_to_hf)
+                                                ling_hybrid_params_to_hf,
+                                                xing4_params_to_hf)
 from mobilefinetuner_tpu.io.safetensors_io import save_safetensors
-from mobilefinetuner_tpu.models import afmoe, gemma3, gpt2, ling_hybrid
+from mobilefinetuner_tpu.models import (afmoe, gemma3, gpt2, ling_hybrid,
+                                        xing4)
 
 WIKI_LINES = [
     " = Heading = ",
@@ -179,6 +182,26 @@ def write_tiny_ling_dir(d: str, seed: int = 0, dtype=None):
     save_safetensors(os.path.join(d, "model.safetensors"),
                      ling_hybrid_params_to_hf(jax.tree.map(np.asarray,
                                                            params)),
+                     metadata={"format": "pt"})
+    return config, params
+
+
+def write_tiny_xing4_dir(d: str, seed: int = 0, dtype=None):
+    """As write_tiny_afmoe_dir, for the Xing4.0 family
+    (io/checkpoints.load_xing4's key scheme)."""
+    import dataclasses
+    import jax.numpy as jnp
+    os.makedirs(d, exist_ok=True)
+    config = Xing4Config.tiny(vocab_size=train_tiny_gpt2_tokenizer(d))
+    with open(os.path.join(d, "config.json"), "w") as f:
+        raw = dataclasses.asdict(config)
+        for k in ("experts_held", "attention_impl", "mtp_loss_weight"):
+            del raw[k]
+        json.dump({"model_type": "xing4_0", **raw}, f)
+    params = xing4.init_params(config, jax.random.PRNGKey(seed),
+                               dtype or jnp.float32)
+    save_safetensors(os.path.join(d, "model.safetensors"),
+                     xing4_params_to_hf(jax.tree.map(np.asarray, params)),
                      metadata={"format": "pt"})
     return config, params
 

@@ -319,6 +319,102 @@ def test_the_ling_cell_s_train_step_compiles_and_fits(topo, as_tpu):
     assert compiled_peak_bytes(c) <= 0.85 * size_ling.BYTES_LIMIT
 
 
+# Xing4.0's cell (xing_lora_long): latent attention at S = 8,192 in
+# every block, q and k zero-padded 192 -> 256, v at 128: whole-S K and V
+# are 12 MiB of VMEM TO THE BYTE, which the default limit refuses (PR 33:
+# `_kv_vmem_limit` now asks for it from 12 MiB on, not above), the merged
+# backward's operands as laid out are over its budget, so `auto` takes
+# the split pair
+XING4_FLASH = (1, 32, 8192, 192, 128)
+
+
+def test_flash_compiles_at_two_head_sizes_at_the_xing4_cell_s_length(
+        topo, as_tpu):
+    from mobilefinetuner_tpu.ops.attention import attention, resolve_impl
+    from mobilefinetuner_tpu.ops.flash_attention import (_kv_vmem_limit,
+                                                         resolve_bwd_impl)
+    B, n, S, dq, dv = XING4_FLASH
+    qk, v = sds(topo, (B, n, S, dq), BF16), sds(topo, (B, n, S, dv), BF16)
+    assert resolve_impl(S, dq) == "flash"
+    assert _kv_vmem_limit(S, 256, dv, 2) == 24 * 2 ** 20
+    assert _kv_vmem_limit(S, 128, 128, 2) is None       # Trinity's shape
+    bwd = resolve_bwd_impl(S, 256, 512, 2, dv)
+
+    def loss(q, k, v):
+        return attention(q, k, v, impl="auto", is_causal=True,
+                         scale=0.1446796).astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert assert_kernel(c, 2 if bwd == "merged" else 3) >= 2
+
+
+def test_the_stream_s_mixes_compile_at_the_xing4_cell_s_shapes(topo,
+                                                              as_tpu):
+    """ops/hyper_connections.py forward and backward on a four-row bf16
+    stream of 8,192 tokens at width 3584: XLA ops only, the token axis
+    minor in every coefficient, and the program stays under 3 GiB around
+    0.5 GiB of operands (no [T, 4, 4] tile-padded temporaries)."""
+    from mobilefinetuner_tpu.ops import hyper_connections as hc
+    n, T, C = 4, 8192, 3584
+    x = sds(topo, (n, 1, T, C), BF16)
+    hcp = {"phi": sds(topo, (n * C, 24), BF16),
+           "b": sds(topo, (24,), jnp.float32),
+           "alpha": sds(topo, (3,), jnp.float32)}
+
+    def loss(x, hcp):
+        pre, post, res, err = hc.coefficients(
+            x, hcp, iters=20, eps=1e-6, clamp=(-30.0, 30.0), norm_eps=1e-6)
+        u = hc.pre_mix(x, pre)
+        return hc.post_mix(x, u * 2, post, res).astype(jnp.float32).sum() \
+            + err
+
+    c = compile_for(jax.grad(loss), x, hcp)
+    assert tpu_custom_calls(c.as_text()) == 0
+    assert compiled_peak_bytes(c) < 3 * 2 ** 30
+
+
+def test_grouped_product_compiles_at_the_xing4_cell_s_widths(topo, as_tpu):
+    """K 3584 / N 1024 and back over 64 held experts, 8,192 tokens top-4:
+    the tiles ops/moe.gmm_tiling derives from the shape (K over 3072: its
+    largest 128-multiple divisor, 1792)."""
+    from mobilefinetuner_tpu.ops import moe
+    T, k, H, I, n = 8192, 4, 3584, 1024, 64
+    assert moe.gmm_tiling(H, I) == (128, 1792, 1024)
+    assert moe.gmm_tiling(I, H) == (128, 1024, 512)
+    ex = {"gate_w": sds(topo, (n, H, I), BF16),
+          "up_w": sds(topo, (n, H, I), BF16),
+          "down_w": sds(topo, (n, I, H), BF16)}
+
+    def f(m, idx, w, ex):
+        return moe.expert_layer(m, idx, w, ex, (0, n))[0] \
+            .astype(jnp.float32).sum()
+
+    c = compile_for(jax.grad(f, argnums=(0, 2)), sds(topo, (T, H), BF16),
+                    sds(topo, (T, k), jnp.int32),
+                    sds(topo, (T, k), jnp.float32), ex)
+    assert assert_kernel(c, 6) == 6
+
+
+@pytest.mark.slow
+def test_the_xing4_cell_s_train_step_compiles_and_fits(topo, as_tpu):
+    """The LoRA step of xing_lora_long as benchmark/tools/size_xing4.py
+    builds it, at the cell's length and batch size: it compiles for a
+    v5e, holds the flash and grouped-product kernels of six blocks, and
+    stays within 0.85 of bytes_limit."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench, os.path.join(bench, "tools")]
+    import size_xing4
+    from harness import common
+    from harness.traffic import argv_value
+    B = int(argv_value(common.load_cell("xing_lora_long")
+                       ["traffic_file"]["argv"], "--batch_size"))
+    c = size_xing4.lower_step(B, topo.devices[0]).compile()
+    assert tpu_custom_calls(c.as_text()) == 111
+    assert compiled_peak_bytes(c) <= 0.85 * size_xing4.BYTES_LIMIT
+
+
 # --------------------------- paged decode attention -------------------------
 
 # (S, KV, G, D, bT, M, NB, L) and the pages a grid step folds there

@@ -67,6 +67,13 @@ AFMOE_SCOPES = TRAIN_SCOPES + MOE_SCOPES + ("attn_window", "attn_full")
 LING_SCOPES = TRAIN_SCOPES + MOE_SCOPES + (
     "kda_proj_conv", "kda_gate", "kda_scan", "kda_out", "mla_proj",
     "attn_latent", "mla_out")
+# Xing4.0's (models/xing4.py, ops/hyper_connections.py): the stream's
+# coefficients and its two mixes around every sublayer, latent
+# attention's three, and `mtp` around the multi-token-prediction module
+# (its block's scopes nest under it, its loss chunks too)
+XING4_SCOPES = TRAIN_SCOPES + MOE_SCOPES + (
+    "mhc_coeff", "mhc_pre", "mhc_post", "mla_proj", "attn_latent",
+    "mla_out", "mtp")
 
 
 def _ensure_cpu_devices() -> None:
@@ -158,7 +165,12 @@ def _prog_expert_lora(model, cfg, init_lora, scopes):
             lora=lo, remat=True, with_aux=True)
         s, n = chunked_lm_cross_entropy_sum(hidden, p["lm_head"],
                                             mb["labels"], num_chunks=2)
-        return s, n, aux["counters"]
+        counters = aux["counters"]
+        if "second" in aux:     # a family's second loss, as the CLI adds it
+            from mobilefinetuner_tpu.cli.expert_lora import add_second_loss
+            s, counters = add_second_loss(s, n, counters, aux["second"],
+                                          p["lm_head"], mb["labels"])
+        return s, n, counters
 
     step = make_train_step(loss_fn, tc, mask=mask, donate=True)
     opt = init_optimizer(lora, tc, mask)
@@ -190,6 +202,16 @@ def prog_train_ling_lora():
     return _prog_expert_lora(
         ling_hybrid, LingHybridConfig.tiny().with_expert_parallel(4, 1),
         init_lora_ling, LING_SCOPES)
+
+
+def prog_train_xing4_lora():
+    """Xing4.0, every expert held, the multi-token-prediction module's
+    second loss in the step."""
+    from mobilefinetuner_tpu.core.config import Xing4Config
+    from mobilefinetuner_tpu.lora.lora import init_lora_xing4
+    from mobilefinetuner_tpu.models import xing4
+    return _prog_expert_lora(xing4, Xing4Config.tiny(), init_lora_xing4,
+                             XING4_SCOPES)
 
 
 def prog_train_gpt2_fsdp():
@@ -458,6 +480,7 @@ PROGRAMS = {
     "train_gpt2_lora": prog_train_gpt2_lora,
     "train_afmoe_lora": prog_train_afmoe_lora,
     "train_ling_lora": prog_train_ling_lora,
+    "train_xing4_lora": prog_train_xing4_lora,
     "train_gpt2_fsdp": prog_train_gpt2_fsdp,
     "decode_gpt2_paged": prog_decode_gpt2_paged,
     "decode_gpt2_paged_tp": prog_decode_gpt2_paged_tp,

@@ -198,7 +198,12 @@ class MetricsRegistry:
                           "blocks_in_use",
                           # PR 30: does the paged kernel's live range
                           # engage (its chunk size: run_start below)
-                          "paged_live_chunk_share"):
+                          "paged_live_chunk_share",
+                          # PR 34: does the serve loop run ahead (the
+                          # carried share of decode dispatches), and
+                          # what a late EOS costs
+                          "steps_carried", "steps_uploaded",
+                          "eos_discarded_tokens"):
                     self._gauge(f"mft_serve_{f}", g(f))
                 # page-pool occupancy: fraction of allocatable pages
                 # held by live requests (parked cache pages count free)

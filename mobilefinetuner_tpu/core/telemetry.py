@@ -292,6 +292,14 @@ EVENT_SCHEMA: Dict[str, Dict[str, tuple]] = {
         # serves); the chunk's size is run_start.config
         # .paged_pages_per_step
         "paged_live_chunk_share": _OPT_NUM,
+        # PR 34 (optional on read): how often the serve loop's
+        # run-ahead engages — cumulative decode dispatches that took
+        # tok/pos from the previous step's outputs on the device /
+        # from the host arrays (they sum to `step`), and row-steps run
+        # past an EOS the host saw one step late (discarded)
+        "steps_carried": _OPT_NUM,
+        "steps_uploaded": _OPT_NUM,
+        "eos_discarded_tokens": _OPT_NUM,
     },
     # one memory-admission verdict (core/memory_guard.py, DESIGN.md
     # §21): immediately post-compile (phase=preflight), on a caught
@@ -523,7 +531,9 @@ OPTIONAL_FIELDS: Dict[str, frozenset] = {
                              "drained_ms", "drained_parts_ms"}),
     "serve_stats": frozenset({"hbm_mb", "pool_mb", "mesh",
                               "prefix_hit_rate", "cow_copies",
-                              "blocks_in_use", "paged_live_chunk_share"}),
+                              "blocks_in_use", "paged_live_chunk_share",
+                              "steps_carried", "steps_uploaded",
+                              "eos_discarded_tokens"}),
     "run_end": frozenset({"goodput", "reason"}),
     "checkpoint": frozenset({"snapshot_ms", "write_ms", "bytes", "mb_s",
                              "async"}),

@@ -89,6 +89,16 @@ invariant tests/test_serve_robustness.py asserts after every injected
 fault. None of this touches the compiled programs: rejects, timeouts,
 sheds, containment, and drain are host-side bookkeeping, so the ≤2
 post-warmup trace invariant holds across every fault path.
+
+PR 34 (DESIGN.md §16): the loop runs ONE DECODE STEP AHEAD of the host.
+The compiled step hands back its own next inputs (`nxt`, `pos + 1`), so
+a steady turn dispatches step k+1 from device arrays and only then
+reads step k's tokens; slot state the host touches (admission, finish,
+release, cancel, expiry, containment, shutdown) marks the carried state
+stale, and a stale turn folds the step in flight in first and feeds the
+next dispatch from the host arrays, as every turn did before. Same
+executable, same tokens; `steps_carried` / `steps_uploaded` /
+`eos_discarded_tokens` in health() say how often it engages.
 """
 
 from __future__ import annotations
@@ -373,6 +383,17 @@ class Request:
                 / (len(self.tokens) - 1) * 1000.0)
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A decode step dispatched and not yet folded into the host's
+    state: its tokens (a device array whose copy to the host was
+    started at dispatch), the rows it decoded — (request, slot) as they
+    stood when it was queued — and the dispatch's clock reading."""
+    nxt: jax.Array
+    rows: List[tuple]
+    t0: float
+
+
 class ServeEngine:
     """The serving loop. Drive it with submit() + step() (or drain());
     close() terminates the telemetry stream.
@@ -542,7 +563,25 @@ class ServeEngine:
         self._key = np.zeros((S, 2), np.uint32)
         self._slots: List[Optional[Request]] = [None] * S
         self.queue: collections.deque = collections.deque()
-        self.decode_steps = 0
+        self.decode_steps = 0          # decode steps DISPATCHED
+        # --- run-ahead state (PR 34): the loop dispatches decode step
+        # k+1 before it reads step k's tokens. `_stale` says the host
+        # touched slot state since the last dispatch (an admission, a
+        # release): the next dispatch then takes tok/pos/tbl/aid from
+        # the host arrays above; else it takes tok/pos from the last
+        # dispatch's own outputs (`_carry`) and tbl/aid/sampling from
+        # the device arrays kept in `_placed`, the table alone placed
+        # again on a turn that appended a page. `_inflight` is the step
+        # dispatched and not yet folded into the host's state.
+        self._stale = True
+        self._tbl_dirty = False        # a page was appended since
+        self._carry: tuple = ()        # (tok, pos) of the next step
+        self._placed: list = []        # [tbl, aid, *sampling]
+        self._inflight: Optional[_InFlight] = None
+        self._t_fold = 0.0             # when the last step's tokens landed
+        self.steps_carried = 0         # dispatches fed from the device
+        self.steps_uploaded = 0        # dispatches fed from the host
+        self.eos_discarded_tokens = 0  # row-steps run past a late EOS
         self._next_id = 0
         self._t0 = time.perf_counter()
         # --- robustness state (round 14) --------------------------------
@@ -609,7 +648,13 @@ class ServeEngine:
                 nxt = _select(logits, pos + 1, *samp)
             else:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return nxt, pk, pv
+            # the step hands back its own next inputs: a live row (its
+            # table holds a page) feeds the token it emitted at the
+            # next position; an idle row stays the data it is (tok 0,
+            # pos 0), however many steps are carried
+            live = tbl[:, 0] != TRASH_BLOCK
+            return (jnp.where(live, nxt, 0), jnp.where(live, pos + 1, 0),
+                    pk, pv)
 
         def write_py(pool_k, pool_v, k, v, block_ids):
             self.trace_counts["write_prefill"] += 1
@@ -655,7 +700,7 @@ class ServeEngine:
         self._step = jax.jit(
             step_py, donate_argnums=(2, 3) if donate else (),
             out_shardings=None if shd is None
-            else (shd.repl, pool_sh, pool_sh))
+            else (shd.repl, shd.repl, pool_sh, pool_sh))
         self._write = jax.jit(
             write_py, donate_argnums=(0, 1) if donate else (),
             out_shardings=None if shd is None else (pool_sh, pool_sh))
@@ -1110,6 +1155,7 @@ class ServeEngine:
         self._tok[slot], self._pos[slot] = tok0, P
         self._tbl[slot] = TRASH_BLOCK
         self._tbl[slot, :len(req.blocks)] = req.blocks
+        self._stale = True
         if self.prefix is not None:
             # every FULL prompt block this prefill computed is now
             # shareable (first writer wins on races); decode never
@@ -1160,6 +1206,7 @@ class ServeEngine:
         self._pos[slot] = P - 1
         self._tbl[slot] = TRASH_BLOCK
         self._tbl[slot, :len(req.blocks)] = req.blocks
+        self._stale = True
         if self.tracer.enabled:
             # no prefill span: the whole prompt came from cached pages
             self._req_span(
@@ -1235,6 +1282,7 @@ class ServeEngine:
         self._tok[slot], self._pos[slot] = tok0, P
         self._tbl[slot] = TRASH_BLOCK
         self._tbl[slot, :len(req.blocks)] = req.blocks
+        self._stale = True
         if self.prefix is not None:
             for key, b in zip(req.cache_keys, req.blocks):
                 self.prefix.register(key, b)
@@ -1252,6 +1300,7 @@ class ServeEngine:
         if s < 0:   # admission died before the slot was taken: nothing
             return  # slot-side to clean (containment path)
         self._slots[s] = None
+        self._stale = True
         self._tok[s] = self._pos[s] = self._aid[s] = 0
         self._tbl[s] = TRASH_BLOCK
         if self.cfg.sampling:
@@ -1304,6 +1353,9 @@ class ServeEngine:
         # that died after dispatch may have invalidated the donated
         # buffers (and their contents described only the dead requests)
         self.pool_k, self.pool_v = self._init_pools()
+        # the step in flight died with the pools it wrote: its tokens
+        # belong to requests that have just failed
+        self._inflight, self._stale = None, True
         if self.prefix is not None:
             # the rebuilt pools hold NONE of the cached contents: drop
             # every mapping and parked page (the releases above just
@@ -1321,6 +1373,41 @@ class ServeEngine:
         14: timeout, error, and shutdown-rejected) — filter on
         `.state` when only completions matter.
 
+        The loop runs ONE STEP AHEAD of the host (PR 34): a turn
+        dispatches decode step k+1 and only then reads step k's tokens,
+        so the host's reading, bookkeeping and the caller's own turn
+        overlap the device's next step. The step's per-slot state
+        stays on the device: step k+1 takes `tok` / `pos` from step k's
+        outputs, and the table, adapter ids and sampling arrays from
+        device arrays placed again only when the host changed them.
+        The order of a turn follows from what the engine sees of its
+        own state:
+
+          steady   nothing touched slot state since the last dispatch:
+                   serve.expire, serve.page_append (from the host's
+                   `_pos`, advanced at dispatch), serve.upload (the
+                   table, if a page was appended), serve.dispatch of
+                   k+1, serve.sync + serve.emit of k;
+          stale    an admission, a finish, a release, a cancel, an
+                   expiry, a contained failure or a shutdown touched
+                   slot state — or the step in flight completes a
+                   request by LENGTH, which the host counts without
+                   reading a token: serve.sync + serve.emit of k
+                   first, then expire / admit, then serve.upload of
+                   tok/pos/tbl/aid from the host arrays and
+                   serve.dispatch of k+1, left in flight;
+          fill     nothing in flight (the first step; after a drain):
+                   a stale turn without its sync and emit.
+
+        So a request's tokens reach `Request.tokens` one device step
+        after the device made them, a row never runs a step past its
+        last position, and a request ending by `eos_id` is seen one
+        step late: the row's one extra token is discarded
+        (`eos_discarded_tokens`), never appended. A slot stays taken
+        until its last token is folded in, and an engine that is `idle`
+        has nothing in flight. `steps_carried` / `steps_uploaded` count
+        the dispatches of either kind.
+
         The iteration is one `serve.step` span (core/trace.py) whose
         children name its phases: serve.expire, serve.admit (one per
         admitted request; serve.prefill inside), serve.prefill_chunk,
@@ -1332,6 +1419,12 @@ class ServeEngine:
     def _step_phases(self) -> List[Request]:
         cfg, span = self.cfg, self.tracer.span
         done: List[Request] = []
+        # a turn that touches the rows of the step in flight — or
+        # follows one that did — folds that step's tokens in FIRST
+        if self._inflight is not None and (
+                self._stale or self._turn_ends_a_row(time.perf_counter())):
+            if not self._guard(self._settle, done):
+                return done
         with span("serve.expire", "loop"):
             # a preemption signal is observed at the step boundary
             # (never inside a dispatch): stop admissions, reject the
@@ -1415,6 +1508,11 @@ class ServeEngine:
             if req.done:                 # eos/cap on the final chunk
                 done.append(req)
 
+        # slot state touched THIS turn (an admission, a final chunk, an
+        # expiry) while a step was in flight: its tokens first, so the
+        # host arrays the next dispatch is fed from are whole
+        if self._stale and not self._guard(self._settle, done):
+            return done
         # mid-prefill requests hold idle slot data: the compiled step
         # runs over every slot regardless, but only completed-prefill
         # rows advance host-side
@@ -1434,37 +1532,104 @@ class ServeEngine:
                             f"reservation accounting failed for request "
                             f"{req.id}: {e}") from e
                     self._tbl[req.slot, j] = req.blocks[-1]
+                    self._tbl_dirty = True
 
-        t_step = time.perf_counter()
+        if not self._guard(self._decode_turn, done, live):
+            return done
+        if cfg.stats_every and self.decode_steps % cfg.stats_every == 0:
+            with span("serve.stats", "loop"):
+                self.emit_stats()
+        return done
+
+    def _guard(self, phase: Callable, done: List[Request], *args) -> bool:
+        """Run one decode phase of the turn under crash containment;
+        False where it failed and was contained (the turn ends)."""
         try:
-            if self.step_hook is not None:
-                self.step_hook(self.decode_steps)
-            with span("serve.upload", "loop"):
-                args = self._step_args()
-            with span("serve.dispatch", "loop"):
-                nxt, pool_k, pool_v = self._step(*args)
-            with span("serve.sync", "loop"):
-                # graftlint: disable=sync-hazard(the serve loop's ONE host sync per decode step: this step's tokens drive host-side scheduling)
-                nxt = np.asarray(nxt)
+            phase(done, *args)
+            return True
         except (KeyboardInterrupt, SystemExit):
             raise
         except Exception as e:
             done.extend(self._contain_step_error(e))
-            if cfg.on_step_error == "raise":
+            if self.cfg.on_step_error == "raise":
                 raise
-            return done
-        with span("serve.emit", "loop"):
-            self.pool_k, self.pool_v = pool_k, pool_v
-            self.decode_steps += 1
+            return False
+
+    def _turn_ends_a_row(self, now: float) -> bool:
+        """Whether this turn will let go of a row of the step in
+        flight, as far as the host can tell WITHOUT that step's tokens:
+        the step completes a request by length (the host counts), or a
+        resident's deadline has passed."""
+        return any(len(r.tokens) + 1 >= r.max_new_tokens
+                   for r, s in self._inflight.rows if self._slots[s] is r) \
+            or any(r.deadline_t and now >= r.deadline_t
+                   for r in self.active)
+
+    def _decode_turn(self, done: List[Request], live: List[Request]) -> None:
+        """Dispatch the next decode step over `live`, THEN fold in the
+        step that was in flight (a stale turn folded it in before it
+        touched slot state, and has nothing left to fold)."""
+        span = self.tracer.span
+        if self.step_hook is not None:
+            self.step_hook(self.decode_steps)
+        prev, t0 = self._inflight, time.perf_counter()
+        carried = not self._stale
+        with span("serve.upload", "loop"):
+            args = self._step_args()
+        with span("serve.dispatch", "loop"):
+            # the pools are donated: rebound from the dispatch at once
+            # (a prefill, a chunk or the next step may need them before
+            # this step's tokens are read)
+            nxt, pos, self.pool_k, self.pool_v = self._step(*args)
+            nxt.copy_to_host_async()
+        self._carry, self._stale = (nxt, pos), False
+        self._inflight = _InFlight(nxt, [(r, r.slot) for r in live], t0)
+        for req in live:
+            self._pos[req.slot] += 1     # as the device counts it
+        self.decode_steps += 1
+        if carried:
+            self.steps_carried += 1
+        else:
+            self.steps_uploaded += 1
+        if prev is not None:
+            self._fold(prev, done)
+            if not any(self._slots[s] is r for r, s in self._inflight.rows):
+                # a late EOS ended the last row of the step just queued:
+                # nobody will read it, and an idle engine has nothing
+                # in flight
+                self._settle(done)
+
+    def _settle(self, done: List[Request]) -> None:
+        """Fold the step in flight, where there is one, into the host's
+        state, and leave nothing in flight."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._fold(step, done)
+
+    def _fold(self, step: _InFlight, done: List[Request]) -> None:
+        """A dispatched step's tokens into the host's state: wait for
+        them, then append them, stamp first tokens and finish what
+        ended. A row the host let go of after the step was queued (an
+        EOS seen one step late, a cancel, an expiry) is skipped: its
+        token is not the request's."""
+        with self.tracer.span("serve.sync", "loop"):
+            # graftlint: disable=sync-hazard(the serve loop's ONE host sync per decode step, taken after the NEXT step is queued: this step's tokens drive host-side scheduling)
+            nxt = np.asarray(step.nxt)
+        with self.tracer.span("serve.emit", "loop"):
+            # the step's time as the host can see it: from its dispatch,
+            # or from the landing of the step it was queued behind
+            now = time.perf_counter()
+            step_s = now - max(step.t0, self._t_fold)
+            self._t_fold = now
             with self._health_lock:
-                self._step_ms.append(
-                    (time.perf_counter() - t_step) * 1000.0)
+                self._step_ms.append(step_s * 1000.0)
             if self.watchdog is not None:
-                self.watchdog.pet(self.decode_steps,
-                                  time.perf_counter() - t_step)
-            for req in live:
-                s = req.slot
-                self._pos[s] += 1
+                self.watchdog.pet(self.decode_steps, step_s)
+            for req, s in step.rows:
+                if self._slots[s] is not req:
+                    if req.state == "finished":
+                        self.eos_discarded_tokens += 1
+                    continue
                 self._tok[s] = int(nxt[s])
                 req.tokens.append(int(nxt[s]))
                 if req.awaiting_first:
@@ -1479,23 +1644,28 @@ class ServeEngine:
                         or len(req.tokens) >= req.max_new_tokens:
                     self._finish(req)
                     done.append(req)
-        if cfg.stats_every and self.decode_steps % cfg.stats_every == 0:
-            with span("serve.stats", "loop"):
-                self.emit_stats()
-        return done
 
     def _step_args(self) -> list:
-        """The decode step's operands from the engine's live state."""
-        args = [self.params, self.bank.tree if self.bank else None,
-                self.pool_k, self.pool_v,
-                self._dev(self._tok), self._dev(self._pos),
-                self._dev(self._tbl), self._dev(self._aid)]
-        if self.cfg.sampling:
-            # sampling state rides AFTER the legacy args so the pool
-            # donation indices (2, 3) never move
-            args += [self._dev(self._temp), self._dev(self._topk),
-                     self._dev(self._topp), self._dev(self._key)]
-        return args
+        """The decode step's operands from the engine's live state:
+        slot state the host touched since the last dispatch is placed
+        from the host arrays, the rest are the device arrays the last
+        dispatch left. Every host array is COPIED on its way: the
+        device array outlives this turn, the host array is written in
+        place on the next, and a backend may alias what it was given."""
+        put = lambda a: self._dev(a.copy())
+        if self._stale:
+            self._carry = (put(self._tok), put(self._pos))
+            self._placed = [None, put(self._aid)]
+            if self.cfg.sampling:
+                # sampling state rides AFTER the legacy args so the pool
+                # donation indices (2, 3) never move
+                self._placed += [put(self._temp), put(self._topk),
+                                 put(self._topp), put(self._key)]
+        if self._stale or self._tbl_dirty:
+            self._placed[0] = put(self._tbl)
+            self._tbl_dirty = False
+        return [self.params, self.bank.tree if self.bank else None,
+                self.pool_k, self.pool_v, *self._carry, *self._placed]
 
     def lower_decode_step(self):
         """The decode step lowered at the engine's live shapes, for
@@ -1538,6 +1708,7 @@ class ServeEngine:
         decoding — step()/drain() finish them. Returns the rejected
         requests. Idempotent once draining."""
         self.draining = True
+        self._stale = True
         out: List[Request] = []
         while self.queue:
             req = self.queue.popleft()
@@ -1589,6 +1760,13 @@ class ServeEngine:
             "cow_copies": self.cow_copies,
             "parked_blocks": self.alloc.parked_blocks,
             "paged_live_chunk_share": self._live_chunk_share(),
+            # PR 34, how often the run-ahead engages: decode dispatches
+            # fed from the device's own outputs / from the host arrays
+            # (they sum to decode_steps), and the row-steps run past an
+            # EOS the host saw one step late (discarded, never emitted)
+            "steps_carried": self.steps_carried,
+            "steps_uploaded": self.steps_uploaded,
+            "eos_discarded_tokens": self.eos_discarded_tokens,
             "counts": {s: int(self.counts.get(s, 0))
                        for s in Request.TERMINAL},
         }
@@ -1626,6 +1804,9 @@ class ServeEngine:
             prefix_hit_rate=h["prefix_hit_rate"],
             cow_copies=h["cow_copies"],
             paged_live_chunk_share=h["paged_live_chunk_share"],
+            steps_carried=h["steps_carried"],
+            steps_uploaded=h["steps_uploaded"],
+            eos_discarded_tokens=h["eos_discarded_tokens"],
             **h["counts"])
 
     # ------------------------------------------------------------ teardown --
@@ -1636,6 +1817,11 @@ class ServeEngine:
         reads a served SIGTERM exactly like a trained one."""
         if self._closed:
             return
+        if self._inflight is not None:
+            # closed with requests still resident (a drained engine has
+            # nothing in flight): read the step out so nothing is left
+            # running; what it finishes is in the stream
+            self._guard(self._settle, [])
         self._closed = True
         if self.guard is not None:
             self.guard.uninstall()

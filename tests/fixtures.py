@@ -7,6 +7,7 @@ SURVEY.md §4.2)."""
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 
@@ -24,6 +25,26 @@ from mobilefinetuner_tpu.io.checkpoints import (afmoe_params_to_hf,
 from mobilefinetuner_tpu.io.safetensors_io import save_safetensors
 from mobilefinetuner_tpu.models import (afmoe, gemma3, gpt2, ling_hybrid,
                                         xing4)
+
+@contextlib.contextmanager
+def backend_compiles():
+    """The backend compilations of this process while the block runs, as
+    a list that grows (jax.monitoring, the way the benchmark's
+    `harness/serve_engine.CompileCounter` counts them): a second
+    lowering of a program whose trace is cached shows here and nowhere
+    in `trace_counts`."""
+    seen = []
+
+    def on(event, *_a, **_k):
+        if event.endswith("backend_compile_duration"):
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+
 
 WIKI_LINES = [
     " = Heading = ",

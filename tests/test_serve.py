@@ -13,6 +13,7 @@ import dataclasses
 import json
 import os
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -603,6 +604,247 @@ def test_enqueue_event_reports_tenant_slot(gemma_params, tmp_path):
     assert ev[(rb.id, "enqueue")]["adapter"] == 1
     assert ev[(rb.id, "cancel")]["adapter"] == 1
     assert ev[(r0.id, "enqueue")]["adapter"] is None
+
+
+# --------------------------- run-ahead (PR 34) -------------------------------
+# The loop dispatches decode step k+1 before it reads step k's tokens;
+# slot state is carried on the device between host touches. What that
+# may never change: a request's tokens, its length, the one executable.
+
+def sampled_reference(params, prompt, n, temperature, top_k, top_p, seed):
+    """A seeded-sampling request replayed WITHOUT the engine: the full
+    forward over prompt + tokens so far, then the engine's convention —
+    the request's raw key folded with the emitted token's ABSOLUTE
+    position — through `sample_per_row`."""
+    from mobilefinetuner_tpu.models.generate import sample_per_row
+    key = jnp.asarray([[(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF]],
+                      jnp.uint32)
+    ids, out = list(prompt), []
+    for _ in range(n):
+        logits = gpt2.forward(GPT2_CFG, params,
+                              jnp.asarray([ids], jnp.int32))[:, -1]
+        folded = jax.vmap(jax.random.fold_in)(
+            key, jnp.asarray([len(ids)], jnp.int32))
+        tok = int(sample_per_row(
+            logits, jnp.asarray([temperature], jnp.float32),
+            jnp.asarray([top_k], jnp.int32),
+            jnp.asarray([top_p], jnp.float32), folded)[0])
+        out.append(tok)
+        ids.append(tok)
+    return out
+
+
+def assert_run_ahead_accounting(eng, min_uploaded):
+    """After a drain: nothing in flight, every dispatch counted once as
+    carried or uploaded, both kinds seen, nothing leaked."""
+    h = eng.health()
+    assert eng.idle and eng._inflight is None
+    assert h["steps_carried"] + h["steps_uploaded"] == h["decode_steps"]
+    assert h["steps_carried"] > 0 and h["steps_uploaded"] >= min_uploaded
+    assert eng.alloc.in_use == 0
+    assert eng.trace_counts["decode_step"] == 1
+
+
+def test_run_ahead_matches_generate_gpt2_with_a_sampled_row(gpt2_params):
+    """Seven requests over three slots (>= 3 turnovers), pages of four
+    tokens (every row crosses pages while its state is carried), ragged
+    lengths, one seeded-sampling request in the mix: greedy rows equal
+    generate(), the sampled row equals its replay, to the token."""
+    eng = ServeEngine(
+        "gpt2", GPT2_CFG, gpt2_params,
+        ServeConfig(num_slots=3, block_T=4, num_blocks=64, max_prompt=16,
+                    max_new_tokens=12, sampling=True))
+    rng = np.random.default_rng(11)
+    lens = ((5, 12), (9, 7), (2, 10), (13, 12), (7, 3), (3, 9), (11, 6))
+    samp = dict(temperature=0.9, top_k=40, top_p=0.95, seed=(7 << 32) | 1234)
+    reqs = [eng.submit(list(rng.integers(1, 200, n)), max_new_tokens=m,
+                       **(samp if i == 3 else {}))
+            for i, (n, m) in enumerate(lens)]
+    eng.drain()
+    for i, r in enumerate(reqs):
+        assert r.state == "finished" and len(r.tokens) == r.max_new_tokens
+        if i == 3:
+            want = sampled_reference(
+                gpt2_params, r.prompt, r.max_new_tokens, samp["temperature"],
+                samp["top_k"], samp["top_p"], samp["seed"])
+            assert want != oracle("gpt2", gpt2_params, r)   # it sampled
+        else:
+            want = oracle("gpt2", gpt2_params, r)
+        assert r.tokens == want, f"req {r.id}"
+    assert_run_ahead_accounting(eng, min_uploaded=4)
+    assert eng.health()["eos_discarded_tokens"] == 0
+    eng.close()
+
+
+def test_run_ahead_matches_per_adapter_generate_gemma(gemma_params):
+    """The same for the sliding-window family with adapters routed per
+    slot: six requests over two slots, two tenants and the base."""
+    a1, a2 = rand_lora(5), rand_lora(9)
+    eng = ServeEngine(
+        "gemma", GEMMA_CFG, gemma_params,
+        ServeConfig(num_slots=2, block_T=4, num_blocks=64, max_prompt=24,
+                    max_new_tokens=10),
+        bank=AdapterBank(rand_lora(5), capacity=2))
+    eng.load_adapter("t1", a1)
+    eng.load_adapter("t2", a2)
+    rng = np.random.default_rng(2)
+    route = ["t1", "t2", None, "t1", "t2", None]
+    trees = {"t1": a1, "t2": a2, None: None}
+    reqs = [eng.submit(list(rng.integers(3, 190, n)), max_new_tokens=m,
+                       adapter=a)
+            for (n, m), a in zip(((7, 9), (18, 5), (11, 10), (4, 7),
+                                  (21, 8), (9, 4)), route)]
+    eng.drain()
+    for r, a in zip(reqs, route):
+        assert r.tokens == oracle("gemma", gemma_params, r,
+                                  lora=trees[a]), f"req {r.id} ({a})"
+    assert_run_ahead_accounting(eng, min_uploaded=4)
+    eng.close()
+
+
+def test_late_eos_discards_one_token_and_frees_the_row(gpt2_params):
+    """A request whose EOS comes while the next step is already queued:
+    it finishes with the EOS as its last token, the one row-step past
+    it is discarded (counted, never appended), its pages and slot are
+    free, and the neighbour's tokens are what they are without it."""
+    cfg = ServeConfig(num_slots=2, block_T=4, num_blocks=32, max_prompt=16,
+                      max_new_tokens=12)
+    rng = np.random.default_rng(3)
+    for _ in range(32):
+        probe = list(rng.integers(1, 200, 6))
+        other = list(rng.integers(1, 200, 4))
+        row = oracle("gpt2", gpt2_params, types.SimpleNamespace(
+            prompt=probe, max_new_tokens=8))
+        js = [j for j in range(1, 6) if row[j] not in row[:j]]
+        if not js:
+            continue
+        eos = row[js[0]]
+        far = oracle("gpt2", gpt2_params, types.SimpleNamespace(
+            prompt=other, max_new_tokens=12))
+        if eos not in far:
+            break
+    else:
+        pytest.fail("no probe with a fresh token before the cap and a "
+                    "neighbour that never emits it")
+    j = js[0]
+    eng = ServeEngine("gpt2", GPT2_CFG, gpt2_params, cfg, eos_id=eos)
+    short = eng.submit(probe, max_new_tokens=8)
+    long_ = eng.submit(other, max_new_tokens=12)
+    while short.state == "active" or short.state == "queued":
+        eng.step()
+    assert short.state == "finished" and short.tokens == row[:j + 1]
+    assert short.tokens[-1] == eos and not short.blocks
+    assert eng._slots[short.slot] is None and long_.state == "active"
+    eng.drain()
+    assert long_.tokens == far and len(long_.tokens) == 12
+    h = eng.health()
+    assert h["eos_discarded_tokens"] == 1
+    assert_run_ahead_accounting(eng, min_uploaded=2)
+    eng.close()
+
+
+@pytest.mark.parametrize("slots", [1, 2])
+def test_length_end_is_foreseen_to_the_token(gpt2_params, slots):
+    """A request ending by length holds exactly `max_new_tokens` tokens
+    and its row never runs a step past its last position: with an EOS
+    configured that nobody emits, nothing is discarded — and with one
+    slot the engine dispatches exactly sum(max_new - 1) decode steps."""
+    eng = ServeEngine(
+        "gpt2", GPT2_CFG, gpt2_params,
+        ServeConfig(num_slots=slots, block_T=4, num_blocks=32,
+                    max_prompt=16, max_new_tokens=12), eos_id=210)
+    rng = np.random.default_rng(5)
+    reqs = [eng.submit(list(rng.integers(1, 200, n)), max_new_tokens=m)
+            for n, m in ((5, 1), (3, 2), (8, 12), (4, 5), (6, 3))]
+    eng.drain()
+    for r in reqs:
+        want = oracle("gpt2", gpt2_params, r)
+        assert 210 not in want
+        assert r.state == "finished" and r.tokens == want
+        assert len(r.tokens) == r.max_new_tokens
+    h = eng.health()
+    assert h["eos_discarded_tokens"] == 0
+    assert h["steps_carried"] + h["steps_uploaded"] == h["decode_steps"]
+    if slots == 1:
+        assert h["decode_steps"] == sum(r.max_new_tokens - 1 for r in reqs)
+    assert eng.idle and eng._inflight is None and eng.alloc.in_use == 0
+    eng.close()
+
+
+def test_close_and_drain_leave_nothing_in_flight(gpt2_params, tmp_path):
+    """drain() ends idle with no step queued; close() on an engine
+    abandoned mid-flight reads its step out first; and the counters
+    ride `serve_stats` and /metrics."""
+    from mobilefinetuner_tpu.core.metrics_http import MetricsRegistry
+    stream = str(tmp_path / "s.jsonl")
+    eng = ServeEngine(
+        "gpt2", GPT2_CFG, gpt2_params,
+        ServeConfig(num_slots=2, block_T=4, num_blocks=32, max_prompt=16,
+                    max_new_tokens=12, stats_every=2),
+        telemetry=Telemetry(stream))
+    reg = MetricsRegistry()
+    eng.telemetry.add_observer(reg.observe)
+    rng = np.random.default_rng(8)
+    for n in (5, 9, 3):
+        eng.submit(list(rng.integers(1, 200, n)), max_new_tokens=9)
+    eng.drain()
+    assert_run_ahead_accounting(eng, min_uploaded=2)
+    left = eng.submit(list(rng.integers(1, 200, 7)), max_new_tokens=9)
+    for _ in range(3):
+        eng.step()
+    assert eng._inflight is not None and left.state == "active"
+    n_before = len(left.tokens)
+    eng.close()
+    assert eng._inflight is None and len(left.tokens) == n_before + 1
+    assert left.tokens == oracle("gpt2", gpt2_params, left)[:n_before + 1]
+    with open(stream) as f:
+        recs = [json.loads(l) for l in f.read().splitlines() if l.strip()]
+    for rec in recs:
+        assert validate_event(rec) is None, (rec, validate_event(rec))
+    stats = [r for r in recs if r["event"] == "serve_stats"]
+    assert stats and all(
+        r["steps_carried"] + r["steps_uploaded"] == r["step"]
+        and r["eos_discarded_tokens"] == 0 for r in stats)
+    assert stats[-1]["steps_carried"] > 0
+    text = reg.render()
+    for name in ("steps_carried", "steps_uploaded", "eos_discarded_tokens"):
+        assert f"mft_serve_{name} " in text
+
+
+def test_carried_and_stale_turns_share_one_executable(gpt2_params):
+    """The arrays a dispatch hands back and the ones the host places
+    must reach the decode step alike: after the first stale and the
+    first carried turn no turn of either kind — page appends, turnovers,
+    a cancel — traces or compiles anything."""
+    from fixtures import backend_compiles
+    eng = ServeEngine(
+        "gpt2", GPT2_CFG, gpt2_params,
+        ServeConfig(num_slots=2, block_T=4, num_blocks=64, max_prompt=16,
+                    max_new_tokens=12, sampling=True))
+    rng = np.random.default_rng(13)
+    prompt = lambda n: list(rng.integers(1, 200, n))
+    for n in (5, 9, 3):                  # warm: every program, both turns
+        eng.submit(prompt(n), max_new_tokens=6)
+    eng.drain()
+    assert eng.steps_carried > 0 and eng.steps_uploaded > 0
+    carried0, uploaded0 = eng.steps_carried, eng.steps_uploaded
+    traces0 = eng.total_traces()
+    with backend_compiles() as compiles:
+        reqs = [eng.submit(prompt(n), max_new_tokens=m, temperature=t,
+                           seed=n)
+                for n, m, t in ((4, 12, 0.0), (11, 7, 0.8), (6, 12, 0.0),
+                                (2, 9, 0.0), (13, 5, 0.7))]
+        eng.step()
+        eng.step()
+        eng.cancel(eng.active[0])
+        eng.drain()
+        assert compiles == []
+    assert eng.total_traces() == traces0
+    assert eng.trace_counts["decode_step"] == 1
+    assert eng.steps_carried - carried0 >= 8
+    assert eng.steps_uploaded - uploaded0 >= 4
+    assert all(r.done for r in reqs)
+    eng.close()
 
 
 def test_cpu_e2e_serve_bench_smoke(gpt2_params, tmp_path):

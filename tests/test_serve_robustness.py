@@ -187,8 +187,8 @@ def test_active_deadline_returns_partial_output(params, tmp_path):
                         deadline_ms=60_000.0)
     healthy = eng.submit(list(rng.integers(1, 200, 7)), max_new_tokens=8)
     eng.step()                      # admit (first token) + one decode
-    eng.step()
-    assert doomed.state == "active" and len(doomed.tokens) == 3
+    eng.step()                      # ...folded in behind the second
+    assert doomed.state == "active" and len(doomed.tokens) == 2
     # force the deadline into the past at a known boundary — the
     # wall-clock version of "the budget ran out mid-generation",
     # without a timing-dependent sleep
@@ -254,6 +254,61 @@ def test_step_error_fails_active_queue_survives(params, tmp_path):
         assert validate_event(rec) is None, (rec, validate_event(rec))
     assert any(r.get("event") == "serve_stats" for r in recs)
     assert_terminal_accounting(recs, [warm] + reqs, eng)
+
+
+@pytest.mark.parametrize("fault", ["cancel", "deadline", "step_error"])
+def test_fault_while_a_step_is_in_flight(params, tmp_path, fault):
+    """The loop runs one step ahead (PR 34), so a cancel, a deadline and
+    a dispatch failure each arrive while a decode step is queued on the
+    device: the victim keeps exactly the tokens folded in (a prefix of
+    its oracle), the in-flight step's other rows lose nothing, the
+    allocator balances, and whoever survives — the neighbour, or after
+    containment rebuilt the pools the queue — stays oracle-equal."""
+    eng = make_engine(params, tmp_path, num_slots=2, block_T=4)
+    rng = np.random.default_rng(17)
+    reqs = [eng.submit(list(rng.integers(1, 200, int(n))),
+                       max_new_tokens=8) for n in (5, 9, 3, 7)]
+    for _ in range(3):
+        eng.step()
+    victim, neighbour = reqs[0], reqs[1]
+    assert eng._inflight is not None and not eng._stale
+    assert victim.state == neighbour.state == "active"
+    seen = len(victim.tokens)
+    if fault == "cancel":
+        eng.cancel(victim)
+        assert len(victim.tokens) == seen and eng._inflight is not None
+        done = eng.step()
+    elif fault == "deadline":
+        victim.deadline_t = time.perf_counter() - 1e-3
+        done = eng.step()
+        assert victim in done and victim.reason == "deadline"
+    else:
+        def boom(step):
+            eng.step_hook = None    # one-shot
+            raise RuntimeError("injected")
+        eng.step_hook = boom
+        done = eng.step()
+        assert victim in done and neighbour in done
+        assert neighbour.state == "error" and eng._inflight is None
+    want = {"cancel": "cancelled", "deadline": "timeout",
+            "step_error": "error"}[fault]
+    assert victim.state == want and not victim.blocks
+    assert victim.tokens == oracle(params, victim)[:len(victim.tokens)]
+    assert seen <= len(victim.tokens) <= seen + 1
+    eng.drain()
+    for r in reqs:
+        if r.state == "finished":
+            assert r.tokens == oracle(params, r), f"req {r.id}"
+        else:
+            assert r.tokens == oracle(params, r)[:len(r.tokens)]
+    assert [r.state for r in reqs[2:]] == ["finished", "finished"]
+    assert (neighbour.state == "finished") == (fault != "step_error")
+    h = eng.health()
+    assert eng._inflight is None and h["eos_discarded_tokens"] == 0
+    assert h["steps_carried"] + h["steps_uploaded"] == h["decode_steps"]
+    assert eng.trace_counts["decode_step"] == 1
+    eng.close()
+    assert_terminal_accounting(read_events(eng.telemetry.path), reqs, eng)
 
 
 def test_on_step_error_raise_policy(params, tmp_path):
@@ -446,9 +501,12 @@ def test_watchdog_fires_on_injected_hang(params, tmp_path):
     try:
         warm = eng.submit([1, 2, 3], max_new_tokens=2)
         eng.drain()
+        # the third dispatch of the request: the loop runs one step
+        # ahead, so by then two of its steps have been folded in and
+        # the watchdog's median is a warm step's, not the compile's
         serve_bench.install_inject(
-            eng, f"hang:{eng.decode_steps + 1}:1.2")
-        req = eng.submit([4, 5, 6, 7], max_new_tokens=4)
+            eng, f"hang:{eng.decode_steps + 3}:1.2")
+        req = eng.submit([4, 5, 6, 7], max_new_tokens=6)
         eng.drain()
     finally:
         wd.stop()

@@ -143,6 +143,40 @@ def test_sharded_pallas_path_token_parity(params, baseline):
     assert got == baseline["gpt2"]
 
 
+def test_sharded_run_ahead_is_one_executable(params):
+    """Under the mesh the decode step's carried operands (its own
+    outputs, pinned replicated) and the ones the host places
+    (`device_put(..., repl)`) must be the same kind of array: after the
+    first stale and the first carried turn nothing traces or compiles,
+    whichever kind a turn is."""
+    from fixtures import backend_compiles
+    eng = ServeEngine(
+        "gpt2", GPT2_CFG, params["gpt2"],
+        ServeConfig(num_slots=2, block_T=4, num_blocks=32, max_prompt=16,
+                    max_new_tokens=8, mesh_tp=2))
+    try:
+        rng = np.random.default_rng(3)
+        prompt = lambda n: rng.integers(1, 211, size=n).tolist()
+        for n in (5, 9, 3):
+            eng.submit(prompt(n), max_new_tokens=5)
+        eng.drain()
+        assert eng.steps_carried > 0 and eng.steps_uploaded > 0
+        carried0, uploaded0 = eng.steps_carried, eng.steps_uploaded
+        traces0 = eng.total_traces()
+        with backend_compiles() as compiles:
+            for n, m in ((4, 8), (11, 6), (6, 8), (2, 7)):
+                eng.submit(prompt(n), max_new_tokens=m)
+            eng.drain()
+            assert compiles == []
+        assert eng.total_traces() == traces0
+        assert eng.trace_counts["decode_step"] == 1
+        assert eng.steps_carried - carried0 >= 6
+        assert eng.steps_uploaded - uploaded0 >= 3
+        assert eng._inflight is None
+    finally:
+        eng.close()
+
+
 # ------------------------- placement unit tests --------------------------
 
 def test_shard_heads_axis_choice():

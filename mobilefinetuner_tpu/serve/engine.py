@@ -1119,27 +1119,32 @@ class ServeEngine:
         mask = np.zeros((1, cfg.max_prompt), np.int32)
         ids[0, :P], mask[0, :P] = req.prompt, 1
         bank_tree = self.bank.tree if self.bank else None
-        t_prefill = time.perf_counter()
-        with self.tracer.span("serve.prefill", "loop"):
-            tok0, k, v = self._prefill(
-                self.params, bank_tree, self._dev(ids), self._dev(mask),
-                # graftlint: disable=sync-hazard(host int wrapped for the device; nothing is pulled back)
-                self._dev(np.asarray([req.aid], np.int32)),
-                *self._samp_args(req))
-            # scatter the prompt pages; table rows past the prompt stay
-            # trash
-            block_ids = np.full(cfg.max_prompt // cfg.block_T, TRASH_BLOCK,
-                                np.int32)
-            block_ids[:len(req.blocks)] = req.blocks
-            # the write DONATES the pools (non-CPU): if it raises, the
-            # old buffers may already be consumed — flag the window so
-            # the admission containment knows one-victim recovery is not
-            # enough
-            self._pools_at_risk = True
-            self.pool_k, self.pool_v = self._write(
-                self.pool_k, self.pool_v, k, v, self._dev(block_ids))
-            self._pools_at_risk = False
-            tok0 = int(tok0)             # host sync: the first token
+        t_prefill, span = time.perf_counter(), self.tracer.span
+        # the prefill is cut where the host blocks: two dispatches that
+        # return at once, then the one wait of a turnover
+        with span("serve.prefill", "loop"):
+            with span("serve.prefill.dispatch", "loop"):
+                tok0, k, v = self._prefill(
+                    self.params, bank_tree, self._dev(ids), self._dev(mask),
+                    # graftlint: disable=sync-hazard(host int wrapped for the device; nothing is pulled back)
+                    self._dev(np.asarray([req.aid], np.int32)),
+                    *self._samp_args(req))
+            with span("serve.prefill.page_write", "loop"):
+                # scatter the prompt pages; table rows past the prompt
+                # stay trash
+                block_ids = np.full(cfg.max_prompt // cfg.block_T,
+                                    TRASH_BLOCK, np.int32)
+                block_ids[:len(req.blocks)] = req.blocks
+                # the write DONATES the pools (non-CPU): if it raises,
+                # the old buffers may already be consumed — flag the
+                # window so the admission containment knows one-victim
+                # recovery is not enough
+                self._pools_at_risk = True
+                self.pool_k, self.pool_v = self._write(
+                    self.pool_k, self.pool_v, k, v, self._dev(block_ids))
+                self._pools_at_risk = False
+            with span("serve.prefill.first_token", "loop"):
+                tok0 = int(tok0)         # host sync: the first token
         now = time.perf_counter()
         req.admit_t = req.first_token_t = now
         if self.tracer.enabled:
@@ -1410,9 +1415,14 @@ class ServeEngine:
 
         The iteration is one `serve.step` span (core/trace.py) whose
         children name its phases: serve.expire, serve.admit (one per
-        admitted request; serve.prefill inside), serve.prefill_chunk,
+        admitted request; serve.prefill inside, cut into
+        serve.prefill.dispatch, serve.prefill.page_write and
+        serve.prefill.first_token — the one-shot prefill's two
+        dispatches and its wait for the first token, the one place a
+        turnover blocks on the device), serve.prefill_chunk,
         serve.page_append, serve.upload, serve.dispatch, serve.sync,
-        serve.emit, serve.stats."""
+        serve.emit, serve.stats. A turn that admits nothing enters
+        none of the prefill's spans."""
         with self.tracer.span("serve.step", "loop"):
             return self._step_phases()
 

@@ -25,8 +25,9 @@ sys.path[:0] = [TESTS, os.path.join(REPO, "tools"),
                 os.path.join(REPO, "benchmark", "tests")]
 
 import test_span_readers as bench_cases  # noqa: E402  (benchmark/tests)
+import test_turn_readers as turn_cases  # noqa: E402  (benchmark/tests)
 from fixtures import write_tiny_gpt2_dir, write_wikitext_dir  # noqa: E402
-from harness import span_readers, xplane  # noqa: E402  (benchmark/)
+from harness import span_readers, turn_readers, xplane  # noqa: E402  (benchmark/)
 
 from mobilefinetuner_tpu.core.config import GPT2Config  # noqa: E402
 from mobilefinetuner_tpu.core.telemetry import (Telemetry,  # noqa: E402
@@ -120,14 +121,22 @@ SERVE_CFG = dataclasses.replace(
     n_layer=2, embd_pdrop=0.0, resid_pdrop=0.0, attn_pdrop=0.0)
 
 
+# what a steady turn enters, before and after the prefill was cut
+STEADY_SPANS = {"serve.expire", "serve.page_append", "serve.upload",
+                "serve.dispatch", "serve.sync", "serve.emit"}
+
+
 def test_serve_step_is_split_into_phases_and_causes_no_retrace(tmp_path):
     """A tiny ServeEngine under a session: every decode turn is exactly
     one `serve.step` holding one `serve.dispatch`; a STEADY turn
     dispatches step k+1 and then holds the one `serve.sync` and
     `serve.emit` of step k, a stale turn folds k in before it touches
     slot state and dispatches, a fill turn has nothing to fold; every
-    admission is a `serve.admit` holding its `serve.prefill`; the spans
-    cause no retrace; and the benchmark's reader finds the host turn."""
+    admission is a `serve.admit` holding its `serve.prefill`, which is
+    cut into its two dispatches and its first-token wait (PR 35) while
+    a steady turn enters the spans it entered before the cut; the spans
+    cause no retrace; and the benchmark's readers find the host turn,
+    the turnover and the carried share the engine counted."""
     params = gpt2.init_params(SERVE_CFG, jax.random.PRNGKey(0))
     eng = ServeEngine("gpt2", SERVE_CFG, params, ServeConfig(
         num_slots=2, block_T=8, num_blocks=16, max_prompt=16,
@@ -175,10 +184,32 @@ def test_serve_step_is_split_into_phases_and_causes_no_retrace(tmp_path):
     assert len(admits) == len(prefills) == 3
     assert all(a[0] <= p[0] and p[1] <= a[1]
                for a, p in zip(admits, prefills))
+    # every one-shot prefill: one of each child, in the order the code
+    # runs them, disjoint, and nothing of the prefill left between them
+    kids = [[c for s in steps for c in s["children"].get(name, ())]
+            for name in turn_readers.PREFILL_CHILDREN]
+    assert [len(k) for k in kids] == [3, 3, 3]
+    for p, *cut in zip(prefills, *kids):
+        assert p[0] <= cut[0][0] and cut[-1][1] <= p[1]
+        assert all(a[1] <= b[0] for a, b in zip(cut, cut[1:]))
+        assert sum(c[1] - c[0] for c in cut) >= 0.9 * (p[1] - p[0])
+    for s in steady:
+        assert set(s["children"]) == STEADY_SPANS
     obs = {"kind": "serve_engine"}
     turn = span_readers.serve_host_turn_ms(obs, prof.trace, {})
     assert turn is not None and turn > 0
     assert obs["notes"]["serve_turn"]["turns"] >= 2
+    # the new readers on the same trace: what the engine counted as
+    # carried is what the spans show as steady; no device plane here
+    assert turn_readers.serve_carried_share(obs, prof.trace, {}) \
+        == pytest.approx(100.0 * n_carried / n_decode)
+    counts = obs["notes"]["serve_turnover"]["turns"]
+    assert counts["steady"] == n_carried and counts["turnover"] >= 1
+    assert counts["steady"] + counts["stale"] + counts["fill"] \
+        + counts["turnover"] == n_decode
+    assert turn_readers.serve_turnover_ms(obs, prof.trace, {}) \
+        >= turn_readers.serve_first_token_wait_ms(obs, prof.trace, {}) > 0
+    assert turn_readers.serve_turnover_idle_ms(obs, prof.trace, {}) is None
 
 
 # --------------------------- the train loop ----------------------------------
@@ -298,6 +329,17 @@ def test_span_reader(reader, obs, trace, want, notes_ok):
     bench_cases.check(reader, obs, trace, want, notes_ok)
 
 
+@pytest.mark.parametrize("reader,obs,trace,want,notes_ok",
+                         [c[1:] for c in turn_cases.CASES],
+                         ids=[c[0] for c in turn_cases.CASES])
+def test_turn_reader(reader, obs, trace, want, notes_ok):
+    """benchmark/tests/test_turn_readers.py's cases, in tier-1: the four
+    kinds of turn, a device plane whose gaps straddle span boundaries,
+    a trace without the prefill's children, without a device plane,
+    without a `serve.step`."""
+    turn_cases.check(reader, obs, trace, want, notes_ok)
+
+
 def listed_with_its_reader(name: str) -> None:
     """A per-layer metric of BENCHMARK.json, wherever it stands in the
     list: its file names a callable reader and agrees with the entry."""
@@ -330,6 +372,20 @@ def test_the_expert_cell_s_layer_metrics_are_listed_with_their_readers(name):
     from harness import common
     assert common.named("layer_metrics", name)["workloads"] \
         == ["trinity_lora_s8192"]
+
+
+@pytest.mark.parametrize("name", ["serve_turnover_ms_batch",
+                                  "serve_first_token_wait_ms_batch",
+                                  "serve_turnover_idle_ms_batch",
+                                  "device_idle_turnover_share_batch",
+                                  "serve_carried_share_batch"])
+def test_the_turnover_s_layer_metrics_are_listed_with_their_readers(name):
+    """PR 35's five, each reported in `md_batchgen_long` alone."""
+    listed_with_its_reader(name)
+    from harness import common
+    spec = common.named("layer_metrics", name)
+    assert spec["workloads"] == ["md_batchgen_long"]
+    assert spec["moves"] == "serve_tokens_per_s"
 
 
 # --------------------------- what the spans replaced -------------------------

@@ -5,7 +5,8 @@ entry point like cli/train_lora_afmoe.py.
 
 What the family adds to `run_start.config`: `layer_kinds` (k = delta
 rule, m = latent attention, in layer order), `kda_chunk`,
-`kda_impl_resolved` (chunked | recurrent), `attention_impl_resolved`
+`kda_impl_resolved` (kernel | chunked | recurrent: read from the run's
+shapes, models/ling_hybrid.resolve_kda_impl), `attention_impl_resolved`
 (the latent layers' softmax attention: flash | xla) and
 `attn_latent_bwd_resolved` (merged | split, where flash); to
 `step_stats`: `moe_groups_here` beside the `moe_*` counters.
@@ -52,7 +53,8 @@ def resolve(args, config: LingHybridConfig) -> LingHybridConfig:
             c.v_head_dim)
     args.layer_kinds = ",".join(k[0] for k in c.layer_kinds)
     args.kda_chunk = c.kda_chunk
-    args.kda_impl_resolved = ling_hybrid.resolve_kda_impl(S, c.kda_chunk)
+    args.kda_impl_resolved = ling_hybrid.resolve_kda_impl(
+        S, c.kda_chunk, c.head_dim)
     log.info(f"attention_impl={args.attention_impl} -> {impl} (latent "
              f"layers; backward {args.attn_latent_bwd_resolved}); delta "
              f"rule -> {args.kda_impl_resolved} at chunk {c.kda_chunk}; "

@@ -156,10 +156,16 @@ def _l2(x):
     return xf * jax.lax.rsqrt((xf * xf).sum(-1, keepdims=True) + 1e-12)
 
 
-def resolve_kda_impl(S: int, chunk: int) -> str:
-    """The delta rule's 'auto': the chunked form wherever the length is
-    whole chunks, the token-by-token scan otherwise (odd test lengths)."""
-    return "chunked" if S % chunk == 0 else "recurrent"
+def resolve_kda_impl(S: int, chunk: int, head_dim: int) -> str:
+    """The delta rule's 'auto', read from shapes (ops/kda.resolve_impl):
+    `kernel` (the Pallas kernels) where the length is whole chunks and
+    the head whole 128-lane tiles, `chunked` (the same algebra as XLA
+    scans) where the length is whole chunks and the head is not,
+    `recurrent` (the token-by-token scan) otherwise (odd test lengths).
+    The kernels are not wrapped in shard_map, so GSPMD could not
+    partition them; the family's entry point (cli/expert_lora.main)
+    refuses a mesh of more than one device before anything is resolved."""
+    return kda.resolve_impl(S, chunk, head_dim, head_dim)
 
 
 def _kda_mixer(c, p, h, lora, dt):
@@ -183,7 +189,7 @@ def _kda_mixer(c, p, h, lora, dt):
             h, p["b_w"].astype(dt), preferred_element_type=F32)
         ).transpose(0, 2, 1)                              # [B, n, S]
     with jax.named_scope("kda_scan"):
-        if resolve_kda_impl(S, c.kda_chunk) == "chunked":
+        if resolve_kda_impl(S, c.kda_chunk, d) != "recurrent":
             o = kda.kda_chunked(q, k, v, g, beta, chunk=c.kda_chunk)
         else:
             o = kda.kda_recurrent(q, k, v, g, beta)[0].astype(dt)

@@ -34,14 +34,34 @@ chunk. Everything inside a block runs in float32 at "highest"
 precision — the pair products, the solve and the products with the
 state; q, k, v come in and o goes out in the activations' dtype.
 
-The backward is written by hand as a reverse scan over BLOCKS of
-`block_chunks` chunks (4: PERF.md section 6, PR 31, has the chip's
-readings at 2 to 32): the forward keeps only the state that enters each
-block (2 MB a block at 32 heads of 128 x 128), the backward recomputes a
-block from that state and pulls the cotangents of (state out, o) back
-through it — so no intermediate of the within-chunk algebra outlives its
-block, whatever S is. One block is the unit of batched work: its
-chunks' solves and pair products are one batched op each.
+The backward is written by hand, over BLOCKS of `block_chunks` chunks
+(4: PERF.md section 6, PR 31, has the chip's readings at 2 to 32): the
+forward keeps only the state that enters each block (2 MB a block at 32
+heads of 128 x 128), the backward walks the blocks last to first,
+recomputes a block from that state and pulls the cotangents of (state
+out, o) back through it — so no intermediate of the within-chunk algebra
+outlives its block, whatever S is.
+
+Two implementations of that one algorithm, picked from the shapes
+(`resolve_impl`), behind one `custom_vjp` with the same residuals (q, k,
+v, g, beta, the block-entry states) and the same names for a remat
+policy (`SAVE_NAMES`):
+
+  `kernel`   ops/kda_pallas.py: a forward and a backward Pallas (Mosaic)
+             kernel, grid (heads, blocks), a head's float32 state in a
+             VMEM scratch across the blocks and a chunk's whole algebra
+             on VMEM tiles; HBM sees the inputs, o, the block-entry
+             states, dO and the five cotangents. Where S is whole
+             chunks AND the head is whole 128-lane tiles (the published
+             128). Its docstring says what lives where.
+  `chunked`  this module: XLA einsums under a scan over blocks (one
+             block is the unit of batched work: its chunks' solves and
+             pair products are one batched op each), the backward
+             `jax.vjp` of `_block` under a reverse scan. Where S is
+             whole chunks and the head is not lane-aligned (the CPU
+             tiny preset), and the oracle the kernel is held to.
+
+A length that is not whole chunks takes `kda_recurrent`.
 
 Layout: q, k, g [B, H, S, dk], v [B, H, S, dv], beta [B, H, S], as the
 attention kernels take theirs. q and k arrive normalised and scaled by
@@ -55,6 +75,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from mobilefinetuner_tpu.ops import kda_pallas, pallas_util
 
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -224,12 +246,14 @@ def _blocks(x, nB: int, nb: int, C: int):
 SAVE_NAMES = ("kda_o", "kda_states")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _chunked(q, k, v, g, beta, C, sub, nb):
-    return _chunked_fwd(q, k, v, g, beta, C, sub, nb)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _chunked(q, k, v, g, beta, C, sub, nb, impl):
+    return _chunked_fwd(q, k, v, g, beta, C, sub, nb, impl)[0]
 
 
-def _chunked_fwd(q, k, v, g, beta, C, sub, nb):
+def _scan_fwd(q, k, v, g, beta, C, sub, nb):
+    """The XLA form: a scan over blocks. (o [B, H, S, dv] in v's dtype,
+    the state entering each block [nB, B, H, dk, dv])."""
     B, H, S, dk = q.shape
     dv = v.shape[-1]
     nB = S // (nb * C)
@@ -240,18 +264,13 @@ def _chunked_fwd(q, k, v, g, beta, C, sub, nb):
         s_out, o = fn(s, *x)
         return s_out, (o.astype(v.dtype), s)
 
-    with jax.named_scope("kda_scan"):
-        _, (o, s_in) = jax.lax.scan(step, jnp.zeros((B, H, dk, dv), F32),
-                                    xs)
-        o = checkpoint_name(jnp.moveaxis(o, 0, 2).reshape(B, H, S, dv),
-                            SAVE_NAMES[0])
-        s_in = checkpoint_name(s_in, SAVE_NAMES[1])
-    return o, (q, k, v, g, beta, s_in)
+    _, (o, s_in) = jax.lax.scan(step, jnp.zeros((B, H, dk, dv), F32), xs)
+    return jnp.moveaxis(o, 0, 2).reshape(B, H, S, dv), s_in
 
 
-def _chunked_bwd(C, sub, nb, res, do):
-    q, k, v, g, beta, s_in = res
-    B, H, S, dk = q.shape
+def _scan_bwd(q, k, v, g, beta, s_in, do, C, sub, nb):
+    """`jax.vjp` of a block under a reverse scan over the blocks."""
+    S = q.shape[2]
     nB = S // (nb * C)
     xs = tuple(_blocks(x, nB, nb, C) for x in (q, k, v, g, beta))
     fn = functools.partial(_block, sub=sub)
@@ -262,13 +281,38 @@ def _chunked_bwd(C, sub, nb, res, do):
         ds0, *dargs = pull((ds, do_b.astype(F32)))
         return ds0, tuple(dargs)
 
+    _, grads = jax.lax.scan(
+        step, jnp.zeros_like(s_in[0]),
+        (s_in, _blocks(do, nB, nb, C), xs), reverse=True)
+    back = lambda d, x: jnp.moveaxis(d, 0, 2).reshape(x.shape) \
+        .astype(x.dtype)
+    return tuple(back(d, x) for d, x in zip(grads, (q, k, v, g, beta)))
+
+
+def _impl_fns(impl):
+    """(forward, backward) of an implementation. The kernels' calls are
+    jitted: they get the interpret policy as read here, at the caller's
+    trace, as a static argument."""
+    if impl != "kernel":
+        return _scan_fwd, _scan_bwd
+    interpret = pallas_util.interpret_mode()
+    return (functools.partial(kda_pallas.forward, interpret=interpret),
+            functools.partial(kda_pallas.backward, interpret=interpret))
+
+
+def _chunked_fwd(q, k, v, g, beta, C, sub, nb, impl):
+    fwd = _impl_fns(impl)[0]
     with jax.named_scope("kda_scan"):
-        _, grads = jax.lax.scan(
-            step, jnp.zeros_like(s_in[0]),
-            (s_in, _blocks(do, nB, nb, C), xs), reverse=True)
-        back = lambda d, x: jnp.moveaxis(d, 0, 2).reshape(x.shape) \
-            .astype(x.dtype)
-        return tuple(back(d, x) for d, x in zip(grads, (q, k, v, g, beta)))
+        o, s_in = fwd(q, k, v, g, beta, C, sub, nb)
+        o = checkpoint_name(o, SAVE_NAMES[0])
+        s_in = checkpoint_name(s_in, SAVE_NAMES[1])
+    return o, (q, k, v, g, beta, s_in)
+
+
+def _chunked_bwd(C, sub, nb, impl, res, do):
+    bwd = _impl_fns(impl)[1]
+    with jax.named_scope("kda_scan"):
+        return bwd(*res, do, C, sub, nb)
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
@@ -280,14 +324,42 @@ def pick_block_chunks(S: int, chunk: int, want: int = 4) -> int:
     return max(b for b in range(1, min(want, n) + 1) if n % b == 0)
 
 
+IMPLS = ("kernel", "chunked")
+
+
+def resolve_impl(S: int, chunk: int, dk: int, dv: int,
+                 sub: int = 16) -> str:
+    """Which implementation a shape gets: `kernel` (ops/kda_pallas.py)
+    where S is whole chunks and the head is whole 128-lane tiles;
+    `chunked` (the XLA scans) where S is whole chunks and the head is
+    not; `recurrent` (the token-by-token scan) otherwise."""
+    if S % chunk or chunk % sub:
+        return "recurrent"
+    return "kernel" if kda_pallas.supported(S, chunk, sub, dk, dv) \
+        else "chunked"
+
+
 def kda_chunked(q, k, v, g, beta, *, chunk: int = 64, sub: int = 16,
-                block_chunks: int = 4):
+                block_chunks: int = 4, impl: str = "auto"):
     """o [B, H, S, dv] in v's dtype; see the module docstring. S must be
     a multiple of `chunk` and `chunk` of `sub`; g is expected in float32
-    (the cumulated gate loses the decay's small differences in bf16)."""
+    (the cumulated gate loses the decay's small differences in bf16).
+    impl: `kernel` | `chunked` (IMPLS), or `auto`: `resolve_impl` of the
+    shapes."""
     S = q.shape[2]
     if S % chunk or chunk % sub:
         raise ValueError(f"kda_chunked: S={S} must be a multiple of "
                          f"chunk={chunk}, and chunk of sub={sub}")
+    dk, dv = q.shape[-1], v.shape[-1]
+    if impl == "auto":
+        impl = resolve_impl(S, chunk, dk, dv, sub)
+    elif impl not in IMPLS:
+        raise ValueError(f"kda_chunked: impl={impl!r} is none of {IMPLS}")
+    elif impl == "kernel" and not kda_pallas.supported(S, chunk, sub, dk,
+                                                       dv):
+        raise ValueError(
+            f"kda_chunked: impl='kernel' needs heads of whole 128-lane "
+            f"tiles and sub-chunks of whole sublane tiles, got dk={dk}, "
+            f"dv={dv}, sub={sub}")
     return _chunked(q, k, v, g, beta, chunk, sub,
-                    pick_block_chunks(S, chunk, block_chunks))
+                    pick_block_chunks(S, chunk, block_chunks), impl)

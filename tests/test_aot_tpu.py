@@ -259,25 +259,78 @@ def test_flash_compiles_at_two_head_sizes_at_the_latent_cell_s_length(
     assert assert_kernel(c, 3) == 3         # forward, dQ, dK/dV
 
 
-def test_the_chunked_delta_rule_compiles_at_the_cell_s_shapes(topo,
-                                                              as_tpu):
-    """ops/kda.py forward and its hand-written backward at 32 heads of
-    128 over 16,384 tokens: XLA ops only (no Mosaic kernel), and the
-    blocks' intermediates do not outlive their block — the whole program
-    stays under 3 GiB around 0.9 GiB of operands."""
+KDA_CELL = (1, 32, 16384, 128)
+
+
+def kda_grad_for(topo, impl):
+    """Forward + backward of the chunked delta rule at the cell's shapes
+    (bf16 q, k, v; float32 gate and beta), compiled for the described
+    chip."""
     from mobilefinetuner_tpu.ops import kda
-    B, n, S, d = 1, 32, 16384, 128
+    B, n, S, d = KDA_CELL
     x = sds(topo, (B, n, S, d), BF16)
     g = sds(topo, (B, n, S, d), jnp.float32)
     beta = sds(topo, (B, n, S), jnp.float32)
 
     def loss(q, k, v, g, beta):
-        return kda.kda_chunked(q, k, v, g, beta).astype(jnp.float32).sum()
+        return kda.kda_chunked(q, k, v, g, beta, impl=impl) \
+            .astype(jnp.float32).sum()
 
-    c = compile_for(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x, g,
-                    beta)
+    return compile_for(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), x, x, x, g,
+                       beta)
+
+
+def test_the_chunked_delta_rule_compiles_at_the_cell_s_shapes(topo,
+                                                              as_tpu):
+    """ops/kda.py's XLA form, forward and its backward (the vjp of a
+    block under a reverse scan), at 32 heads of 128 over 16,384 tokens:
+    XLA ops only (no Mosaic kernel), and the blocks' intermediates do
+    not outlive their block — the whole program stays under 3 GiB
+    around 0.9 GiB of operands."""
+    c = kda_grad_for(topo, "chunked")
     assert tpu_custom_calls(c.as_text()) == 0
     assert compiled_peak_bytes(c) < 3 * 2 ** 30
+
+
+def test_the_delta_rule_kernel_compiles_at_the_cell_s_shapes(topo, as_tpu):
+    """ops/kda_pallas.py at the same shapes, where the shape rule takes
+    it: the program is TWO Mosaic calls — `kda_fwd` and `kda_bwd` — at
+    float32 contract precision inside (Mosaic takes `Precision.HIGHEST`
+    on float32 operands; it has no such product for bf16 ones), the
+    forward within the default scoped VMEM and the backward within the
+    32 MiB it asks for, and under the same 3 GiB of HBM: operands, o,
+    the 128 MiB of block-entry states and the five cotangents."""
+    from mobilefinetuner_tpu.ops import kda
+    B, n, S, d = KDA_CELL
+    assert kda.resolve_impl(S, 64, d, d) == "kernel"
+    c = kda_grad_for(topo, "auto")
+    text = c.as_text()
+    assert assert_kernel(c, 2) == 2
+    assert "kda_fwd" in text and "kda_bwd" in text
+    assert compiled_peak_bytes(c) < 3 * 2 ** 30
+
+
+def test_the_delta_rule_kernel_reads_the_interpret_policy_at_every_trace(
+        topo, monkeypatch):
+    """The kernels' calls are jitted (the layers share one trace), with
+    the interpret policy a static argument read by their caller: a shape
+    first traced interpreted, as the CPU tests trace it, is lowered to
+    the two Mosaic calls when the same process then describes a chip."""
+    from mobilefinetuner_tpu.ops import kda
+    shapes = [jax.ShapeDtypeStruct((1, 2, 128, 128), jnp.float32)] * 4 \
+        + [jax.ShapeDtypeStruct((1, 2, 128), jnp.float32)]
+
+    def loss(*a):
+        return kda.kda_chunked(*a, impl="kernel").sum()
+
+    def mosaic_calls(xs):        # in the lowered (StableHLO) text
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *xs).as_text().count("custom_call @tpu_custom_call")
+
+    with jax.default_matmul_precision("default"):
+        assert mosaic_calls(shapes) == 0
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert mosaic_calls(on_chip0(topo, shapes)) == 2
 
 
 def test_grouped_product_compiles_at_the_ling_cell_s_widths(topo, as_tpu):

@@ -109,15 +109,24 @@ GATES = {"whole": (-5.0, 0.0), "fast_end": (-5.0, -4.9),
          "slow_end": (-0.01, 0.0)}
 
 
+# the kernel (ops/kda_pallas.py, in Pallas interpret mode here) takes
+# heads of whole 128-lane tiles; the XLA form runs at the small head too
+KDA_IMPLS = {"chunked": dict(H=2, d=16), "kernel": dict(H=2, d=128)}
+
+
+@pytest.mark.parametrize("impl", sorted(KDA_IMPLS))
 @pytest.mark.parametrize("gate", sorted(GATES))
 @pytest.mark.parametrize("S_,block_chunks", [(64, 16), (256, 2), (192, 16)])
-def test_chunked_delta_rule_matches_the_recurrence(gate, S_, block_chunks):
+def test_chunked_delta_rule_matches_the_recurrence(gate, S_, block_chunks,
+                                                   impl):
     """Forward and every gradient, over chunk (64), sub-chunk (16) and
     block boundaries: 256 tokens in blocks of 2 chunks are two blocks,
-    192 tokens are one block of three."""
-    args, ct = kda_inputs(3, S_, *GATES[gate])
+    192 tokens are one block of three. Every case for both
+    implementations of the chunked form."""
+    args, ct = kda_inputs(3, S_, *GATES[gate], **KDA_IMPLS[impl])
     chunked = lambda *a: kda.kda_chunked(*a, chunk=64, sub=16,
-                                         block_chunks=block_chunks)
+                                         block_chunks=block_chunks,
+                                         impl=impl)
     want = kda.kda_recurrent(*args)[0]
     got = chunked(*args)
     np.testing.assert_allclose(got, want, atol=2e-6)
@@ -131,31 +140,84 @@ def test_chunked_delta_rule_matches_the_recurrence(gate, S_, block_chunks):
         assert np.isfinite(np.asarray(a)).all(), name
 
 
-def test_chunked_delta_rule_takes_bf16_inputs_and_computes_in_float32():
+@pytest.mark.parametrize("impl", sorted(KDA_IMPLS))
+def test_chunked_delta_rule_takes_bf16_inputs_and_computes_in_float32(impl):
     """bf16 q, k, v in, bf16 out; inside, float32: the output is the
     float32 recurrence of the ROUNDED inputs to float32's own accuracy,
     and within bf16's rounding of the unrounded one."""
-    args, _ = kda_inputs(5, 256, -5.0, 0.0)
+    args, _ = kda_inputs(5, 256, -5.0, 0.0, **KDA_IMPLS[impl])
     q, k, v, g, beta = args
     want = kda.kda_recurrent(*args)[0]
     lo = lambda x: x.astype(jnp.bfloat16)
-    got = kda.kda_chunked(lo(q), lo(k), lo(v), g, beta,
-                          block_chunks=2).astype(jnp.float32)
+    got = kda.kda_chunked(lo(q), lo(k), lo(v), g, beta, block_chunks=2,
+                          impl=impl).astype(jnp.float32)
     ref_scale = float(jnp.abs(want).max())
     assert float(jnp.abs(got - want).max()) < 2e-2 * ref_scale
     rounded = kda.kda_recurrent(lo(q), lo(k), lo(v), g, beta)[0]
-    # the output itself is rounded to bf16 once: 2^-9 of its size
-    assert float(jnp.abs(got - rounded).max()) < 2.5e-3 * ref_scale
-    assert kda.kda_chunked(lo(q), lo(k), lo(v), g, beta).dtype \
-        == jnp.bfloat16
+    # element by element: the float32 result (the recurrence's to the 2e-6
+    # of the float32 cases above) rounded to bf16 ONCE — half a unit in
+    # the last of its 8 significant bits, at most 2^-8 of the element.
+    # A bf16 pass over the state, or a second rounding, does not fit
+    assert bool((jnp.abs(got - rounded)
+                 <= 2.0 ** -8 * jnp.abs(rounded) + 2e-6).all())
+    assert kda.kda_chunked(lo(q), lo(k), lo(v), g, beta,
+                           impl=impl).dtype == jnp.bfloat16
+    # the gradients come back in their inputs' dtypes
+    grads = jax.grad(lambda *a: kda.kda_chunked(*a, impl=impl).astype(
+        jnp.float32).sum(), argnums=range(5))(lo(q), lo(k), lo(v), g, beta)
+    assert [x.dtype for x in grads] == [jnp.bfloat16] * 3 \
+        + [jnp.float32] * 2
+
+
+@pytest.mark.parametrize("impl", sorted(KDA_IMPLS))
+def test_chunked_delta_rule_holds_its_digits_when_keys_repeat(impl):
+    """One key, written again and again at beta 0.95 with almost no
+    decay: (I + Diag(beta) M) is then beta under the whole diagonal, the
+    case in which a Neumann series of the inverse cancels terms of
+    binomial size (PERF.md section 6, PR 31). The substitution — in the
+    XLA form and in the kernel's row-by-row blocks and strips — reads
+    what it reads on random keys."""
+    (q, k, v, g, _), ct = kda_inputs(7, 256, -0.01, 0.0, **KDA_IMPLS[impl])
+    args = (q, jnp.broadcast_to(k[:, :, :1], k.shape), v, g,
+            jnp.full(k.shape[:3], 0.95))
+    chunked = lambda *a: kda.kda_chunked(*a, impl=impl)
+    np.testing.assert_allclose(chunked(*args), kda.kda_recurrent(*args)[0],
+                               atol=2e-6)
+    gw = jax.grad(lambda *a: (kda.kda_recurrent(*a)[0] * ct).sum(),
+                  argnums=range(5))(*args)
+    gg = jax.grad(lambda *a: (chunked(*a) * ct).sum(),
+                  argnums=range(5))(*args)
+    for name, a, b in zip("q k v g beta".split(), gg, gw):
+        assert float(jnp.abs(a - b).max()) \
+            <= 2e-5 * max(float(jnp.abs(b).max()), 1.0), name
 
 
 def test_chunked_delta_rule_refuses_a_ragged_length():
     args, _ = kda_inputs(0, 80, -1.0, 0.0)
     with pytest.raises(ValueError, match="multiple of"):
         kda.kda_chunked(*args, chunk=64)
-    assert ling_hybrid.resolve_kda_impl(80, 64) == "recurrent"
-    assert ling_hybrid.resolve_kda_impl(16384, 64) == "chunked"
+
+
+def test_the_delta_rule_s_implementation_is_read_from_shapes():
+    """`kernel` where the length is whole chunks and the head whole
+    128-lane tiles (the cell: 16,384 tokens, chunk 64, heads of 128),
+    `chunked` at the tiny preset's small heads, `recurrent` at a ragged
+    length (a mesh never reaches the rule: the entry point refuses it,
+    test_the_cli_refuses_a_mesh_by_name)."""
+    tiny = LingHybridConfig.tiny()
+    assert ling_hybrid.resolve_kda_impl(16384, 64, 128) == "kernel"
+    assert ling_hybrid.resolve_kda_impl(S, tiny.kda_chunk,
+                                        tiny.head_dim) == "chunked"
+    assert ling_hybrid.resolve_kda_impl(16384, 64, 64) == "chunked"
+    assert ling_hybrid.resolve_kda_impl(80, 64, 128) == "recurrent"
+    assert ling_hybrid.resolve_kda_impl(80, 64, tiny.head_dim) \
+        == "recurrent"
+    # asked for by name at a head it cannot tile: refused, not padded
+    args, _ = kda_inputs(0, 64, -1.0, 0.0)
+    with pytest.raises(ValueError, match="128-lane"):
+        kda.kda_chunked(*args, impl="kernel")
+    with pytest.raises(ValueError, match="none of"):
+        kda.kda_chunked(*args, impl="pallas")
 
 
 def test_the_conv_is_a_causal_depthwise_convolution():
@@ -447,6 +509,25 @@ def test_parity_fails_when_a_term_is_removed(monkeypatch, tiny, term):
     assert float(jnp.abs(got - want).max()) > 1e-3
 
 
+def test_the_program_gives_the_same_with_the_kernel_as_with_the_scans(
+        monkeypatch):
+    """At a head of 128 the shape rule takes the Pallas kernels (chunks
+    of 16: one sub-chunk a chunk, two chunks a block): logits and every
+    adapter gradient equal those of the XLA form of the same algebra."""
+    c, params, lora, ids = make(seed=4, head_dim=128)
+    assert ling_hybrid.resolve_kda_impl(S, c.kda_chunk, c.head_dim) \
+        == "kernel"
+    run = jax.value_and_grad(
+        lambda l: prog_loss(c, params, l, ids, remat=True))
+    got, g_got = run(lora)
+    monkeypatch.setattr(kda, "resolve_impl", lambda *a, **kw: "chunked")
+    want, g_want = run(lora)
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        assert float(jnp.abs(a - b).max()) \
+            <= F32_TOL * max(float(jnp.abs(b).max()), 1e-3)
+
+
 # ----------------------------- (d) the router -------------------------------
 
 def router_inputs(seed=0, T=96, H=64, E=32):
@@ -594,12 +675,19 @@ def test_unpublished_forms_are_refused():
 
 # ----------------------------- (g) scopes -----------------------------------
 
-def test_every_op_of_a_mixer_lies_under_one_scope_of_its_kind():
+@pytest.mark.parametrize("kda_impl,head_dim", [("chunked", 16),
+                                               ("kernel", 128)])
+def test_every_op_of_a_mixer_lies_under_one_scope_of_its_kind(kda_impl,
+                                                              head_dim):
     """In the lowered train step, forward and backward, every op whose
     name path runs through `attention` runs through exactly one of the
     family's inner scopes, and every op of an expert MLP through exactly
-    one moe_* scope (their device times add up to the layer's)."""
-    c, params, lora, ids = make(seed=9)
+    one moe_* scope (their device times add up to the layer's) — with
+    the delta rule as XLA scans and, at a head of 128, as the Pallas
+    kernels, whose ops lie under `kda_scan` like the scans'."""
+    c, params, lora, ids = make(seed=9, head_dim=head_dim)
+    assert ling_hybrid.resolve_kda_impl(S, c.kda_chunk, c.head_dim) \
+        == kda_impl
     text = jax.jit(jax.grad(
         lambda l: prog_loss(c, params, l, ids, remat=True))).lower(
         lora).as_text(debug_info=True)
@@ -695,6 +783,26 @@ def test_the_cli_trains_through_run_training_with_counters_and_an_adapter(
     assert ("base_model.model.model.layers.6.attention.q_proj"
             ".lora_B.weight") in keys
     assert not any(".layers.5.attention.k_proj" in k for k in keys)
+
+
+def test_the_cli_refuses_a_mesh_by_name(tmp_path):
+    """A Mosaic kernel outside shard_map cannot be partitioned by GSPMD,
+    and the family runs on one device: a mesh of more than one is
+    refused at the entry point, by the program's name, before any `auto`
+    (the delta rule's among them) is resolved."""
+    from fixtures import write_tiny_ling_dir, write_wikitext_dir
+    from mobilefinetuner_tpu.cli import train_lora_ling
+    write_tiny_ling_dir(str(tmp_path / "m"))
+    write_wikitext_dir(str(tmp_path / "data"))
+    with pytest.raises(SystemExit, match="train_lora_ling runs one "
+                                         "expert-parallel rank on one "
+                                         "device"):
+        train_lora_ling.main([
+            "--pretrained_dir", str(tmp_path / "m"),
+            "--data_dir", str(tmp_path / "data"), "--steps", "1",
+            "--batch_size", "2", "--seq_len", "64", "--dtype", "float32",
+            "--mesh_fsdp", "2",
+            "--lora_out", str(tmp_path / "out.safetensors")])
 
 
 # ----------------------------- (i) the benchmark's cut ----------------------

@@ -1372,6 +1372,10 @@ _COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
 # like %all-gather.1 is followed by `.1`/`)` — no open paren)
 _COLLECTIVE_RE = re.compile(
     r"\b(" + "|".join(_COLLECTIVE_KINDS) + r")(?:-start)?\(")
+# the TPU's reduce-scatter fusion: the header of its called computation,
+# and a call site
+_ARS_HEADER_RE = re.compile(r"^%?all-reduce-scatter[\w.\-]* \(")
+_ARS_CALL_RE = re.compile(r"calls=%?all-reduce-scatter[\w.\-]*")
 
 
 def hlo_named_scopes(hlo_text: str) -> Set[str]:
@@ -1401,14 +1405,33 @@ def assert_hlo_scopes(hlo_text: str, scopes: Iterable[str]) -> None:
         f"named scopes missing from compiled HLO metadata: {missing}"
 
 
-def hlo_collective_census(hlo_text: str) -> Dict[str, int]:
+def hlo_collective_census(hlo_text: str,
+                          scopes: Iterable[str] = ()) -> Dict[str, int]:
     """{collective_kind: count} over compiled HLO text. Async pairs
     (all-gather-start/-done) count ONCE (the -start); the census is the
     pod-bill observable — a GSPMD regression that materializes a
-    V-sharded embed all-gather moves a number here."""
+    V-sharded embed all-gather moves a number here.
+
+    The TPU compiler emits a reduce-scatter as a fusion that calls an
+    `all-reduce-scatter...` computation holding an all-reduce: the call
+    counts as one reduce-scatter, the computation's own ops not at all.
+    scopes: count only the collectives whose op_name has one of these
+    as a path component (missing_hlo_scopes' match) — e.g. the block's
+    `attention` / `mlp`, which only ops inside the layer scan carry."""
     counts = {k: 0 for k in _COLLECTIVE_KINDS}
-    for m in _COLLECTIVE_RE.finditer(hlo_text):
-        counts[m.group(1)] += 1
+    want = set(scopes)
+    in_ars = False
+    for line in hlo_text.splitlines():
+        if in_ars or _ARS_HEADER_RE.match(line):
+            in_ars = not line.startswith("}")
+            continue
+        if want and not want & hlo_named_scopes(line):
+            continue
+        if _ARS_CALL_RE.search(line):
+            counts["reduce-scatter"] += 1
+            continue
+        for m in _COLLECTIVE_RE.finditer(line):
+            counts[m.group(1)] += 1
     return counts
 
 

@@ -83,10 +83,14 @@ def init_params(config: GPT2Config, key: jax.Array,
     }
 
 
+def _dense(x, w, b):
+    return x @ w + b
+
+
 def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
            lora_dropout=0.0, dropout_rng=None, cp_mesh=None,
            cp_axis="fsdp", collect_kv: bool = False,
-           lora_impl: str = "auto", mesh=None):
+           lora_impl: str = "auto", mesh=None, dense=_dense):
     """One pre-LN transformer block. bp leaves are THIS layer's weights
     (already sliced out of the [L, ...] stacks by the scan body); layer_idx
     (traced scalar) indexes the still-stacked LoRA leaves and salts
@@ -95,7 +99,9 @@ def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
     mesh: the mesh a batch-parallel step is partitioned over, for the
     attention dispatcher (ops/attention.attention).
     collect_kv: also return this layer's (k, v) head tensors [B, H, S, D]
-    (KV-cache prefill, models/generate.py)."""
+    (KV-cache prefill, models/generate.py).
+    dense(x, w, b): the block's four products (x @ w + b by default; the
+    layer gather over an FSDP mesh hands parallel/mesh.gathered_dense)."""
     eps = config.layer_norm_epsilon
     H, D = config.n_head, config.head_dim
     B, S, E = x.shape
@@ -113,7 +119,7 @@ def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
     # op metadata (asserted by tests/test_telemetry.py; DESIGN.md §13)
     with jax.named_scope("attention"):
         h = layer_norm(x, bp["ln_1"]["g"], bp["ln_1"]["b"], eps)
-        qkv = h @ bp["attn"]["qkv_w"] + bp["attn"]["qkv_b"]
+        qkv = dense(h, bp["attn"]["qkv_w"], bp["attn"]["qkv_b"])
         qkv = lora(qkv, h, "attn_qkv", 0)
         # split-QKV adapters hit only their column range of the fused
         # c_attn output (reference: lora_injector.h:169-191
@@ -142,7 +148,7 @@ def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
                             attn_dropout=config.attn_pdrop,
                             attn_dropout_rng=attn_rng)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, E)
-        proj = ctx @ bp["attn"]["proj_w"] + bp["attn"]["proj_b"]
+        proj = dense(ctx, bp["attn"]["proj_w"], bp["attn"]["proj_b"])
         proj = lora(proj, ctx, "attn_proj", 1)
         proj = _dropout(proj, config.resid_pdrop,
                         None if rng is None else jax.random.fold_in(rng, 7))
@@ -150,10 +156,10 @@ def _block(config: GPT2Config, bp, x, padding_mask, lora_b, layer_idx,
 
     with jax.named_scope("mlp"):
         h = layer_norm(x, bp["ln_2"]["g"], bp["ln_2"]["b"], eps)
-        fc = h @ bp["mlp"]["fc_w"] + bp["mlp"]["fc_b"]
+        fc = dense(h, bp["mlp"]["fc_w"], bp["mlp"]["fc_b"])
         fc = lora(fc, h, "mlp_fc_in", 2)
         act = gelu_new(fc)
-        out = act @ bp["mlp"]["proj_w"] + bp["mlp"]["proj_b"]
+        out = dense(act, bp["mlp"]["proj_w"], bp["mlp"]["proj_b"])
         out = lora(out, act, "mlp_fc_out", 3)
         out = _dropout(out, config.resid_pdrop,
                        None if rng is None else jax.random.fold_in(rng, 8))
@@ -184,6 +190,11 @@ def hidden_states(config: GPT2Config, params, input_ids,
     (post-embedding and post-block activations) for the alignment harness
     (reference: train_lora_gemma.cpp:620-920 npy dumps, GPT2_ALIGN_DUMP_DIR
     in gpt2_model.cpp:327-399).
+    mesh: the mesh a batch-parallel step is partitioned over. Where its
+    fsdp axis holds more than one device (and neither sequence
+    parallelism nor streaming is on), each layer's weights are gathered
+    whole inside the scan body and the block's activations stay on the
+    batch axes (_layer_gather; DESIGN.md §31a).
     """
     from mobilefinetuner_tpu.parallel.offload import resolve_offload
     B, S = input_ids.shape
@@ -207,17 +218,26 @@ def hidden_states(config: GPT2Config, params, input_ids,
                      None if dropout_rng is None
                      else jax.random.fold_in(dropout_rng, 1000))
     padding_mask = attention_mask
+    from mobilefinetuner_tpu.parallel.mesh import gathers_layers, on_batch
     from mobilefinetuner_tpu.parallel.offload import layer_slicer
-    slice_layer = layer_slicer(params["blocks"], stream, compute_dtype)
+    if stream is None and cp_mesh is None and gathers_layers(mesh):
+        slice_layer, dense = _layer_gather(config, params["blocks"], mesh,
+                                           compute_dtype)
+        pin = lambda t: on_batch(t, mesh)
+    else:
+        slice_layer = layer_slicer(params["blocks"], stream, compute_dtype)
+        dense, pin = _dense, lambda t: t
     lora_b = None if lora is None else lora.get("blocks")
 
     embed_out = x
 
     def body(x, i):
-        r = _block(config, slice_layer(i), x, padding_mask, lora_b, i,
+        r = _block(config, slice_layer(i), pin(x), padding_mask, lora_b, i,
                    lora_dropout, dropout_rng, cp_mesh, cp_axis,
-                   collect_kv=collect_kv, lora_impl=lora_impl, mesh=mesh)
+                   collect_kv=collect_kv, lora_impl=lora_impl, mesh=mesh,
+                   dense=dense)
         x2, kv = r if collect_kv else (r, None)
+        x2 = pin(x2)
         return x2, (kv if collect_kv else (x2 if collect_layers else None))
     if remat or stream is not None:
         body = jax.checkpoint(body)
@@ -230,6 +250,32 @@ def hidden_states(config: GPT2Config, params, input_ids,
     if collect_layers:
         return x, {"embed": embed_out, "layers": extras}
     return x
+
+
+def _layer_gather(config: GPT2Config, blocks, mesh, compute_dtype):
+    """(slice_layer, dense) of the layer gather over an FSDP mesh:
+    slice_layer(i) is this layer's slice of the stacks as they are held
+    (no whole-stack cast), the norms gathered whole (layer_norm applies
+    them in float32 either way); the four products take their weight and
+    bias shards to parallel/mesh.gathered_dense, which gathers them. Every
+    gradient is summed across devices in float32."""
+    from mobilefinetuner_tpu.parallel.mesh import (gathered_dense,
+                                                   gathered_param)
+    L = config.n_layer
+
+    def slice_layer(i):
+        bp = jax.tree.map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
+            blocks)
+        for norm in ("ln_1", "ln_2"):
+            bp[norm] = jax.tree.map(
+                lambda t: gathered_param(t, mesh, compute_dtype, L),
+                bp[norm])
+        return bp
+
+    def dense(x, w, b):
+        return gathered_dense(x, w, b, mesh, compute_dtype, L)
+    return slice_layer, dense
 
 
 def forward(config: GPT2Config, params, input_ids, attention_mask=None,

@@ -16,9 +16,11 @@ Mesh axes:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -86,6 +88,107 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
     """Batch axis sharded over both mesh axes (data-parallel over the full
     device set; fsdp doubles as a DP axis for activations)."""
     return NamedSharding(mesh, P(("data", "fsdp")))
+
+
+# ------------------------ the layer gather (ZeRO-3) -------------------------
+# A layer scan over an FSDP mesh, left to the partitioner with feature-
+# sharded weights, computes parts of the block feature-sharded and moves
+# [B, S, k·E] activations between layouts (all-to-all). ZeRO-3 does the
+# opposite: each layer's weights are gathered whole, in the compute dtype,
+# where the layer runs, and the activations stay on the batch axes — so
+# every product is local, and the weight gradients go back to their shards
+# as one reduce-scatter each, summed in float32 (DESIGN.md §31a).
+
+def gathers_layers(mesh: Optional[Mesh]) -> bool:
+    """Does a layer scan over `mesh` gather each layer's weights? Where
+    the fsdp axis holds more than one device, and nowhere else: a mesh of
+    one fsdp device shards no weight."""
+    return mesh is not None and mesh.shape.get("fsdp", 1) > 1
+
+
+def on_batch(x, mesh: Mesh):
+    """x pinned to the batch axes (batch_sharding), its other axes whole."""
+    return jax.lax.with_sharding_constraint(x, batch_sharding(mesh))
+
+
+def gathered(t, mesh: Mesh, dtype):
+    """One layer's slice of a sharded leaf, cast to `dtype` on its shard
+    and gathered whole on every device."""
+    return jax.lax.with_sharding_constraint(t.astype(dtype),
+                                            replicated_sharding(mesh))
+
+
+def layer_slice_sharding(stacked_shape: Tuple[int, ...],
+                         mesh: Mesh) -> NamedSharding:
+    """Where one layer's slice of a stacked [L, ...] leaf lives under
+    params_shardings' rule: the stack's spec without its layer axis."""
+    return NamedSharding(
+        mesh, P(*tuple(fsdp_spec_for(stacked_shape, mesh))[1:]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def gathered_param(t, mesh: Mesh, dtype, n_layer: int):
+    """`gathered`, handed back in t's own dtype: the values a product's
+    weights take (rounded to `dtype`) for a leaf that is applied in
+    float32, such as a norm's scale. Backward: the cotangent as it comes,
+    float32 for a float32 leaf, constrained back to the slice's shard."""
+    return gathered(t, mesh, dtype).astype(t.dtype)
+
+
+def _gathered_param_fwd(t, mesh, dtype, n_layer):
+    return gathered(t, mesh, dtype).astype(t.dtype), None
+
+
+def _gathered_param_bwd(mesh, dtype, n_layer, _, ct):
+    return (jax.lax.with_sharding_constraint(
+        ct, layer_slice_sharding((n_layer,) + ct.shape, mesh)),)
+
+
+gathered_param.defvjp(_gathered_param_fwd, _gathered_param_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def gathered_dense(x, w, b, mesh: Mesh, dtype, n_layer: int):
+    """x @ w + b with w [in, out] and b [out] this layer's slices of the
+    FSDP-sharded stacks of `n_layer` layers: both gathered whole in
+    `dtype`, x and the product on the batch axes.
+
+    Backward: the weights are gathered again (nothing gathered is kept
+    for it), dx is local, and dW / db are the device's own rows summed
+    in float32 and constrained back to the slice's shard — one float32
+    reduce-scatter each over the devices (an all-reduce where the leaf
+    is replicated), where a bf16 product's cotangent would be summed in
+    bf16 across chips."""
+    return _gathered_product(x, w, b, mesh, dtype)
+
+
+def _gathered_product(x, w, b, mesh, dtype):
+    return on_batch(x @ gathered(w, mesh, dtype) + gathered(b, mesh, dtype),
+                    mesh)
+
+
+def _gathered_dense_fwd(x, w, b, mesh, dtype, n_layer):
+    # the residuals: x, the shard in the compute dtype (what the stack's
+    # cast kept before), and zero-size markers of the leaves' own dtypes
+    return _gathered_product(x, w, b, mesh, dtype), (
+        x, w.astype(dtype), jnp.zeros((0,), w.dtype),
+        jnp.zeros((0,), b.dtype))
+
+
+def _gathered_dense_bwd(mesh, dtype, n_layer, res, dy):
+    x, w_c, w0, b0 = res
+    rows = tuple(range(x.ndim - 1))
+    dx = on_batch(dy @ gathered(w_c, mesh, dtype).T, mesh)
+    dw = jax.lax.dot_general(x, dy, ((rows, rows), ((), ())),
+                             preferred_element_type=jnp.float32)
+    db = jnp.sum(dy, axis=rows, dtype=jnp.float32)
+    dw, db = (jax.lax.with_sharding_constraint(
+        g, layer_slice_sharding((n_layer,) + g.shape, mesh))
+        for g in (dw, db))
+    return dx, dw.astype(w0.dtype), db.astype(b0.dtype)
+
+
+gathered_dense.defvjp(_gathered_dense_fwd, _gathered_dense_bwd)
 
 
 def sp_batch_sharding(mesh: Mesh) -> NamedSharding:

@@ -173,6 +173,80 @@ def test_flash_over_the_batch_axes_compiles_on_four_chips(topo, as_tpu,
         jax.jit(grads(None)).lower(x, x, x, pad)
 
 
+def full_ft_fsdp_step(topo, cfg: GPT2Config, B: int, S: int, data: int,
+                      fsdp: int):
+    """The full-FT optimizer step as cli/gpt2_full_finetune builds it over
+    a (data, fsdp) mesh of the described chips: every parameter trainable
+    and FSDP-sharded by the CLI's rule, Adam's state on the params'
+    shardings, the batch over both axes, bf16, remat, `auto` attention.
+    Compiled; and its parameter shapes."""
+    from mobilefinetuner_tpu.models import gpt2
+    from mobilefinetuner_tpu.ops.loss import lm_cross_entropy_sum
+    from mobilefinetuner_tpu.parallel.mesh import (batch_sharding,
+                                                   make_mesh,
+                                                   params_shardings,
+                                                   replicated_sharding)
+    from mobilefinetuner_tpu.train.trainer import (TrainConfig,
+                                                   init_optimizer,
+                                                   make_train_step)
+    mesh = make_mesh(data=data, fsdp=fsdp, devices=topo.devices)
+    repl = replicated_sharding(mesh)
+    tc = TrainConfig(total_steps=1000, schedule="constant")
+    params = jax.eval_shape(
+        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    opt = jax.eval_shape(lambda p: init_optimizer(p, tc, None), params)
+
+    def loss_fn(params_t, _unused, mb):
+        logits = gpt2.forward(cfg, params_t, mb["input_ids"],
+                              attention_mask=mb["attention_mask"],
+                              compute_dtype=BF16, remat=True, mesh=mesh)
+        return lm_cross_entropy_sum(logits, mb["labels"])
+
+    psh, osh = params_shardings(params, mesh), params_shardings(opt, mesh)
+    batch = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
+             "attention_mask": jax.ShapeDtypeStruct((B, S), jnp.float32),
+             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    step = make_train_step(loss_fn, tc, mask=None, donate=True,
+                           out_shardings=(psh, osh, repl))
+    return step.lower(
+        place(params, psh), None, place(opt, osh),
+        place(batch, jax.tree.map(lambda _: batch_sharding(mesh), batch)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)).compile()
+
+
+def assert_layer_gather(compiled) -> dict:
+    """The layer scan of an FSDP full-FT step (the ops under the block's
+    `attention` / `mlp` scopes): weights gathered, no activation moved
+    between layouts, and every gradient sent back to its shard by a
+    reduce-scatter — the TPU's reduce-scatter fusion — in float32."""
+    from mobilefinetuner_tpu.core.static_checks import (
+        hlo_collective_census, hlo_named_scopes)
+    text = compiled.as_text()
+    census = hlo_collective_census(text, scopes=("attention", "mlp"))
+    assert census["all-to-all"] == 0, census
+    assert census["all-gather"] > 0 and census["reduce-scatter"] > 0, census
+    for line in text.splitlines():
+        if "calls=%all-reduce-scatter" in line and \
+                {"attention", "mlp"} & hlo_named_scopes(line):
+            out_types = line.split("=", 1)[1].split(" fusion(")[0]
+            assert "f32[" in out_types and "bf16[" not in out_types, line
+    return census
+
+
+@pytest.mark.parametrize("data,fsdp", [(1, 4), (2, 2)])
+def test_the_layer_gather_compiles_on_four_chips(topo, as_tpu, data, fsdp):
+    """The full-FT step of a small GPT-2 at S = 1024 over four described
+    chips (flash under shard_map, as in the cell): the layer gather's
+    collectives and nothing else inside the scan."""
+    cfg = dataclasses.replace(GPT2Config.tiny(vocab_size=512), n_embd=256,
+                              n_head=4, n_layer=2, n_positions=1024,
+                              embd_pdrop=0.0, resid_pdrop=0.0,
+                              attn_pdrop=0.0)
+    compiled = full_ft_fsdp_step(topo, cfg, 8, 1024, data, fsdp)
+    assert_kernel(compiled, at_least=3)
+    assert_layer_gather(compiled)
+
+
 @pytest.mark.parametrize("bwd_impl", ["merged", "split"])
 @pytest.mark.parametrize("window", [None, 4096])
 def test_flash_compiles_at_the_expert_cell_s_shapes(topo, as_tpu, window,
@@ -913,55 +987,46 @@ def test_fsdp_train_step_compiles_on_four_chips(topo, as_tpu, B, S, kernels):
     assert_fits(compiled)
 
 
-@pytest.mark.slow
-def test_the_fsdp_cell_s_full_ft_step_compiles_and_fits(topo, as_tpu):
+@pytest.fixture(scope="module")
+def fsdp_cell_step(topo):
     """`xl_fullft_fsdp4`'s step as cli/gpt2_full_finetune builds it:
-    gpt2-xl, every parameter trainable and FSDP-sharded over (1, 4) with
-    Adam's state on the params' shardings, global B 32 x S 1024, bf16,
-    remat, `auto` attention — the flash kernels under shard_map on 8
-    sequences a chip. Prints the compiled peak (PERF.md §4 quotes it)."""
-    from mobilefinetuner_tpu.models import gpt2
-    from mobilefinetuner_tpu.ops.loss import lm_cross_entropy_sum
-    from mobilefinetuner_tpu.parallel.mesh import (batch_sharding,
-                                                   make_mesh,
-                                                   params_shardings,
-                                                   replicated_sharding)
-    from mobilefinetuner_tpu.train.trainer import (TrainConfig,
-                                                   init_optimizer,
-                                                   make_train_step)
-    B, S = 32, 1024
+    gpt2-xl over (1, 4), global B 32 x S 1024, bf16, remat, `auto`
+    attention — the flash kernels under shard_map on 8 sequences a chip.
+    Compiled once for the tests below (as_tpu's patches, module-wide)."""
     cfg = dataclasses.replace(GPT2Config.gpt2_xl(), embd_pdrop=0.0,
                               resid_pdrop=0.0, attn_pdrop=0.0)
-    mesh = make_mesh(data=1, fsdp=4, devices=topo.devices)
-    repl = replicated_sharding(mesh)
-    tc = TrainConfig(total_steps=1000, schedule="constant")
-    params = jax.eval_shape(
-        lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
-    opt = jax.eval_shape(lambda p: init_optimizer(p, tc, None), params)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        with jax.default_matmul_precision("default"):
+            return full_ft_fsdp_step(topo, cfg, 32, 1024, 1, 4)
 
-    def loss_fn(params_t, _unused, mb):
-        logits = gpt2.forward(cfg, params_t, mb["input_ids"],
-                              attention_mask=mb["attention_mask"],
-                              compute_dtype=BF16, remat=True, mesh=mesh)
-        return lm_cross_entropy_sum(logits, mb["labels"])
 
-    psh, osh = params_shardings(params, mesh), params_shardings(opt, mesh)
-    batch = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
-             "attention_mask": jax.ShapeDtypeStruct((B, S), jnp.float32),
-             "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
-    step = make_train_step(loss_fn, tc, mask=None, donate=True,
-                           out_shardings=(psh, osh, repl))
-    compiled = step.lower(
-        place(params, psh), None, place(opt, osh),
-        place(batch, jax.tree.map(lambda _: batch_sharding(mesh), batch)),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl)).compile()
+@pytest.mark.slow
+def test_the_fsdp_cell_s_full_ft_step_compiles_and_fits(fsdp_cell_step):
+    """The cell's step fits a chip with the flash kernels in it. Prints
+    the compiled peak and the census (PERF.md §4 and §6 quote them)."""
+    from mobilefinetuner_tpu.core.static_checks import hlo_collective_census
+    compiled = fsdp_cell_step
     assert_kernel(compiled, at_least=3)   # forward, its remat, backward
-    census = assert_fsdp_collectives(compiled)
+    assert_fsdp_collectives(compiled)
+    census = {k: n for k, n in hlo_collective_census(
+        compiled.as_text()).items() if n}
     peak = compiled_peak_bytes(compiled)
     print(f"\nxl_fullft_fsdp4 step, described v5e:2x2: compiled peak "
           f"{peak / 1e9:.2f} GB a device = {peak / 16_909_336_064:.3f} of "
           f"bytes_limit; collectives {census}")
     assert peak < 0.85 * 16_909_336_064
+
+
+@pytest.mark.slow
+def test_the_fsdp_cell_s_layer_scan_gathers_and_moves_no_activations(
+        fsdp_cell_step):
+    """Inside the cell's layer scan: the weight gathers and float32
+    reduce-scatters, no all-to-all (the parent's partitioner put four
+    [8, 1024, 4, 1600] all-to-alls in the MLP's backward alone)."""
+    census = assert_layer_gather(fsdp_cell_step)
+    print(f"\nxl_fullft_fsdp4 layer scan, described v5e:2x2: "
+          f"{ {k: n for k, n in census.items() if n} }")
 
 
 @pytest.mark.slow

@@ -508,6 +508,36 @@ def test_hlo_census_donation_and_scope_helpers():
         ["emb", "optimizer"]
 
 
+_HLO_TPU_RS = '''HloModule jit_step
+
+%all-reduce-scatter.4.clone (input_0: f32[256,768]) -> f32[256,192] {
+  %input_0 = f32[256,768]{1,0} parameter(0)
+  %all-reduce.39 = f32[256,768]{1,0} all-reduce(f32[256,768]{1,0} %input_0), replica_groups={{0,1,2,3}}
+  ROOT %ds = f32[256,192]{1,0} dynamic-slice(%all-reduce.39, %z, %o)
+}
+
+ENTRY main {
+  %ag = bf16[256,768]{1,0} all-gather(bf16[256,192]{1,0} %p0), metadata={op_name="jit(step)/while/body/attention/convert_element_type"}
+  %fusion.367 = f32[256,192]{1,0} fusion(f32[256,768]{1,0} %d), kind=kCustom, calls=%all-reduce-scatter.4.clone, metadata={op_name="jit(step)/transpose(jvp())/while/body/attention/dot_general"}
+  %a2a = bf16[4,8]{1,0} all-to-all(bf16[4,8]{1,0} %e), metadata={op_name="jit(step)/jvp(embed)/gather"}
+}
+'''
+
+
+def test_census_counts_the_tpu_reduce_scatter_fusion_and_filters_scopes():
+    """The TPU's reduce-scatter is a fusion calling an
+    `all-reduce-scatter` computation: one reduce-scatter, and the
+    all-reduce inside it is not counted; `scopes` keeps the collectives
+    whose op_name has one of them as a path component."""
+    census = hlo_collective_census(_HLO_TPU_RS)
+    assert census["reduce-scatter"] == 1 and census["all-reduce"] == 0
+    assert census["all-gather"] == 1 and census["all-to-all"] == 1
+    block = hlo_collective_census(_HLO_TPU_RS, scopes=("attention", "mlp"))
+    assert (block["all-gather"], block["reduce-scatter"],
+            block["all-to-all"]) == (1, 1, 0)
+    assert hlo_collective_census(_HLO, scopes=("mlp",))["all-reduce"] == 1
+
+
 def test_jaxpr_helpers_find_dots_and_pallas():
     import jax.numpy as jnp
 

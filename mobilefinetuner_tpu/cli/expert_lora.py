@@ -1,14 +1,17 @@
-"""What the LoRA CLIs of the sparse-expert families share: one trainer
-entry point over `cli/common.run_training`, parametrised by a record
-that says what a family is (cli/train_lora_afmoe.py,
-cli/train_lora_ling.py and cli/train_lora_xing4.py each hold one and
-nothing else).
+"""What the LoRA CLIs of the sparse-expert and hybrid families share: one
+trainer entry point over `cli/common.run_training`, parametrised by a
+record that says what a family is (cli/train_lora_afmoe.py,
+cli/train_lora_ling.py, cli/train_lora_xing4.py and
+cli/train_lora_phi4flash.py each hold one and nothing else; a family
+without experts selects nothing by `--expert_parallel 1 --expert_rank
+0`).
 
 Common to them: the flags; `--expert_parallel N --expert_rank r`
 describing the deployment this process is one rank of (it holds experts
 [r·E/N, (r+1)·E/N); they select no code path: 1/0 holds every expert); a
 checkpoint published in bf16 that stays bf16 from disk to the MXU; the
-chunked cross-entropy over the rows of the head held here; `step_stats`
+chunked cross-entropy over the rows of the head held here, in
+`loss_chunks` sequence chunks; `step_stats`
 carrying the model's own device counters (`moe_assign_here`,
 `moe_load_max_over_mean`, `moe_dropped`, and whatever else the family's
 `hidden_states(..., with_aux=True)` counts); adapters saved in the
@@ -54,8 +57,24 @@ from mobilefinetuner_tpu.ops.loss import (IGNORE_INDEX,
 from mobilefinetuner_tpu.optim import adam as adam_mod
 
 log = get_logger()
-# sequence chunks of the cross-entropy: [B, S/8, V] logits at a time
+# sequence chunks of the cross-entropy: at least LOSS_CHUNKS, and as many
+# more (doubling) as keep one chunk's float32 logits [B, S/c, V] within
+# LOSS_CHUNK_BYTES (loss_chunks)
 LOSS_CHUNKS = 8
+LOSS_CHUNK_BYTES = 1 << 29
+
+
+def loss_chunks(B: int, S: int, V: int) -> int:
+    """The number of sequence chunks the head and the cross-entropy run
+    in for a [B, S] batch over V rows: the least c >= LOSS_CHUNKS, doubled
+    from it, whose [B, ceil(S / c), V] float32 logits fit LOSS_CHUNK_BYTES
+    (and at most S). One chunk's logits and their gradient are the loss's
+    peak, so a 200k-row head at 16k tokens takes 32 where the families
+    with smaller heads keep 8."""
+    c = LOSS_CHUNKS
+    while B * -(-S // c) * V * 4 > LOSS_CHUNK_BYTES and 2 * c <= S:
+        c *= 2
+    return c
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +100,9 @@ class ExpertFamily:
     peft_layer_ids: Optional[Callable] = None
     # parser -> None: the family's own flags (`resolve` reads them)
     add_flags: Optional[Callable] = None
+    # frozen params -> the head's [V, H] rows (a tied head reads the
+    # embedding)
+    head: Callable = lambda p: p["lm_head"]
 
 
 def build_parser(fam: ExpertFamily) -> argparse.ArgumentParser:
@@ -128,13 +150,45 @@ def add_second_loss(s, n, counters, second, head, labels, accum: int = 1):
         moved = jnp.concatenate(
             [labels[:, k:], jnp.full_like(labels[:, :k], IGNORE_INDEX)], 1)
         s2, n2 = chunked_lm_cross_entropy_sum(
-            second["hidden"], head, moved, num_chunks=LOSS_CHUNKS)
+            second["hidden"], head, moved,
+            num_chunks=loss_chunks(*labels.shape, head.shape[0]))
     nf = jnp.maximum(n.astype(jnp.float32), 1.0)
     n2f = jnp.maximum(n2.astype(jnp.float32), 1.0)
     counters = {**counters, "sum": {
         **counters.get("sum", {}), "loss_main": s / nf / accum,
         f"loss_{second['name']}": s2 / n2f / accum}}
     return s + second["weight"] * s2 * (nf / n2f), counters
+
+
+def make_nll(fam: ExpertFamily, args, config, fetch_fn, compute_dtype,
+             accum: int):
+    """The step's loss, nll(lora, frozen, micro-batch, train) -> (sum,
+    count, counters): the family's hidden states as `args` set them up,
+    the head's chunked cross-entropy and, in training, a second hidden
+    state's loss (module docstring). `main` trains on it; a check of the
+    trainer builds the same step from it."""
+    def nll(lora_t, frozen, mb, train: bool):
+        rng = mb["dropout_rng"][0] if train and "dropout_rng" in mb \
+            else None
+        p = fetch_fn(frozen)
+        hidden, aux = fam.model.hidden_states(
+            config, p, mb["input_ids"],
+            attention_mask=mb["attention_mask"], lora=lora_t,
+            compute_dtype=compute_dtype, remat=train and args.remat,
+            lora_dropout=args.lora_dropout if train else 0.0,
+            dropout_rng=rng, lora_impl=args.lora_impl, with_aux=True)
+        head = fam.head(p)
+        s, n = chunked_lm_cross_entropy_sum(
+            hidden, head, mb["labels"],
+            num_chunks=loss_chunks(*mb["labels"].shape, head.shape[0]))
+        counters = aux["counters"]
+        second = aux.get("second") if train else None
+        if second is not None:
+            s, counters = add_second_loss(
+                s, n, counters, second, head, mb["labels"], accum)
+        return s, n, counters
+
+    return nll
 
 
 def main(fam: ExpertFamily, argv=None) -> int:
@@ -198,25 +252,8 @@ def main(fam: ExpertFamily, argv=None) -> int:
         args, {t: fam.targets[t](config) for t in spec.targets or []},
         spec.rank, compute_dtype)
 
-    def nll(lora_t, frozen, mb, train: bool):
-        rng = mb["dropout_rng"][0] if train and "dropout_rng" in mb \
-            else None
-        p = fetch_fn(frozen)
-        hidden, aux = fam.model.hidden_states(
-            config, p, mb["input_ids"],
-            attention_mask=mb["attention_mask"], lora=lora_t,
-            compute_dtype=compute_dtype, remat=train and args.remat,
-            lora_dropout=args.lora_dropout if train else 0.0,
-            dropout_rng=rng, lora_impl=args.lora_impl, with_aux=True)
-        s, n = chunked_lm_cross_entropy_sum(
-            hidden, p["lm_head"], mb["labels"], num_chunks=LOSS_CHUNKS)
-        counters = aux["counters"]
-        second = aux.get("second") if train else None
-        if second is not None:
-            s, counters = add_second_loss(
-                s, n, counters, second, p["lm_head"], mb["labels"],
-                tc.grad_accum_steps)
-        return s, n, counters
+    nll = make_nll(fam, args, config, fetch_fn, compute_dtype,
+                   tc.grad_accum_steps)
 
     def loss_fn(lora_t, frozen, mb):
         return nll(lora_t, frozen, mb, True)

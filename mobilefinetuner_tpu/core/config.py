@@ -549,3 +549,117 @@ class Xing4Config:
                                  "mscale": 1, "mscale_all_dim": 1},
                    n_routed_experts=8, num_experts_per_tok=2,
                    max_position_embeddings=128)
+
+
+@dataclasses.dataclass
+class Phi4FlashConfig:
+    """Microsoft's Phi-4-mini-flash-reasoning (`model_type: phi4flash`),
+    the SambaY decoder-hybrid-decoder: a self-decoder of Mamba layers
+    (even indices) beside sliding-window attention (odd), then at
+    L/2 one more Mamba layer whose scan readout is kept as the memory
+    and at L/2 + 1 full attention whose keys and values are kept, then a
+    cross-decoder of gated memory units (even) beside cross-attention over
+    that one layer's keys and values (odd). Every attention is
+    differential. The published `config.json` keys (DESIGN.md §34 says
+    which equation reads which), plus the Mamba sizes the config leaves
+    to the family's code (`mamba_*`: Mamba's and Samba's convention,
+    assumed), `ssm_chunk`, the scan's chunk (ops/ssm.py), and
+    `mixer_chunk`, the tokens a Mamba mixer and an MLP take at a time
+    (models/phi4flash.by_pieces: what bounds a layer's backward). Defaults
+    are the published model's, whole: nothing is cut."""
+
+    vocab_size: int = 200064
+    hidden_size: int = 2560
+    intermediate_size: int = 10240
+    num_hidden_layers: int = 32
+    num_attention_heads: int = 40
+    num_key_value_heads: int = 20
+    sliding_window: int = 512
+    mb_per_layer: int = 2
+    layer_norm_eps: float = 1e-5
+    hidden_act: str = "silu"
+    tie_word_embeddings: bool = True
+    mlp_bias: bool = False
+    lm_head_bias: bool = False
+    max_position_embeddings: int = 262144
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None
+    ssm_chunk: int = 64
+    mixer_chunk: int = 2048
+    attention_impl: str = "auto"
+
+    def __post_init__(self):
+        if self.mamba_dt_rank is None:
+            self.mamba_dt_rank = -(-self.hidden_size // 16)
+        L = self.num_hidden_layers
+        if (self.mb_per_layer != 2 or L % 4 or self.hidden_act != "silu"
+                or not self.tie_word_embeddings or self.mlp_bias
+                or self.lm_head_bias
+                or self.num_key_value_heads % 2
+                or self.num_attention_heads % self.num_key_value_heads
+                or self.hidden_size % self.num_attention_heads):
+            raise ValueError(
+                "Phi4FlashConfig: only the published SambaY form is "
+                "implemented (a Mamba layer every second layer, a layer "
+                "count that halves into two even decoders, SiLU, a tied "
+                "head without biases, an even number of key heads that "
+                "divides the query heads' pairs)")
+
+    def layer_kind(self, i: int) -> str:
+        """mamba | swa | full | gmu | cross (the memory's Mamba layer
+        at L/2 is `mamba`, the keys' and values' layer at L/2 + 1 is
+        `full`)."""
+        mid = self.num_hidden_layers // 2
+        if i <= mid:
+            return "mamba" if i % 2 == 0 else "swa"
+        if i == mid + 1:
+            return "full"
+        return "gmu" if i % 2 == 0 else "cross"
+
+    @property
+    def layer_kinds(self) -> List[str]:
+        return [self.layer_kind(i) for i in range(self.num_hidden_layers)]
+
+    @property
+    def num_pairs(self) -> int:
+        """(Mamba, window attention) pairs of the self-decoder; the
+        cross-decoder has one fewer (GMU, cross-attention) pairs."""
+        return self.num_hidden_layers // 4
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    def with_expert_parallel(self, n: int, rank: int) -> "Phi4FlashConfig":
+        """The trainer's deployment flags: the family has no experts, so
+        only the whole model (1, 0) is a deployment of it."""
+        if (n, rank) != (1, 0):
+            raise ValueError(f"expert_parallel={n} rank={rank}: "
+                             "Phi-4-mini-flash has no experts to share")
+        return self
+
+    @classmethod
+    def from_pretrained(cls, model_dir: str) -> "Phi4FlashConfig":
+        raw = _load_config_json(model_dir)
+        return cls(**{f.name: raw[f.name] for f in dataclasses.fields(cls)
+                      if f.name in raw})
+
+    @classmethod
+    def tiny(cls, vocab_size: int = 300) -> "Phi4FlashConfig":
+        """Tiny config for tests: 8 layers (m s m s | m f | g c: two
+        self-decoder pairs, the middle pair, one cross pair), hidden 64
+        in 8 query heads of 8 and 4 key heads (4 and 2 differential
+        pairs), Mamba inner 128 with a state of 16, a window of 8, a scan
+        chunk of 8 and pieces of 16 tokens (a test of 40 runs two whole
+        pieces and a remainder)."""
+        return cls(vocab_size=vocab_size, hidden_size=64,
+                   intermediate_size=128, num_hidden_layers=8,
+                   num_attention_heads=8, num_key_value_heads=4,
+                   sliding_window=8, max_position_embeddings=256,
+                   ssm_chunk=8, mixer_chunk=16)

@@ -26,7 +26,8 @@ import numpy as np
 
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
                                              LingHybridConfig,
-                                             Gemma3TextConfig, Xing4Config)
+                                             Gemma3TextConfig,
+                                             Phi4FlashConfig, Xing4Config)
 from mobilefinetuner_tpu.io.safetensors_io import (CheckpointIntegrityError,
                                                    SafeTensorsReader,
                                                    atomic_publish,
@@ -525,6 +526,131 @@ def ling_hybrid_params_to_hf(params,
         if "mlp" not in lp:
             p[mlp + "gate.weight"] = np.asarray(lp["router_w"]).T
             p[mlp + "gate.expert_bias"] = np.asarray(lp["expert_bias"])
+    return p
+
+
+# --------------------------- Phi-4-mini-flash ------------------------------
+#
+# Key scheme of models/phi4flash.py's checkpoints — ASSUMED (the
+# family's modelling code is not in this installation; Mamba's module
+# names for the scan's weights, `attn` for every layer's mixer as in
+# Samba, DESIGN.md §34):
+#   model.embed_tokens.weight (the head is tied: no lm_head),
+#   model.final_layernorm.{weight,bias},
+#   model.layers.{i}.input_layernorm / post_attention_layernorm
+#     .{weight,bias}, .mlp.fc1.weight [2I, H], .mlp.fc2.weight [H, I],
+#   mamba  .attn.{in_proj, x_proj, dt_proj, out_proj}.weight,
+#          .attn.dt_proj.bias, .attn.conv1d.weight [d_in, 1, K],
+#          .attn.conv1d.bias, .attn.A_log [d_in, N], .attn.D [d_in]
+#   attention  .attn.{q_proj, k_proj, v_proj, o_proj}.weight (no k, v in
+#          a cross layer), .attn.lambda_{q1,k1,q2,k2} [d],
+#          .attn.subln.weight [2 d]
+#   gmu    .attn.in_proj.weight [d_in, H], .attn.out_proj.weight
+# Linear weights are [out, in].
+
+_PHI4_LINEAR = {"mamba": (("in_w", "in_proj"), ("x_w", "x_proj"),
+                          ("dt_w", "dt_proj"), ("out_w", "out_proj")),
+                "attn": (("q_w", "q_proj"), ("k_w", "k_proj"),
+                         ("v_w", "v_proj"), ("o_w", "o_proj")),
+                "gmu": (("in_w", "in_proj"), ("out_w", "out_proj"))}
+_PHI4_PLAIN = {"mamba": (("dt_b", "dt_proj.bias"), ("conv_b", "conv1d.bias"),
+                         ("A_log", "A_log"), ("D", "D")),
+               "attn": (("lq1", "lambda_q1"), ("lk1", "lambda_k1"),
+                        ("lq2", "lambda_q2"), ("lk2", "lambda_k2"),
+                        ("subln", "subln.weight")),
+               "gmu": ()}
+_PHI4_NORMS = (("ln1", "input_layernorm"), ("ln2", "post_attention_layernorm"))
+
+
+def _phi4_slots(c: Phi4FlashConfig):
+    """(layer index, group, kind, stack position or None) of every layer:
+    where it lives in models/phi4flash.py's tree."""
+    P, mid = c.num_pairs, c.num_hidden_layers // 2
+    for i in range(P):
+        yield 2 * i, "self", "mamba", i
+        yield 2 * i + 1, "self", "attn", i
+    yield mid, "mid", "mamba", None
+    yield mid + 1, "mid", "attn", None
+    for i in range(P - 1):
+        yield mid + 2 + 2 * i, "cross", "gmu", i
+        yield mid + 3 + 2 * i, "cross", "attn", i
+
+
+def load_phi4flash(model_dir: str, config: Optional[Phi4FlashConfig] = None):
+    """(config, params in models/phi4flash.py's layout) from a directory
+    in the key scheme above; a BF16 tensor stays bf16, so a frozen base
+    published in bf16 is resident at 2 bytes a parameter."""
+    if config is None:
+        config = Phi4FlashConfig.from_pretrained(model_dir)
+    c = config
+    readers = _tensor_readers(model_dir)
+
+    def get(key, linear=False):
+        a = readers[key].load(key, keep_bf16=True)
+        return a.T if linear else a      # [out, in] -> [in, out]
+
+    def layer(i, kind):
+        pre = _LING_LAYER.format(i)
+        at = pre + "attn."
+        lp = {}
+        for name, hf in _PHI4_NORMS:
+            lp[name + "_w"] = get(f"{pre}{hf}.weight")
+            lp[name + "_b"] = get(f"{pre}{hf}.bias")
+        lp["mlp"] = {"fc1_w": get(pre + "mlp.fc1.weight", True),
+                     "fc2_w": get(pre + "mlp.fc2.weight", True)}
+        mix = {name: get(f"{at}{hf}.weight", True)
+               for name, hf in _PHI4_LINEAR[kind]
+               if f"{at}{hf}.weight" in readers}
+        mix.update({name: get(at + hf) for name, hf in _PHI4_PLAIN[kind]})
+        if kind == "mamba":
+            w = get(at + "conv1d.weight")                 # [d_in, 1, K]
+            mix["conv_w"] = w.reshape(w.shape[0], w.shape[-1]).T
+        lp["mixer"] = mix
+        return lp
+
+    groups: Dict[str, Dict[str, list]] = {}
+    for i, group, kind, _ in _phi4_slots(c):
+        groups.setdefault(group, {}).setdefault(kind, []).append(
+            layer(i, kind))
+    stack = lambda layers: {k: (stack(
+        [l[k] for l in layers]) if isinstance(layers[0][k], dict)
+        else np.stack([l[k] for l in layers])) for k in layers[0]}
+    params = {g: {k: (v[0] if g == "mid" else stack(v))
+                  for k, v in kinds.items()} for g, kinds in groups.items()}
+    params.update(embed=get("model.embed_tokens.weight"),
+                  final_ln_w=get("model.final_layernorm.weight"),
+                  final_ln_b=get("model.final_layernorm.bias"))
+    return config, params
+
+
+def phi4flash_params_to_hf(params, config: Phi4FlashConfig
+                           ) -> Dict[str, np.ndarray]:
+    """models/phi4flash.py's tree -> the key scheme above (the inverse of
+    load_phi4flash)."""
+    p = {"model.embed_tokens.weight": np.asarray(params["embed"]),
+         "model.final_layernorm.weight": np.asarray(params["final_ln_w"]),
+         "model.final_layernorm.bias": np.asarray(params["final_ln_b"])}
+    for i, group, kind, at in _phi4_slots(config):
+        lp = params[group][kind]
+        if at is not None:
+            lp = {k: ({kk: vv[at] for kk, vv in v.items()}
+                      if isinstance(v, dict) else v[at])
+                  for k, v in lp.items()}
+        pre = _LING_LAYER.format(i)
+        for name, hf in _PHI4_NORMS:
+            p[f"{pre}{hf}.weight"] = np.asarray(lp[name + "_w"])
+            p[f"{pre}{hf}.bias"] = np.asarray(lp[name + "_b"])
+        p[pre + "mlp.fc1.weight"] = np.asarray(lp["mlp"]["fc1_w"]).T
+        p[pre + "mlp.fc2.weight"] = np.asarray(lp["mlp"]["fc2_w"]).T
+        mix = lp["mixer"]
+        for name, hf in _PHI4_LINEAR[kind]:
+            if name in mix:
+                p[f"{pre}attn.{hf}.weight"] = np.asarray(mix[name]).T
+        for name, hf in _PHI4_PLAIN[kind]:
+            p[f"{pre}attn.{hf}"] = np.asarray(mix[name])
+        if kind == "mamba":
+            w = np.asarray(mix["conv_w"]).T               # [d_in, K]
+            p[f"{pre}attn.conv1d.weight"] = w.reshape(w.shape[0], 1, -1)
     return p
 
 

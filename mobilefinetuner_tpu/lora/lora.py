@@ -120,6 +120,26 @@ XING4_TARGETS = {
     "o_proj": lambda c: (c.num_attention_heads * c.v_head_dim,
                          c.hidden_size),
 }
+# Phi-4-mini-flash (models/phi4flash.py): a target's stack runs over the
+# layers of its kind in layer order (models/phi4flash.stack_layer_ids):
+# q/o over every attention layer, k/v over those with keys of their own,
+# in/out over the Mamba layers, gmu_in/gmu_out over the gated memory
+# units. The conv, x/dt projections, A_log, D, lambdas, norms, MLPs,
+# embedding and head stay frozen.
+PHI4FLASH_TARGETS = {
+    "q_proj": lambda c: (c.hidden_size,
+                         c.num_attention_heads * c.head_dim),
+    "k_proj": lambda c: (c.hidden_size,
+                         c.num_key_value_heads * c.head_dim),
+    "v_proj": lambda c: (c.hidden_size,
+                         c.num_key_value_heads * c.head_dim),
+    "o_proj": lambda c: (c.num_attention_heads * c.head_dim,
+                         c.hidden_size),
+    "in_proj": lambda c: (c.hidden_size, 2 * c.ssm_inner),
+    "out_proj": lambda c: (c.ssm_inner, c.hidden_size),
+    "gmu_in_proj": lambda c: (c.hidden_size, c.ssm_inner),
+    "gmu_out_proj": lambda c: (c.ssm_inner, c.hidden_size),
+}
 # targets with ONE site instead of a per-layer stack: A [in, r],
 # B [r, out] (no leading L axis; maybe_lora's ndim checks skip the
 # layer_idx slice for them)
@@ -247,6 +267,21 @@ def init_lora_xing4(config, spec: LoRASpec, key: jax.Array,
     return init_lora(
         dims, config.num_hidden_layers + config.num_nextn_predict_layers,
         spec, key, dtype)
+
+
+def init_lora_phi4flash(config, spec: LoRASpec, key: jax.Array,
+                        dtype=jnp.float32) -> dict:
+    """One stack a target, over the layers of the target's kind
+    (models/phi4flash.stack_sizes)."""
+    from mobilefinetuner_tpu.models.phi4flash import stack_sizes
+    n = stack_sizes(config)
+    tree = {}
+    for name in sorted(spec.targets or list(PHI4FLASH_TARGETS)):
+        tree.update(init_lora(
+            {name: PHI4FLASH_TARGETS[name](config)}, n[name], spec,
+            jax.random.fold_in(key, sorted(PHI4FLASH_TARGETS).index(name)),
+            dtype)["blocks"])
+    return {"blocks": tree}
 
 
 def stack_adapters(loras) -> dict:

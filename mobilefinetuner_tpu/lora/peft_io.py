@@ -64,10 +64,21 @@ XING4_PEFT_MODULES = {
     "kv_b_proj": "model.layers.{}.self_attn.kv_b_proj",
     "o_proj": "model.layers.{}.self_attn.o_proj",
 }
+# Phi-4-mini-flash (io/checkpoints.py's key scheme: every mixer under
+# `attn`): stacks run over a kind's layers, export/import take
+# `layer_ids` (models/phi4flash.stack_layer_ids)
+PHI4FLASH_PEFT_MODULES = {
+    **{t: "model.layers.{}.attn." + t
+       for t in ("q_proj", "k_proj", "v_proj", "o_proj", "in_proj",
+                 "out_proj")},
+    "gmu_in_proj": "model.layers.{}.attn.in_proj",
+    "gmu_out_proj": "model.layers.{}.attn.out_proj",
+}
 PEFT_MODULES = {"gpt2": GPT2_PEFT_MODULES, "gemma": GEMMA_PEFT_MODULES,
                 "afmoe": AFMOE_PEFT_MODULES,
                 "ling_hybrid": LING_PEFT_MODULES,
-                "xing4_0": XING4_PEFT_MODULES}
+                "xing4_0": XING4_PEFT_MODULES,
+                "phi4flash": PHI4FLASH_PEFT_MODULES}
 # For adapter_config.json target_modules. PEFT suffix-matches these against
 # full module paths, so they must be path-qualified: a bare "c_proj" would
 # match BOTH attn.c_proj and mlp.c_proj and make PEFT instantiate phantom
@@ -83,10 +94,13 @@ LING_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
                        for t, m in LING_PEFT_MODULES.items()}
 XING4_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
                         for t, m in XING4_PEFT_MODULES.items()}
+PHI4FLASH_TARGET_MODULES = {t: m.rsplit(".", 1)[1]
+                            for t, m in PHI4FLASH_PEFT_MODULES.items()}
 PEFT_CONFIG = {"gpt2": (True, PEFT_TARGET_MODULES), "gemma": (False, None),
                "afmoe": (False, None),
                "ling_hybrid": (False, LING_TARGET_MODULES),
-               "xing4_0": (False, XING4_TARGET_MODULES)}
+               "xing4_0": (False, XING4_TARGET_MODULES),
+               "phi4flash": (False, PHI4FLASH_TARGET_MODULES)}
 
 
 # ----------------------------- native format --------------------------------

@@ -17,14 +17,15 @@ import numpy as np
 from mobilefinetuner_tpu.core.config import (AfmoeConfig, GPT2Config,
                                               Gemma3TextConfig,
                                               LingHybridConfig,
-                                              Xing4Config)
+                                              Phi4FlashConfig, Xing4Config)
 from mobilefinetuner_tpu.io.checkpoints import (afmoe_params_to_hf,
                                                 gpt2_params_to_hf,
                                                 ling_hybrid_params_to_hf,
+                                                phi4flash_params_to_hf,
                                                 xing4_params_to_hf)
 from mobilefinetuner_tpu.io.safetensors_io import save_safetensors
 from mobilefinetuner_tpu.models import (afmoe, gemma3, gpt2, ling_hybrid,
-                                        xing4)
+                                        phi4flash, xing4)
 
 @contextlib.contextmanager
 def backend_compiles():
@@ -223,6 +224,26 @@ def write_tiny_xing4_dir(d: str, seed: int = 0, dtype=None):
                                dtype or jnp.float32)
     save_safetensors(os.path.join(d, "model.safetensors"),
                      xing4_params_to_hf(jax.tree.map(np.asarray, params)),
+                     metadata={"format": "pt"})
+    return config, params
+
+
+def write_tiny_phi4flash_dir(d: str, seed: int = 0, dtype=None):
+    """As write_tiny_afmoe_dir, for the Phi-4-mini-flash family
+    (io/checkpoints.load_phi4flash's key scheme)."""
+    import dataclasses
+    import jax.numpy as jnp
+    os.makedirs(d, exist_ok=True)
+    config = Phi4FlashConfig.tiny(vocab_size=train_tiny_gpt2_tokenizer(d))
+    with open(os.path.join(d, "config.json"), "w") as f:
+        raw = dataclasses.asdict(config)
+        del raw["attention_impl"]
+        json.dump({"model_type": "phi4flash", **raw}, f)
+    params = phi4flash.init_params(config, jax.random.PRNGKey(seed),
+                                   dtype or jnp.float32)
+    save_safetensors(os.path.join(d, "model.safetensors"),
+                     phi4flash_params_to_hf(jax.tree.map(np.asarray, params),
+                                            config),
                      metadata={"format": "pt"})
     return config, params
 

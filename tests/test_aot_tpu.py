@@ -542,6 +542,52 @@ def test_the_xing4_cell_s_train_step_compiles_and_fits(topo, as_tpu):
     assert compiled_peak_bytes(c) <= 0.85 * size_xing4.BYTES_LIMIT
 
 
+# Phi-4-mini-flash's cell (phi4flash_lora_long): differential attention
+# is two flash calls a layer at head 64 against a value of 128, 20 query
+# heads over 10 key heads (a group of 2), 16,384 tokens, over a window of
+# 512 or the whole causal prefix
+PHI4FLASH_FLASH = (1, 20, 10, 16384, 64, 128)
+
+
+@pytest.mark.parametrize("window", [512, None])
+def test_flash_compiles_for_differential_attention_at_the_phi4flash_cell(
+        topo, as_tpu, window):
+    from mobilefinetuner_tpu.ops.attention import attention, resolve_impl
+    B, nq, nkv, S, d, dv = PHI4FLASH_FLASH
+    q, k = sds(topo, (B, nq, S, d), BF16), sds(topo, (B, nkv, S, d), BF16)
+    v = sds(topo, (B, nkv, S, dv), BF16)
+    assert resolve_impl(S, d) == "flash"
+
+    def loss(q, k, v):
+        return attention(q, k, v, impl="auto", is_causal=True,
+                         sliding_window=window, scale=d ** -0.5).astype(
+            jnp.float32).sum()
+
+    c = compile_for(jax.grad(loss, argnums=(0, 1, 2)), q, k, v)
+    assert assert_kernel(c, 2) >= 2
+
+
+@pytest.mark.slow
+def test_the_phi4flash_cell_s_train_step_compiles_and_fits(topo, as_tpu):
+    """The LoRA step of phi4flash_lora_long as
+    benchmark/tools/size_phi4flash.py builds it, at the cell's length and
+    batch size: it compiles for a v5e, holds the flash kernels of both
+    maps of all 16 attention layers and the LoRA epilogues, and stays
+    within 0.85 of bytes_limit."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path[:0] = [bench, os.path.join(bench, "tools")]
+    import size_phi4flash
+    from harness import common
+    from harness.traffic import argv_value
+    B = int(argv_value(common.load_cell("phi4flash_lora_long")
+                       ["traffic_file"]["argv"], "--batch_size"))
+    c = size_phi4flash.lower_step(B, topo.devices[0]).compile()
+    assert_kernel(c, 3 * 2 * 3)        # fwd, recomputed fwd, bwd: 3 calls
+    assert compiled_peak_bytes(c) <= 0.85 * size_phi4flash.BYTES_LIMIT
+
+
 # --------------------------- paged decode attention -------------------------
 
 # (S, KV, G, D, bT, M, NB, L) and the pages a grid step folds there

@@ -74,6 +74,13 @@ LING_SCOPES = TRAIN_SCOPES + MOE_SCOPES + (
 XING4_SCOPES = TRAIN_SCOPES + MOE_SCOPES + (
     "mhc_coeff", "mhc_pre", "mhc_post", "mla_proj", "attn_latent",
     "mla_out", "mtp")
+# Phi-4-mini-flash's (models/phi4flash.py): the selective scan's
+# projections, conv and scan, the gated memory units, differential
+# attention's projections and its two maps (no `attention` scope: the
+# family's mixers are named for what they are)
+PHI4FLASH_SCOPES = ("embed", "mlp", "loss", "optimizer", "ssm_proj",
+                    "ssm_conv", "ssm_scan", "gmu", "diff_attn_proj",
+                    "diff_attn")
 
 
 def _ensure_cpu_devices() -> None:
@@ -139,10 +146,11 @@ def prog_train_gpt2_lora():
     return text, retraces, TRAIN_SCOPES
 
 
-def _prog_expert_lora(model, cfg, init_lora, scopes):
-    """Single-device LoRA optimizer step of a sparse-expert family (one
-    expert-parallel rank), donate=True, the model's counters riding the
-    metrics."""
+def _prog_expert_lora(model, cfg, init_lora, scopes, head="lm_head"):
+    """Single-device LoRA optimizer step of a family on
+    cli/expert_lora.py's entry point (one expert-parallel rank where it
+    has experts), donate=True, the model's counters riding the metrics;
+    `head` names the frozen leaf the loss scores against."""
     import jax
     import jax.numpy as jnp
     from mobilefinetuner_tpu.lora.lora import LoRASpec, trainable_mask
@@ -163,13 +171,13 @@ def _prog_expert_lora(model, cfg, init_lora, scopes):
         hidden, aux = model.hidden_states(
             cfg, p, mb["input_ids"], attention_mask=mb["attention_mask"],
             lora=lo, remat=True, with_aux=True)
-        s, n = chunked_lm_cross_entropy_sum(hidden, p["lm_head"],
+        s, n = chunked_lm_cross_entropy_sum(hidden, p[head],
                                             mb["labels"], num_chunks=2)
         counters = aux["counters"]
         if "second" in aux:     # a family's second loss, as the CLI adds it
             from mobilefinetuner_tpu.cli.expert_lora import add_second_loss
             s, counters = add_second_loss(s, n, counters, aux["second"],
-                                          p["lm_head"], mb["labels"])
+                                          p[head], mb["labels"])
         return s, n, counters
 
     step = make_train_step(loss_fn, tc, mask=mask, donate=True)
@@ -476,11 +484,22 @@ def prog_multitenant_gpt2():
     return text, retraces, TRAIN_SCOPES
 
 
+def prog_train_phi4flash_lora():
+    """Phi-4-mini-flash (SambaY), the tied head."""
+    from mobilefinetuner_tpu.core.config import Phi4FlashConfig
+    from mobilefinetuner_tpu.lora.lora import init_lora_phi4flash
+    from mobilefinetuner_tpu.models import phi4flash
+    return _prog_expert_lora(phi4flash, Phi4FlashConfig.tiny(),
+                             init_lora_phi4flash, PHI4FLASH_SCOPES,
+                             head="embed")
+
+
 PROGRAMS = {
     "train_gpt2_lora": prog_train_gpt2_lora,
     "train_afmoe_lora": prog_train_afmoe_lora,
     "train_ling_lora": prog_train_ling_lora,
     "train_xing4_lora": prog_train_xing4_lora,
+    "train_phi4flash_lora": prog_train_phi4flash_lora,
     "train_gpt2_fsdp": prog_train_gpt2_fsdp,
     "decode_gpt2_paged": prog_decode_gpt2_paged,
     "decode_gpt2_paged_tp": prog_decode_gpt2_paged_tp,
